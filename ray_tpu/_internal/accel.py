@@ -28,12 +28,15 @@ Three concerns, one per-process module:
   (``rtpu_goodput_seconds_total{bucket=...}``). Wired into the train
   controller's report fold, the paged-engine decode tick, and bench.py.
 
-JAX is never imported by this module at module scope, and snapshot /
-install paths only touch JAX when the process has ALREADY imported it
-(``"jax" in sys.modules``) unless the caller forces it — initializing
-JAX from an observability sweep would grab the host's TPU chip lock
-(see accelerators/tpu.py). ``force_jax=True`` is reserved for the
-process the user is driving (cli devices / accel_summary caller).
+JAX is never imported by this module at module scope. The compile
+listeners arm once the process has imported jax; device snapshots only
+touch JAX in a process whose backend is ALREADY initialised
+(:func:`backend_initialized`) unless the caller forces it — a driver
+that imported jax to build a config holds no chip, and opening the
+backend from an observability sweep would take the chip away from its
+own workers (see accelerators/tpu.py). ``force_jax=True`` is reserved
+for the process the user is driving (cli devices / accel_summary
+caller).
 
 Kill switch: ``RTPU_NO_ACCEL_METRICS=1`` — zero listeners installed,
 snapshots return empty, StepTimer/report_step become no-ops.
@@ -436,14 +439,23 @@ def _live_buffer_bytes_by_device() -> Dict[int, int]:
     return per_dev
 
 
+def backend_initialized() -> bool:
+    """True once this process has opened a JAX backend (and so holds
+    whatever chips it was given). Importing jax does not count."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
 def snapshot_devices(force_jax: bool = False) -> List[Dict[str, Any]]:
     """One row per local device: identity, HBM used/peak/limit, and the
-    peak-FLOPs denominator. Empty when disabled, or when jax was never
-    imported here (initializing jax from an observability sweep would
-    grab the TPU chip lock) unless ``force_jax``."""
+    peak-FLOPs denominator. Empty when disabled, or when this process
+    has not opened a backend (doing so from an observability sweep
+    would grab the TPU chip lock) unless ``force_jax``."""
     if accel_disabled():
         return []
-    if not force_jax and "jax" not in sys.modules:
+    if not force_jax and not backend_initialized():
         return []
     import jax
 
@@ -585,20 +597,15 @@ _device_kind_cache: List[Optional[str]] = [None]
 
 
 def _default_device_kind() -> str:
-    """device_kind of local device 0, cached; "cpu" when jax was never
-    imported (don't initialize a backend from a metrics fold)."""
+    """device_kind of local device 0, cached once a backend is open;
+    "cpu" in a process that has none (a metrics fold must not
+    initialize one)."""
     kind = _device_kind_cache[0]
     if kind is None:
-        if "jax" in sys.modules:
-            import jax
-            try:
-                kind = getattr(jax.local_devices()[0], "device_kind",
-                               "cpu")
-            except Exception:  # noqa: BLE001 — backend init can fail
-                logger.debug("device-kind probe failed", exc_info=True)
-                kind = "cpu"
-        else:
-            kind = "cpu"
+        if not backend_initialized():
+            return "cpu"
+        import jax
+        kind = getattr(jax.local_devices()[0], "device_kind", "cpu")
         _device_kind_cache[0] = kind
     return kind
 
@@ -865,12 +872,12 @@ def accel_report(force_jax: bool = False) -> Dict[str, Any]:
     """Everything this process knows about its accelerators: device
     rows, compile tracking, step telemetry, and any pressure rows the
     caller should publish. ``devices`` stays empty in processes that
-    never imported jax (see snapshot_devices) unless ``force_jax``."""
+    have no backend open (see snapshot_devices) unless ``force_jax``."""
     disabled = accel_disabled()
     report: Dict[str, Any] = {
         "pid": os.getpid(),
         "disabled": disabled,
-        "jax_initialized": "jax" in sys.modules,
+        "jax_initialized": backend_initialized(),
         "devices": [],
         "compile": compile_summary(),
         "steps": step_summary(),

@@ -8,8 +8,10 @@ MFU, the gauge says 40%" a permanent support thread.
 Keys are device-kind substrings (matched against
 ``jax.Device.device_kind.lower()``, first match wins — more specific
 generations first). Values are peak dense bf16 FLOP/s per chip from the
-published TPU specs; "cpu" is a nominal 1 TFLOP/s so CPU smoke runs
-still produce a finite MFU line.
+published TPU specs; "cpu" is a nominal 1 TFLOP/s so the CPU test runs
+still fold a finite (and meaningless) MFU gauge. A device that is not
+in the table is an error, not a default: a utilization divided by
+another chip's peak is a wrong number under a right name.
 """
 
 from __future__ import annotations
@@ -25,12 +27,8 @@ PEAK_FLOPS = {
     "v5e": 197e12,
     "v4": 275e12,
     "v3": 123e12,
-    "cpu": 1e12,  # nominal, so CPU smoke runs produce a line
+    "cpu": 1e12,  # nominal: CPU test runs only, never a device metric
 }
-
-# Unknown accelerator kinds fall back to the v5e figure — wrong MFU
-# beats no MFU, and the table is one entry away from correct.
-DEFAULT_PEAK_FLOPS = 197e12
 
 
 def peak_flops_for_kind(device_kind: Optional[str]) -> float:
@@ -39,7 +37,9 @@ def peak_flops_for_kind(device_kind: Optional[str]) -> float:
     for key, value in PEAK_FLOPS.items():
         if key in kind:
             return value
-    return DEFAULT_PEAK_FLOPS
+    raise KeyError(
+        f"no peak FLOP/s on record for device kind {device_kind!r}; add "
+        f"it to accelerators.flops.PEAK_FLOPS with its published source")
 
 
 def peak_flops(device) -> float:

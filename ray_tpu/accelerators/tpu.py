@@ -21,6 +21,7 @@ from __future__ import annotations
 import glob as _glob_module
 import logging
 import os
+import re
 from typing import Dict, List, Optional
 
 logger = logging.getLogger(__name__)
@@ -70,6 +71,30 @@ def autodetect_num_chips(glob=_glob_module.glob) -> int:
     if vfio:
         return len(vfio)
     return 0
+
+
+def compile_cache_dir(env=os.environ) -> str:
+    """Point `env` at the persistent XLA compile cache and return its
+    directory. Every process about to own a chip calls this before it
+    imports jax (the raylet does it for the workers it spawns on a chip
+    lease). JAX_COMPILATION_CACHE_DIR wins where it is set; otherwise the
+    cache lives at ONE fixed path inside the checkout — the path is part
+    of the cache key, so a directory that moves never hits."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                          os.path.join(checkout, ".jax_cache"))
+
+
+def on_virtual_cpu_mesh(n_devices: int, env=os.environ) -> bool:
+    """Was this process started on a virtual CPU mesh of at least
+    `n_devices`? Read from the environment: a launcher that asked jax for
+    its devices would open whatever chip the host has, in a parent that
+    only wants to start a child."""
+    forced = re.search(r"--xla_force_host_platform_device_count=(\d+)",
+                       env.get("XLA_FLAGS", ""))
+    return env.get("JAX_PLATFORMS") == "cpu" and forced is not None \
+        and int(forced.group(1)) >= n_devices
 
 
 def validate_chip_request(num_chips: float) -> None:

@@ -64,8 +64,9 @@ def spawn_gcs(port: int, session: str, persist: Optional[str] = None,
               env: Optional[Dict[str, str]] = None) -> subprocess.Popen:
     """Run a GCS as a real subprocess (gcs_main) and wait for its
     readiness line — the killable head for failover drills."""
-    proc_env = dict(os.environ)
-    proc_env.setdefault("JAX_PLATFORMS", "cpu")
+    # a GCS holds no chip: forced, since a chip host's ambient
+    # environment names the TPU platform (setdefault would keep it)
+    proc_env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc_env.update(env or {})
     cmd = [sys.executable, "-m", "ray_tpu._internal.gcs_main",
            "--host", "127.0.0.1", "--port", str(port),

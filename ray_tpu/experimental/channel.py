@@ -204,8 +204,8 @@ class DeviceChannel:
     # Arrays kept staged until overwritten. The ctrl channel is a
     # ONE-SLOT SPSC (put blocks until the reader ACKS the previous
     # message, whatever the byte capacity), so a writer can be at most
-    # ~2 entries ahead of the reader's payload pull — the RPC-fallback
-    # unstage below can never evict an entry the reader still needs.
+    # ~2 entries ahead of the reader's payload pull — the window below
+    # can never drop an entry the reader still needs.
     _PIN_DEPTH = 4
 
     def __init__(self, path: str, _role: str = "writer"):
@@ -230,24 +230,12 @@ class DeviceChannel:
                 "DeviceChannel.put blocked on the device-object HBM "
                 f"budget for {timeout}s (pinned={dobj.pinned_bytes()}B)")
         self._uuid += 1
-        if server is not None:
-            server.await_pull(self._uuid, [array])
-            addr = dobj._server_addr
-            rpc_addr = None
-        else:
-            # No transfer API in this runtime: stage for the chunked
-            # RPC pull (still no host shared memory for the payload).
-            dobj.stage_rpc(self._uuid, array)
-            addr = ""
-            from .._internal.core_worker import get_core_worker
-            rpc_addr = tuple(get_core_worker().rpc_address)
+        server.await_pull(self._uuid, [array])
         self._staged.append((self._uuid, array, nbytes))
         if len(self._staged) > self._PIN_DEPTH:
-            old_uuid, _, old_bytes = self._staged.pop(0)
+            _, _, old_bytes = self._staged.pop(0)
             dobj.release_bytes(old_bytes)
-            if server is None:
-                dobj.unstage_rpc(old_uuid)
-        self._ctrl.put((addr, rpc_addr, self._uuid,
+        self._ctrl.put((dobj._server_addr, self._uuid,
                         tuple(array.shape), str(array.dtype)), timeout)
 
     def get(self, timeout: Optional[float] = 10.0):
@@ -255,31 +243,13 @@ class DeviceChannel:
         import numpy as np
 
         from . import device_objects as dobj
-        addr, rpc_addr, uuid, shape, dtype = self._ctrl.get(timeout)
-        if not addr:
-            return self._rpc_get(rpc_addr, uuid, shape, dtype)
-        server = dobj._ensure_server()
-        if server is None:
-            raise RuntimeError(
-                "writer published a PJRT transfer address but this "
-                "process's jax has no transfer API")
+        addr, uuid, shape, dtype = self._ctrl.get(timeout)
         if self._conn is None:
-            self._conn = server.connect(addr)
+            self._conn = dobj._ensure_server().connect(addr)
         spec = jax.ShapeDtypeStruct(
             shape, np.dtype(dtype),
             sharding=jax.sharding.SingleDeviceSharding(jax.devices()[0]))
         return self._conn.pull(uuid, [spec])[0]
-
-    def _rpc_get(self, rpc_addr, uuid, shape, dtype):
-        import numpy as np
-
-        from . import device_objects as dobj
-        from .._internal.core_worker import get_core_worker
-
-        client = get_core_worker().clients.get(tuple(rpc_addr))
-        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
-        return dobj._chunk_pull(client, "device_object_fetch_staged",
-                                nbytes, dtype, shape, uuid=uuid)
 
     def close(self):
         self._ctrl.close()
@@ -287,9 +257,8 @@ class DeviceChannel:
     def destroy(self):
         if self._staged:
             from . import device_objects as dobj
-            for uuid, _, nbytes in self._staged:
+            for _, _, nbytes in self._staged:
                 dobj.release_bytes(nbytes)
-                dobj.unstage_rpc(uuid)
         self._staged.clear()
         self._ctrl.destroy()
 

@@ -20,12 +20,9 @@ them device-to-device). TPU-native design:
   producer's `_free_owned_object` fires `on_free` and the pin is
   released.
 
-Transport selection: `jax.experimental.transfer` (PJRT cross-runtime
-DMA) when the installed jax has it; otherwise a chunked RPC pull over
-the native ring (`device_object_fetch`) — the payload still never
-touches the object store or /dev/shm (the property the zero-copy tests
-pin), it just rides the worker's socket instead of the PJRT transport.
-A descriptor with an empty `transfer_addr` means "pull me over RPC".
+Transport: `jax.experimental.transfer` (PJRT cross-runtime DMA) — the
+payload never touches the object store or /dev/shm (the property the
+zero-copy tests pin).
 """
 
 from __future__ import annotations
@@ -152,30 +149,10 @@ class DeviceObjectDescriptor:
 # Chunk size for the RPC-fallback pull: big enough to amortize the
 # per-call overhead, small enough that a 1 GiB array never builds a
 # frame near the ring's 1 GiB oversized-prefix guard.
-_FETCH_CHUNK = 64 * 1024 * 1024
-
-_transfer_mod = [None]  # [None]=unprobed, [False]=unavailable, [module]
-
-
-def _transfer_module():
-    if _transfer_mod[0] is None:
-        try:
-            from jax.experimental import transfer
-            _transfer_mod[0] = transfer
-        except ImportError:
-            # older jax (e.g. 0.4.x): no PJRT transfer API — the RPC
-            # fallback transport takes over
-            _transfer_mod[0] = False
-    return _transfer_mod[0] or None
-
-
 def _ensure_server():
-    """The PJRT transfer server, or None when the installed jax has no
-    transfer API (consumers then pull over RPC)."""
+    """This process's PJRT transfer server (started on first use)."""
     global _server, _server_addr
-    transfer = _transfer_module()
-    if transfer is None:
-        return None
+    from jax.experimental import transfer
     with _lock:
         if _server is None:
             import jax
@@ -217,7 +194,7 @@ def device_put_ref(array, *, timeout_s: Optional[float] = None
         _pinned[oid] = array
         _pinned_nbytes[oid] = nbytes
     desc = DeviceObjectDescriptor(
-        object_hex=oid.hex(), transfer_addr=_server_addr or "",
+        object_hex=oid.hex(), transfer_addr=_server_addr,
         producer_rpc_addr=tuple(worker.rpc_address),
         shape=tuple(array.shape), dtype=str(np.dtype(array.dtype)),
         nbytes=nbytes)
@@ -268,13 +245,7 @@ def _pull(desc: DeviceObjectDescriptor):
     metrics.pull_bytes.inc(desc.nbytes)
     worker = get_core_worker()
     client = worker.clients.get(tuple(desc.producer_rpc_addr))
-    if not desc.transfer_addr:
-        return _rpc_pull(desc, client)
     server = _ensure_server()
-    if server is None:
-        # Producer published a transfer address this process cannot
-        # dial (no transfer API here) — fall back to the RPC pull.
-        return _rpc_pull(desc, client)
     # Ask the producer to stage the array for one pull under a fresh
     # uuid (await_pull is single-shot; N consumers = N stagings).
     reply = client.call_sync("device_object_stage",
@@ -296,43 +267,6 @@ def _pull(desc: DeviceObjectDescriptor):
     return out[0]
 
 
-def _chunk_pull(client, method: str, nbytes: int, dtype: str, shape,
-                **ids):
-    """Consumer half of the RPC-fallback transport, shared by the
-    descriptor pull and DeviceChannel: bounded chunks (every frame far
-    below the ring's 1 GiB guard), one host->device copy at the end."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    buf = bytearray(nbytes)
-    offset = 0
-    while offset < nbytes:
-        length = min(_FETCH_CHUNK, nbytes - offset)
-        reply = client.call_sync(method, offset=offset, length=length,
-                                 timeout=120, **ids)
-        if not reply.get("ok"):
-            raise RuntimeError(
-                f"device object chunk pull ({method} {ids}) failed: "
-                f"{reply.get('error')}")
-        data = reply["data"]
-        buf[offset:offset + len(data)] = data
-        offset += len(data)
-    # frombuffer over the bytearray is a zero-copy view; jnp.asarray is
-    # the single host->device copy (2x nbytes peak, not 3x).
-    return jnp.asarray(np.frombuffer(buf, dtype=np.dtype(dtype))
-                       .reshape(shape))
-
-
-def _rpc_pull(desc: DeviceObjectDescriptor, client):
-    """Fallback transport: pull the pinned array in bounded chunks over
-    the producer's RPC ring. The payload never enters the object store
-    or /dev/shm (and peak staging memory on the producer stays one host
-    copy of the array)."""
-    return _chunk_pull(client, "device_object_fetch", desc.nbytes,
-                       desc.dtype, desc.shape,
-                       object_hex=desc.object_hex)
-
-
 # -- producer-side plumbing -------------------------------------------------
 
 def _stage_for_pull(object_hex: str) -> Dict[str, Any]:
@@ -346,68 +280,6 @@ def _stage_for_pull(object_hex: str) -> Dict[str, Any]:
         _next_uuid[0] += 1
     _ensure_server().await_pull(uuid, [array])
     return {"ok": True, "uuid": uuid}
-
-
-# uuid -> jax.Array staged for RPC-fallback DeviceChannel pulls
-_rpc_staged: Dict[int, Any] = {}
-
-# ("pin", oid) / ("staged", uuid) -> flat uint8 host view of an array
-# mid-chunk-pull: ONE device->host materialization per pull sequence,
-# not per chunk (np.asarray of a 1 GiB array for each 64 MiB chunk was
-# O(nbytes^2/chunk)). Evicted when the last chunk is served, on free,
-# and on unstage, so a dead consumer can't pin a host copy forever.
-_host_views: Dict[Any, Any] = {}
-
-
-def _chunk_of(key, array, offset: int, length: int) -> Dict[str, Any]:
-    import numpy as np
-
-    with _lock:
-        flat = _host_views.get(key)
-    if flat is None:
-        flat = np.asarray(array).reshape(-1).view(np.uint8)
-        with _lock:
-            _host_views[key] = flat
-    data = flat[offset:offset + length].tobytes()
-    if offset + length >= flat.size:
-        with _lock:
-            _host_views.pop(key, None)
-    return {"ok": True, "data": data}
-
-
-def _fetch_chunk(object_hex: str, offset: int, length: int
-                 ) -> Dict[str, Any]:
-    """RPC handler body: one bounded chunk of a pinned array (the
-    fallback transport — no jax transfer API in this runtime)."""
-    oid = ObjectID.from_hex(object_hex)
-    with _lock:
-        array = _pinned.get(oid)
-    if array is None:
-        return {"ok": False, "error": "not pinned in this process"}
-    return _chunk_of(("pin", oid), array, offset, length)
-
-
-def _fetch_staged_chunk(uuid: int, offset: int, length: int
-                        ) -> Dict[str, Any]:
-    """Same, for DeviceChannel's keep-alive staging window."""
-    with _lock:
-        array = _rpc_staged.get(uuid)
-    if array is None:
-        return {"ok": False, "error": "not staged (window advanced?)"}
-    return _chunk_of(("staged", uuid), array, offset, length)
-
-
-def stage_rpc(uuid: int, array) -> None:
-    """DeviceChannel writer-side staging for the RPC fallback."""
-    ensure_handlers()
-    with _lock:
-        _rpc_staged[uuid] = array
-
-
-def unstage_rpc(uuid: int) -> None:
-    with _lock:
-        _rpc_staged.pop(uuid, None)
-        _host_views.pop(("staged", uuid), None)
 
 
 _hook_installed = False
@@ -426,32 +298,14 @@ def _register_free_hook():
     async def handle_device_object_stage(object_hex: str):
         return _stage_for_pull(object_hex)
 
-    async def handle_device_object_fetch(object_hex: str, offset: int,
-                                         length: int):
-        return _fetch_chunk(object_hex, offset, length)
-
-    async def handle_device_object_fetch_staged(uuid: int, offset: int,
-                                                length: int):
-        return _fetch_staged_chunk(uuid, offset, length)
-
     worker.server.register("device_object_stage", handle_device_object_stage)
-    worker.server.register("device_object_fetch", handle_device_object_fetch)
-    worker.server.register("device_object_fetch_staged",
-                           handle_device_object_fetch_staged)
     worker.device_object_free_hooks.append(on_free)
     _hook_installed = True
-
-
-def ensure_handlers():
-    """Public alias: DeviceChannel's RPC-fallback writer needs the
-    fetch handlers installed without pinning an object ref."""
-    _register_free_hook()
 
 
 def on_free(object_id: ObjectID):
     with _lock:
         _pinned.pop(object_id, None)
-        _host_views.pop(("pin", object_id), None)
         nbytes = _pinned_nbytes.pop(object_id, 0)
     if nbytes:
         release_bytes(nbytes)

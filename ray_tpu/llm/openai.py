@@ -155,13 +155,16 @@ class OpenAIServer(LLMServer):
 def build_openai_app(engine_config, *, model_id: str = "ray-tpu-llm",
                      tokenizer=None, name: str = "OpenAIServer",
                      num_replicas: int = 1, params=None,
-                     max_ongoing_requests: int = 64):
+                     max_ongoing_requests: int = 64,
+                     ray_actor_options: Optional[Dict[str, Any]] = None):
     """OpenAI-compatible application over the TPU engine (reference:
     serve/llm/__init__.py:168 build_openai_app). Deploy with
     `serve.run(app, request_router="prefix")` for prompt-prefix replica
-    affinity."""
+    affinity; `ray_actor_options={"num_tpus": n}` gives each replica n
+    chips."""
     from .. import serve
     deployment = serve.deployment(
         OpenAIServer, name=name, num_replicas=num_replicas,
-        max_ongoing_requests=max_ongoing_requests)
+        max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=ray_actor_options)
     return deployment.bind(engine_config, params, model_id, tokenizer)

@@ -28,6 +28,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import os
 import queue
 import time
@@ -67,6 +68,29 @@ class PagedEngineConfig:
     @property
     def pages_per_seq(self) -> int:
         return -(-self.max_len // self.page_size)
+
+
+@functools.lru_cache(maxsize=8)
+def _param_init(cfg: LlamaConfig, mesh):
+    """(init program, param shardings) of a model on a mesh. Random
+    weights are made ON the device(s), already in their final layout, by
+    one jitted program — an eager flax init dispatches (and compiles)
+    every initializer op by op. Cached, so engines of one configuration
+    share one trace and one compile."""
+    from ..parallel.mesh import unbox
+    model = LlamaModel(cfg)
+    sample = jnp.zeros((1, 8), jnp.int32)
+    pshard = None
+    if mesh is not None:
+        from ..parallel.mesh import DEFAULT_LOGICAL_AXIS_RULES
+        from ..parallel.spmd import logical_names_tree, shardings_tree
+        names = logical_names_tree(model, jax.random.PRNGKey(0), sample)
+        pshard = shardings_tree(names, mesh,
+                                dict(DEFAULT_LOGICAL_AXIS_RULES))
+    def init_params(rng):
+        return unbox(model.init(rng, sample)["params"])
+
+    return jax.jit(init_params, out_shardings=pshard), pshard
 
 
 class PagePool:
@@ -147,34 +171,20 @@ class PagedLLMEngine:
         rng = jax.random.PRNGKey(config.seed)
         self._page_sharding = None
         self._dense_sharding = None
+        init, pshard = _param_init(cfg, mesh)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as PSpec
-            from ..parallel.mesh import DEFAULT_LOGICAL_AXIS_RULES, unbox
-            from ..parallel.spmd import logical_names_tree, shardings_tree
-            rules = dict(DEFAULT_LOGICAL_AXIS_RULES)
-            sample = jnp.zeros((1, 8), jnp.int32)
-            names = logical_names_tree(self.model, rng, sample)
-            pshard = shardings_tree(names, mesh, rules)
-            if params is None:
-                def _init(r):
-                    p = unbox(self.model.init(r, sample)["params"])
-                    return jax.tree_util.tree_map(
-                        jax.lax.with_sharding_constraint, p, pshard)
-                with mesh:
-                    params = jax.jit(_init)(rng)
-            else:
-                # Params from a single-device engine or a checkpoint:
-                # scatter to the mesh layout.
-                params = jax.device_put(params, pshard)
             # pages: [kv_heads, pages, page_size, hd] sharded on kv_heads
             self._page_sharding = NamedSharding(mesh, PSpec("tensor"))
             # dense prefill caches: [1, kv_heads, L, hd]
             self._dense_sharding = NamedSharding(mesh, PSpec(None, "tensor"))
-        elif params is None:
-            from ..parallel.mesh import unbox
-            params = unbox(self.model.init(
-                rng, jnp.zeros((1, 8), jnp.int32))["params"])
+        if params is None:
+            params = init(rng)
+        elif pshard is not None:
+            # Params from a single-device engine or a checkpoint:
+            # scatter to the mesh layout.
+            params = jax.device_put(params, pshard)
         self.params = params
         self._rng = rng
         kvh, hd = cfg.num_kv_heads, cfg.head_dim_
@@ -358,14 +368,43 @@ class PagedLLMEngine:
 
         self._gather_pages = jax.jit(gather_pages, donate_argnums=(2,))
 
+    def decode_program_text(self) -> str:
+        """Compiled text of the decode step at this engine's shapes: the
+        one-shot probe of what the backend was really handed (is the
+        Pallas paged-attention `tpu_custom_call` in it, or the gather
+        path?). Lowers from shapes alone, so the live page pools are
+        neither read nor donated; with a persistent compile cache the
+        compile is a hit."""
+        cfg = self.config
+        B = cfg.max_batch
+
+        def like(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=a.sharding)
+
+        def vec(dtype, *shape):
+            return jax.ShapeDtypeStruct((B,) + shape, dtype)
+
+        with self._mesh_scope():
+            lowered = self._decode.lower(
+                jax.tree_util.tree_map(like, self.params),
+                [like(p) for p in self.k_pages],
+                [like(p) for p in self.v_pages],
+                vec(jnp.int32, cfg.pages_per_seq), vec(jnp.int32),
+                vec(jnp.int32, 1),
+                jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
+                vec(jnp.float32),
+                vec(jnp.int32), vec(jnp.float32))
+        return lowered.compile().as_text()
+
     def _mesh_scope(self):
         """Context for jit calls: marks the serving mesh active so the
         model's attention detects the tensor axis at trace time
         (shard_map over the Pallas/gather kernel)."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        from ..parallel.mesh import serving_mesh
-        return serving_mesh(self.mesh)
+        from ..parallel.mesh import kernel_mesh
+        return kernel_mesh(self.mesh)
 
     # -- submission / cancel ---------------------------------------------
 
@@ -1395,6 +1434,8 @@ class PagedLLMEngine:
             "prefix_hits": self._prefix_hits,
             "prefix_misses": self._prefix_misses,
             "preemptions": self._preemptions,
+            # pool-balance audit; exact only between steps
+            "leaked_pages": self.page_leak_check(),
             "continuous": self._continuous,
             "tp": self._tp,
             "hbm_cache_bytes": cache_bytes,

@@ -329,6 +329,37 @@ class LLMServer:
     def engine_stats(self) -> Dict[str, Any]:
         return self._engine.stats()
 
+    async def device_report(self) -> Dict[str, Any]:
+        """What this replica really runs on, read in its own process:
+        the backend's platform, device kind and count, each device's
+        `memory_stats()`, the accel plane's compile and step folds, and
+        (paged engine) the Pallas kernels found in the compiled decode
+        step. The caller — a driver that must stay
+        off JAX — learns from this whether a chip lease became a chip."""
+        def probe():
+            import os
+            import jax
+            from .._internal import accel
+            from ..ops.attention import pallas_kernels
+            devices = jax.devices()
+            report: Dict[str, Any] = {
+                "pid": os.getpid(),
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+                "memory": [d.memory_stats() for d in devices],
+                "compile": accel.compile_summary(),
+                "steps": accel.step_summary(),
+            }
+            if self._paged:
+                report["decode_kernels"] = pallas_kernels(
+                    self._engine.decode_program_text())
+            return report
+        # off-loop: the probe compiles, and a blocked loop fails the
+        # replica's health check
+        return await asyncio.get_running_loop().run_in_executor(
+            None, probe)
+
     def autoscaling_metrics(self) -> Dict[str, Any]:
         """Replica autoscaling hook (replica.get_metrics() folds this
         into the controller's closed loop): the engine's waiting-queue
@@ -342,11 +373,15 @@ class LLMServer:
 def build_llm_deployment(engine_config, *, name: str = "LLMServer",
                          num_replicas: int = 1, params=None,
                          max_ongoing_requests: int = 64,
-                         mesh_config=None):
+                         mesh_config=None,
+                         ray_actor_options: Optional[Dict[str, Any]] = None):
     """Serve application for the engine
-    (reference: serve/llm/__init__.py:92 build_llm_deployment)."""
+    (reference: serve/llm/__init__.py:92 build_llm_deployment).
+    `ray_actor_options={"num_tpus": n}` gives each replica n chips: its
+    worker then opens the TPU backend or dies with the backend's error."""
     from .. import serve
     deployment = serve.deployment(
         LLMServer, name=name, num_replicas=num_replicas,
-        max_ongoing_requests=max_ongoing_requests)
+        max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=ray_actor_options)
     return deployment.bind(engine_config, params, mesh_config)

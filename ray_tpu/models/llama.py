@@ -25,8 +25,10 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
-from ..ops.attention import flash_attention
+from ..ops.attention import flash_attention, flash_uses_pallas
+from ..parallel.mesh import current_kernel_mesh
 
 Dtype = Any
 
@@ -176,6 +178,25 @@ def apply_rope(x, cos, sin, positions):
     return rotated.astype(x.dtype)
 
 
+def _flash_on_mesh(q, k, v):
+    """Causal flash attention under the active kernel mesh. GSPMD cannot
+    partition a Mosaic custom call, so where the Pallas kernel will run
+    on a multi-device mesh it is shard_mapped over batch (data, fsdp)
+    and heads (tensor): attention is independent across both, so no
+    collectives. The jnp paths partition on their own."""
+    mesh = current_kernel_mesh()
+    if mesh is None or mesh.size == 1 or not flash_uses_pallas(q, k):
+        return flash_attention(q, k, v)
+    if mesh.shape["sequence"] > 1:
+        raise NotImplementedError(
+            "the flash kernel cannot run on a sequence-sharded mesh; "
+            "use parallel.ring_attention")
+    spec = PartitionSpec(("data", "fsdp"), "tensor", None, None)
+    return jax.shard_map(
+        flash_attention, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)(q, k, v)
+
+
 class Attention(nn.Module):
     config: LlamaConfig
 
@@ -284,17 +305,16 @@ class Attention(nn.Module):
             # the Pallas custom call itself, hence the explicit map
             # (reference places TP engine workers via
             # vllm_models.py:169-178; here TP is a mesh axis).
-            from ..parallel.mesh import current_serving_mesh
-            pm = current_serving_mesh()
+            pm = current_kernel_mesh()
             tp = int(pm.shape.get("tensor", 1)) if pm is not None else 1
             if tp > 1:
                 from jax.sharding import PartitionSpec as _P
-                from ..parallel._compat import shard_map as _shard_map
-                out1 = _shard_map(
+                out1 = jax.shard_map(
                     paged_kernel, mesh=pm,
                     in_specs=(_P(None, "tensor", None), _P("tensor"),
                               _P("tensor"), _P(None), _P(None, None)),
-                    out_specs=_P(None, "tensor", None))(
+                    out_specs=_P(None, "tensor", None),
+                    check_vma=False)(
                         q1, kp, vp, lengths, block_tables)
             else:
                 out1 = paged_kernel(q1, kp, vp, lengths, block_tables)
@@ -341,7 +361,7 @@ class Attention(nn.Module):
                 from ..ops.attention import attention_chunked
                 out = attention_chunked(q, k, v, True)
             else:
-                out = flash_attention(q, k, v, True, None)
+                out = _flash_on_mesh(q, k, v)
         out = jnp.transpose(out, (0, 2, 1, 3))  # [b, s, h, d]
         proj = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,

@@ -26,22 +26,15 @@ shift the causal mask for sequence-parallel callers.
 
 from __future__ import annotations
 
+import collections
 import functools
-from typing import Optional, Tuple
+import re
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-if pltpu is not None and not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 names it TPUCompilerParams; alias so the kernels below
-    # track the current spelling while older toolchains keep working.
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -154,14 +147,12 @@ DEFAULT_BLOCK_K = 512
 def _interpret():
     """Pallas `interpret=` argument: off on real TPU, TPU-interpreter off-TPU.
 
-    The plain HLO interpreter (`interpret=True`) cannot lower `program_id` on
-    CPU in this JAX version; `pltpu.InterpretParams` simulates the Mosaic
-    grid/DMA semantics on any backend and is the supported test path.
+    The plain HLO interpreter (`interpret=True`) cannot lower `program_id`
+    on CPU; `pltpu.InterpretParams` simulates the Mosaic grid/DMA
+    semantics on any backend and is the supported test path.
     """
     if jax.default_backend() == "tpu":
         return False
-    if pltpu is None or not hasattr(pltpu, "InterpretParams"):
-        return True
     return pltpu.InterpretParams()
 
 
@@ -169,9 +160,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
                 acc_scratch, *, sm_scale, causal, block_q, block_k):
     kb = pl.program_id(2)
     nk = pl.num_programs(2)
-    # program_id must be bound at kernel top level: inside a pl.when
-    # branch the interpret-mode cond jaxpr keeps the raw primitive,
-    # which has no CPU lowering (jax < 0.5).
     qb = pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -330,8 +318,6 @@ def _kernel_params(sq: int, sk: int, d: int):
 
 
 def _pallas_ok(q, k) -> bool:
-    if pltpu is None:
-        return False
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_q, block_k = _kernel_params(sq, sk, d)
@@ -382,6 +368,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q.reshape(b * h, sq, d), k.reshape(b * hk, sk, d),
       v.reshape(b * hk, sk, d))
     return out.reshape(b, h, sq, d), lse[..., 0]
@@ -448,6 +435,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, sm_scale):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
+            name="flash_bwd_kv",
         )(qf, kf, vf, dof, lse, delta)
         return dk, dv
 
@@ -483,6 +471,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, sm_scale):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
+            name="flash_bwd_q",
         )(qf, kf, vf, dof, lse, delta)
         return dq
 
@@ -515,6 +504,26 @@ def _flash_tpu_bwd(causal, sm_scale, residuals, g):
 
 
 _flash_tpu.defvjp(_flash_tpu_fwd, _flash_tpu_bwd)
+
+
+_KERNEL_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*[/(]([A-Za-z_]\w*)\)*/pallas_call"')
+
+
+def pallas_kernels(compiled_text: str) -> Dict[str, int]:
+    """Mosaic kernels in a compiled program, counted by the name their
+    `pallas_call` was given (`compiled.as_text()` of a program built for
+    the TPU). The branches below and in `models.llama` give way to jnp
+    paths by shape without a word; this is the trace of which one a
+    program really took."""
+    return dict(collections.Counter(_KERNEL_CALL.findall(compiled_text)))
+
+
+def flash_uses_pallas(q, k) -> bool:
+    """Will `flash_attention(q, k, ...)` (no offsets) run the Pallas
+    kernel here? True on the TPU backend for shapes the kernel takes."""
+    return not _interpret() and _pallas_ok(q, k)
 
 
 def flash_attention(q, k, v, causal: bool = True,

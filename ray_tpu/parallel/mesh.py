@@ -31,27 +31,28 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# The mesh a serving engine is currently tracing/executing under.
-# Model code (e.g. the paged-attention kernel dispatch) reads this to
-# decide whether to shard_map over a tensor axis — our own channel, no
-# dependency on jax's legacy thread-resources internals.
-_SERVING_MESH: contextvars.ContextVar[Optional[Mesh]] = \
-    contextvars.ContextVar("rtpu_serving_mesh", default=None)
+# The mesh the program being traced will run on, set by whoever builds
+# the jitted program (the serving engine, the train step). GSPMD cannot
+# partition a Mosaic custom call, so model code reads this to shard_map
+# its Pallas kernels (flash, paged attention) over the mesh — our own
+# channel, no dependency on jax's legacy thread-resources internals.
+_KERNEL_MESH: contextvars.ContextVar[Optional[Mesh]] = \
+    contextvars.ContextVar("rtpu_kernel_mesh", default=None)
 
 
 @contextlib.contextmanager
-def serving_mesh(mesh: Optional[Mesh]):
-    """Mark `mesh` active for model-side sharding decisions (trace-time:
-    wrap every jit call whose trace should see it)."""
-    token = _SERVING_MESH.set(mesh)
+def kernel_mesh(mesh: Optional[Mesh]):
+    """Mark `mesh` active for model-side kernel sharding (trace-time:
+    wrap every trace that should see it)."""
+    token = _KERNEL_MESH.set(mesh)
     try:
         yield mesh
     finally:
-        _SERVING_MESH.reset(token)
+        _KERNEL_MESH.reset(token)
 
 
-def current_serving_mesh() -> Optional[Mesh]:
-    return _SERVING_MESH.get()
+def current_kernel_mesh() -> Optional[Mesh]:
+    return _KERNEL_MESH.get()
 
 AXIS_ORDER = ("data", "fsdp", "expert", "pipeline", "sequence", "tensor")
 

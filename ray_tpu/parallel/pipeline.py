@@ -20,7 +20,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import CHECK_KW as _CHECK_KW, shard_map
 
 
 def stack_stage_params(per_stage_params: list) -> Any:
@@ -46,10 +45,10 @@ def gpipe(stage_fn: Callable, num_stages: int, num_microbatches: int,
         mb = jnp.reshape(x, (num_microbatches, -1) + x.shape[1:])
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(axis_name), P()),  # params: stage-sharded; x: repl
             out_specs=P(),
-            **_CHECK_KW)
+            check_vma=False)
         def run(params_shard, mb_all):
             # Each device holds its stage's params with leading dim 1.
             params_local = jax.tree_util.tree_map(

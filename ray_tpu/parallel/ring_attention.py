@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import CHECK_KW, shard_map
 
 NEG_INF = -1e30
 
@@ -64,8 +63,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sequence",
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=spec, **CHECK_KW)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
     def _ring(q_blk, k_blk, v_blk):
         b, h, s_local, d = q_blk.shape
         rank = jax.lax.axis_index(axis_name)
@@ -116,8 +115,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = "sequence",
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=spec, **CHECK_KW)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
     def _ulysses(q_blk, k_blk, v_blk):
         # [b, H, S/n, d] -> [b, H/n, S, d]
         def swap_in(x):

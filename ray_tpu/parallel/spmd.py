@@ -20,9 +20,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import CHECK_KW as _CHECK_KW, shard_map
-from .mesh import (DEFAULT_LOGICAL_AXIS_RULES, logical_to_mesh_axes,
-                   named_sharding, params_shardings, unbox)
+from .mesh import (DEFAULT_LOGICAL_AXIS_RULES, kernel_mesh,
+                   logical_to_mesh_axes, named_sharding, params_shardings,
+                   unbox)
 
 
 class TrainState(flax.struct.PyTreeNode):
@@ -113,7 +113,9 @@ def make_train_step(loss_fn: Callable, mesh: Mesh,
             lambda x: jax.lax.with_sharding_constraint(
                 x, batch_sharding) if x.ndim == len(batch_axes) else x,
             batch)
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        # the model shard_maps its Pallas kernels over this mesh
+        with kernel_mesh(mesh):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
         new_state = state.apply_gradients(grads)
         metrics = {"loss": loss,
                    "grad_norm": optax.global_norm(grads)}
@@ -321,10 +323,10 @@ def make_zero1_train_step(loss_fn: Callable, mesh: Mesh,
         batch_specs = jax.tree_util.tree_map(lambda _: batch_spec, batch)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(param_specs, P(ax), P(ax), batch_specs),
             out_specs=(P(), P(ax), P(ax), P(), P()),
-            **_CHECK_KW)
+            check_vma=False)
         def run(params, m_l, v_l, batch_l):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch_l)
             flat = _flatten_f32(grads)
@@ -382,10 +384,10 @@ def make_zero1_apply_step(mesh: Mesh, state: Zero1State,
         grad_specs = jax.tree_util.tree_map(lambda _: P(), grads)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(param_specs, grad_specs, P(ax), P(ax)),
             out_specs=(P(), P(ax), P(ax), P()),
-            **_CHECK_KW)
+            check_vma=False)
         def run(params, grads, m_l, v_l):
             flat = jnp.pad(_flatten_f32(grads), (0, pad_n - n))
             gnorm = jnp.sqrt(jnp.sum(flat * flat))

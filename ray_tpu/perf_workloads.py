@@ -33,6 +33,7 @@ from __future__ import annotations
 import argparse
 import json
 import logging
+import os
 import time
 
 
@@ -265,7 +266,7 @@ def _drive_engine_arm(engine, workload) -> dict:
         "ttft_p95_s": round(ttfts[int(len(ttfts) * 0.95)], 4),
         "prefill_tokens": int(_prefill_tokens_counter() - prefill0),
         "preemptions": stats["preemptions"],
-        "leaked_pages": engine.page_leak_check(),
+        "leaked_pages": stats["leaked_pages"],
         "outputs": outputs,
     }
 
@@ -447,10 +448,7 @@ class _SatLLMServer:
             async def __call__(self, http_request):
                 body = http_request.json()
                 if body.get("op") == "leak_check":
-                    stats = self._engine.stats()
-                    stats["leaked_pages"] = \
-                        self._engine.page_leak_check()
-                    return stats
+                    return self._engine.stats()
                 if body.get("op") == "reqtrace_flush":
                     import asyncio
 
@@ -952,6 +950,10 @@ def bench_soak(duration_s: float = 45.0, seed: int = 1234,
 
 
 def main():
+    # Host-plane workloads, CPU by design (hidden-64 toys): this process
+    # runs JAX itself, so on a chip host it stays off the chip by
+    # environment (read when jax is first imported).
+    os.environ["JAX_PLATFORMS"] = "cpu"
     parser = argparse.ArgumentParser()
     parser.add_argument("--which", default="all")
     parser.add_argument("--soak-seconds", type=float, default=45.0)

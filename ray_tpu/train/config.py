@@ -72,7 +72,15 @@ class ScalingConfig:
     def worker_resources(self) -> Dict[str, float]:
         resources = dict(self.resources_per_worker or {})
         if self.use_tpu and "TPU" not in resources:
-            resources["TPU"] = 4  # chips per host default
+            # one worker per host: it takes every chip the host has
+            from ..accelerators.tpu import autodetect_num_chips
+            chips = autodetect_num_chips()
+            if not chips:
+                raise ValueError(
+                    "use_tpu=True but no TPU chip was detected on this "
+                    "host; name the chips per worker with "
+                    "resources_per_worker={'TPU': n}")
+            resources["TPU"] = chips
         resources.setdefault("CPU", 1)
         return resources
 

@@ -139,10 +139,9 @@ class WorkerGroup:
                 f"(per-worker {resources})")
 
         worker_cls = ray_tpu.remote(TrainWorker)
+        # A worker whose bundle holds chips gets the TPU backend from the
+        # raylet (lease -> JAX_PLATFORMS=tpu); nothing to ask for here.
         env_vars = {}
-        if self.scaling.use_tpu:
-            env_vars["RTPU_WORKER_JAX_PLATFORMS"] = "tpu,cpu"
-            env_vars["JAX_PLATFORMS"] = ""
         if self.scaling.virtual_devices:
             # The --dryrun7b harness: each worker gets an n-device
             # virtual CPU mesh so the full GSPMD sharding compiles and

@@ -14,7 +14,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ...parallel._compat import CHECK_KW, shard_map
 
 # In-jit aliases (use inside shard_map bodies).
 allreduce = jax.lax.psum
@@ -34,8 +33,8 @@ def device_allreduce(x, mesh: Mesh, axis_name: str = "data",
     """Allreduce a global array sharded over `axis_name` (one jitted op)."""
     spec = in_spec if in_spec is not None else P(axis_name)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, **CHECK_KW)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def _ar(blk):
         return jax.lax.psum(blk, axis_name)
 
@@ -45,8 +44,8 @@ def device_allreduce(x, mesh: Mesh, axis_name: str = "data",
 def device_allgather(x, mesh: Mesh, axis_name: str = "data"):
     spec = P(axis_name)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=P(), **CHECK_KW)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=P(), check_vma=False)
     def _ag(blk):
         return jax.lax.all_gather(blk, axis_name, tiled=True)
 
@@ -69,8 +68,8 @@ def hierarchical_allreduce(x, mesh: Mesh, ici_axis: str = "fsdp",
     size (psum_scatter's tiling contract)."""
     spec = in_spec if in_spec is not None else P((dcn_axis, ici_axis))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, **CHECK_KW)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def _h(blk):
         part = jax.lax.psum_scatter(blk, ici_axis, tiled=True)
         part = jax.lax.psum(part, dcn_axis)
@@ -100,8 +99,8 @@ def quantized_allreduce(x, mesh: Mesh, axis_name: str = "data",
     """Standalone jitted quantized allreduce over one (DCN) axis."""
     spec = in_spec if in_spec is not None else P(axis_name)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, **CHECK_KW)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def _qar(blk):
         return quantized_psum(blk, axis_name, block=block)
 
@@ -117,8 +116,8 @@ def hierarchical_quantized_allreduce(x, mesh: Mesh,
     intra-slice all-gather."""
     spec = in_spec if in_spec is not None else P((dcn_axis, ici_axis))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, **CHECK_KW)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def _hq(blk):
         part = jax.lax.psum_scatter(blk, ici_axis, tiled=True)
         part = quantized_psum(part, dcn_axis, block=block)

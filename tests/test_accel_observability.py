@@ -210,8 +210,8 @@ def test_peak_flops_table_shared_with_bench():
     assert flops.peak_flops_for_kind("TPU v5 lite") == 197e12
     assert flops.peak_flops_for_kind("TPU v5p") == 459e12
     assert flops.peak_flops_for_kind("cpu") == 1e12
-    assert flops.peak_flops_for_kind("martian-npu") \
-        == flops.DEFAULT_PEAK_FLOPS
+    with pytest.raises(KeyError, match="martian-npu"):
+        flops.peak_flops_for_kind("martian-npu")
 
     class FakeDev:
         device_kind = "TPU v4"

@@ -1,0 +1,241 @@
+"""The chip's compiler, without the chip: the kernels of the two main paths
+AOT-compiled for a described `v5e:2x2` at Llama-3-8B widths, plus the
+bring-up rules a CPU run can hold the repo to (a chip lease becomes the TPU
+platform, `chip_smoke.py` refuses to pass off-chip).
+
+Interpret mode proves a kernel's arithmetic; only this compile proves the
+TPU will take it (tiling, VMEM, partitioning). The kernels choose their
+branch from `jax.default_backend()`, which here says "cpu", so each test
+steers that question itself — compiled the obvious way, the same programs
+hold no kernel at all and prove nothing.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep libtpu out of /tmp
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# Llama-3-8B attention widths
+HEADS, KV_HEADS, HEAD_DIM = 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described (not attached) v5e chips."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Answer the kernels' backend question as the chip would, and keep
+    the persistent compile cache out of it (an AOT entry written here
+    cannot be read back without a chip; the next run would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _kernels(fn, *specs):
+    from ray_tpu.ops.attention import pallas_kernels
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    return pallas_kernels(text)
+
+
+def _qkv(batch, seq, sharding):
+    def spec(heads):
+        return jax.ShapeDtypeStruct((batch, heads, seq, HEAD_DIM),
+                                    jnp.bfloat16, sharding=sharding)
+    return spec(HEADS), spec(KV_HEADS), spec(KV_HEADS)
+
+
+def test_flash_forward_compiles_for_v5e(v5e, as_tpu):
+    from ray_tpu.ops.attention import flash_attention
+    kernels = _kernels(flash_attention,
+                       *_qkv(2, 2048, SingleDeviceSharding(v5e[0])))
+    assert kernels == {"flash_fwd": 1}
+
+
+def test_flash_forward_backward_compiles_for_v5e(v5e, as_tpu):
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    kernels = _kernels(jax.grad(loss, argnums=(0, 1, 2)),
+                       *_qkv(2, 2048, SingleDeviceSharding(v5e[0])))
+    assert kernels == {"flash_fwd": 1, "flash_bwd_kv": 1, "flash_bwd_q": 1}
+
+
+def test_flash_on_a_mesh_is_shard_mapped(v5e, as_tpu):
+    """GSPMD refuses to partition a Mosaic call ("wrap the call in a
+    shard_map"): under the train step's kernel mesh the model maps the
+    kernel over batch (fsdp) and heads (tensor) itself."""
+    from ray_tpu.models.llama import _flash_on_mesh
+    from ray_tpu.parallel import MeshConfig
+    from ray_tpu.parallel.mesh import kernel_mesh
+    mesh = MeshConfig(data=1, fsdp=2, tensor=2).build(v5e)
+    sharding = NamedSharding(mesh, P(("data", "fsdp"), "tensor"))
+
+    def attend(q, k, v):
+        with kernel_mesh(mesh):
+            return _flash_on_mesh(q, k, v)
+
+    assert _kernels(attend, *_qkv(2, 2048, sharding)) == {"flash_fwd": 1}
+
+
+def _paged_decode_layer(mesh=None):
+    """One Attention layer's paged decode step (q_len 1) through the
+    model's own branch: page scatter + the stock paged-attention kernel."""
+    import dataclasses
+
+    from ray_tpu.models.llama import Attention, LlamaConfig
+    from ray_tpu.parallel.mesh import kernel_mesh
+    cfg = dataclasses.replace(LlamaConfig.llama3_8b(), dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16)
+    layer = Attention(cfg)
+
+    def decode(params, x, kp, vp, tables, lengths):
+        cache = {"k": kp, "v": vp, "block_tables": tables,
+                 "lengths": lengths}
+        with kernel_mesh(mesh):
+            out, new = layer.apply({"params": params}, x, lengths[:, None],
+                                   cache, None)
+        return out, new["k"], new["v"]
+
+    return cfg, layer, decode
+
+
+def _paged_specs(cfg, layer, batch, pages, page_size, place):
+    from ray_tpu.parallel.mesh import unbox
+    x = jnp.zeros((batch, 1, cfg.hidden_size), cfg.dtype)
+    params = jax.eval_shape(
+        lambda: unbox(layer.init(jax.random.PRNGKey(0), x,
+                                 jnp.zeros((batch, 1), jnp.int32))["params"]))
+
+    def spec(shape, dtype, names=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(names))
+    pool = (cfg.num_kv_heads, pages, page_size, cfg.head_dim_)
+    return (jax.tree_util.tree_map(
+                lambda a: spec(a.shape, a.dtype), params),
+            spec(x.shape, x.dtype),
+            spec(pool, cfg.dtype, ("tensor",)),
+            spec(pool, cfg.dtype, ("tensor",)),
+            spec((batch, 2048 // page_size), jnp.int32),
+            spec((batch,), jnp.int32))
+
+
+def test_paged_decode_compiles_for_v5e(v5e, as_tpu):
+    cfg, layer, decode = _paged_decode_layer()
+    one = SingleDeviceSharding(v5e[0])
+    specs = _paged_specs(cfg, layer, 8, 512, 16, lambda names: one)
+    assert _kernels(decode, *specs) == {"paged_attention": 1}
+
+
+def test_paged_decode_tensor_parallel_compiles_for_v5e(v5e, as_tpu):
+    """tensor=4: the paged kernel runs per shard under shard_map (local
+    heads 8/2), which a pallas_call only accepts with check_vma off."""
+    from ray_tpu.parallel import MeshConfig
+    mesh = MeshConfig(data=1, tensor=4).build(v5e)
+    cfg, layer, decode = _paged_decode_layer(mesh)
+    specs = _paged_specs(cfg, layer, 8, 512, 16,
+                         lambda names: NamedSharding(mesh, P(*names)))
+    assert _kernels(decode, *specs) == {"paged_attention": 1}
+
+
+def test_obvious_compile_holds_no_kernel(v5e):
+    """Why the tests above steer the backend question: unsteered, the
+    public entry point compiles for the described chip without complaint
+    and without one Mosaic call in it."""
+    from ray_tpu.ops.attention import flash_attention
+    text = jax.jit(flash_attention).lower(
+        *_qkv(1, 512, SingleDeviceSharding(v5e[0]))).compile().as_text()
+    assert "tpu_custom_call" not in text
+
+
+# ---------------------------------------------------------------------------
+# bring-up rules that hold on any machine
+# ---------------------------------------------------------------------------
+
+def test_chip_lease_names_the_tpu_platform():
+    """A lease that holds chips is its own worker environment with
+    JAX_PLATFORMS=tpu (open the chip or die); every other lease keeps the
+    plain key, whose workers the raylet forces onto the CPU."""
+    from ray_tpu._internal.raylet import Raylet
+    from ray_tpu._internal.resources import ResourceSet
+    from ray_tpu._internal.task_spec import runtime_env_key
+    key = Raylet._env_key(None, {}, ResourceSet({"CPU": 1}))
+    assert key == runtime_env_key({})
+    chip = Raylet._env_key(None, {}, ResourceSet({"TPU": 1}))
+    assert dict(chip[0]) == {"JAX_PLATFORMS": "tpu"} and chip[1:] == key[1:]
+    # a runtime env that names a platform itself is left alone
+    named = {"env_vars": {"JAX_PLATFORMS": "cpu", "A": "1"}}
+    assert Raylet._env_key(None, named, ResourceSet({"TPU": 4})) \
+        == runtime_env_key(named)
+
+
+def test_use_tpu_takes_the_detected_chips_or_raises(monkeypatch):
+    from ray_tpu.train import ScalingConfig
+    monkeypatch.setenv("RTPU_NUM_TPU_CHIPS", "1")
+    assert ScalingConfig(use_tpu=True).worker_resources()["TPU"] == 1
+    monkeypatch.setenv("RTPU_NUM_TPU_CHIPS", "0")
+    with pytest.raises(ValueError, match="no TPU chip"):
+        ScalingConfig(use_tpu=True).worker_resources()
+    explicit = ScalingConfig(use_tpu=True, resources_per_worker={"TPU": 4})
+    assert explicit.worker_resources()["TPU"] == 4
+
+
+def test_compile_cache_dir_is_fixed_and_yields_to_the_environment():
+    from ray_tpu.accelerators.tpu import compile_cache_dir
+    env = {}
+    assert compile_cache_dir(env) == os.path.join(REPO, ".jax_cache")
+    assert env == {"JAX_COMPILATION_CACHE_DIR":
+                   os.path.join(REPO, ".jax_cache")}
+    env = {"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}
+    assert compile_cache_dir(env) == "/elsewhere"
+    assert env == {"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}
+
+
+def test_launchers_read_the_virtual_mesh_from_the_environment():
+    """bench.py and __graft_entry__.py respawn onto virtual CPU devices by
+    reading the environment; asking jax would open the host's chip."""
+    from ray_tpu.accelerators.tpu import on_virtual_cpu_mesh
+    env = {"JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--a=b --xla_force_host_platform_device_count=8"}
+    assert on_virtual_cpu_mesh(8, env) and on_virtual_cpu_mesh(4, env)
+    assert not on_virtual_cpu_mesh(16, env)
+    assert not on_virtual_cpu_mesh(8, dict(env, JAX_PLATFORMS="tpu,cpu"))
+    assert not on_virtual_cpu_mesh(1, {})
+
+
+@pytest.mark.parametrize("args", [(), ("--chips", "4")])
+def test_chip_smoke_refuses_to_pass_off_chip(args):
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=110)
+    assert out.returncode != 0
+    last = out.stdout.strip().splitlines()[-1]
+    assert '"ok": true' not in out.stdout
+    with pytest.raises(ValueError):
+        json.loads(last)  # a failure prints no result object

@@ -223,11 +223,11 @@ def two_slice_mesh():
 def _psum_ref(x, mesh, axes):
     import jax
     from jax.sharding import PartitionSpec as P
-    from ray_tpu.parallel._compat import CHECK_KW, shard_map
+    from jax import shard_map
     spec = P(("data", "fsdp"))
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, **CHECK_KW)
+                       out_specs=spec, check_vma=False)
     def _ar(blk):
         return jax.lax.psum(blk, axes)
 
