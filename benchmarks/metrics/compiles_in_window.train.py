@@ -1,0 +1,6 @@
+"""Compiles the train worker counted inside the window; must be 0."""
+from benchmarks.harness import readers
+
+
+def read(record):
+    return readers.compiles_in_window(record)
