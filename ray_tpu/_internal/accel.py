@@ -27,6 +27,9 @@ Three concerns, one per-process module:
   device-compute / host-blocked buckets
   (``rtpu_goodput_seconds_total{bucket=...}``). Wired into the train
   controller's report fold, the paged-engine decode tick, and bench.py.
+  ``StepTimer.phase(name)`` splits a step into named intervals that
+  are also spans on the profiler's clock; the paged engine's whole
+  continuous tick is the kind ``tick``, tiled by ten of them.
 
 JAX is never imported by this module at module scope. The compile
 listeners arm once the process has imported jax; device snapshots only
@@ -44,6 +47,7 @@ snapshots return empty, StepTimer/report_step become no-ops.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import sys
@@ -371,27 +375,49 @@ def install_import_hook() -> bool:
     return True
 
 
-def uninstall() -> None:
-    """Best-effort listener removal (tests; the unregister API is
-    private to jax so failures just leave idle listeners behind)."""
+def _unregister(modules, names, callback) -> bool:
+    """Take one of this module's listeners out of jax.monitoring, by the
+    public name jax 0.9 has or the private one older jax had. False when
+    neither exists or jax refused (it asserts the callback is there)."""
+    unregister = next((getattr(m, n) for m, n in zip(modules, names)
+                       if hasattr(m, n)), None)
+    if unregister is None:
+        logger.debug("jax.monitoring has none of %s", names)
+        return False
     try:
-        from jax._src import monitoring as _m  # import OUTSIDE the lock
-    except Exception:  # noqa: BLE001 — private API may move
-        logger.debug("jax._src.monitoring unavailable", exc_info=True)
-        _m = None
+        unregister(callback)
+    except Exception:  # noqa: BLE001 — not registered, or the API moved
+        logger.debug("jax.monitoring %s failed", names[0], exc_info=True)
+        return False
+    return True
+
+
+def uninstall() -> None:
+    """Listener removal (tests). ``installed`` turns False only when
+    both callbacks are really gone: one jax still holds would be
+    registered a SECOND time by the next ensure_installed(), every
+    compile second counted twice and the device bucket clipped to 0."""
+    try:  # import OUTSIDE the lock (see ensure_installed)
+        import jax.monitoring as public
+        from jax._src import monitoring as private
+    except Exception:  # noqa: BLE001 — jax genuinely unavailable
+        logger.debug("jax.monitoring unavailable", exc_info=True)
+        return
+    modules = (public, private)
     tracker = _TRACKER
     with tracker.lock:
         if not tracker.installed:
             return
-        if _m is not None:
-            try:
-                _m._unregister_event_duration_listener_by_callback(
-                    _on_duration_event)
-                _m._unregister_event_listener_by_callback(_on_event)
-            except Exception:  # noqa: BLE001 — private API may move
-                logger.debug("jax.monitoring unregister failed",
-                             exc_info=True)
-        tracker.installed = False
+        gone = _unregister(
+            modules, ("unregister_event_duration_listener",
+                      "_unregister_event_duration_listener_by_callback"),
+            _on_duration_event)
+        gone = _unregister(
+            modules, ("unregister_event_listener",
+                      "_unregister_event_listener_by_callback"),
+            _on_event) and gone
+        if gone:
+            tracker.installed = False
 
 
 def compile_seconds_total() -> float:
@@ -569,7 +595,7 @@ def emit_pressure_event(message: str, fields: Optional[Dict[str, Any]]
 # ---------------------------------------------------------------------------
 
 # kind -> fold of every reported step in this process
-_step_stats: Dict[str, Dict[str, float]] = {}
+_step_stats: Dict[str, Dict[str, Any]] = {}
 _STEP_LOCK = threading.Lock()
 _EWMA_ALPHA = 0.2
 
@@ -610,20 +636,33 @@ def _default_device_kind() -> str:
     return kind
 
 
+def _sum_phases(total: Dict[str, float],
+                part: Optional[Dict[str, float]]) -> None:
+    """Add a step's seconds by phase name into a running fold."""
+    if part:
+        for name, seconds in part.items():
+            total[name] = total.get(name, 0.0) + seconds
+
+
 def report_step(kind: str, wall_s: float, tokens: int = 0,
                 device_s: float = 0.0, compile_s: float = 0.0,
                 flops: float = 0.0,
                 device_kind: Optional[str] = None,
                 steps: int = 1,
-                comm_s: float = 0.0) -> Optional[Dict[str, float]]:
+                comm_s: float = 0.0,
+                phases: Optional[Dict[str, float]] = None,
+                cpu_s: float = 0.0) -> Optional[Dict[str, float]]:
     """Fold one step (or ``steps`` uniform steps) into the process's
     step telemetry: step-time histogram, tokens/s EWMA gauge, MFU gauge
     (``flops`` = total FLOPs the interval performed, divided by wall
     and the shared peak table), and the compile/device/comm/host
     goodput split (``comm_s`` = host-plane collective time, so
     comm-bound and compute-bound steps are distinguishable;
-    host-blocked = wall − compile − device − comm). Returns the
-    derived numbers, or None when the plane is disabled."""
+    host-blocked = wall − compile − device − comm). ``phases`` (a
+    StepTimer's named intervals, seconds by name) and ``cpu_s`` (the
+    stepping thread's own CPU seconds) are summed into the kind's
+    ``step_summary()`` row as they come. Returns the derived numbers,
+    or None when the plane is disabled."""
     if accel_disabled() or wall_s <= 0:
         return None
     metrics = accel_metrics()
@@ -663,7 +702,8 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
         agg = _step_stats.setdefault(kind, {
             "steps": 0, "wall_s": 0.0, "tokens": 0,
             "compile_s": 0.0, "device_s": 0.0, "comm_s": 0.0,
-            "host_s": 0.0, "tokens_per_s": 0.0, "mfu": 0.0})
+            "host_s": 0.0, "tokens_per_s": 0.0, "mfu": 0.0,
+            "cpu_s": 0.0, "phases": {}})
         agg["steps"] += steps
         agg["wall_s"] += wall_s
         agg["tokens"] += tokens
@@ -671,6 +711,8 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
         agg["device_s"] += device_s
         agg["comm_s"] += comm_s
         agg["host_s"] += host_s
+        agg["cpu_s"] += cpu_s
+        _sum_phases(agg["phases"], phases)
         if tokens_per_s is not None:
             prev = agg["tokens_per_s"]
             agg["tokens_per_s"] = tokens_per_s if not prev else \
@@ -689,7 +731,7 @@ def step_summary() -> List[Dict[str, Any]]:
     with _STEP_LOCK:
         out = []
         for kind, agg in _step_stats.items():
-            row = dict(agg, kind=kind)
+            row = dict(agg, kind=kind, phases=dict(agg["phases"]))
             steps = max(1, int(agg["steps"]))
             row["mean_step_s"] = agg["wall_s"] / steps
             out.append(row)
@@ -707,7 +749,7 @@ class StepAccumulator:
 
     __slots__ = ("kind", "every", "device_kind",
                  "_n", "_wall", "_tokens", "_device", "_compile",
-                 "_comm", "_flops")
+                 "_comm", "_flops", "_cpu", "_phases")
 
     def __init__(self, kind: str, every: int = 16,
                  device_kind: Optional[str] = None):
@@ -716,12 +758,15 @@ class StepAccumulator:
         self.device_kind = device_kind
         self._n = 0
         self._wall = self._device = self._compile = 0.0
-        self._comm = self._flops = 0.0
+        self._comm = self._flops = self._cpu = 0.0
         self._tokens = 0
+        self._phases: Dict[str, float] = {}
 
     def add(self, wall_s: float, tokens: int = 0, device_s: float = 0.0,
             compile_s: float = 0.0, flops: float = 0.0,
-            comm_s: float = 0.0):
+            comm_s: float = 0.0,
+            phases: Optional[Dict[str, float]] = None,
+            cpu_s: float = 0.0):
         self._n += 1
         self._wall += wall_s
         self._tokens += tokens
@@ -729,6 +774,8 @@ class StepAccumulator:
         self._compile += compile_s
         self._comm += comm_s
         self._flops += flops
+        self._cpu += cpu_s
+        _sum_phases(self._phases, phases)
         if self._n >= self.every:
             self.flush()
 
@@ -740,12 +787,28 @@ class StepAccumulator:
             self.kind, self._wall, tokens=self._tokens,
             device_s=self._device, compile_s=self._compile,
             flops=self._flops, device_kind=self.device_kind, steps=n,
-            comm_s=self._comm)
+            comm_s=self._comm, phases=self._phases, cpu_s=self._cpu)
         self._n = 0
         self._wall = self._device = self._compile = 0.0
-        self._comm = self._flops = 0.0
+        self._comm = self._flops = self._cpu = 0.0
         self._tokens = 0
+        self._phases = {}
         return out
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` — a host span on the
+    profiler's clock, above the device's ops in any ``.xplane.pb`` of
+    this process — or None in a process that has not imported jax: this
+    module imports it for no span."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name)
+
+
+# what phase() hands out under the kill switch
+_NO_PHASE = contextlib.nullcontext()
 
 
 class StepTimer:
@@ -754,19 +817,31 @@ class StepTimer:
     ::
 
         with StepTimer("decode", tokens=n, flops=2 * params * n) as t:
-            host_side_prep()
+            with t.phase("stage"):
+                args = host_side_prep()
             with t.device():
-                out = jitted_step(...)   # device-compute bucket
+                out = jitted_step(*args)   # device-compute bucket
         # exit: wall split into compile (jax.monitoring delta during the
-        # step) / device (time inside t.device()) / host (the rest)
+        # step) / device (time inside t.device()) / host (the rest);
+        # t.phases == {"stage": ..., "device": ...} seconds by name
+
+    ``phase(name)`` accumulates wall seconds under ``name`` and, while
+    open, holds the span ``<kind>/<name>`` on the profiler's clock; the
+    timer holds ``<kind>`` for its whole extent, so phases nest under
+    it. ``device()`` and ``comm()`` are the phases the goodput split
+    reads. ``cpu_s`` is ``time.thread_time()`` over the timer's extent:
+    wall minus it is time this thread did not run (blocked on the
+    device or on I/O, or waiting for the GIL).
 
     ``sink``: a StepAccumulator to fold into instead of reporting
-    immediately (hot loops — see the paged decode tick). Near-zero when
-    the plane is disabled: __enter__/__exit__ degrade to two attribute
-    checks and report nothing."""
+    immediately (hot loops — see the paged engine's tick). Near-zero
+    when the plane is disabled: __enter__/__exit__ degrade to two
+    attribute checks, phase() hands out one shared no-op, no span is
+    built and nothing is reported."""
 
     __slots__ = ("kind", "tokens", "flops", "device_kind", "enabled",
-                 "device_s", "comm_s", "result", "sink", "_t0", "_c0")
+                 "phases", "cpu_s", "result", "sink", "_t0", "_c0",
+                 "_cpu0", "_span")
 
     def __init__(self, kind: str, tokens: int = 0, flops: float = 0.0,
                  device_kind: Optional[str] = None,
@@ -777,89 +852,122 @@ class StepTimer:
         self.device_kind = device_kind
         self.sink = sink
         self.enabled = not accel_disabled()
-        self.device_s = 0.0
-        self.comm_s = 0.0
+        self.phases: Dict[str, float] = {}
+        self.cpu_s = 0.0
         self.result: Optional[Dict[str, float]] = None
         self._t0 = 0.0
         self._c0 = 0.0
+        self._cpu0 = 0.0
+        self._span = None
+
+    @property
+    def device_s(self) -> float:
+        return self.phases.get("device", 0.0)
+
+    @property
+    def comm_s(self) -> float:
+        return self.phases.get("comm", 0.0)
 
     def __enter__(self) -> "StepTimer":
         if self.enabled:
             ensure_installed()
+            span = self._span = _annotation(self.kind)
+            if span is not None:
+                span.__enter__()
             self._c0 = backend_compile_seconds_total()
+            self._cpu0 = time.thread_time()
             self._t0 = time.perf_counter()
         return self
 
+    def phase(self, name: str):
+        """``with timer.phase("admit"):`` — see the class docstring."""
+        return _Phase(self, name) if self.enabled else _NO_PHASE
+
     def device(self):
-        return _DeviceSpan(self)
+        """The device-compute bucket: the phase ``device``, less any
+        backend compile that fell inside it."""
+        return self.phase("device")
 
     def comm(self):
         """``with timer.comm():`` — host-plane collective time (gradient
         allreduce, loss reduction) lands in the ``comm`` goodput bucket
         instead of being misread as host-blocked."""
-        return _CommSpan(self)
+        return self.phase("comm")
 
-    def __exit__(self, exc_type, _exc, _tb):
-        if not self.enabled or exc_type is not None:
+    def outside(self, name: str, seconds: float) -> None:
+        """A pre-measured interval that lies OUTSIDE this timer's extent
+        (the paged engine's gap between two ticks): summed with the
+        phases under ``name``, not part of ``wall_s``, no span — in a
+        trace it is the space between two ``<kind>`` spans."""
+        if self.enabled:
+            self.phases[name] = self.phases.get(name, 0.0) + seconds
+
+    def __exit__(self, exc_type, exc, tb):
+        if not self.enabled:
             return False
         wall = time.perf_counter() - self._t0
+        self.cpu_s = time.thread_time() - self._cpu0
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            return False
         compile_s = backend_compile_seconds_total() - self._c0
         if self.sink is not None:
             self.sink.add(wall, tokens=self.tokens,
                           device_s=self.device_s, compile_s=compile_s,
-                          flops=self.flops, comm_s=self.comm_s)
+                          flops=self.flops, comm_s=self.comm_s,
+                          phases=self.phases, cpu_s=self.cpu_s)
         else:
             self.result = report_step(
                 self.kind, wall, tokens=self.tokens,
                 device_s=self.device_s, compile_s=compile_s,
                 flops=self.flops, device_kind=self.device_kind,
-                comm_s=self.comm_s)
+                comm_s=self.comm_s, phases=self.phases,
+                cpu_s=self.cpu_s)
         return False
 
 
-class _DeviceSpan:
-    """Accumulates time spent inside ``with timer.device():`` into the
-    owning StepTimer's device-compute bucket. A span that straddles an
-    XLA recompile (the first call of a freshly-traced step fn compiles
-    INSIDE the span) would bill the compile seconds as device compute;
-    the disjoint backend-compile window the tracker already measures is
-    subtracted, so those seconds land in the compile bucket alone."""
+class _Phase:
+    """One named interval of a StepTimer. Its seconds run from before
+    its span opens to after it closes, so back-to-back phases tile the
+    timer's wall with nothing between them but a ``with`` statement.
 
-    __slots__ = ("_timer", "_t0", "_c0")
+    The phase ``device`` is the goodput split's device-compute bucket: a
+    span that straddles an XLA recompile (the first call of a
+    freshly-traced step fn compiles INSIDE the span) would bill the
+    compile seconds as device compute; the disjoint backend-compile
+    window the tracker already measures is subtracted, so those seconds
+    land in the compile bucket alone."""
 
-    def __init__(self, timer: StepTimer):
+    __slots__ = ("_timer", "_name", "_t0", "_c0", "_span")
+
+    def __init__(self, timer: StepTimer, name: str):
         self._timer = timer
+        self._name = name
         self._t0 = 0.0
         self._c0 = 0.0
-
-    def __enter__(self):
-        self._c0 = backend_compile_seconds_total()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, _exc, _tb):
-        span = time.perf_counter() - self._t0
-        span -= backend_compile_seconds_total() - self._c0
-        self._timer.device_s += max(0.0, span)
-        return False
-
-
-class _CommSpan:
-    """Accumulates time spent inside ``with timer.comm():`` into the
-    owning StepTimer's comm (host-plane collective) bucket."""
-
-    __slots__ = ("_timer", "_t0")
-
-    def __init__(self, timer: StepTimer):
-        self._timer = timer
-        self._t0 = 0.0
+        self._span = None
 
     def __enter__(self):
         self._t0 = time.perf_counter()
+        if self._name == "device":
+            self._c0 = backend_compile_seconds_total()
+        span = self._span = _annotation(
+            self._timer.kind + "/" + self._name)
+        if span is not None:
+            span.__enter__()
         return self
 
-    def __exit__(self, exc_type, _exc, _tb):
-        self._timer.comm_s += time.perf_counter() - self._t0
+    def __exit__(self, exc_type, exc, tb):
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        name = self._name
+        seconds = time.perf_counter() - self._t0
+        if name == "device":
+            seconds = max(0.0, seconds - (
+                backend_compile_seconds_total() - self._c0))
+        phases = self._timer.phases
+        phases[name] = phases.get(name, 0.0) + seconds
         return False
 
 

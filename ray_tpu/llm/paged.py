@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._internal import accel as _accel
 from .._internal.config import CONFIG
 from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
 from . import reqtrace
@@ -48,6 +49,11 @@ from .radix import RadixPrefixCache
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
 _GAUGE_TAGS = {"engine": "paged", "pid": str(os.getpid())}
+
+
+def _no_phase(_name: str):
+    """`StepTimer.phase` for a tick that is not split."""
+    return contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -235,7 +241,6 @@ class PagedLLMEngine:
         # tick): decode forward ≈ 2 FLOPs per param per token. Checked
         # once here so a killed plane costs the tick two attribute
         # loads, nothing more.
-        from .._internal import accel as _accel
         self._accel = _accel if not _accel.accel_disabled() else None
         if self._accel is not None:
             # listeners precede this engine's prefill/decode compiles
@@ -244,6 +249,13 @@ class PagedLLMEngine:
         # every 16 ticks — the tick itself pays a perf_counter pair
         self._step_accum = _accel.StepAccumulator("decode") \
             if self._accel is not None else None
+        # the whole continuous tick by phase (kind "tick"; "decode" above
+        # keeps its extent, dispatch to last callback, inside it)
+        self._tick_accum = _accel.StepAccumulator("tick") \
+            if self._accel is not None else None
+        # perf_counter at the last tick's end if work was left then:
+        # the next tick's `between` runs from it
+        self._tick_end: Optional[float] = None
         self._num_params = sum(
             int(np.prod(p.shape))
             for p in jax.tree_util.tree_leaves(self.params))
@@ -553,20 +565,45 @@ class PagedLLMEngine:
         """One continuous-batching tick: reap cancellations, fill freed
         slots from the waiting queue (radix prefix match, tail-only
         prefill setup), advance bounded chunked prefill, then decode the
-        running batch — admission happens every tick, not per drain."""
+        running batch — admission happens every tick, not per drain.
+
+        The accel plane's `tick` row splits it by phase (README, "Tick
+        phases"): reap / admit / prefill / grow / stage / dispatch /
+        wait / emit / gauges tile the tick, and `between` is the time
+        since the last tick's end while work was waiting — the serving
+        loop's executor hop and whatever else held this thread."""
+        entered = time.perf_counter()
         finished: List[Tuple[GenerationRequest, Any]] = []
-        self._reap_cancelled()
-        self._admit_continuous()
-        self._prefill_tick(finished)
-        active = [i for i, s in enumerate(self.seqs)
-                  if s.request is not None and s.phase == "decode"]
-        if active:
-            finished.extend(self._decode_tick(active))
-        elif self._step_accum is not None:
-            self._step_accum.flush()
-        self._steps += 1
-        self._set_gauges()
+        tick = _accel.StepTimer("tick", sink=self._tick_accum)
+        if self._tick_end is not None:
+            tick.outside("between", entered - self._tick_end)
+        with tick:
+            with tick.phase("reap"):
+                self._reap_cancelled()
+            with tick.phase("admit"):
+                self._admit_continuous()
+            with tick.phase("prefill"):
+                self._prefill_tick(finished)
+                active = [i for i, s in enumerate(self.seqs)
+                          if s.request is not None and s.phase == "decode"]
+            if active:
+                finished.extend(self._decode_tick(active, tick.phase))
+            with tick.phase("gauges"):
+                self._steps += 1
+                self._set_gauges()
+        if self.has_work():
+            self._tick_end = time.perf_counter()
+        else:
+            self._tick_end = None
+            # drained: flush the partial windows so step telemetry
+            # never lags an idle engine by up to `every` ticks
+            self._flush_step_rows()
         return finished
+
+    def _flush_step_rows(self):
+        for accum in (self._step_accum, self._tick_accum):
+            if accum is not None:
+                accum.flush()
 
     def _waiting_count(self) -> int:
         return self._pending.qsize() + len(self._parked)
@@ -805,13 +842,16 @@ class PagedLLMEngine:
             logits, seq.dense_caches = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 seq.dense_caches, jnp.asarray(off, jnp.int32))
-        if off + take == len(prompt):
+        last = off + take == len(prompt)
+        if last:
             seq.last_logits = np.asarray(logits[0, take - 1], np.float64)
         seq.prefill_off = off + take
         if trace and seq.request is not None:
+            # only a prompt's last chunk waits for the device (the
+            # fetch above); every other dur_s is the launch alone
             reqtrace.record(
                 seq.request.request_id, reqtrace.PREFILL_CHUNK,
-                tokens=take, bucket=chunk,
+                tokens=take, bucket=chunk, fenced=last or None,
                 dur_s=round(time.monotonic() - chunk_t0, 6),
                 compile_s=round(
                     self._compile_total() - compile_t0, 6) or None)
@@ -1275,57 +1315,62 @@ class PagedLLMEngine:
             self.pool.decref(page)
         self._by_id.pop(seq.request.request_id, None)
 
-    def _decode_tick(self, active: List[int]):
+    def _decode_tick(self, active: List[int], phase=_no_phase):
+        """Decode one token for every row in `active`. `phase` is the
+        continuous tick's `StepTimer.phase` (the legacy arm's tick is
+        not split)."""
         tick_start = time.monotonic()
         cfg = self.config
         B = cfg.max_batch
-        # cancelled sequences release before the step
         finished = []
-        for i in list(active):
-            seq = self.seqs[i]
-            if seq.cancelled:
-                request = seq.request
-                self._release(seq)
-                self.seqs[i] = _Seq()
-                active.remove(i)
-                llm_metrics().requests_finished.inc(
-                    tags=dict(_TAGS, outcome="cancelled"))
-                reqtrace.record(request.request_id, reqtrace.CANCELLED,
-                                where="decode")
-                callback = getattr(request, "_done_callback", None)
-                if callback is not None:
-                    callback(request, None)  # None = cancelled
-        if self._continuous and active:
-            # lazy page growth (+ preemption under pressure) replaces
-            # the legacy upfront prompt+max_new reservation
-            active = self._ensure_decode_pages(active)
+        with phase("grow"):
+            # cancelled sequences release before the step
+            for i in list(active):
+                seq = self.seqs[i]
+                if seq.cancelled:
+                    request = seq.request
+                    self._release(seq)
+                    self.seqs[i] = _Seq()
+                    active.remove(i)
+                    llm_metrics().requests_finished.inc(
+                        tags=dict(_TAGS, outcome="cancelled"))
+                    reqtrace.record(request.request_id,
+                                    reqtrace.CANCELLED, where="decode")
+                    callback = getattr(request, "_done_callback", None)
+                    if callback is not None:
+                        callback(request, None)  # None = cancelled
+            if self._continuous and active:
+                # lazy page growth (+ preemption under pressure) replaces
+                # the legacy upfront prompt+max_new reservation
+                active = self._ensure_decode_pages(active)
         if not active:
             return finished
-        trace = not reqtrace.reqtrace_disabled()
-        if trace:
-            # snapshot ids now: finished slots are reset before the
-            # compile delta is attributed below
-            trace_rids = [self.seqs[i].request.request_id
-                          for i in active]
-            compile_t0 = self._compile_total()
-        block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        tokens = np.zeros((B, 1), np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        top_ps = np.ones((B,), np.float32)
-        for i in active:
-            seq = self.seqs[i]
-            block_tables[i, :len(seq.pages)] = seq.pages
-            lengths[i] = seq.length
-            tokens[i, 0] = seq.last_token
-            temp = seq.request.temperature
-            temps[i] = temp if temp is not None else cfg.temperature
-            req_k = getattr(seq.request, "top_k", None)
-            top_ks[i] = req_k if req_k else 0
-            req_p = getattr(seq.request, "top_p", None)
-            top_ps[i] = req_p if req_p is not None else 1.0
-        self._rng, key = jax.random.split(self._rng)
+        with phase("stage"):
+            trace = not reqtrace.reqtrace_disabled()
+            if trace:
+                # snapshot ids now: finished slots are reset before the
+                # compile delta is attributed below
+                trace_rids = [self.seqs[i].request.request_id
+                              for i in active]
+                compile_t0 = self._compile_total()
+            block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            tokens = np.zeros((B, 1), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            top_ps = np.ones((B,), np.float32)
+            for i in active:
+                seq = self.seqs[i]
+                block_tables[i, :len(seq.pages)] = seq.pages
+                lengths[i] = seq.length
+                tokens[i, 0] = seq.last_token
+                temp = seq.request.temperature
+                temps[i] = temp if temp is not None else cfg.temperature
+                req_k = getattr(seq.request, "top_k", None)
+                top_ks[i] = req_k if req_k else 0
+                req_p = getattr(seq.request, "top_p", None)
+                top_ps[i] = req_p if req_p is not None else 1.0
+            self._rng, key = jax.random.split(self._rng)
         accel = self._accel
         timer = accel.StepTimer(
             "decode", tokens=len(active),
@@ -1336,63 +1381,79 @@ class PagedLLMEngine:
             with self._mesh_scope():
                 with (timer.device() if timer is not None
                       else contextlib.nullcontext()):
-                    out, self.k_pages, self.v_pages = self._decode(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.asarray(block_tables), jnp.asarray(lengths),
-                        jnp.asarray(tokens), key, jnp.asarray(temps),
-                        jnp.asarray(top_ks), jnp.asarray(top_ps))
-                    out = np.asarray(out)  # fences the dispatch
-            if trace:
-                compile_s = self._compile_total() - compile_t0
-                if compile_s > 1e-6:
-                    # every active request's wall clock contained the
-                    # stall — charge it to each (why_slow's compile
-                    # bucket, subtracted from its decode span)
-                    for rid in trace_rids:
-                        reqtrace.record(rid, reqtrace.COMPILE,
-                                        compile_s=round(compile_s, 6),
-                                        phase="decode")
-            for i in active:
-                seq = self.seqs[i]
-                token = int(out[i])
-                seq.generated.append(token)
-                seq.last_token = token
-                seq.length += 1
-                self._tokens_generated += 1
-                self._emit_token(seq, token)
-                request = seq.request
-                hit_eos = (cfg.eos_token is not None
-                           and token == cfg.eos_token)
-                # total includes tokens generated before a preemption
-                # (empty resume on the legacy arm and fresh sequences)
-                total_gen = len(seq.resume) + len(seq.generated)
-                capacity = len(seq.pages) * cfg.page_size
-                at_capacity = (not self._continuous
-                               and seq.length + 1 >= capacity)
-                if hit_eos \
-                        or total_gen >= request.max_new_tokens \
-                        or at_capacity \
-                        or seq.length >= cfg.max_len - 1:
-                    tokens = seq.resume + list(seq.generated)
-                    finished.append((request, tokens))
-                    callback = getattr(request, "_done_callback", None)
-                    if callback is not None:
-                        callback(request, tokens)
-                    self._release(seq)
-                    self.seqs[i] = _Seq()
-            metrics = llm_metrics()
-            metrics.token_latency.observe(time.monotonic() - tick_start,
-                                          tags=_TAGS)
-            metrics.decode_tokens.inc(len(active), tags=_TAGS)
-            for request, _tokens in finished:
-                metrics.requests_finished.inc(
-                    tags=dict(_TAGS, outcome="done"))
-                reqtrace.record(request.request_id, reqtrace.FINISHED,
-                                tokens=len(_tokens))
-                submit_ts = getattr(request, "_submit_ts", None)
-                if submit_ts is not None:
-                    metrics.request_latency.observe(
-                        time.monotonic() - submit_ts, tags=_TAGS)
+                    with phase("stage"):
+                        args = (jnp.asarray(block_tables),
+                                jnp.asarray(lengths), jnp.asarray(tokens),
+                                key, jnp.asarray(temps),
+                                jnp.asarray(top_ks), jnp.asarray(top_ps))
+                    with phase("dispatch"):
+                        out, self.k_pages, self.v_pages = self._decode(
+                            self.params, self.k_pages, self.v_pages,
+                            *args)
+                        # freed here, inside a phase, not after the last
+                        del args
+                    with phase("wait"):
+                        # fences the dispatch: the host blocks here for
+                        # this tick's un-fenced prefill chunk too
+                        out = np.asarray(out)
+            with phase("emit"):
+                if trace:
+                    compile_s = self._compile_total() - compile_t0
+                    if compile_s > 1e-6:
+                        # every active request's wall clock contained
+                        # the stall — charge it to each (why_slow's
+                        # compile bucket, subtracted from its decode
+                        # span)
+                        for rid in trace_rids:
+                            reqtrace.record(
+                                rid, reqtrace.COMPILE,
+                                compile_s=round(compile_s, 6),
+                                phase="decode")
+                for i in active:
+                    seq = self.seqs[i]
+                    token = int(out[i])
+                    seq.generated.append(token)
+                    seq.last_token = token
+                    seq.length += 1
+                    self._tokens_generated += 1
+                    self._emit_token(seq, token)
+                    request = seq.request
+                    hit_eos = (cfg.eos_token is not None
+                               and token == cfg.eos_token)
+                    # total includes tokens generated before a
+                    # preemption (empty resume on the legacy arm and
+                    # fresh sequences)
+                    total_gen = len(seq.resume) + len(seq.generated)
+                    capacity = len(seq.pages) * cfg.page_size
+                    at_capacity = (not self._continuous
+                                   and seq.length + 1 >= capacity)
+                    if hit_eos \
+                            or total_gen >= request.max_new_tokens \
+                            or at_capacity \
+                            or seq.length >= cfg.max_len - 1:
+                        tokens = seq.resume + list(seq.generated)
+                        finished.append((request, tokens))
+                        callback = getattr(request, "_done_callback",
+                                           None)
+                        if callback is not None:
+                            callback(request, tokens)
+                        self._release(seq)
+                        self.seqs[i] = _Seq()
+            with phase("gauges"):
+                metrics = llm_metrics()
+                metrics.token_latency.observe(
+                    time.monotonic() - tick_start, tags=_TAGS)
+                metrics.decode_tokens.inc(len(active), tags=_TAGS)
+                for request, _tokens in finished:
+                    metrics.requests_finished.inc(
+                        tags=dict(_TAGS, outcome="done"))
+                    reqtrace.record(request.request_id,
+                                    reqtrace.FINISHED,
+                                    tokens=len(_tokens))
+                    submit_ts = getattr(request, "_submit_ts", None)
+                    if submit_ts is not None:
+                        metrics.request_latency.observe(
+                            time.monotonic() - submit_ts, tags=_TAGS)
         return finished
 
     # -- conveniences ------------------------------------------------------
@@ -1414,8 +1475,7 @@ class PagedLLMEngine:
         return [results[i] for i in range(len(prompts))]
 
     def stats(self) -> Dict[str, Any]:
-        if self._step_accum is not None:
-            self._step_accum.flush()  # surfaces the partial window
+        self._flush_step_rows()  # surfaces the partial window
         cache_bytes = (2 * self.config.model.num_layers *
                        int(np.prod(self.k_pages[0].shape)) *
                        self.k_pages[0].dtype.itemsize)

@@ -10,9 +10,11 @@ clock into a per-process ring:
            -> PREEMPTED/PARKED -> RESUMED -> ...
            -> FINISHED | CANCELLED | FAILED
 
-plus ROUTED (proxy-side replica choice) and COMPILE (XLA compile stall
+plus ROUTED (proxy-side replica choice), COMPILE (XLA compile stall
 attributed to every request whose wall clock contained it, via the
-accel-plane compile-seconds tracker delta). Events carry the request id
+accel-plane compile-seconds tracker delta) and STREAMED (one per
+streamed request, when the replica's stream ends: polls, tokens and how
+long tokens lay in the replica before a poll took them). Events carry the request id
 the proxy accepts or generates (``X-RTPU-Request-Id``, echoed back on
 ndjson/SSE streams) and the optional tenant/route labels threaded down
 through router -> replica -> engine.
@@ -67,6 +69,11 @@ FINISHED = "FINISHED"
 CANCELLED = "CANCELLED"
 FAILED = "FAILED"
 COMPILE = "COMPILE"
+# replica-side account of a streamed request's token hand-off, stamped
+# on the replica's loop when its stream ends (around the engine's
+# terminal event, on either side); the folds below leave it out of the
+# request's extent
+STREAMED = "STREAMED"
 
 TERMINAL = frozenset({FINISHED, CANCELLED, FAILED})
 
@@ -234,7 +241,11 @@ def _buckets(rows: List[Dict[str, Any]], end: float,
     """Decompose one request's wall clock over [QUEUED, min(end, hi)]
     into queue / prefill_compute / park / decode / compile / other.
     ``hi=first_token_ts`` gives the TTFT decomposition; ``hi=None`` the
-    e2e one. Invariant: buckets sum to the clipped wall clock (other
+    e2e one. ``prefill_compute`` sums PREFILL_CHUNK ``dur_s``: host
+    wall of the chunk's call, which waits for the device only where the
+    event says ``fenced`` (a prompt's last chunk); every other chunk
+    reports its launch, and its device time is ``chunk_prefill`` in a
+    profiler trace. Invariant: buckets sum to the clipped wall clock (other
     absorbs scheduler gaps between prefill chunks and unmatched
     intervals)."""
     out = {"queue": 0.0, "prefill_compute": 0.0, "park": 0.0,
@@ -243,6 +254,7 @@ def _buckets(rows: List[Dict[str, Any]], end: float,
     state = "queue"          # queue | park | prefill | decode
     state_t0 = queued_ts
     window_total = 0.0       # prefill-window time (ADMITTED -> DECODE)
+    decode_compile = 0.0     # COMPILE stalls: inside the decode spans
 
     def close(until: float):
         nonlocal window_total
@@ -283,16 +295,18 @@ def _buckets(rows: List[Dict[str, Any]], end: float,
         elif event == COMPILE:
             dur = float(args.get("compile_s", 0.0))
             t0 = ts - dur
-            covered = _clip(t0, ts, hi)
-            out["compile"] += covered
-            # decode-phase compile stalls sit inside the decode span
-            out["decode"] -= min(out["decode"], covered)
+            decode_compile += _clip(t0, ts, hi)
     if state not in ("done",):
         close(end)
     # prefill-window time not spent computing or compiling is scheduler
     # interleave (decode ticks of OTHER requests sharing the engine)
     out["other"] += max(
         0.0, window_total - out["prefill_compute"] - out["compile"])
+    # decode-phase compile stalls sit inside the decode span, which is
+    # whole only now (the event precedes the span's close)
+    decode_compile = min(decode_compile, out["decode"])
+    out["decode"] -= decode_compile
+    out["compile"] += decode_compile
     for k in out:
         out[k] = round(out[k], 6)
     return out
@@ -305,6 +319,9 @@ def lifecycle(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
     # when the proxy's ROUTED precedes it, the routing gap is real
     # client-perceived latency and must land in the queue bucket —
     # otherwise the bucket sums drift from ttft_s/e2e_s by that gap.
+    # STREAMED is stamped when the replica's stream ends, which can be
+    # after the terminal event: it is not part of the request's extent
+    rows = [r for r in rows if r["event"] != STREAMED] or rows
     queued_ts = rows[0]["ts"]
     labels = {}
     outcome = None
@@ -390,7 +407,7 @@ def to_chrome_trace(payloads: List[Dict[str, Any]]
             elif event == COMPILE:
                 dur = float(args.get("compile_s", 0.0))
                 emit("xla_compile", ts - dur, ts, args)
-            elif event in (PREEMPTED, RESUMED, ROUTED) \
+            elif event in (PREEMPTED, RESUMED, ROUTED, STREAMED) \
                     or event in TERMINAL:
                 rows.append({
                     "name": event.lower(), "cat": "reqtrace",
@@ -406,8 +423,10 @@ def why_slow(request_id: str,
              payloads: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Latency attribution for one request: TTFT and e2e decomposed
     into queue / prefill-compute / park / decode / compile / other
-    seconds, next to the raw lifecycle events. A request-id PREFIX is
-    accepted when unambiguous."""
+    seconds, next to the raw lifecycle events. "prefill compute" is
+    host wall of the chunk calls: launch time for every chunk but the
+    ``fenced`` one (see ``_buckets``). A request-id PREFIX is accepted
+    when unambiguous."""
     by_rid = request_events(payloads)
     rows = by_rid.get(str(request_id))
     if rows is None:

@@ -19,14 +19,23 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 import uuid
 from typing import Any, Dict, List, Optional
+
+from . import reqtrace
 
 logger = logging.getLogger(__name__)
 
 
 class _Stream:
-    __slots__ = ("tokens", "event", "done", "error", "request_id")
+    """One streamed request's buffer between the engine's thread and the
+    proxy's long-polls, with the hand-off's own account: how long
+    tokens lay here before a poll took them (reqtrace STREAMED)."""
+
+    __slots__ = ("tokens", "event", "done", "error", "request_id",
+                 "oldest", "polls", "delivered", "hold_sum_s",
+                 "hold_max_s")
 
     def __init__(self, request_id: str):
         self.tokens: List[int] = []
@@ -34,6 +43,32 @@ class _Stream:
         self.done = False
         self.error: Optional[str] = None
         self.request_id = request_id
+        # time.monotonic() on the ENGINE's thread at the emit of the
+        # oldest token still in `tokens`
+        self.oldest = 0.0
+        self.polls = 0          # polls answered with tokens
+        self.delivered = 0      # tokens those polls took
+        self.hold_sum_s = 0.0   # over those polls: answer - oldest
+        self.hold_max_s = 0.0
+
+    def take(self) -> List[int]:
+        """Everything buffered, for one poll's answer."""
+        tokens, self.tokens = self.tokens, []
+        if tokens:
+            hold = time.monotonic() - self.oldest
+            self.polls += 1
+            self.delivered += len(tokens)
+            self.hold_sum_s += hold
+            self.hold_max_s = max(self.hold_max_s, hold)
+        return tokens
+
+    def close(self) -> None:
+        """The stream is over (its last poll answered, or cancelled):
+        one event per request — per token would overrun the ring."""
+        reqtrace.record(self.request_id, reqtrace.STREAMED,
+                        polls=self.polls, tokens=self.delivered,
+                        hold_sum_s=round(self.hold_sum_s, 6),
+                        hold_max_s=round(self.hold_max_s, 6))
 
 
 class LLMServer:
@@ -228,7 +263,11 @@ class LLMServer:
         self._streams[stream_id] = stream
 
         def on_token(request, token):
+            emitted = time.monotonic()  # on the engine's thread
+
             def _push():
+                if not stream.tokens:
+                    stream.oldest = emitted
                 stream.tokens.append(int(token))
                 stream.event.set()
             loop.call_soon_threadsafe(_push)
@@ -274,8 +313,8 @@ class LLMServer:
                 await asyncio.wait_for(stream.event.wait(), timeout_s)
             except asyncio.TimeoutError:
                 pass
-        tokens, stream.tokens = stream.tokens, []
-        done = stream.done and not stream.tokens
+        tokens = stream.take()
+        done = stream.done
         # every batch echoes the request id so clients can correlate
         # chunks (and why_slow the request) mid-stream
         out = {"tokens": tokens, "done": done,
@@ -284,12 +323,14 @@ class LLMServer:
             out["error"] = stream.error
         if done:
             self._streams.pop(stream_id, None)
+            stream.close()
         return out
 
     async def cancel_stream(self, stream_id: str) -> bool:
         stream = self._streams.pop(stream_id, None)
         if stream is None:
             return False
+        stream.close()
         return await self.cancel(stream.request_id)
 
     async def cancel(self, request_id: str) -> bool:
