@@ -282,8 +282,10 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
 
     device = {k: report[k] for k in ("platform", "kind", "count")}
     kernels = report["decode_kernels"]
+    pool_copies = report["decode_pool_copies"]
     say(f"smoke: serve[{label}] replica pid {report['pid']} on "
         f"{json.dumps(device)}; decode kernels {json.dumps(kernels)}; "
+        f"pool-shaped copies in the decode step {pool_copies}; "
         f"stats steps={stats['steps']} tokens={stats['tokens_generated']} "
         f"prefix_hits={stats['prefix_hits']} "
         f"leaked_pages={stats['leaked_pages']} tp={stats['tp']}")
@@ -295,6 +297,13 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
               f"compiled decode step holds {kernels} — expected the "
               f"paged-attention tpu_custom_call once per layer "
               f"({model.num_layers})")
+        # the token's K/V write and the kernel share the pool's layout:
+        # a copy shaped like a whole pool is the relayout that cost 38 %
+        # of the decode step (PERF.md, PR 29)
+        check(pool_copies == 0,
+              f"compiled decode step copies a whole page pool "
+              f"{pool_copies} times: the K/V write and the paged kernel "
+              f"no longer agree on the pool's layout")
     check(stats["leaked_pages"] == 0,
           f"{stats['leaked_pages']} leaked KV pages")
     compiles = report["compile"]

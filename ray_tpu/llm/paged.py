@@ -31,6 +31,7 @@ import dataclasses
 import functools
 import os
 import queue
+import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -97,6 +98,16 @@ def _param_init(cfg: LlamaConfig, mesh):
         return unbox(model.init(rng, sample)["params"])
 
     return jax.jit(init_params, out_shardings=pshard), pshard
+
+
+def pool_copies(compiled_text: str, pool_shape) -> int:
+    """`copy` ops of a compiled program (`compiled.as_text()`) whose
+    result has the shape of one whole page pool: each moves the pool to
+    another layout or memory. Indexed by (page, offset) alone, the decode
+    token's write cost four a layer a tick (PERF.md, PR 29)."""
+    dims = ",".join(map(str, pool_shape))
+    return len(re.findall(
+        rf"= \w+\[{dims}\]\S* copy(?:-done)?\(", compiled_text))
 
 
 class PagePool:
@@ -380,13 +391,9 @@ class PagedLLMEngine:
 
         self._gather_pages = jax.jit(gather_pages, donate_argnums=(2,))
 
-    def decode_program_text(self) -> str:
-        """Compiled text of the decode step at this engine's shapes: the
-        one-shot probe of what the backend was really handed (is the
-        Pallas paged-attention `tpu_custom_call` in it, or the gather
-        path?). Lowers from shapes alone, so the live page pools are
-        neither read nor donated; with a persistent compile cache the
-        compile is a hit."""
+    def lower_decode(self):
+        """The decode step lowered at this engine's shapes, from shapes
+        alone: the live page pools are neither read nor donated."""
         cfg = self.config
         B = cfg.max_batch
 
@@ -398,7 +405,7 @@ class PagedLLMEngine:
             return jax.ShapeDtypeStruct((B,) + shape, dtype)
 
         with self._mesh_scope():
-            lowered = self._decode.lower(
+            return self._decode.lower(
                 jax.tree_util.tree_map(like, self.params),
                 [like(p) for p in self.k_pages],
                 [like(p) for p in self.v_pages],
@@ -407,7 +414,21 @@ class PagedLLMEngine:
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
                 vec(jnp.float32),
                 vec(jnp.int32), vec(jnp.float32))
-        return lowered.compile().as_text()
+
+    def decode_program_text(self) -> str:
+        """Compiled text of the decode step: the one-shot probe of what
+        the backend was really handed (is the Pallas paged-attention
+        `tpu_custom_call` in it, or the gather path? is a page pool
+        relaid out?). With a persistent compile cache the compile is a
+        hit."""
+        return self.lower_decode().compile().as_text()
+
+    def pool_copies(self, compiled_text: str) -> int:
+        """Whole-pool copies (`pool_copies`) at this engine's pool shape
+        as one device holds it. The decode step must hold none."""
+        pool = self.k_pages[0]
+        return pool_copies(compiled_text,
+                           pool.sharding.shard_shape(pool.shape))
 
     def _mesh_scope(self):
         """Context for jit calls: marks the serving mesh active so the

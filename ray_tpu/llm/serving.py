@@ -393,8 +393,9 @@ class LLMServer:
                 "steps": accel.step_summary(),
             }
             if self._paged:
-                report["decode_kernels"] = pallas_kernels(
-                    self._engine.decode_program_text())
+                text = self._engine.decode_program_text()
+                report["decode_kernels"] = pallas_kernels(text)
+                report["decode_pool_copies"] = self._engine.pool_copies(text)
             return report
         # off-loop: the probe compiles, and a blocked loop fails the
         # replica's health check

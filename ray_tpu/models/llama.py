@@ -197,6 +197,25 @@ def _flash_on_mesh(q, k, v):
         out_specs=spec, check_vma=False)(q, k, v)
 
 
+def write_token_rows(pool, rows, block_tables, lengths):
+    """One decode token per batch row into a page pool.
+    pool [kv_heads, num_pages, page_size, head_dim]; rows [kv_heads, B,
+    head_dim]; row b lands at (block_tables[b, lengths[b] // page_size],
+    lengths[b] % page_size) of every kv head. The scatter indexes all
+    three leading dimensions, so its window is head_dim alone and is
+    contiguous in the pool's own layout, the paged kernel's: indexed by
+    (page, offset) with the kv heads as a window, XLA:TPU relays the whole
+    pool out and back around the scatter, every layer, every tick. Rows
+    that are not decoding write to the null page their table names and
+    may collide there."""
+    page_size = pool.shape[2]
+    batch = jnp.arange(rows.shape[1])
+    page_of = block_tables[batch, lengths // page_size]
+    offset = lengths % page_size
+    heads = jnp.arange(pool.shape[0])[:, None]
+    return pool.at[heads, page_of[None, :], offset[None, :]].set(rows)
+
+
 class Attention(nn.Module):
     config: LlamaConfig
 
@@ -242,16 +261,9 @@ class Attention(nn.Module):
             block_tables = kv_cache["block_tables"]
             lengths = kv_cache["lengths"]
             page_size = kp.shape[2]
-            B = q.shape[0]
-            rows = jnp.arange(B)
-            page_of = block_tables[rows, lengths // page_size]
-            offset = lengths % page_size
             # k,v are [B, kvh, 1, hd] -> write [kvh, B, hd] rows
             k_rows = jnp.transpose(k[:, :, 0, :], (1, 0, 2)).astype(kp.dtype)
             v_rows = jnp.transpose(v[:, :, 0, :], (1, 0, 2)).astype(vp.dtype)
-            kp = kp.at[:, page_of, offset, :].set(k_rows)
-            vp = vp.at[:, page_of, offset, :].set(v_rows)
-            new_cache = dict(kv_cache, k=kp, v=vp)
             q1 = q[:, :, 0, :]  # [B, heads, hd]
 
             def paged_kernel(q_, kp_, vp_, lengths_, tables_):
@@ -298,26 +310,39 @@ class Attention(nn.Module):
                 return jnp.einsum("bhk,bhkd->bhd", probs,
                                   gv.astype(jnp.float32))
 
+            def write_then_attend(q_, kp_, vp_, k_rows_, v_rows_, lengths_,
+                                  tables_):
+                """Per shard (LOCAL heads and kv heads; the whole arrays
+                when there is no tensor axis): the token's rows go into
+                the pools, then the kernel reads the pools."""
+                kp_ = write_token_rows(kp_, k_rows_, tables_, lengths_)
+                vp_ = write_token_rows(vp_, v_rows_, tables_, lengths_)
+                return (paged_kernel(q_, kp_, vp_, lengths_, tables_),
+                        kp_, vp_)
+
             # Tensor-parallel serving: when tracing under a serving mesh
-            # whose `tensor` axis is >1, run the kernel per-shard via
+            # whose `tensor` axis is >1, write and attend per-shard via
             # shard_map (heads/kv_heads sharded, attention is
             # head-parallel so no collectives). GSPMD cannot partition
-            # the Pallas custom call itself, hence the explicit map
+            # the Pallas custom call itself, and an index over the
+            # sharded kv-head dimension written outside the map could
+            # make it gather the pool, hence the explicit map
             # (reference places TP engine workers via
             # vllm_models.py:169-178; here TP is a mesh axis).
             pm = current_kernel_mesh()
             tp = int(pm.shape.get("tensor", 1)) if pm is not None else 1
+            step = write_then_attend
             if tp > 1:
-                from jax.sharding import PartitionSpec as _P
-                out1 = jax.shard_map(
-                    paged_kernel, mesh=pm,
-                    in_specs=(_P(None, "tensor", None), _P("tensor"),
-                              _P("tensor"), _P(None), _P(None, None)),
-                    out_specs=_P(None, "tensor", None),
-                    check_vma=False)(
-                        q1, kp, vp, lengths, block_tables)
-            else:
-                out1 = paged_kernel(q1, kp, vp, lengths, block_tables)
+                pool = PartitionSpec("tensor")
+                heads = PartitionSpec(None, "tensor", None)
+                whole = PartitionSpec()
+                step = jax.shard_map(
+                    write_then_attend, mesh=pm,
+                    in_specs=(heads, pool, pool, pool, pool, whole, whole),
+                    out_specs=(heads, pool, pool), check_vma=False)
+            out1, kp, vp = step(
+                q1, kp, vp, k_rows, v_rows, lengths, block_tables)
+            new_cache = dict(kv_cache, k=kp, v=vp)
             out = out1[:, :, None, :].astype(cfg.dtype)
         elif kv_cache is not None:
             # Decode: write new K/V at cache_index, attend over the cache.

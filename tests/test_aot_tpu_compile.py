@@ -11,6 +11,7 @@ hold no kernel at all and prove nothing.
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -161,6 +162,34 @@ def test_paged_decode_tensor_parallel_compiles_for_v5e(v5e, as_tpu):
     specs = _paged_specs(cfg, layer, 8, 512, 16,
                          lambda names: NamedSharding(mesh, P(*names)))
     assert _kernels(decode, *specs) == {"paged_attention": 1}
+
+
+@pytest.mark.parametrize("tensor", [1, 4])
+def test_paged_decode_relays_no_page_pool_out(v5e, as_tpu, tensor):
+    """The token's K/V write leaves the donated pools in the paged
+    kernel's layout: the compiled layer copies no whole pool (indexed by
+    (page, offset) alone, XLA:TPU copied K and V out to another layout
+    and back, every layer, every tick), on one chip and per shard. At
+    the serve cell's 2048 pages: a shard of a few MB the compiler may
+    stage through fast memory, which is a copy but no relayout."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.parallel import MeshConfig
+    mesh = MeshConfig(data=1, tensor=tensor).build(v5e) \
+        if tensor > 1 else None
+    cfg, layer, decode = _paged_decode_layer(mesh)
+    one = SingleDeviceSharding(v5e[0])
+    specs = _paged_specs(
+        cfg, layer, 8, 2048, 16,
+        lambda names: NamedSharding(mesh, P(*names)) if mesh else one)
+    compiled = jax.jit(decode, donate_argnums=(2, 3)).lower(
+        *specs).compile()
+    pool = (cfg.num_kv_heads // tensor, 2048, 16, cfg.head_dim_)
+    text = compiled.as_text()
+    assert "bf16[" + ",".join(map(str, pool)) + "]" in text
+    assert pool_copies(text, pool) == 0
+    # both pools still alias in place (bf16: two bytes an element)
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * 2 * math.prod(pool)
 
 
 def test_obvious_compile_holds_no_kernel(v5e):

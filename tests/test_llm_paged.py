@@ -227,3 +227,136 @@ def test_queue_pressure_admission_bounded_by_pages():
     assert len(out) == 10
     assert all(len(o) == 8 for o in out)
     assert paged.pool.num_free() >= 16 - 1 - 10  # prefix entries may pin
+
+
+# ---------------------------------------------------------------------------
+# the decode token's write into the page pool
+# ---------------------------------------------------------------------------
+
+_PS, _PAGES, _HD = 8, 12, 16   # page size, pages (page 0 the null page)
+
+# lengths per batch row, block tables per batch row. A row that is not
+# decoding has length 0 and a table of null pages.
+_WRITE_CASES = {
+    "offset_0": ([0, 16], [[3, 4, 5], [6, 7, 8]]),
+    "offset_last": ([_PS - 1, 2 * _PS - 1], [[3, 4, 5], [6, 7, 8]]),
+    "fresh_page": ([_PS, 2 * _PS], [[3, 9, 0], [6, 7, 11]]),
+    "null_page_collision": ([5, 0, 0], [[2, 0, 0], [0, 0, 0], [0, 0, 0]]),
+}
+
+
+def _numpy_write(pool, rows, tables, lengths):
+    """rows[h, b] to pool[h, page, offset], one element of the index at
+    a time; returns the pool and, per target, the batch rows aimed at it."""
+    out = pool.copy()
+    writers = {}
+    for b, n in enumerate(lengths):
+        target = (tables[b][n // _PS], n % _PS)
+        writers.setdefault(target, []).append(b)
+        for h in range(pool.shape[0]):
+            out[(h,) + target] = rows[h, b]
+    return out, writers
+
+
+def _assert_written(got, pool, rows, tables, lengths):
+    want, writers = _numpy_write(pool, rows, tables, lengths)
+    for (page, off), batch in writers.items():
+        if len(batch) > 1:
+            # colliding rows: any one of them may land, whole
+            for h in range(pool.shape[0]):
+                assert any(np.array_equal(got[h, page, off], rows[h, b])
+                           for b in batch)
+            want[:, page, off] = got[:, page, off]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 4])
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_decode_write_equals_numpy_write(case, kv_heads):
+    """`write_token_rows` against an index-by-index numpy write of the
+    same rows into a random bf16 pool: the rows land at (kv head,
+    table[len // ps], len % ps) and no other element changes."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import write_token_rows
+    lengths, tables = _WRITE_CASES[case]
+    rng = np.random.default_rng(len(case) + kv_heads)
+    pool = np.asarray(jnp.asarray(
+        rng.standard_normal((kv_heads, _PAGES, _PS, _HD)), jnp.bfloat16))
+    rows = np.asarray(jnp.asarray(
+        rng.standard_normal((kv_heads, len(lengths), _HD)), jnp.bfloat16))
+    got = jax.jit(write_token_rows)(
+        pool, rows, jnp.asarray(tables, jnp.int32),
+        jnp.asarray(lengths, jnp.int32))
+    assert got.dtype == jnp.bfloat16
+    _assert_written(np.asarray(got), pool, rows, tables, lengths)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 4])
+def test_paged_branch_writes_its_k_and_v_rows(kv_heads):
+    """Through the model's own paged-decode branch (32 query heads on 8
+    and on 4 kv heads): the K pool takes the rotated key rows and the V
+    pool the value rows, at the same places, and nothing else moves."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import (Attention, apply_rope,
+                                      rope_frequencies)
+    from ray_tpu.parallel.mesh import unbox
+    cfg = LlamaConfig(vocab_size=128, hidden_size=64, num_heads=32,
+                      num_kv_heads=kv_heads, head_dim=_HD, max_seq_len=64,
+                      dtype=jnp.float32, remat=False, use_flash=False,
+                      attention_impl="reference")
+    lengths, tables = [_PS - 1, _PS, 0, 0], \
+        [[3, 4, 5], [6, 7, 8], [0, 0, 0], [0, 0, 0]]
+    rng = np.random.default_rng(kv_heads)
+    x = jnp.asarray(rng.standard_normal((4, 1, 64)), jnp.float32)
+    positions = jnp.asarray(lengths, jnp.int32)[:, None]
+    layer = Attention(cfg)
+    params = unbox(layer.init(jax.random.PRNGKey(0), x, positions)["params"])
+    pools = {n: rng.standard_normal(
+        (kv_heads, _PAGES, _PS, _HD)).astype(np.float32) for n in "kv"}
+    cache = {"k": jnp.asarray(pools["k"]), "v": jnp.asarray(pools["v"]),
+             "block_tables": jnp.asarray(tables, jnp.int32),
+             "lengths": jnp.asarray(lengths, jnp.int32)}
+    _, new = layer.apply({"params": params}, x, positions, cache, None)
+
+    def rows(name, rotate):
+        r = jnp.einsum("bsd,dhk->bhsk", x, params[name]["kernel"])
+        if rotate:
+            r = apply_rope(r, *rope_frequencies(_HD, cfg.max_seq_len,
+                                                cfg.rope_theta), positions)
+        return np.asarray(jnp.transpose(r[:, :, 0, :], (1, 0, 2)))
+
+    for name, proj in (("k", "k_proj"), ("v", "v_proj")):
+        got = np.asarray(new[name])
+        want, _ = _numpy_write(pools[name], rows(proj, name == "k"),
+                               tables, lengths)
+        untouched = want == pools[name]
+        np.testing.assert_array_equal(got[untouched],
+                                      pools[name][untouched])
+        want[:, 0, 0] = got[:, 0, 0]     # two idle rows collide there
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        assert (got[:, 3, _PS - 1] != pools[name][:, 3, _PS - 1]).all()
+        assert (got[:, 7, 0] != pools[name][:, 7, 0]).all()
+
+
+def test_decode_program_scatters_by_head_page_offset(engines):
+    """The engine's decode step, lowered from shapes: every scatter into
+    a page pool indexes kv head, page and offset, so its window is
+    head_dim alone. Indexed by (page, offset) with the kv heads in the
+    window, XLA:TPU relays every layer's whole pool out and back around
+    the scatter each tick (PERF.md, PR 29)."""
+    import re
+    _slot, paged = engines
+    text = paged.lower_decode().as_text()
+    pool = "x".join(map(str, paged.k_pages[0].shape)) + "xbf16"
+    scatters = re.findall(
+        r'"stablehlo\.scatter".*?scatter_dimension_numbers = '
+        r'#stablehlo\.scatter<([^>]*)>.*?-> tensor<([^>]*)>', text, re.S)
+    into_pool = [dims for dims, result in scatters if result == pool]
+    assert len(into_pool) == 2 * paged.config.model.num_layers
+    for dims in into_pool:
+        assert "inserted_window_dims = [0, 1, 2]" in dims
+        assert "update_window_dims = [2]" in dims
