@@ -10,8 +10,8 @@ is the bar it sets). vs_baseline > 1.0 means above-target MFU.
 
 `python bench.py` needs a chip and exits non-zero without one: a number
 from the CPU is never printed under the chip metric's name. The
-`--multichip`, `--serve`, `--rpc` and `--dryrun7b` arms are host-plane A/Bs
-on (virtual) CPU devices by design.
+`--multichip`, `--rpc` and `--dryrun7b` arms are host-plane A/Bs on
+(virtual) CPU devices by design.
 """
 
 import json
@@ -23,7 +23,7 @@ if __name__ == "__main__":
     # Decided from the arguments, before jax is imported (it reads these
     # variables then): the CPU A/B arms hold this process to the CPU; the
     # chip cell points jax at the persistent compile cache.
-    if "--serve" in sys.argv or "--rpc" in sys.argv:
+    if "--rpc" in sys.argv:
         os.environ["JAX_PLATFORMS"] = "cpu"
     else:
         from ray_tpu.accelerators.tpu import compile_cache_dir
@@ -795,53 +795,6 @@ def _unboxed_init(model, rng, tokens):
     return unbox(model.init(rng, tokens)["params"])
 
 
-def serve_bench(out_path: str = "BENCH_serve_r01.json") -> dict:
-    """LLM serving headline (`bench.py --serve`): the in-process
-    continuous-batching vs RTPU_NO_CONT_BATCH legacy engine A/B plus
-    the radix shared-prefix arm — req/s, p50/p95 TTFT, prefill FLOPs
-    saved — recorded as a BENCH_serve JSON artifact. Also runs the
-    request-lifecycle tracing on/off A/B (same seed, same weights):
-    reqtrace overhead must stay within machine noise."""
-    from ray_tpu.perf_workloads import (reqtrace_overhead_ab,
-                                        serve_engine_ab)
-
-    ab = serve_engine_ab()
-    rab = reqtrace_overhead_ab()
-    result = {
-        "metric": "llm_serve_engine_ab",
-        "backend": jax.default_backend(),
-        "requests": ab["continuous"]["requests"],
-        "continuous": {k: ab["continuous"][k] for k in
-                       ("requests_per_s", "decode_tokens_per_s",
-                        "ttft_p50_s", "ttft_p95_s", "prefill_tokens",
-                        "preemptions", "leaked_pages")},
-        "legacy": {k: ab["legacy"][k] for k in
-                   ("requests_per_s", "decode_tokens_per_s",
-                    "ttft_p50_s", "ttft_p95_s", "prefill_tokens",
-                    "preemptions", "leaked_pages")},
-        "radix_shared_prefix": {
-            k: ab["radix_shared_prefix"][k] for k in
-            ("prefill_tokens", "prompt_tokens_submitted",
-             "prefill_tokens_saved_frac", "shared_prefix_hits")},
-        "reqtrace_ab": {
-            "on": {k: rab["reqtrace_on"][k] for k in
-                   ("requests_per_s", "decode_tokens_per_s",
-                    "ttft_p50_s", "ttft_p95_s")},
-            "off": {k: rab["reqtrace_off"][k] for k in
-                    ("requests_per_s", "decode_tokens_per_s",
-                     "ttft_p50_s", "ttft_p95_s")},
-            "gates": rab["gates"],
-        },
-        "gates": ab["gates"],
-        "passed": ab["passed"] and rab["passed"],
-    }
-    print(json.dumps(result))
-    if out_path:
-        with open(out_path, "w") as f:
-            json.dump(result, f, indent=1)
-    return result
-
-
 def rpc_transport_bench(out_path: str = "BENCH_rpc_r01.json") -> dict:
     """Transport-observatory overhead (`bench.py --rpc`): real-socket
     loopback echo with instrumentation on vs the RTPU_NO_RPC_METRICS
@@ -880,8 +833,6 @@ if __name__ == "__main__":
             gspmd_parity_dryrun()
     elif "--multichip" in sys.argv:
         multichip_ab(out_path="MULTICHIP_r06.json")
-    elif "--serve" in sys.argv:
-        serve_bench()
     elif "--rpc" in sys.argv:
         rpc_transport_bench()
     else:

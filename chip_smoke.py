@@ -362,11 +362,8 @@ def phase_serve_tensor_parallel(plan: Plan) -> Dict[str, Any]:
 
     def compare(ask, prompts):
         free = {k: ask(prompts[k], n_new)["tokens"] for k in ("a", "b")}
-        # two tokens asked, the first compared: a fresh request's finish
-        # conditions are first applied on the decode tick, so
-        # max_new_tokens=1 returns two (engine quirk, ROADMAP D2)
         forced = concurrently([
-            (lambda k=k, i=i: ask(prompts[k] + expected[k][:i], 2)
+            (lambda k=k, i=i: ask(prompts[k] + expected[k][:i], 1)
              ["tokens"][0])
             for k in ("a", "b") for i in range(n_new)])
         want = [t for k in ("a", "b") for t in expected[k]]

@@ -352,10 +352,6 @@ _DEFAULTS: Dict[str, Any] = {
     # constructed, nothing piggybacks on the metrics flush —
     # exact-legacy behavior with zero rings and zero extra threads.
     "no_reqtrace": False,
-    # Kill switch for continuous batching in the paged LLM engine:
-    # exact-legacy per-drain admission (blocking inline prefill, upfront
-    # page reservation, token-tuple prefix LRU, no preemption).
-    "no_cont_batch": False,
     # Kill switch for the RPC/transport observatory: zero rpc/ring/chaos
     # series constructed, no slow-RPC watchdog ring, no frame-meta trace
     # propagation — exact-legacy frames on the wire, so mixed on/off

@@ -10,7 +10,7 @@ vs the slot engine (`engine.py`): HBM scales with tokens-in-flight
 shared byte-identically across requests via a prefix hash (system
 prompts stored once); admission blocks on page budget, not slot shape.
 
-Scheduling is CONTINUOUS (iteration-level) by default: every tick fills
+Scheduling is continuous (iteration-level): every tick fills
 freed slots from the waiting queue, advances at most
 `prefill_decode_ratio` chunked-prefill chunks interleaved with the
 decode batch, and under page pressure preempts the youngest sequence
@@ -18,9 +18,7 @@ decode batch, and under page pressure preempts the youngest sequence
 tokens as a prompt extension) instead of exhausting the pool. Prefix
 reuse rides a radix tree over KV pages (`radix.py`): admission maps the
 longest cached prefix copy-on-write into the block table and prefills
-only the tail. RTPU_NO_CONT_BATCH=1 is the exact-legacy per-drain A/B
-arm (blocking inline prefill, upfront page reservation, token-tuple
-prefix LRU, no preemption).
+only the tail.
 """
 
 from __future__ import annotations
@@ -50,11 +48,6 @@ from .radix import RadixPrefixCache
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
 _GAUGE_TAGS = {"engine": "paged", "pid": str(os.getpid())}
-
-
-def _no_phase(_name: str):
-    """`StepTimer.phase` for a tick that is not split."""
-    return contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -215,32 +208,17 @@ class PagedLLMEngine:
         self.k_pages = [_zero_pages() for _ in range(cfg.num_layers)]
         self.v_pages = [_zero_pages() for _ in range(cfg.num_layers)]
         self.pool = PagePool(P)
-        # scheduling mode: continuous (per-tick admission, chunked
-        # prefill interleave, preemption, radix prefix tree) unless the
-        # exact-legacy kill switch is set. Read once — a mode is an
-        # engine-lifetime property, not a per-tick branch.
-        self._continuous = not CONFIG.no_cont_batch
-        self.radix: Optional[RadixPrefixCache] = None
-        if self._continuous:
-            self.radix = RadixPrefixCache(
-                self.pool, ps,
-                max_entries=int(CONFIG.prefix_cache_entries))
-        # waiting queue (continuous mode): _pending is the thread-safe
-        # ingress; the tick drains it into _parked, which also receives
-        # preempted requests at its FRONT (they re-admit first)
+        self.radix = RadixPrefixCache(
+            self.pool, ps, max_entries=int(CONFIG.prefix_cache_entries))
+        # waiting queue: _pending is the thread-safe ingress; the tick
+        # drains it into _parked, which also receives preempted requests
+        # at its FRONT (they re-admit first)
         self._parked: "collections.deque" = collections.deque()
         self._admit_clock = 0
         self._preemptions = 0
         # recent TTFTs feed autoscaling_metrics() (median over a window)
         self._recent_ttfts: "collections.deque" = collections.deque(
             maxlen=64)
-        # prefix cache: hash(token-prefix through page k) -> per-layer page
-        self.prefix_pages: Dict[Tuple, List[int]] = {}
-        # true LRU: ordered keys, O(1) move-to-end on hit / popitem on
-        # evict (the old list.pop(0) was an O(n) shift and hits never
-        # refreshed recency — a hot system prompt aged out under churn)
-        self._prefix_lru: "collections.OrderedDict[Tuple, None]" = \
-            collections.OrderedDict()
         self._prefix_hits = 0
         self._prefix_misses = 0
         self.seqs: List[_Seq] = [_Seq() for _ in range(config.max_batch)]
@@ -453,6 +431,7 @@ class PagedLLMEngine:
         reqtrace.record(request.request_id, reqtrace.QUEUED,
                         engine="paged", prompt_tokens=n,
                         max_new=request.max_new_tokens,
+                        prefilled=hasattr(request, "_prefilled") or None,
                         tenant=getattr(request, "tenant", None),
                         route=getattr(request, "route", None))
         self._pending.put(request)
@@ -467,21 +446,10 @@ class PagedLLMEngine:
         (prefill/decode disaggregation): `dense_caches` are per-layer
         (k, v) arrays trimmed to the prompt's pages, `last_logits` the
         prompt's final-position logits. Admission (page budget, prefix
-        sharing) happens on the normal scheduler tick."""
-        n = len(request.prompt_tokens)
-        if n >= self.config.max_len:
-            raise ValueError("prompt longer than max_len")
-        request._done_callback = done_callback  # type: ignore
-        request._token_callback = token_callback  # type: ignore
-        request._submit_ts = time.monotonic()  # type: ignore
-        reqtrace.record(request.request_id, reqtrace.QUEUED,
-                        engine="paged", prompt_tokens=n,
-                        max_new=request.max_new_tokens, prefilled=True,
-                        tenant=getattr(request, "tenant", None),
-                        route=getattr(request, "route", None))
-        self._pending.put((request, dense_caches, last_logits))
-        llm_metrics().queue_depth.set(self._pending.qsize(),
-                                      tags=_GAUGE_TAGS)
+        sharing) happens on the normal scheduler tick, which installs
+        them where a local prefill would have finished its last chunk."""
+        request._prefilled = (dense_caches, last_logits)  # type: ignore
+        self.submit(request, done_callback, token_callback)
 
     def cancel(self, request_id: str) -> bool:
         """Abort a request: frees its slot+pages on the next tick if
@@ -491,44 +459,33 @@ class PagedLLMEngine:
             seq.cancelled = True
             return True
         # queued: rebuild the queue without it
-        kept, found = [], False
+        kept = []
         dropped = None
         try:
             while True:
-                entry = self._pending.get_nowait()
-                r = entry[0] if isinstance(entry, tuple) else entry
-                if r.request_id == request_id and not found:
-                    found = True
-                    dropped = r
+                request = self._pending.get_nowait()
+                if request.request_id == request_id and dropped is None:
+                    dropped = request
                     continue
-                kept.append(entry)
+                kept.append(request)
         except queue.Empty:
             pass
-        for r in kept:
-            self._pending.put(r)
-        if not found:
-            # continuous-mode waiting queue (drained arrivals and
-            # preemption-parked requests)
-            for entry in list(self._parked):
-                r = entry[0] if isinstance(entry, tuple) else entry
-                if r.request_id == request_id:
+        for request in kept:
+            self._pending.put(request)
+        if dropped is None:
+            # drained arrivals and preemption-parked requests
+            for request in list(self._parked):
+                if request.request_id == request_id:
                     try:
-                        self._parked.remove(entry)
+                        self._parked.remove(request)
                     except ValueError:
                         break  # admitted concurrently
-                    found = True
-                    dropped = r
+                    dropped = request
                     break
         if dropped is not None:
             # queued cancellations must still resolve their waiters
-            llm_metrics().requests_finished.inc(
-                tags=dict(_TAGS, outcome="cancelled"))
-            reqtrace.record(dropped.request_id, reqtrace.CANCELLED,
-                            where="queued")
-            callback = getattr(dropped, "_done_callback", None)
-            if callback is not None:
-                callback(dropped, None)  # None = cancelled
-        return found
+            self._end_request(dropped, None, where="queued")
+        return dropped is not None
 
     def has_work(self) -> bool:
         return (not self._pending.empty()) or bool(self._parked) or \
@@ -539,50 +496,47 @@ class PagedLLMEngine:
         serving drive loop calls this when step() raises — callers must
         see the failure, not hang on a silently-spinning engine)."""
         for i, seq in enumerate(self.seqs):
-            if seq.request is None:
-                continue
-            request = seq.request
-            self._release(seq)
-            self.seqs[i] = _Seq()
-            llm_metrics().requests_finished.inc(
-                tags=dict(_TAGS, outcome="error"))
-            reqtrace.record(request.request_id, reqtrace.FAILED,
-                            error=type(error).__name__)
-            callback = getattr(request, "_done_callback", None)
-            if callback is not None:
-                callback(request, error)
+            if seq.request is not None:
+                self._end_request(seq.request, error, index=i)
         self._drain_pending()
         while self._parked:
-            entry = self._parked.popleft()
-            r = entry[0] if isinstance(entry, tuple) else entry
-            llm_metrics().requests_finished.inc(
-                tags=dict(_TAGS, outcome="error"))
-            reqtrace.record(r.request_id, reqtrace.FAILED,
-                            error=type(error).__name__)
-            callback = getattr(r, "_done_callback", None)
-            if callback is not None:
-                callback(r, error)
+            self._end_request(self._parked.popleft(), error)
+
+    def _end_request(self, request: GenerationRequest, result,
+                     index: Optional[int] = None,
+                     where: Optional[str] = None):
+        """The one way a request leaves the engine. `result` is what its
+        waiter receives and names the outcome: the tokens (done), None
+        (cancelled, `where` it was) or the exception (error). `index` is
+        the slot it holds, if any: pages released, slot reset."""
+        if index is not None:
+            self._release(self.seqs[index])
+            self.seqs[index] = _Seq()
+        metrics = llm_metrics()
+        if result is None:
+            outcome = "cancelled"
+            reqtrace.record(request.request_id, reqtrace.CANCELLED,
+                            where=where)
+        elif isinstance(result, Exception):
+            outcome = "error"
+            reqtrace.record(request.request_id, reqtrace.FAILED,
+                            error=type(result).__name__)
+        else:
+            outcome = "done"
+            reqtrace.record(request.request_id, reqtrace.FINISHED,
+                            tokens=len(result))
+            submit_ts = getattr(request, "_submit_ts", None)
+            if submit_ts is not None:
+                metrics.request_latency.observe(
+                    time.monotonic() - submit_ts, tags=_TAGS)
+        metrics.requests_finished.inc(tags=dict(_TAGS, outcome=outcome))
+        callback = getattr(request, "_done_callback", None)
+        if callback is not None:
+            callback(request, result)
 
     # -- scheduler tick ----------------------------------------------------
 
     def step(self) -> List[Tuple[GenerationRequest, Any]]:
-        if self._continuous:
-            return self._step_continuous()
-        self._admit()
-        finished = []
-        active = [i for i, s in enumerate(self.seqs)
-                  if s.request is not None]
-        if active:
-            finished.extend(self._decode_tick(active))
-        elif self._step_accum is not None:
-            # idle tick: flush the partial window so step telemetry
-            # never lags a drained engine by up to `every` ticks
-            self._step_accum.flush()
-        self._steps += 1
-        self._set_gauges()
-        return finished
-
-    def _step_continuous(self) -> List[Tuple[GenerationRequest, Any]]:
         """One continuous-batching tick: reap cancellations, fill freed
         slots from the waiting queue (radix prefix match, tail-only
         prefill setup), advance bounded chunked prefill, then decode the
@@ -602,13 +556,13 @@ class PagedLLMEngine:
             with tick.phase("reap"):
                 self._reap_cancelled()
             with tick.phase("admit"):
-                self._admit_continuous()
+                self._admit(finished)
             with tick.phase("prefill"):
                 self._prefill_tick(finished)
                 active = [i for i, s in enumerate(self.seqs)
                           if s.request is not None and s.phase == "decode"]
             if active:
-                finished.extend(self._decode_tick(active, tick.phase))
+                self._decode_tick(active, tick.phase, finished)
             with tick.phase("gauges"):
                 self._steps += 1
                 self._set_gauges()
@@ -641,30 +595,17 @@ class PagedLLMEngine:
         metrics.kv_occupancy.set(self.config.num_pages - 1 - free,
                                  tags=_GAUGE_TAGS)
         metrics.waiting.set(self._waiting_count(), tags=_GAUGE_TAGS)
-        if self.radix is not None:
-            shared = self.radix.shared_pages()
-        else:
-            shared = sum(1 for p in self.prefix_pinned_pages()
-                         if self.pool.refs[p] > 1)
-        metrics.shared_pages.set(shared, tags=_GAUGE_TAGS)
+        metrics.shared_pages.set(self.radix.shared_pages(),
+                                 tags=_GAUGE_TAGS)
 
     def _reap_cancelled(self):
         """Release cancelled sequences in ANY phase (a mid-prefill
         cancel must return its pages too) before admission reuses the
         slots."""
         for i, seq in enumerate(self.seqs):
-            if seq.request is None or not seq.cancelled:
-                continue
-            request = seq.request
-            self._release(seq)
-            self.seqs[i] = _Seq()
-            llm_metrics().requests_finished.inc(
-                tags=dict(_TAGS, outcome="cancelled"))
-            reqtrace.record(request.request_id, reqtrace.CANCELLED,
-                            where=seq.phase)
-            callback = getattr(request, "_done_callback", None)
-            if callback is not None:
-                callback(request, None)  # None = cancelled
+            if seq.request is not None and seq.cancelled:
+                self._end_request(seq.request, None, index=i,
+                                  where=seq.phase)
 
     def _drain_pending(self):
         try:
@@ -672,10 +613,6 @@ class PagedLLMEngine:
                 self._parked.append(self._pending.get_nowait())
         except queue.Empty:
             pass
-
-    def _next_admit_id(self) -> int:
-        self._admit_clock += 1
-        return self._admit_clock
 
     # -- park bookkeeping (request observatory + park histogram) ---------
 
@@ -716,64 +653,51 @@ class PagedLLMEngine:
                                 parked_s=round(parked, 6))
         return getattr(request, "_rt_park_total", 0.0)
 
-    def _admit_continuous(self):
+    def _admit(self, finished: List):
         self._drain_pending()
         for index, seq in enumerate(self.seqs):
             if seq.request is not None:
                 continue
             if not self._parked:
                 return
-            entry = self._parked.popleft()
-            prefilled = isinstance(entry, tuple)
-            request = entry[0] if prefilled else entry
+            request = self._parked.popleft()
             try:
-                if prefilled:
-                    # disaggregated prefill: the KV arrives whole, so
-                    # this admission reserves pages up front (legacy
-                    # budget), prefix machinery still rides the radix
-                    need = self._pages_needed(request)
-                    if self.pool.num_free() < need and \
-                            self.radix is not None:
-                        self.radix.evict_pages(
-                            need - self.pool.num_free())
-                    if self.pool.num_free() < need:
-                        self._park_note(request, "no_pages")
-                        self._parked.appendleft(entry)
-                        return
-                    self._admit_prefilled(index, request, entry[1],
-                                          entry[2])
-                elif not self._begin_prefill(index, request):
+                if not self._begin_prefill(index, request):
                     self._park_note(request, "no_pages")
-                    self._parked.appendleft(entry)
+                    self._parked.appendleft(request)
                     return
+                shipped = getattr(request, "_prefilled", None)
+                if shipped is None:
+                    self._stage_prefill_cache(seq)
+                else:
+                    # prefilled elsewhere (`submit_prefilled`): enters
+                    # where a local prefill would have finished its last
+                    # chunk; after a preemption it re-prefills locally
+                    del request._prefilled
+                    caches, seq.last_logits = shipped
+                    seq.dense_caches = [(jnp.asarray(k), jnp.asarray(v))
+                                        for (k, v) in caches]
+                    seq.prefill_off = len(seq.prompt)
+                    self._finish_prefill(index, finished)
             except Exception as e:  # noqa: BLE001
-                llm_metrics().requests_finished.inc(
-                    tags=dict(_TAGS, outcome="error"))
-                reqtrace.record(request.request_id, reqtrace.FAILED,
-                                error=type(e).__name__)
-                callback = getattr(request, "_done_callback", None)
-                if callback is not None:
-                    callback(request, e)
+                held = self.seqs[index].request is request
+                self._end_request(request, e, index if held else None)
 
     def _begin_prefill(self, index: int,
                        request: GenerationRequest) -> bool:
         """Admit a request into the prefill phase: radix-match the
         longest cached prefix (mapped copy-on-write into the block
-        table), allocate only the tail prompt pages, and gather the
-        shared span into the dense chunk cache so the tail attends over
-        it without recomputing. Returns False when pages are short even
-        after pressure eviction (caller re-parks the request)."""
-        cfg = self.config
-        ps = cfg.page_size
+        table) and allocate only the tail prompt pages. Returns False
+        when pages are short even after pressure eviction (caller
+        re-parks the request)."""
+        ps = self.config.page_size
         resume = list(getattr(request, "_resume_tokens", []))
         prompt = list(request.prompt_tokens) + resume
         shared = self._match_prefix(prompt)
         n_prompt_pages = -(-len(prompt) // ps)
         tail_pages = n_prompt_pages - len(shared)
         if self.pool.num_free() < tail_pages:
-            if self.radix is not None:
-                self.radix.evict_pages(
-                    tail_pages - self.pool.num_free())
+            self.radix.evict_pages(tail_pages - self.pool.num_free())
             if self.pool.num_free() < tail_pages:
                 for page in shared:
                     self.pool.decref(page)
@@ -783,13 +707,6 @@ class PagedLLMEngine:
             page = self.pool.alloc()
             assert page is not None, "budget checked above"
             new_ids.append(page)
-        with self._mesh_scope():
-            dense = self._dense_zero_caches()
-            if shared:
-                pad = np.zeros(cfg.pages_per_seq, np.int32)
-                pad[:len(shared)] = shared
-                dense = self._gather_pages(self.k_pages, self.v_pages,
-                                           dense, jnp.asarray(pad))
         seq = self.seqs[index]
         seq.request = request
         seq.prompt = prompt
@@ -802,9 +719,10 @@ class PagedLLMEngine:
         seq.last_token = 0
         seq.cancelled = False
         seq.prefill_off = len(shared) * ps
-        seq.dense_caches = dense
+        seq.dense_caches = None
         seq.last_logits = None
-        seq.admit_at = self._next_admit_id()
+        self._admit_clock += 1
+        seq.admit_at = self._admit_clock
         self._by_id[request.request_id] = seq
         self._unpark_note(request)
         reqtrace.record(request.request_id, reqtrace.ADMITTED,
@@ -812,6 +730,19 @@ class PagedLLMEngine:
                         tail_pages=tail_pages,
                         resume_tokens=len(resume) or None)
         return True
+
+    def _stage_prefill_cache(self, seq: _Seq):
+        """The dense chunk cache of a sequence about to prefill, with
+        its shared span gathered in so the tail attends over it without
+        recomputing."""
+        with self._mesh_scope():
+            dense = self._dense_zero_caches()
+            if seq.own_from:
+                pad = np.zeros(self.config.pages_per_seq, np.int32)
+                pad[:seq.own_from] = seq.pages[:seq.own_from]
+                dense = self._gather_pages(self.k_pages, self.v_pages,
+                                           dense, jnp.asarray(pad))
+        seq.dense_caches = dense
 
     def _prefill_tick(self, finished: List):
         """Advance at most `prefill_decode_ratio` prefill chunks,
@@ -841,8 +772,8 @@ class PagedLLMEngine:
 
     def _prefill_chunk(self, seq: _Seq):
         """One bucket-rounded chunk of `seq`'s remaining prompt into its
-        dense cache (same program as the legacy inline prefill — one
-        compiled shape per bucket)."""
+        dense cache — one compiled shape per bucket, whatever the
+        prompt's length."""
         cfg = self.config
         prompt = seq.prompt
         largest = cfg.prefill_buckets[-1]
@@ -877,7 +808,6 @@ class PagedLLMEngine:
                 compile_s=round(
                     self._compile_total() - compile_t0, 6) or None)
         # counts COMPUTED tokens only — a radix-shared span costs zero
-        # here, which is exactly the prefill-FLOPs win the A/B measures
         llm_metrics().prefill_tokens.inc(take, tags=_TAGS)
 
     def _write_owned_pages(self, dense_caches, write_ids, start_page):
@@ -898,8 +828,6 @@ class PagedLLMEngine:
         """Prompt fully cached: write the owned tail pages, commit full
         pages to the radix, sample the first token from the prefill
         logits, and move the sequence to the decode phase."""
-        cfg = self.config
-        ps = cfg.page_size
         seq = self.seqs[index]
         request = seq.request
         prompt = seq.prompt
@@ -916,12 +844,11 @@ class PagedLLMEngine:
         seq.generated = [first_token]
         seq.last_token = first_token
         self._tokens_generated += 1
-        metrics = llm_metrics()
         submit_ts = getattr(request, "_submit_ts", None)
         park_s = getattr(request, "_rt_park_total", 0.0)
         if submit_ts is not None and not seq.resume:
             ttft = time.monotonic() - submit_ts
-            metrics.ttft.observe(ttft, tags=_TAGS)
+            llm_metrics().ttft.observe(ttft, tags=_TAGS)
             self._recent_ttfts.append(ttft)
             # the DECODE stamp splits a parked request's TTFT: park_s
             # is the admission-blocked share, the rest is real prefill
@@ -933,37 +860,31 @@ class PagedLLMEngine:
                             resumed=True,
                             park_s=round(park_s, 6) or None)
         self._emit_token(seq, first_token)
-        if seq.resume:
-            # a resumed sequence may hit its budget/eos on the token the
-            # tail prefill just produced — apply the decode-tick finish
-            # conditions here so resume never overshoots the unpreempted
-            # run (token parity)
-            hit_eos = (cfg.eos_token is not None
-                       and first_token == cfg.eos_token)
-            total = len(seq.resume) + len(seq.generated)
-            if hit_eos or total >= request.max_new_tokens \
-                    or seq.length >= cfg.max_len - 1:
-                tokens = seq.resume + list(seq.generated)
-                finished.append((request, tokens))
-                callback = getattr(request, "_done_callback", None)
-                if callback is not None:
-                    callback(request, tokens)
-                self._release(seq)
-                self.seqs[index] = _Seq()
-                metrics.requests_finished.inc(
-                    tags=dict(_TAGS, outcome="done"))
-                reqtrace.record(request.request_id, reqtrace.FINISHED,
-                                tokens=len(tokens))
-                if submit_ts is not None:
-                    metrics.request_latency.observe(
-                        time.monotonic() - submit_ts, tags=_TAGS)
+        if self._finished_after(seq, first_token):
+            finished.append(self._finish(index))
+
+    def _finished_after(self, seq: _Seq, token: int) -> bool:
+        """Whether `token`, just emitted, was `seq`'s last: EOS, the
+        request's budget (tokens from before a preemption count), or
+        the engine's length cap."""
+        cfg = self.config
+        generated = len(seq.resume) + len(seq.generated)
+        return (cfg.eos_token is not None and token == cfg.eos_token) \
+            or generated >= seq.request.max_new_tokens \
+            or seq.length >= cfg.max_len - 1
+
+    def _finish(self, index: int) -> Tuple[GenerationRequest, List[int]]:
+        seq = self.seqs[index]
+        request = seq.request
+        tokens = seq.resume + seq.generated
+        self._end_request(request, tokens, index=index)
+        return request, tokens
 
     def _alloc_page(self) -> Optional[int]:
         """Allocate with radix pressure relief: cold unshared prefix
         pages are reclaimed before giving up."""
         page = self.pool.alloc()
-        if page is None and self.radix is not None \
-                and self.radix.evict_pages(1):
+        if page is None and self.radix.evict_pages(1):
             page = self.pool.alloc()
         return page
 
@@ -1013,78 +934,11 @@ class PagedLLMEngine:
         self._preemptions += 1
         llm_metrics().preemptions.inc(tags=dict(_TAGS, reason=reason))
 
-    def _pages_needed(self, request: GenerationRequest) -> int:
-        total = len(request.prompt_tokens) + request.max_new_tokens
-        return -(-min(total + 1, self.config.max_len)
-                 // self.config.page_size)
-
-    def _admit(self):
-        for index, seq in enumerate(self.seqs):
-            if seq.request is not None:
-                continue
-            try:
-                entry = self._pending.get_nowait()
-            except queue.Empty:
-                return
-            # plain request (local prefill) or (request, caches, logits)
-            # from submit_prefilled (disaggregated prefill)
-            prefilled = isinstance(entry, tuple)
-            request = entry[0] if prefilled else entry
-            if self.pool.num_free() < self._pages_needed(request):
-                # page budget exhausted: requeue and stop admitting —
-                # decode completions will free pages
-                self._pending.put(entry)
-                return
-            try:
-                if prefilled:
-                    self._admit_prefilled(index, request, entry[1],
-                                          entry[2])
-                else:
-                    self._prefill_into(index, request)
-            except Exception as e:  # noqa: BLE001
-                llm_metrics().requests_finished.inc(
-                    tags=dict(_TAGS, outcome="error"))
-                reqtrace.record(request.request_id, reqtrace.FAILED,
-                                error=type(e).__name__)
-                callback = getattr(request, "_done_callback", None)
-                if callback is not None:
-                    callback(request, e)
-
     def _bucket(self, n: int) -> int:
         for b in self.config.prefill_buckets:
             if n <= b:
                 return b
         return self.config.prefill_buckets[-1]
-
-    def _run_chunked_prefill(self, prompt: List[int]):
-        """Prefill the whole prompt in bucket-sized chunks against a dense
-        per-request cache; returns (last_token_logits, dense_caches). One
-        compiled program per bucket size, regardless of prompt length."""
-        with self._mesh_scope():
-            caches = self._dense_zero_caches()
-            largest = self.config.prefill_buckets[-1]
-            off = 0
-            last_logits = None
-            while off < len(prompt):
-                rem = len(prompt) - off
-                chunk = self._bucket(min(rem, largest))
-                take = min(rem, chunk)
-                tokens = np.zeros((1, chunk), np.int32)
-                tokens[0, :take] = prompt[off:off + take]
-                # pad positions clamp to the rope table; their garbage K/V
-                # lands past the prompt and is never copied to pages
-                positions = np.minimum(
-                    np.arange(off, off + chunk, dtype=np.int32),
-                    self.config.model.max_seq_len - 1)[None, :]
-                logits, caches = self._chunk_prefill(
-                    self.params, jnp.asarray(tokens),
-                    jnp.asarray(positions), caches,
-                    jnp.asarray(off, jnp.int32))
-                if off + take == len(prompt):
-                    last_logits = np.asarray(  # host-sync ok: once per prompt, scoring path
-                        logits[0, take - 1], np.float64)
-                off += take
-            return last_logits, caches
 
     def prefill_only(self, prompt: List[int]):
         """Run chunked prefill WITHOUT admitting a sequence: returns
@@ -1093,81 +947,15 @@ class PagedLLMEngine:
         disaggregation (reference:
         llm/_internal/serve/deployments/prefill_decode_disagg/) — the KV
         ships to a decode engine's `submit_prefilled`."""
-        last_logits, caches = self._run_chunked_prefill(prompt)
+        seq = _Seq(prompt=list(prompt))
+        self._stage_prefill_cache(seq)
+        while seq.prefill_off < len(seq.prompt):
+            self._prefill_chunk(seq)
         n_tok = -(-len(prompt) // self.config.page_size) * \
             self.config.page_size
         out = [(np.asarray(k[:, :, :n_tok]), np.asarray(v[:, :, :n_tok]))
-               for (k, v) in caches]
-        return last_logits, out
-
-    def _prefill_into(self, index: int, request: GenerationRequest):
-        # chunked dense prefill of the whole prompt (compute), paged
-        # storage — prompts run to max_len, not the largest bucket
-        last_logits, dense_caches = self._run_chunked_prefill(
-            request.prompt_tokens)
-        self._admit_prefilled(index, request, dense_caches, last_logits)
-
-    def _admit_prefilled(self, index: int, request: GenerationRequest,
-                         dense_caches, last_logits):
-        """Install an already-prefilled request: page allocation, prefix
-        sharing/registration, first-token pick, sequence setup.
-        `dense_caches` may be numpy (shipped from a prefill server) or
-        on-device arrays (local prefill)."""
-        cfg = self.config
-        prompt = request.prompt_tokens
-        ps = cfg.page_size
-        dense_caches = [(jnp.asarray(k), jnp.asarray(v))
-                        for (k, v) in dense_caches]
-        # 1. prefix reuse: full pages whose token prefix is already pooled
-        shared = self._match_prefix(prompt)
-        n_pages = self._pages_needed(request)
-        new_ids = []
-        for _ in range(n_pages - len(shared)):
-            page = self.pool.alloc()
-            assert page is not None, "admission checked the budget"
-            new_ids.append(page)
-        # write only non-shared pages holding PROMPT tokens (shared ones
-        # are byte-identical by construction; generation-room pages are
-        # filled token-by-token at decode — and a disaggregated prefill
-        # ships a cache trimmed to exactly the prompt pages)
-        n_prompt_pages = -(-len(prompt) // ps)
-        write_ids = new_ids[:max(0, n_prompt_pages - len(shared))]
-        if write_ids:
-            self._write_owned_pages(dense_caches, write_ids, len(shared))
-        pages = shared + new_ids
-        # 3. register newly-complete full-page prefixes for reuse
-        self._register_prefix(prompt, pages)
-        # 4. first token from the prefill logits
-        first_token = self._first_token(request, last_logits)
-        seq = self.seqs[index]
-        seq.request = request
-        seq.prompt = list(prompt)
-        seq.resume = []
-        seq.phase = "decode"
-        seq.pages = pages
-        seq.own_from = len(shared)
-        seq.length = len(prompt)
-        seq.generated = [first_token]
-        seq.last_token = first_token
-        seq.cancelled = False
-        seq.admit_at = self._next_admit_id()
-        self._by_id[request.request_id] = seq
-        self._tokens_generated += 1
-        park_s = self._unpark_note(request)
-        reqtrace.record(request.request_id, reqtrace.ADMITTED,
-                        shared_pages=len(shared),
-                        tail_pages=len(new_ids))
-        metrics = llm_metrics()
-        metrics.prefill_tokens.inc(len(prompt), tags=_TAGS)
-        submit_ts = getattr(request, "_submit_ts", None)
-        if submit_ts is not None:
-            ttft = time.monotonic() - submit_ts
-            metrics.ttft.observe(ttft, tags=_TAGS)
-            self._recent_ttfts.append(ttft)
-            reqtrace.record(request.request_id, reqtrace.DECODE,
-                            ttft_s=round(ttft, 6),
-                            park_s=round(park_s, 6) or None)
-        self._emit_token(seq, first_token)
+               for (k, v) in seq.dense_caches]
+        return seq.last_logits, out
 
     def _first_token(self, request: GenerationRequest,
                      last_logits) -> int:
@@ -1194,99 +982,35 @@ class PagedLLMEngine:
 
     def _match_prefix(self, prompt: List[int]) -> List[int]:
         """Longest cached full-page prefix of `prompt`: refcounted page
-        ids the caller maps copy-on-write into its block table (radix
-        walk in continuous mode, token-tuple LRU on the legacy arm)."""
-        ps = self.config.page_size
-        n_full = len(prompt) // ps
-        if self.radix is not None:
-            shared = self.radix.match(prompt)
-            if shared:
-                self._prefix_hits += 1
-                llm_metrics().prefix_hits.inc(tags=_TAGS)
-            elif n_full:
-                self._prefix_misses += 1
-                llm_metrics().prefix_misses.inc(tags=_TAGS)
-            return shared
-        shared: List[int] = []
-        for k in range(n_full, 0, -1):
-            key = tuple(prompt[:k * ps])
-            hit = self.prefix_pages.get(key)
-            if hit is not None:
-                # incref every layer-0 page id (ids shared across layers)
-                for page in hit:
-                    self.pool.incref(page)
-                shared = list(hit)
-                # a hit refreshes recency — hot prefixes (system
-                # prompts) must not age out while they're being
-                # reused. Ancestor keys (shorter prefixes of the hit,
-                # whose pages this hit shares) refresh too, so
-                # eviction order never inverts the sharing hierarchy.
-                for j in range(1, k + 1):
-                    akey = tuple(prompt[:j * ps])
-                    if akey in self._prefix_lru:
-                        self._prefix_lru.move_to_end(akey)
-                self._prefix_hits += 1
-                llm_metrics().prefix_hits.inc(tags=_TAGS)
-                break
-        else:
-            if n_full:
-                self._prefix_misses += 1
-                llm_metrics().prefix_misses.inc(tags=_TAGS)
+        ids the caller maps copy-on-write into its block table."""
+        shared = self.radix.match(prompt)
+        if shared:
+            self._prefix_hits += 1
+            llm_metrics().prefix_hits.inc(tags=_TAGS)
+        elif len(prompt) >= self.config.page_size:
+            self._prefix_misses += 1
+            llm_metrics().prefix_misses.inc(tags=_TAGS)
         return shared
 
     def _register_prefix(self, prompt: List[int], pages: List[int]):
-        """Commit the full prompt pages for reuse, then enforce the
-        entry budget (`RTPU_PREFIX_CACHE_ENTRIES`)."""
-        ps = self.config.page_size
-        n_full = len(prompt) // ps
-        if self.radix is not None:
-            # re-read the flag so tests / live reconfig take effect
-            self.radix.max_entries = int(CONFIG.prefix_cache_entries)
-            if n_full:
-                self.radix.insert(prompt, pages[:n_full])
-            llm_metrics().prefix_entries.set(self.radix.entries,
-                                             tags=_GAUGE_TAGS)
-            return
-        for k in range(1, n_full + 1):
-            key = tuple(prompt[:k * ps])
-            if key not in self.prefix_pages:
-                for page in pages[:k]:
-                    self.pool.incref(page)
-                self.prefix_pages[key] = pages[:k]
-                self._prefix_lru[key] = None
-        self._evict_prefixes()
+        """Commit the full prompt pages for reuse; the radix enforces
+        the entry budget (`RTPU_PREFIX_CACHE_ENTRIES`)."""
+        n_full = len(prompt) // self.config.page_size
+        # re-read the flag so tests / live reconfig take effect
+        self.radix.max_entries = int(CONFIG.prefix_cache_entries)
+        if n_full:
+            self.radix.insert(prompt, pages[:n_full])
+        llm_metrics().prefix_entries.set(self.radix.entries,
+                                         tags=_GAUGE_TAGS)
 
-    def _evict_prefixes(self, max_entries: Optional[int] = None):
-        if max_entries is None:
-            max_entries = int(CONFIG.prefix_cache_entries)
-        if self.radix is not None:
-            self.radix.evict(max_entries)
-            llm_metrics().prefix_entries.set(self.radix.entries,
-                                             tags=_GAUGE_TAGS)
-            return
-        while len(self._prefix_lru) > max_entries:
-            key, _ = self._prefix_lru.popitem(last=False)  # oldest first
-            pages = self.prefix_pages.pop(key, None)
-            if pages:
-                for page in pages:
-                    self.pool.decref(page)
-        llm_metrics().prefix_entries.set(len(self._prefix_lru),
+    def _evict_prefixes(self, max_entries: int):
+        self.radix.evict(max_entries)
+        llm_metrics().prefix_entries.set(self.radix.entries,
                                          tags=_GAUGE_TAGS)
 
     def prefix_pinned_pages(self) -> set:
-        """Distinct physical pages the prefix store holds a reference
-        on (radix nodes or legacy LRU entries)."""
-        if self.radix is not None:
-            return set(self.radix.pages())
-        return {p for pages in self.prefix_pages.values() for p in pages}
-
-    def release_prefix_cache(self) -> int:
-        """Evict every unshared prefix entry (pages mapped by live
-        sequences stay pinned until they release). Returns pages
-        freed back to the pool."""
-        before = self.pool.num_free()
-        self._evict_prefixes(max_entries=0)
-        return self.pool.num_free() - before
+        """Distinct physical pages the radix holds a reference on."""
+        return set(self.radix.pages())
 
     def page_leak_check(self) -> int:
         """Pool-balance audit: recompute every page's expected refcount
@@ -1299,13 +1023,8 @@ class PagedLLMEngine:
         for seq in self.seqs:
             for page in seq.pages:
                 expected[page] += 1
-        if self.radix is not None:
-            for page in self.radix.pages():
-                expected[page] += 1
-        else:
-            for pages in self.prefix_pages.values():
-                for page in pages:
-                    expected[page] += 1
+        for page in self.radix.pages():
+            expected[page] += 1
         bad = int(np.sum(expected != self.pool.refs))
         # the free list must hold exactly the zero-ref pages
         if len(self.pool._free) != int(np.sum(self.pool.refs[1:] == 0)):
@@ -1336,36 +1055,25 @@ class PagedLLMEngine:
             self.pool.decref(page)
         self._by_id.pop(seq.request.request_id, None)
 
-    def _decode_tick(self, active: List[int], phase=_no_phase):
+    def _decode_tick(self, active: List[int], phase, finished: List):
         """Decode one token for every row in `active`. `phase` is the
-        continuous tick's `StepTimer.phase` (the legacy arm's tick is
-        not split)."""
+        tick's `StepTimer.phase`; requests that end here are appended
+        to `finished`."""
         tick_start = time.monotonic()
         cfg = self.config
         B = cfg.max_batch
-        finished = []
         with phase("grow"):
-            # cancelled sequences release before the step
+            # cancelled since this tick's reap: release before the step
             for i in list(active):
                 seq = self.seqs[i]
                 if seq.cancelled:
-                    request = seq.request
-                    self._release(seq)
-                    self.seqs[i] = _Seq()
                     active.remove(i)
-                    llm_metrics().requests_finished.inc(
-                        tags=dict(_TAGS, outcome="cancelled"))
-                    reqtrace.record(request.request_id,
-                                    reqtrace.CANCELLED, where="decode")
-                    callback = getattr(request, "_done_callback", None)
-                    if callback is not None:
-                        callback(request, None)  # None = cancelled
-            if self._continuous and active:
-                # lazy page growth (+ preemption under pressure) replaces
-                # the legacy upfront prompt+max_new reservation
-                active = self._ensure_decode_pages(active)
+                    self._end_request(seq.request, None, index=i,
+                                      where="decode")
+            # lazy page growth (+ preemption under pressure)
+            active = self._ensure_decode_pages(active)
         if not active:
-            return finished
+            return
         with phase("stage"):
             trace = not reqtrace.reqtrace_disabled()
             if trace:
@@ -1438,44 +1146,13 @@ class PagedLLMEngine:
                     seq.length += 1
                     self._tokens_generated += 1
                     self._emit_token(seq, token)
-                    request = seq.request
-                    hit_eos = (cfg.eos_token is not None
-                               and token == cfg.eos_token)
-                    # total includes tokens generated before a
-                    # preemption (empty resume on the legacy arm and
-                    # fresh sequences)
-                    total_gen = len(seq.resume) + len(seq.generated)
-                    capacity = len(seq.pages) * cfg.page_size
-                    at_capacity = (not self._continuous
-                                   and seq.length + 1 >= capacity)
-                    if hit_eos \
-                            or total_gen >= request.max_new_tokens \
-                            or at_capacity \
-                            or seq.length >= cfg.max_len - 1:
-                        tokens = seq.resume + list(seq.generated)
-                        finished.append((request, tokens))
-                        callback = getattr(request, "_done_callback",
-                                           None)
-                        if callback is not None:
-                            callback(request, tokens)
-                        self._release(seq)
-                        self.seqs[i] = _Seq()
+                    if self._finished_after(seq, token):
+                        finished.append(self._finish(i))
             with phase("gauges"):
                 metrics = llm_metrics()
                 metrics.token_latency.observe(
                     time.monotonic() - tick_start, tags=_TAGS)
                 metrics.decode_tokens.inc(len(active), tags=_TAGS)
-                for request, _tokens in finished:
-                    metrics.requests_finished.inc(
-                        tags=dict(_TAGS, outcome="done"))
-                    reqtrace.record(request.request_id,
-                                    reqtrace.FINISHED,
-                                    tokens=len(_tokens))
-                    submit_ts = getattr(request, "_submit_ts", None)
-                    if submit_ts is not None:
-                        metrics.request_latency.observe(
-                            time.monotonic() - submit_ts, tags=_TAGS)
-        return finished
 
     # -- conveniences ------------------------------------------------------
 
@@ -1509,15 +1186,12 @@ class PagedLLMEngine:
             "active": sum(1 for s in self.seqs if s.request is not None),
             "pending": self._waiting_count(),
             "free_pages": self.pool.num_free(),
-            "prefix_entries": (self.radix.entries
-                               if self.radix is not None
-                               else len(self.prefix_pages)),
+            "prefix_entries": self.radix.entries,
             "prefix_hits": self._prefix_hits,
             "prefix_misses": self._prefix_misses,
             "preemptions": self._preemptions,
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
-            "continuous": self._continuous,
             "tp": self._tp,
             "hbm_cache_bytes": cache_bytes,
             # per-chip residency: pages shard on kv_heads, params on

@@ -13,12 +13,10 @@ dropped serve streams, bounded p99 during failover) and recorded as a
 JSON artifact like the mesh-sustained bench.
 
 Plus the **LLM serving saturation bench** (``--which serve_saturation``
-/ ``bench_serve_saturation``): the paired continuous-batching vs
-RTPU_NO_CONT_BATCH legacy engine A/B (same seed, same weights, same
-mixed-length workload), the radix shared-prefix arm, and a sustained
-streaming load through the real serve proxy — gated on SLOs (p95 TTFT,
-zero dropped streams, zero leaked KV pages, cross-arm token parity)
-and recorded as ``tests/artifacts_serve_saturation.json``. The same
+/ ``bench_serve_saturation``): a sustained mixed-length streaming
+load through the real serve proxy — gated on SLOs (p95 TTFT, zero
+dropped streams, zero leaked KV pages) and recorded as
+``tests/artifacts_serve_saturation.json``. The same
 run regression-gates request-lifecycle tracing overhead (reqtrace
 on/off req/s within noise) and exports the per-request serve timeline
 to ``tests/artifacts_requests_timeline.json``.
@@ -166,9 +164,8 @@ def bench_llm(steps: int = 40):
 
 
 # ---------------------------------------------------------------------------
-# serve_saturation: continuous-batching vs legacy A/B + streaming SLO soak
-# (PR 17 headline — sustained mixed-length saturation load with SLO gates,
-# recorded as tests/artifacts_serve_saturation.json)
+# serve_saturation: sustained mixed-length streaming load with SLO gates,
+# recorded as tests/artifacts_serve_saturation.json
 # ---------------------------------------------------------------------------
 
 
@@ -190,10 +187,7 @@ def _sat_engine_config(num_pages: int = 96):
 def _sat_mixed_workload(seed: int, n: int):
     """Mixed-length saturation mix: 1/3 short chat turns with long
     answers, 1/3 medium, 1/3 long doc-grounded prompts with short
-    answers — the decode-heavy chat shape where upfront
-    prompt+max_new page reservation hurts most (a short question
-    reserves 10 pages for its 64-token answer that lazy allocation
-    grows into one page at a time)."""
+    answers."""
     import numpy as np
     rng = np.random.RandomState(seed)
     reqs = []
@@ -210,20 +204,10 @@ def _sat_mixed_workload(seed: int, n: int):
     return reqs
 
 
-def _prefill_tokens_counter():
-    from ray_tpu.llm._metrics import llm_metrics
-    snap = llm_metrics().prefill_tokens.snapshot()
-    key = ["paged"]
-    for tag_values, value in snap["series"]:
-        if tag_values == key:
-            return value
-    return 0.0
-
-
 def _drive_engine_arm(engine, workload) -> dict:
     """Submit the whole workload up front (saturation) and step the
-    engine to drain, recording per-request TTFT, throughput, prefill
-    tokens computed, preemptions, and the page-ledger balance."""
+    engine to drain, recording per-request TTFT, throughput,
+    preemptions, and the page-ledger balance."""
     from ray_tpu.llm import GenerationRequest
     outputs: dict = {}
     t_submit: dict = {}
@@ -238,7 +222,6 @@ def _drive_engine_arm(engine, workload) -> dict:
             outputs[i] = tokens
         return on_tok, on_done
 
-    prefill0 = _prefill_tokens_counter()
     t0 = time.perf_counter()
     for i, (prompt, max_new) in enumerate(workload):
         on_tok, on_done = make_cbs(i)
@@ -264,112 +247,10 @@ def _drive_engine_arm(engine, workload) -> dict:
         "decode_tokens_per_s": round(gen_tokens / wall, 1),
         "ttft_p50_s": round(ttfts[len(ttfts) // 2], 4),
         "ttft_p95_s": round(ttfts[int(len(ttfts) * 0.95)], 4),
-        "prefill_tokens": int(_prefill_tokens_counter() - prefill0),
         "preemptions": stats["preemptions"],
         "leaked_pages": stats["leaked_pages"],
         "outputs": outputs,
     }
-
-
-def serve_engine_ab(seed: int = 1234, n_requests: int = 24) -> dict:
-    """Paired A/B (same seed, same params, same workload): continuous
-    batching vs the RTPU_NO_CONT_BATCH legacy per-drain scheduler, plus
-    the radix shared-prefix arm. Gates: token parity between arms, zero
-    leaked pages, and >= 2x fewer prefill tokens on the shared-
-    system-prompt workload."""
-    import numpy as np
-
-    from ray_tpu._internal.config import CONFIG
-    from ray_tpu.llm import PagedLLMEngine
-
-    workload = _sat_mixed_workload(seed, n_requests)
-    # Bound the prefix cache for BOTH arms: the legacy scheduler has no
-    # pressure eviction, so an unbounded pinned-prefix store would
-    # starve its admission loop outright on a saturated pool (the
-    # continuous engine evicts unreferenced radix leaves on demand and
-    # preempts — it doesn't need the bound, but a paired A/B does).
-    # A/B pool is deliberately tight (40 pages): the legacy scheduler
-    # reserves ceil((prompt+max_new)/page_size) pages up front per
-    # admission, so page pressure caps its decode concurrency at ~3
-    # sequences, while the continuous engine allocates lazily and
-    # preempts, keeping ~7 of 8 slots decoding — that concurrency gap
-    # is the structural win being measured (a roomy pool makes the
-    # arms compute-identical and the margin pure noise). Floor check:
-    # 39 usable - 12 pinned >= 16 pages, the largest single request,
-    # so legacy admission can never wedge.
-    CONFIG.apply_system_config({"prefix_cache_entries": 12})
-    try:
-        cont = PagedLLMEngine(_sat_engine_config(num_pages=40))
-        params = cont.params
-        assert cont._continuous, \
-            "kill switch armed — A/B needs the default"
-        # warm every compiled program on the measured engine itself
-        # before timing — jit caches are per-instance closures, so an
-        # unwarmed arm would spend its wall clock in the XLA compiler,
-        # not the scheduler. Prompt lengths cover each (chunk bucket,
-        # dense-cache length) pair the workload and its preemption
-        # resumes can hit; the repeated-prefix pair warms gather_pages
-        _warmup = [([1] * 8, 2), ([2] * 30, 2), ([3] * 60, 2),
-                   ([4] * 70, 2), ([5] * 90, 2), ([6] * 100, 2),
-                   ([7] * 24 + [1], 2), ([7] * 24 + [2], 2)]
-        _drive_engine_arm(cont, _warmup)
-        cont_row = _drive_engine_arm(cont, workload)
-        CONFIG.apply_system_config({"no_cont_batch": True})
-        try:
-            legacy = PagedLLMEngine(_sat_engine_config(num_pages=40),
-                                    params=params)
-            assert not legacy._continuous
-            _drive_engine_arm(legacy, _warmup)
-            legacy_row = _drive_engine_arm(legacy, workload)
-        finally:
-            CONFIG.apply_system_config({"no_cont_batch": False})
-    finally:
-        CONFIG.apply_system_config({"prefix_cache_entries": 128})
-    parity_ok = cont_row.pop("outputs") == legacy_row.pop("outputs")
-
-    # radix arm: shared system prompt, unique tails — the shared span
-    # must cost zero prefill FLOPs after the first request
-    rng = np.random.RandomState(seed + 1)
-    system = [int(t) for t in rng.randint(1, 128, size=56)]
-    shared_workload = [
-        (system + [int(t) for t in rng.randint(1, 128,
-                                               size=rng.randint(2, 9))],
-         8)
-        for _ in range(12)]
-    submitted_tokens = sum(len(p) for p, _ in shared_workload)
-    radix_engine = PagedLLMEngine(_sat_engine_config(num_pages=128),
-                                  params=params)
-    # warm the radix cache with one request so the shared system prompt
-    # is resident before the measured batch (concurrently-admitted cold
-    # requests can't hit a prefix that no finisher has registered yet)
-    _drive_engine_arm(radix_engine, [(system + [1], 2)])
-    radix_row = _drive_engine_arm(radix_engine, shared_workload)
-    radix_row.pop("outputs")
-    radix_row["prompt_tokens_submitted"] = submitted_tokens
-    radix_row["prefill_tokens_saved_frac"] = round(
-        1.0 - radix_row["prefill_tokens"] / submitted_tokens, 3)
-    radix_row["shared_prefix_hits"] = radix_engine.stats()["prefix_hits"]
-
-    result = {
-        "seed": seed,
-        "continuous": cont_row,
-        "legacy": legacy_row,
-        "radix_shared_prefix": radix_row,
-        "gates": {
-            "token_parity": parity_ok,
-            "throughput_wins": cont_row["requests_per_s"]
-            > legacy_row["requests_per_s"],
-            "ttft_p95_wins": cont_row["ttft_p95_s"]
-            < legacy_row["ttft_p95_s"],
-            "zero_leaked_pages": cont_row["leaked_pages"] == 0
-            and legacy_row["leaked_pages"] == 0
-            and radix_row["leaked_pages"] == 0,
-            "radix_2x_fewer_prefill_tokens":
-            radix_row["prefill_tokens"] * 2 <= submitted_tokens,
-        },
-    }
-    result["passed"] = all(result["gates"].values())
-    return result
 
 
 def reqtrace_overhead_ab(seed: int = 1234, n_requests: int = 24,
@@ -390,8 +271,8 @@ def reqtrace_overhead_ab(seed: int = 1234, n_requests: int = 24,
     _warmup = [([1] * 8, 2), ([2] * 30, 2), ([3] * 60, 2),
                ([4] * 70, 2), ([5] * 90, 2), ([6] * 100, 2),
                ([7] * 24 + [1], 2), ([7] * 24 + [2], 2)]
-    # same tight pool as serve_engine_ab so the arms see real page
-    # pressure — parks/preemptions are where tracing records most
+    # a tight pool (40 pages) so the arms see real page pressure —
+    # parks/preemptions are where tracing records most
     CONFIG.apply_system_config({"prefix_cache_entries": 12})
     try:
         on_engine = PagedLLMEngine(_sat_engine_config(num_pages=40))
@@ -522,18 +403,15 @@ def bench_serve_saturation(seed: int = 1234, clients: int = 3,
                            timeline_artifact_path: str =
                            "tests/artifacts_requests_timeline.json",
                            skip_cluster: bool = False) -> dict:
-    """PR 17 headline bench: the in-process engine A/B (continuous vs
-    RTPU_NO_CONT_BATCH legacy, radix shared-prefix arm), then sustained
-    mixed-length streaming saturation through the REAL serve proxy.
-    SLO gates: p95 TTFT bounded, zero dropped streams, zero leaked KV
-    pages, preempted requests complete with token parity. Also runs the
+    """Sustained mixed-length streaming saturation through the REAL
+    serve proxy. SLO gates: p95 TTFT bounded, zero dropped streams, zero
+    leaked KV pages. Also runs the
     reqtrace on/off overhead A/B (regression gate: tracing within
     noise) and exports the per-request lifecycle chrome trace next to
     the SLO artifact."""
     import threading
 
-    result = {"seed": seed, "engine_ab": serve_engine_ab(seed),
-              "reqtrace_ab": reqtrace_overhead_ab(seed)}
+    result = {"seed": seed, "reqtrace_ab": reqtrace_overhead_ab(seed)}
 
     if not skip_cluster:
         import ray_tpu
@@ -640,30 +518,17 @@ def bench_serve_saturation(seed: int = 1234, clients: int = 3,
         finally:
             ray_tpu.shutdown()
 
-    result["passed"] = (result["engine_ab"]["passed"]
-                        and result["reqtrace_ab"]["passed"]
+    result["passed"] = (result["reqtrace_ab"]["passed"]
                         and result.get("serve_saturation",
                                        {}).get("passed", True))
-    ab = result["engine_ab"]
-    _report("serve_sat_cont_req_per_s",
-            ab["continuous"]["requests_per_s"], "req/s")
-    _report("serve_sat_legacy_req_per_s",
-            ab["legacy"]["requests_per_s"], "req/s")
-    _report("serve_sat_cont_ttft_p95_s",
-            ab["continuous"]["ttft_p95_s"], "s")
-    _report("serve_sat_legacy_ttft_p95_s",
-            ab["legacy"]["ttft_p95_s"], "s")
-    _report("serve_sat_radix_prefill_saved",
-            ab["radix_shared_prefix"]["prefill_tokens_saved_frac"],
-            "frac")
     rab = result["reqtrace_ab"]
     _report("serve_sat_reqtrace_on_req_per_s",
             rab["reqtrace_on"]["requests_per_s"], "req/s")
     _report("serve_sat_reqtrace_off_req_per_s",
             rab["reqtrace_off"]["requests_per_s"], "req/s")
     _report("serve_sat_passed", 1.0 if result["passed"] else 0.0,
-            "bool", gates=dict(ab["gates"], **{
-                "reqtrace_" + k: v for k, v in rab["gates"].items()}))
+            "bool", gates={
+                "reqtrace_" + k: v for k, v in rab["gates"].items()})
     if artifact_path:
         with open(artifact_path, "w") as f:
             json.dump(result, f, indent=1)

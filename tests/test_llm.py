@@ -10,7 +10,6 @@ import urllib.request
 import numpy as np
 import pytest
 
-import ray_tpu
 from ray_tpu.llm import EngineConfig, GenerationRequest, LLMEngine
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
 
@@ -71,18 +70,6 @@ def test_prompt_too_long_rejected():
     engine = _tiny_engine()
     with pytest.raises(ValueError):
         engine.submit(GenerationRequest(prompt_tokens=list(range(200))))
-
-
-@pytest.fixture
-def llm_cluster():
-    ray_tpu.init(num_cpus=4, object_store_memory=300 * 1024 * 1024)
-    yield
-    try:
-        from ray_tpu import serve
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
 
 
 @pytest.mark.timeout_s(300)

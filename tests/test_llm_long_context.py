@@ -69,7 +69,7 @@ def test_long_shared_prefix_reuses_pages(paged4k):
 
 
 @pytest.mark.timeout_s(300)
-def test_prefix_lru_eviction_under_strain():
+def test_prefix_cache_eviction_under_strain():
     """Many distinct long prefixes overflow the LRU (max 128 entries):
     eviction must cap the table AND return evicted pages to the pool
     (no leak)."""

@@ -33,15 +33,19 @@ def engines():
     return slot, paged
 
 
-def test_greedy_equivalence_under_load(engines):
-    """Identical outputs vs the slot engine with queue depth 4x
+@pytest.mark.parametrize("seed,n_prompts,max_new", [
+    (0, 16, 12),  # 4x max_batch of 4
+    (8, 12, 10),  # the mix the legacy scheduler was held to (PR 17)
+])
+def test_greedy_equivalence_under_load(engines, seed, n_prompts, max_new):
+    """Identical outputs vs the slot engine with queue depth 3-4x
     max_batch (the VERDICT's acceptance bar)."""
     slot, paged = engines
-    rng = np.random.RandomState(0)
+    rng = np.random.RandomState(seed)
     prompts = [list(rng.randint(1, 128, size=rng.randint(4, 30)))
-               for _ in range(16)]  # 4x max_batch of 4
-    out_slot = slot.generate(prompts, max_new_tokens=12)
-    out_paged = paged.generate(prompts, max_new_tokens=12)
+               for _ in range(n_prompts)]
+    out_slot = slot.generate(prompts, max_new_tokens=max_new)
+    out_paged = paged.generate(prompts, max_new_tokens=max_new)
     assert out_slot == out_paged
 
 
@@ -82,51 +86,40 @@ def test_prefix_pages_shared():
     assert paged.stats()["prefix_entries"] >= 3
 
 
-def test_prefix_lru_hit_refreshes_recency_and_counts():
-    """A reused prefix must not age out of the LRU while hot, and
-    stats() exposes the hit/miss counters (PR-12 satellite: the old
-    list-based LRU popped in insertion order regardless of hits).
-    Exercises the LEGACY token-tuple LRU — the RTPU_NO_CONT_BATCH path;
-    the radix cache that replaces it is covered by
-    test_continuous_batching.py."""
-    from ray_tpu._internal.config import CONFIG
-    CONFIG.apply_system_config({"no_cont_batch": True})
-    try:
-        _run_legacy_prefix_lru_checks()
-    finally:
-        CONFIG.apply_system_config({"no_cont_batch": False})
-
-
-def _run_legacy_prefix_lru_checks():
+def test_prefix_hit_refreshes_recency_and_counts():
+    """A reused prefix must not age out of the radix while hot, and
+    stats() exposes the hit/miss counters: the engine's own admission
+    path, where test_radix_lru_evicts_only_unreferenced_leaves drives
+    the tree alone."""
     model = tiny_model()
     paged = PagedLLMEngine(PagedEngineConfig(
         model=model, max_batch=4, max_len=128, page_size=8,
         num_pages=128, prefill_buckets=(32, 64)))
-    assert not paged._continuous
     hot = list(range(1, 17))  # 16 tokens = 2 full pages
     paged.generate([hot + [30]], max_new_tokens=2)
     s0 = paged.stats()
     assert s0["prefix_misses"] >= 1 and s0["prefix_hits"] == 0
+    assert "continuous" not in s0  # one scheduler: nothing to report
+    hot_pages = paged.prefix_pinned_pages()  # nothing else cached yet
+    assert len(hot_pages) == 2
     # a few distinct filler prefixes inserted AFTER the hot one
     rng = np.random.RandomState(7)
     filler = [list(rng.randint(40, 128, size=16)) + [i + 1]
               for i in range(4)]
     paged.generate(filler, max_new_tokens=2)
-    # hit the hot prefix; its keys move to the MRU end
+    # hit the hot prefix through admission: its nodes become the newest
     paged.generate([hot + [31]], max_new_tokens=2)
     s1 = paged.stats()
     assert s1["prefix_hits"] == 1
     assert s1["prefix_misses"] > s0["prefix_misses"]  # fillers missed
-    hot_keys = {tuple(hot[:8]), tuple(hot)}
-    assert hot_keys <= set(paged.prefix_pages)
+    assert s1["prefix_entries"] == 10
     # evict down to 2 entries: insertion order would keep only the
-    # newest fillers; true LRU keeps the hot keys (just refreshed)
+    # newest fillers; LRU keeps the hot nodes (just refreshed)
     paged._evict_prefixes(max_entries=2)
-    assert hot_keys == set(paged.prefix_pages), \
+    assert paged.stats()["prefix_entries"] == 2
+    assert paged.prefix_pinned_pages() == hot_pages, \
         "hot prefix evicted despite being reused (recency not refreshed)"
-    assert len(paged._prefix_lru) == 2
-    # ledger consistency: every LRU key has pages and vice versa
-    assert set(paged._prefix_lru) == set(paged.prefix_pages)
+    assert paged.page_leak_check() == 0
 
 
 def _series_value(metric, tags):

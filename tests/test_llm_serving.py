@@ -93,6 +93,7 @@ def test_pd_disagg_matches_local_prefill():
     while len(results) < len(prompts) and time.monotonic() < deadline:
         decoder.step()
     assert [results[i] for i in range(len(prompts))] == want
+    assert decoder.page_leak_check() == 0
 
 
 @pytest.mark.timeout_s(600)
