@@ -418,6 +418,7 @@ def train_loop(config: Dict[str, Any]) -> Dict[str, Any]:
     from ray_tpu.ops.attention import pallas_kernels
     from ray_tpu.parallel import (MeshConfig, create_train_state,
                                   make_train_step)
+    from ray_tpu.parallel.mesh import collective_counts
 
     devices = jax.devices()
     ctx = train.get_context()
@@ -426,30 +427,37 @@ def train_loop(config: Dict[str, Any]) -> Dict[str, Any]:
         else mesh_config.build(devices[:1])
     rules = mesh_config.rules_dict()
     model_cfg = config["model"]
-    model = LlamaModel(model_cfg)
     batch, seq = config["shape"]
     # bench.py's recipe: bf16 params + adafactor's factored fp32 moments
     tx = optax.chain(optax.clip_by_global_norm(1.0),
                      optax.adafactor(learning_rate=1e-3))
-    state = create_train_state(
-        jax.random.PRNGKey(config["seed"]), model,
-        jnp.zeros((batch, seq), jnp.int32), mesh, tx, rules)
-    placed = [d.memory_stats() for d in devices]
-
-    def loss_fn(params, data):
-        logits = model.apply({"params": params}, data["tokens"])
-        return cross_entropy_loss(logits[:, :-1], data["tokens"][:, 1:])
-
     # ONE fixed batch from the seed, so the loss must fall
     data = {"tokens": jax.random.randint(
         jax.random.PRNGKey(config["seed"] + 1), (batch, seq), 0,
         model_cfg.vocab_size)}
-    step = make_train_step(loss_fn, mesh, rules, state=state)
+
+    def placed_and_compiled(cfg):
+        model = LlamaModel(cfg)
+        state = create_train_state(
+            jax.random.PRNGKey(config["seed"]), model,
+            jnp.zeros((batch, seq), jnp.int32), mesh, tx, rules)
+        placed = [d.memory_stats() for d in devices]
+
+        def loss_fn(params, data):
+            logits = model.apply({"params": params}, data["tokens"])
+            return cross_entropy_loss(logits[:, :-1], data["tokens"][:, 1:])
+
+        step = make_train_step(loss_fn, mesh, rules, state=state)
+        with mesh:
+            t0 = time.perf_counter()
+            compiled = step.lower(state, data).compile()
+            return state, placed, compiled, time.perf_counter() - t0
+
+    state, placed, compiled, compile_s = placed_and_compiled(model_cfg)
+    text = compiled.as_text()
+    kernels = pallas_kernels(text)
+    collectives = {model_cfg.num_layers: collective_counts(text)}
     with mesh:
-        t0 = time.perf_counter()
-        compiled = step.lower(state, data).compile()
-        compile_s = time.perf_counter() - t0
-        kernels = pallas_kernels(compiled.as_text())
         losses, step_s = [], []
         for i in range(config["steps"]):
             t0 = time.perf_counter()
@@ -459,10 +467,20 @@ def train_loop(config: Dict[str, Any]) -> Dict[str, Any]:
             losses.append(loss)
             train.report({"step": i, "loss": loss, "losses": list(losses),
                           "step_time_s": step_s[-1]})
+    memory = [d.memory_stats() for d in devices]
+    if mesh.size > 1:
+        # the same step one layer shallower, compiled and never run: a
+        # layout the partitioner is left to guess shows as all-to-alls
+        # that grow with depth
+        del state, compiled
+        shallow = dataclasses.replace(
+            model_cfg, num_layers=model_cfg.num_layers - 1)
+        collectives[shallow.num_layers] = collective_counts(
+            placed_and_compiled(shallow)[2].as_text())
     return {"pid": os.getpid(), "device": device, "kernels": kernels,
-            "losses": losses, "compile_s": compile_s, "step_s": step_s,
-            "placed": placed,
-            "memory": [d.memory_stats() for d in devices]}
+            "collectives": collectives, "losses": losses,
+            "compile_s": compile_s, "step_s": step_s, "placed": placed,
+            "memory": memory}
 
 
 class KernelParity:
@@ -598,7 +616,8 @@ def fit_once(plan: Plan, mesh_axes: Optional[Dict[str, int]], steps: int,
     say(f"smoke: train[{label}] worker pid {worker['pid']} on "
         f"{json.dumps(worker['device'])}; losses "
         f"{json.dumps([round(x, 4) for x in reported])}; step kernels "
-        f"{json.dumps(kernels)}")
+        f"{json.dumps(kernels)}; step collectives by depth "
+        f"{json.dumps(worker['collectives'])}")
     if not plan.rehearse:
         check(all(kernels.get(k) for k in
                   ("flash_fwd", "flash_bwd_kv", "flash_bwd_q")),
@@ -647,6 +666,10 @@ def phase_train_mesh(plan: Plan) -> Dict[str, Any]:
     check(meshed["losses"][-1] < meshed["losses"][0],
           f"loss did not fall on the mesh: {meshed['losses']}")
     check_spread(plan, "train mesh after placement", meshed["placed"])
+    (_, shallow), (_, deep) = sorted(meshed["collectives"].items())
+    check(deep.get("all-to-all", 0) <= shallow.get("all-to-all", 0),
+          f"the mesh step's all-to-alls grow with depth ({shallow} -> "
+          f"{deep}): the model's activation layout did not engage")
     return meshed["device"]
 
 

@@ -28,9 +28,26 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..ops.attention import flash_attention, flash_uses_pallas
-from ..parallel.mesh import current_kernel_mesh
+from ..parallel.mesh import constrain, current_kernel_mesh
 
 Dtype = Any
+
+# Activation layouts of the no-cache (training) forward, by logical axis
+# (parallel.mesh.DEFAULT_LOGICAL_AXIS_RULES: batch over data x fsdp, heads
+# / mlp / vocab over tensor, the hidden dimension whole). Stated with
+# `constrain` at every site below: left to propagation, GSPMD carries the
+# parameters' `embed -> fsdp` into the residual stream instead and pays
+# eight all-to-alls and an all-reduce of each MLP projection's partial
+# sums a layer (PERF.md, PR 31). The cache branches (serving) keep the
+# programs propagation gives them: `_pin` passes their arrays through.
+_STREAM = ("activation_batch", "activation_seq", "activation_embed")
+_HEADS = ("activation_batch", "activation_seq", "activation_heads", None)
+_MLP_HIDDEN = ("activation_batch", "activation_seq", "activation_mlp")
+_LOGITS = ("activation_batch", "activation_seq", "vocab")
+
+
+def _pin(x, names, no_cache: bool):
+    return constrain(x, names) if no_cache else x
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,6 +240,7 @@ class Attention(nn.Module):
     def __call__(self, x, positions, kv_cache=None, cache_index=None):
         cfg = self.config
         hd = cfg.head_dim_
+        no_cache = kv_cache is None
         dense = lambda feats, names, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name,
@@ -240,6 +258,9 @@ class Attention(nn.Module):
                   "v_proj")(x)
         v = _maybe_lora(x, v, (cfg.num_kv_heads, hd), ("embed",),
                         ("kv_heads", "head_dim"), "v_proj", cfg)
+        # k and v by kv head: where those do not divide the tensor axis,
+        # `constrain` leaves them to the partitioner
+        q, k, v = (_pin(a, _HEADS, no_cache) for a in (q, k, v))
         # [b, s, h, d] -> [b, h, s, d]
         q = jnp.transpose(q, (0, 2, 1, 3))
         k = jnp.transpose(k, (0, 2, 1, 3))
@@ -388,6 +409,7 @@ class Attention(nn.Module):
             else:
                 out = _flash_on_mesh(q, k, v)
         out = jnp.transpose(out, (0, 2, 1, 3))  # [b, s, h, d]
+        out = _pin(out, _HEADS, no_cache)
         proj = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o_proj",
@@ -396,14 +418,14 @@ class Attention(nn.Module):
         proj = _maybe_lora(out, proj, cfg.hidden_size,
                            ("heads", "head_dim"), ("embed",), "o_proj",
                            cfg, axis=(-2, -1))
-        return proj, new_cache
+        return _pin(proj, _STREAM, no_cache), new_cache
 
 
 class MLP(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, no_cache: bool = False):
         cfg = self.config
         gate = nn.DenseGeneral(
             cfg.intermediate_size, use_bias=False, dtype=cfg.dtype,
@@ -420,13 +442,15 @@ class MLP(nn.Module):
         up = _maybe_lora(x, up, cfg.intermediate_size, ("embed",),
                          ("mlp",), "up_proj", cfg)
         hidden = nn.silu(gate) * up
+        hidden = _pin(hidden, _MLP_HIDDEN, no_cache)
         down = nn.DenseGeneral(
             cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="down_proj",
             kernel_init=_partitioned(nn.initializers.lecun_normal(),
                                      ("mlp", "embed")))(hidden)
-        return _maybe_lora(hidden, down, cfg.hidden_size, ("mlp",),
+        down = _maybe_lora(hidden, down, cfg.hidden_size, ("mlp",),
                            ("embed",), "down_proj", cfg)
+        return _pin(down, _STREAM, no_cache)
 
 
 class DecoderBlock(nn.Module):
@@ -435,13 +459,16 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, cache_index=None):
         cfg = self.config
+        no_cache = kv_cache is None
+        normed = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="attn_norm")(x)
         attn_out, new_cache = Attention(cfg, name="attn")(
-            RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="attn_norm")(x),
-            positions, kv_cache, cache_index)
-        x = x + attn_out
-        x = x + MLP(cfg, name="mlp")(
-            RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="mlp_norm")(x))
-        return x, new_cache
+            _pin(normed, _STREAM, no_cache), positions, kv_cache,
+            cache_index)
+        x = _pin(x + attn_out, _STREAM, no_cache)
+        normed = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="mlp_norm")(x)
+        x = x + MLP(cfg, name="mlp")(_pin(normed, _STREAM, no_cache),
+                                     no_cache)
+        return _pin(x, _STREAM, no_cache), new_cache
 
 
 class LlamaModel(nn.Module):
@@ -460,8 +487,7 @@ class LlamaModel(nn.Module):
                                   ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         x = jnp.take(embed, tokens, axis=0).astype(cfg.dtype)
-        x = nn.with_logical_constraint(
-            x, ("activation_batch", "activation_seq", "activation_embed"))
+        x = _pin(x, _STREAM, kv_caches is None)
 
         block = DecoderBlock
         if cfg.remat and kv_caches is None:
@@ -474,10 +500,8 @@ class LlamaModel(nn.Module):
             x, new_cache = block(cfg, name=f"layer_{layer}")(
                 x, positions, cache, cache_index)
             new_caches.append(new_cache)
-            x = nn.with_logical_constraint(
-                x, ("activation_batch", "activation_seq",
-                    "activation_embed"))
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        x = _pin(x, _STREAM, kv_caches is None)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x,
                                 embed.astype(cfg.dtype))
@@ -487,11 +511,9 @@ class LlamaModel(nn.Module):
                 param_dtype=cfg.param_dtype, name="lm_head",
                 kernel_init=_partitioned(nn.initializers.lecun_normal(),
                                          ("embed", "vocab")))(x)
-        logits = nn.with_logical_constraint(
-            logits, ("activation_batch", "activation_seq", None))
         if kv_caches is not None:
             return logits, new_caches
-        return logits
+        return constrain(logits, _LOGITS)
 
 
 def init_kv_caches(config: LlamaConfig, batch: int, max_len: int,

@@ -21,30 +21,38 @@ LOGICAL_AXIS_RULES (t5x-style), overridable per MeshConfig.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import dataclasses
 import math
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# The mesh the program being traced will run on, set by whoever builds
-# the jitted program (the serving engine, the train step). GSPMD cannot
-# partition a Mosaic custom call, so model code reads this to shard_map
-# its Pallas kernels (flash, paged attention) over the mesh — our own
-# channel, no dependency on jax's legacy thread-resources internals.
-_KERNEL_MESH: contextvars.ContextVar[Optional[Mesh]] = \
-    contextvars.ContextVar("rtpu_kernel_mesh", default=None)
+# The mesh the program being traced will run on, with the logical-axis
+# rules its arrays were laid out by, set by whoever builds the jitted
+# program (the serving engine, the train step). GSPMD cannot partition a
+# Mosaic custom call, so model code reads this to shard_map its Pallas
+# kernels (flash, paged attention) over the mesh, and to pin its
+# activations' layout (`constrain`) — our own channel, no dependency on
+# jax's legacy thread-resources internals or flax's global rule state.
+_KERNEL_MESH: contextvars.ContextVar[
+    Tuple[Optional[Mesh], Optional[Dict[str, object]]]] = \
+    contextvars.ContextVar("rtpu_kernel_mesh", default=(None, None))
 
 
 @contextlib.contextmanager
-def kernel_mesh(mesh: Optional[Mesh]):
-    """Mark `mesh` active for model-side kernel sharding (trace-time:
-    wrap every trace that should see it)."""
-    token = _KERNEL_MESH.set(mesh)
+def kernel_mesh(mesh: Optional[Mesh],
+                rules: Optional[Dict[str, object]] = None):
+    """Mark `mesh` active for model-side kernel sharding and activation
+    constraints (trace-time: wrap every trace that should see it).
+    `rules` are the logical-axis rules the program's parameters were
+    placed by; None means DEFAULT_LOGICAL_AXIS_RULES."""
+    token = _KERNEL_MESH.set((mesh, rules))
     try:
         yield mesh
     finally:
@@ -52,7 +60,7 @@ def kernel_mesh(mesh: Optional[Mesh]):
 
 
 def current_kernel_mesh() -> Optional[Mesh]:
-    return _KERNEL_MESH.get()
+    return _KERNEL_MESH.get()[0]
 
 AXIS_ORDER = ("data", "fsdp", "expert", "pipeline", "sequence", "tensor")
 
@@ -265,6 +273,41 @@ def shard_logical(x, mesh: Mesh, logical_axes: Sequence[Optional[str]],
         logical_axes, rules if rules is not None
         else dict(DEFAULT_LOGICAL_AXIS_RULES))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def constrain(x, logical_axes: Sequence[Optional[str]]):
+    """Pin `x` to the layout its logical axis names have on the active
+    kernel mesh (`shard_logical` under `kernel_mesh`'s mesh and rules):
+    how the model states its activations' layout to the partitioner,
+    which otherwise carries the parameters' into them. Returns `x`
+    untouched when no kernel mesh is active, when the mesh has one
+    device, or when a dimension does not divide over its mesh axes (one
+    kv head on `tensor=2`: the partitioner is left to choose, not made
+    to pad)."""
+    mesh, rules = _KERNEL_MESH.get()
+    if mesh is None or mesh.size == 1:
+        return x
+    rules = rules if rules is not None else dict(DEFAULT_LOGICAL_AXIS_RULES)
+    for size, axes in zip(x.shape, logical_to_mesh_axes(logical_axes, rules)):
+        axes = axes if isinstance(axes, tuple) else (axes,)
+        if size % math.prod(mesh.shape[a] for a in axes if a is not None):
+            return x
+    return shard_logical(x, mesh, logical_axes, rules)
+
+
+_COLLECTIVE = re.compile(
+    r" (all-to-all|all-reduce|all-gather|reduce-scatter|collective-permute)"
+    r"(?:-start)?\(.*?\bchannel_id=(\d+)")
+
+
+def collective_counts(compiled_text: str) -> Dict[str, int]:
+    """Collectives of a compiled program (`compiled.as_text()`) by kind.
+    An asynchronous pair counts at its `-start`, and clones that share a
+    `channel_id` (one collective the compiler fused twice) count once.
+    How a step shows that its layout engaged: left to propagation, the
+    train step's all-to-alls grow by eight a layer (PERF.md, PR 31)."""
+    seen = set(_COLLECTIVE.findall(compiled_text))
+    return dict(collections.Counter(kind for kind, _ in seen))
 
 
 def params_shardings(params, mesh: Mesh,

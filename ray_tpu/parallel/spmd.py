@@ -63,14 +63,16 @@ def shardings_tree(names_tree, mesh: Mesh, rules: Dict[str, Any]):
                                   or isinstance(x, tuple))
 
 
-def create_train_state(rng, model: nn.Module, sample_input,
-                       mesh: Mesh, tx: optax.GradientTransformation,
-                       rules: Optional[Dict[str, Any]] = None) -> TrainState:
-    """Initialize parameters *already sharded* across the mesh: the init fn
-    is jitted with sharding constraints inside so no host ever materializes
-    the full parameter tree."""
+def train_state_init(model: nn.Module, sample_input, mesh: Mesh,
+                     tx: optax.GradientTransformation,
+                     rules: Optional[Dict[str, Any]] = None) -> Callable:
+    """The function `create_train_state` jits: rng -> TrainState with the
+    parameters constrained to their logical axes' shardings on `mesh`.
+    Apart so that a program can be compiled from shapes for devices that
+    are described and not attached (`jit(...).lower(key).compile()`'s
+    `output_shardings` are the state's)."""
     rules = rules if rules is not None else dict(DEFAULT_LOGICAL_AXIS_RULES)
-    names = logical_names_tree(model, rng, sample_input)
+    names = logical_names_tree(model, jax.random.PRNGKey(0), sample_input)
     shardings = shardings_tree(names, mesh, rules)
 
     def init_fn(r):
@@ -81,8 +83,18 @@ def create_train_state(rng, model: nn.Module, sample_input,
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=opt_state, apply_fn=model.apply, tx=tx)
 
+    return init_fn
+
+
+def create_train_state(rng, model: nn.Module, sample_input,
+                       mesh: Mesh, tx: optax.GradientTransformation,
+                       rules: Optional[Dict[str, Any]] = None) -> TrainState:
+    """Initialize parameters *already sharded* across the mesh: the init fn
+    is jitted with sharding constraints inside so no host ever materializes
+    the full parameter tree."""
     with mesh:
-        return jax.jit(init_fn)(rng)
+        return jax.jit(train_state_init(model, sample_input, mesh, tx,
+                                        rules))(rng)
 
 
 def make_train_step(loss_fn: Callable, mesh: Mesh,
@@ -93,7 +105,9 @@ def make_train_step(loss_fn: Callable, mesh: Mesh,
     """Build the jitted SPMD train step.
 
     loss_fn(params, batch) -> scalar loss (model.apply inside). The batch is
-    constrained to the data axes; everything else is GSPMD's problem.
+    constrained to the data axes and the model states its activations'
+    layout under the step's kernel mesh (`mesh.constrain`); the collectives
+    are GSPMD's problem.
 
     Pass the concrete initial `state` to pin the step's OUTPUT state to
     the initial state's shardings. Without it, GSPMD may choose output
@@ -113,8 +127,9 @@ def make_train_step(loss_fn: Callable, mesh: Mesh,
             lambda x: jax.lax.with_sharding_constraint(
                 x, batch_sharding) if x.ndim == len(batch_axes) else x,
             batch)
-        # the model shard_maps its Pallas kernels over this mesh
-        with kernel_mesh(mesh):
+        # the model shard_maps its Pallas kernels over this mesh and pins
+        # its activations to the layout these rules give them on it
+        with kernel_mesh(mesh, rules):
             loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
         new_state = state.apply_gradients(grads)
         metrics = {"loss": loss,

@@ -192,6 +192,55 @@ def test_paged_decode_relays_no_page_pool_out(v5e, as_tpu, tensor):
         2 * 2 * math.prod(pool)
 
 
+@pytest.mark.timeout_s(300)
+def test_train_step_on_the_2x2_mesh_keeps_its_stated_layout(v5e, as_tpu):
+    """The `train-yi-2x2` cell's step (its config file's widths, its
+    builder, 8 x 2048 on fsdp=2 x tensor=2) at two layers: the model's
+    `constrain` calls hold the partitioner to batch over fsdp and heads /
+    mlp over tensor. Left to propagation it resharded the residual stream
+    with eight all-to-alls a layer and all-reduced each MLP projection's
+    partial sums of the whole batch over the fsdp pairs, in 3.93 GB of
+    temporaries (my AOT compile of the parent, PR 31)."""
+    import re
+
+    from benchmarks.harness.builders import llama_train
+    from ray_tpu.ops.attention import pallas_kernels
+    from ray_tpu.parallel import MeshConfig, make_train_step
+    from ray_tpu.parallel.mesh import collective_counts, named_sharding
+    from ray_tpu.parallel.spmd import train_state_init
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "yi-1.5-9b-train-2x2.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=2)
+    built = llama_train(config)
+    mesh_config = MeshConfig(**config["mesh_axes"])
+    mesh = mesh_config.build(v5e)
+    rules = mesh_config.rules_dict()
+    batch, seq = 8, 2048
+    tokens = jnp.zeros((batch, seq), jnp.int32)
+    key = jax.random.PRNGKey(0)
+    init = train_state_init(built["module"], tokens, mesh, built["tx"],
+                            rules)
+    with mesh:
+        # create_train_state's program, compiled and never run
+        placed = jax.jit(init).lower(key).compile().output_shardings
+        state = jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            jax.eval_shape(init, key), placed)
+        data = {"tokens": jax.ShapeDtypeStruct(
+            tokens.shape, tokens.dtype,
+            sharding=named_sharding(mesh, ("batch", "seq"), rules))}
+        compiled = make_train_step(built["loss_fn"], mesh, rules,
+                                   state=state).lower(state, data).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"flash_fwd": 4, "flash_bwd_kv": 2,
+                                    "flash_bwd_q": 2}
+    assert collective_counts(text).get("all-to-all", 0) <= 2
+    hidden = f"[{batch},{seq},{config['intermediate_size'] // 2}]"
+    assert not re.findall(
+        re.escape(hidden) + r"\S* all-reduce(?:-start)?\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3.93e9
+
+
 def test_obvious_compile_holds_no_kernel(v5e):
     """Why the tests above steer the backend question: unsteered, the
     public entry point compiles for the described chip without complaint

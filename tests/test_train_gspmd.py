@@ -4,6 +4,8 @@ process baseline), the two-level cross-slice schedule with its DCN byte
 ledger, and the MPMD pipeline (stages as actors, activations as device
 objects — zero host round-trip, measured bubble fraction)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -116,6 +118,227 @@ def test_zero1_hlo_has_reduce_scatter_and_allgather():
     text = step.lower(z1, batch).as_text()
     assert "reduce_scatter" in text or "reduce-scatter" in text
     assert "all-gather" in text or "all_gather" in text
+
+
+# -- the model's activation layout under the train step's mesh (PR 31) ------
+
+def _grad_keeping_sgd(lr):
+    """SGD whose optimiser state is the gradient it was last given: the
+    step's own gradients come out of `make_train_step`'s program."""
+    import jax
+    import optax
+
+    def init(params):
+        return jax.tree_util.tree_map(jax.numpy.zeros_like, params)
+
+    def update(grads, state, params=None):
+        return jax.tree_util.tree_map(lambda g: -lr * g, grads), grads
+    return optax.GradientTransformation(init, update)
+
+
+_LLAMA_TOKENS = (4, 32)
+# `constrain` calls of the no-cache forward: embedding, final norm and
+# logits; a layer's two norms, q, k, v, attention output, o_proj output,
+# MLP hidden, down_proj output, two residual adds
+_LLAMA_SITES = (3, 11)
+
+
+def _tiny_llama(layers):
+    import jax.numpy as jnp
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+    return LlamaModel(LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_layers=layers, num_heads=4, num_kv_heads=2, max_seq_len=64,
+        dtype=jnp.float32, param_dtype=jnp.float32, remat=True,
+        attention_impl="reference"))
+
+
+def _lower_llama_step(layers, meshed):
+    """(mesh, initial state, lowered step) of a tiny float32 Llama on one
+    device or on fsdp=2 x tensor=2."""
+    import jax
+    from ray_tpu.models.llama import cross_entropy_loss
+
+    model = _tiny_llama(layers)
+    mesh_config = MeshConfig(data=1, fsdp=2, tensor=2) if meshed \
+        else MeshConfig(data=1)
+    mesh = mesh_config.build(jax.devices()[:4 if meshed else 1])
+    rules = mesh_config.rules_dict()
+    state = create_train_state(
+        jax.random.PRNGKey(0), model,
+        jax.numpy.zeros(_LLAMA_TOKENS, jax.numpy.int32), mesh,
+        _grad_keeping_sgd(0.1), rules)
+
+    def loss_fn(params, data):
+        logits = model.apply({"params": params}, data["tokens"])
+        return cross_entropy_loss(logits[:, :-1], data["tokens"][:, 1:])
+
+    step = make_train_step(loss_fn, mesh, rules, state=state, donate=False)
+    with mesh:
+        return mesh, state, step.lower(state, _llama_batch())
+
+
+@functools.lru_cache(maxsize=None)
+def _llama_step(layers, meshed):
+    mesh, state, lowered = _lower_llama_step(layers, meshed)
+    return mesh, state, lowered, lowered.compile()
+
+
+def _llama_batch():
+    import jax
+    return {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), _LLAMA_TOKENS, 0, 256)}
+
+
+@pytest.mark.parametrize("layers", [2, 3])
+def test_llama_step_on_mesh_matches_one_device(layers):
+    """Forward, backward and update under fsdp=2 x tensor=2 against the
+    one-device program: the first loss, every gradient leaf (1e-4 of the
+    leaf's largest entry) and the losses of three optimiser steps."""
+    import jax
+
+    runs = {}
+    for meshed in (False, True):
+        mesh, state, _, compiled = _llama_step(layers, meshed)
+        losses = []
+        with mesh:
+            for i in range(3):
+                state, metrics = compiled(state, _llama_batch())
+                losses.append(float(metrics["loss"]))
+                if i == 0:
+                    grads = jax.tree_util.tree_map(np.asarray,
+                                                   state.opt_state)
+        runs[meshed] = losses, grads
+    (want_losses, want), (got_losses, got) = runs[False], runs[True]
+    np.testing.assert_allclose(got_losses, want_losses, rtol=1e-4)
+    assert got_losses[-1] < got_losses[0]
+    leaves = jax.tree_util.tree_leaves_with_path(want)
+    assert len(leaves) == 3 + 9 * layers
+    for (path, w), g in zip(leaves, jax.tree_util.tree_leaves(got)):
+        assert np.abs(w).max() > 0, path
+        assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max(), path
+
+
+def test_llama_step_states_its_layout_to_the_partitioner():
+    """The annotations reach the program: a sharding constraint per
+    annotated site in the lowered step on a mesh, only the batch's on one
+    device, and the compiled step's all-to-alls do not grow with depth
+    (left to propagation the residual stream is resharded eight times a
+    layer: 21 and 29 of them at these widths)."""
+    from ray_tpu.parallel.mesh import collective_counts
+
+    def constraints(layers, meshed):
+        return _llama_step(layers, meshed)[2].as_text().count(
+            "sharding_constraint")
+
+    fixed, per_layer = _LLAMA_SITES
+    for layers in (2, 3):
+        assert constraints(layers, False) == 1
+        assert constraints(layers, True) >= 1 + fixed + per_layer * layers
+    shallow, deep = (collective_counts(_llama_step(n, True)[3].as_text())
+                     for n in (2, 3))
+    assert shallow["all-reduce"] < deep["all-reduce"]  # the reader reads
+    assert deep.get("all-to-all", 0) == shallow.get("all-to-all", 0) <= 2
+
+
+def test_llama_forward_constrains_each_site_once():
+    """The forward alone, traced under a kernel mesh: exactly one
+    constraint a site; none without a mesh, on a mesh of one device, or
+    on the cache branches; k and v are left alone where the kv heads do
+    not divide the tensor axis."""
+    import dataclasses
+
+    import jax
+    from ray_tpu.models.llama import LlamaModel, init_kv_caches
+    from ray_tpu.parallel.mesh import kernel_mesh
+
+    model = _tiny_llama(2)
+    tokens = jax.numpy.zeros(_LLAMA_TOKENS, jax.numpy.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
+
+    def traced(module, mesh, **kw):
+        with kernel_mesh(mesh):
+            return str(jax.make_jaxpr(lambda p: module.apply(
+                {"params": p}, tokens, **kw))(params)).count(
+                    "sharding_constraint")
+
+    four = MeshConfig(data=1, fsdp=2, tensor=2).build(jax.devices()[:4])
+    one = MeshConfig(data=1).build(jax.devices()[:1])
+    fixed, per_layer = _LLAMA_SITES
+    assert traced(model, four) == fixed + per_layer * 2
+    assert traced(model, None) == traced(model, one) == 0
+    caches = init_kv_caches(model.config, _LLAMA_TOKENS[0], 64)
+    assert traced(model, four, kv_caches=caches, cache_index=0) == 0
+    one_kv_head = LlamaModel(dataclasses.replace(model.config,
+                                                 num_kv_heads=1))
+    params = jax.eval_shape(one_kv_head.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
+    assert traced(one_kv_head, four) == fixed + (per_layer - 2) * 2
+
+
+def test_collective_counts_reads_pairs_and_clones_once():
+    from ray_tpu.parallel.mesh import collective_counts
+    text = """
+  %ag = bf16[8,4]{1,0} all-gather(%p), channel_id=7, dimensions={0}
+  %ag.clone = bf16[8,4]{1,0} all-gather(%q), channel_id=7, dimensions={0}
+  %ars = (f32[], f32[]) all-reduce-start(%a, %b), channel_id=9, to_apply=%add
+  %ard = (f32[], f32[]) all-reduce-done(%ars)
+  %ar2 = f32[4]{0} all-reduce(%c), channel_id=10, to_apply=%add
+  %a2a = bf16[4,2,8]{2,1,0} all-to-all(%d), channel_id=11, dimensions={1}
+  %cps = (f32[2], f32[2]) collective-permute-start(%e), channel_id=12
+  %cpd = f32[2] collective-permute-done(%cps)
+  %f = f32[4] fusion(%g), metadata={op_name="jit(f)/all-to-all(x)"}
+"""
+    assert collective_counts(text) == {
+        "all-gather": 1, "all-reduce": 2, "all-to-all": 1,
+        "collective-permute": 1}
+
+
+def _serve_programs(tensor):
+    """Lowered text of a tiny paged engine's decode_step and one
+    chunk_prefill bucket, from shapes."""
+    import jax
+    from ray_tpu.llm import PagedEngineConfig, PagedLLMEngine
+    from ray_tpu.models.llama import LlamaConfig
+    model = LlamaConfig(vocab_size=128, hidden_size=64,
+                        intermediate_size=128, num_layers=2, num_heads=4,
+                        num_kv_heads=4, max_seq_len=256, remat=False,
+                        use_flash=False, attention_impl="reference")
+    mesh = MeshConfig(data=1, tensor=tensor).build(
+        jax.devices()[:tensor]) if tensor > 1 else None
+    engine = PagedLLMEngine(PagedEngineConfig(
+        model=model, max_batch=4, max_len=128, page_size=8, num_pages=64,
+        prefill_buckets=(16,)), mesh=mesh)
+
+    def like(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+    ints = jax.ShapeDtypeStruct((1, 16), jax.numpy.int32)
+    with engine._mesh_scope():
+        prefill = engine._chunk_prefill.lower(
+            jax.tree_util.tree_map(like, engine.params), ints, ints,
+            jax.tree_util.tree_map(like, engine._dense_zero_caches()),
+            jax.ShapeDtypeStruct((), jax.numpy.int32))
+    return engine.lower_decode().as_text(), prefill.as_text()
+
+
+@pytest.mark.parametrize("program", ["train-1-device", "serve-tensor-1",
+                                     "serve-tensor-2"])
+def test_programs_the_layout_must_not_touch(program, monkeypatch):
+    """Where no layout is to be stated the helper changes nothing: the
+    train step on a mesh of one device, and the serving programs (cache
+    branches) with and without a tensor axis, lower to the same text with
+    `constrain` in place and with it bypassed."""
+    from ray_tpu.models import llama
+
+    def lower():
+        if program == "train-1-device":
+            return (_lower_llama_step(2, False)[2].as_text(),)
+        return _serve_programs(int(program[-1]))
+
+    with_helper = lower()
+    monkeypatch.setattr(llama, "constrain", lambda x, names: x)
+    assert lower() == with_helper
 
 
 def test_zero1_apply_step_matches_fused():
