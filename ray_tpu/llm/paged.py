@@ -31,7 +31,8 @@ import os
 import queue
 import re
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (TYPE_CHECKING, Any, Callable, Dict, List, Optional,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,9 @@ from ._metrics import llm_metrics
 from .engine import GenerationRequest
 from .radix import RadixPrefixCache
 
+if TYPE_CHECKING:
+    from ..models.falcon_h1 import FalconH1Config
+
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
 _GAUGE_TAGS = {"engine": "paged", "pid": str(os.getpid())}
@@ -52,7 +56,12 @@ _GAUGE_TAGS = {"engine": "paged", "pid": str(os.getpid())}
 
 @dataclasses.dataclass
 class PagedEngineConfig:
-    model: LlamaConfig
+    # What the engine asks of a model's configuration: its layer count, kv
+    # heads and head size, its type, and its flax module (`module()`; a
+    # LlamaConfig's is LlamaModel). One whose rows carry recurrent state
+    # beside their pages says in what shape and type (`state_shapes()`)
+    # and makes it (`init_state(rows)`); `_module_of` / `_recurrent` below.
+    model: Union[LlamaConfig, "FalconH1Config"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -70,15 +79,25 @@ class PagedEngineConfig:
         return -(-self.max_len // self.page_size)
 
 
+def _module_of(cfg):
+    return LlamaModel(cfg) if isinstance(cfg, LlamaConfig) else cfg.module()
+
+
+def _recurrent(cfg) -> bool:
+    """Whether a row of this model carries recurrent state (a scan
+    layer's) beside its K/V pages."""
+    return hasattr(cfg, "state_shapes")
+
+
 @functools.lru_cache(maxsize=8)
-def _param_init(cfg: LlamaConfig, mesh):
+def _param_init(cfg, mesh):
     """(init program, param shardings) of a model on a mesh. Random
     weights are made ON the device(s), already in their final layout, by
     one jitted program — an eager flax init dispatches (and compiles)
     every initializer op by op. Cached, so engines of one configuration
     share one trace and one compile."""
     from ..parallel.mesh import unbox
-    model = LlamaModel(cfg)
+    model = _module_of(cfg)
     sample = jnp.zeros((1, 8), jnp.int32)
     pshard = None
     if mesh is not None:
@@ -95,9 +114,10 @@ def _param_init(cfg: LlamaConfig, mesh):
 
 def pool_copies(compiled_text: str, pool_shape) -> int:
     """`copy` ops of a compiled program (`compiled.as_text()`) whose
-    result has the shape of one whole page pool: each moves the pool to
-    another layout or memory. Indexed by (page, offset) alone, the decode
-    token's write cost four a layer a tick (PERF.md, PR 29)."""
+    result has the shape of one whole pool (a page pool's, a state
+    pool's): each moves the pool to another layout or memory. Indexed by
+    (page, offset) alone, the decode token's write cost four a layer a
+    tick (PERF.md, PR 29)."""
     dims = ",".join(map(str, pool_shape))
     return len(re.findall(
         rf"= \w+\[{dims}\]\S* copy(?:-done)?\(", compiled_text))
@@ -168,10 +188,14 @@ class PagedLLMEngine:
                  params: Optional[Any] = None, mesh=None):
         self.config = config
         cfg = config.model
-        self.model = LlamaModel(cfg)
+        self.model = _module_of(cfg)
         self.mesh = mesh
         self._tp = int(mesh.shape.get("tensor", 1)) if mesh is not None \
             else 1
+        if self._tp > 1 and _recurrent(cfg):
+            raise NotImplementedError(
+                "recurrent state over a tensor mesh is not built: the "
+                "state pool is not sharded")
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -207,6 +231,17 @@ class PagedLLMEngine:
             return z
         self.k_pages = [_zero_pages() for _ in range(cfg.num_layers)]
         self.v_pages = [_zero_pages() for _ in range(cfg.num_layers)]
+        # recurrent state beside the pages, for a model that has it: per
+        # layer (conv, ssm) pools of max_batch rows, row = slot index (a
+        # slot that is not decoding is masked out of the decode step, and
+        # an install overwrites a row whole); None otherwise
+        self.state = cfg.init_state(config.max_batch) \
+            if _recurrent(cfg) else None
+        # (slot, staged state) of prefills finished this tick, installed
+        # in the tick's `state` phase
+        self._state_due: List[Tuple[int, Any]] = []
+        self._state_installs = 0
+        self._prefix_skipped_recurrent = 0
         self.pool = PagePool(P)
         self.radix = RadixPrefixCache(
             self.pool, ps, max_entries=int(CONFIG.prefix_cache_entries))
@@ -368,6 +403,82 @@ class PagedLLMEngine:
             return out
 
         self._gather_pages = jax.jit(gather_pages, donate_argnums=(2,))
+        if self.state is not None:
+            self._recurrent_programs()
+
+    def _recurrent_programs(self):
+        """The programs of a model whose rows carry recurrent state, in
+        place of the three above that would not know it: the decode step
+        takes and returns the state pools donated beside the page pools,
+        a prefill chunk hands (conv, ssm) on in its staging pytree and is
+        told how many of its tokens are real, and `write_state` installs a
+        finished prefill's state into its slot."""
+        config, cfg, model = self.config, self.config.model, self.model
+        layers = cfg.num_layers
+
+        def decode_step(params, k_pages, v_pages, state, active,
+                        block_tables, lengths, tokens, rng, temperature,
+                        top_k, top_p):
+            caches = [
+                {"k": k_pages[i], "v": v_pages[i], "conv": state[i][0],
+                 "ssm": state[i][1], "active": active,
+                 "block_tables": block_tables, "lengths": lengths}
+                for i in range(layers)]
+            logits, new = model.apply(
+                {"params": params}, tokens, positions=lengths[:, None],
+                kv_caches=caches, cache_index=None)
+            from .sampling import sample_tokens
+            last = logits[:, -1, :].astype(jnp.float32)
+            # sample_tokens sorts the vocabulary whatever is asked (17.8 ms
+            # of a 37.8 ms step at 48 rows x 261,120: PERF.md, PR 32) and
+            # returns the argmax for a row whose temperature is 0: skip it
+            # when every row's is
+            out = jax.lax.cond(
+                jnp.any(temperature > 0),
+                lambda: sample_tokens(rng, last, temperature, top_k,
+                                      top_p).astype(jnp.int32),
+                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
+            return (out, [c[0] for c in new],
+                    [c[1] for c in new], [(c[2], c[3]) for c in new])
+
+        self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3))
+
+        def chunk_prefill(params, tokens, positions, staged, offset, valid):
+            """One prefill chunk of one row. `staged`: {"kv": per-layer
+            dense (k, v), "state": per-layer (conv, ssm)}. Attention
+            overwrites or masks the padded tail; the mixer is told
+            `valid`, the count of real tokens, and keeps the rest out of
+            the state it hands on."""
+            caches = [kv + st for kv, st
+                      in zip(staged["kv"], staged["state"])]
+            logits, new = model.apply(
+                {"params": params}, tokens, positions=positions,
+                kv_caches=caches, cache_index=offset, valid=valid)
+            return logits.astype(jnp.float32), {
+                "kv": [c[:2] for c in new], "state": [c[2:] for c in new]}
+
+        self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
+
+        def _staging_zero():
+            slack = config.prefill_buckets[-1]   # as _dense_zero_caches
+            length = config.pages_per_seq * config.page_size + slack
+            shape = (1, cfg.num_kv_heads, length, cfg.head_dim_)
+            return {"kv": [(jnp.zeros(shape, cfg.dtype),
+                            jnp.zeros(shape, cfg.dtype))
+                           for _ in range(layers)],
+                    "state": cfg.init_state(1)}
+
+        self._dense_zero_caches = jax.jit(_staging_zero)
+
+        def write_state(state, staged, slot):
+            """A finished prefill's state into row `slot` of every pool
+            (the slot's earlier occupant is overwritten whole)."""
+            return [tuple(jax.lax.dynamic_update_slice_in_dim(
+                pool, new.astype(pool.dtype), slot, axis=0)
+                for pool, new in zip(pools, news))
+                for pools, news in zip(state, staged)]
+
+        self._write_state = jax.jit(write_state, donate_argnums=(0,))
 
     def lower_decode(self):
         """The decode step lowered at this engine's shapes, from shapes
@@ -382,11 +493,13 @@ class PagedLLMEngine:
         def vec(dtype, *shape):
             return jax.ShapeDtypeStruct((B,) + shape, dtype)
 
+        state = () if self.state is None else (
+            jax.tree_util.tree_map(like, self.state), vec(jnp.bool_))
         with self._mesh_scope():
             return self._decode.lower(
                 jax.tree_util.tree_map(like, self.params),
                 [like(p) for p in self.k_pages],
-                [like(p) for p in self.v_pages],
+                [like(p) for p in self.v_pages], *state,
                 vec(jnp.int32, cfg.pages_per_seq), vec(jnp.int32),
                 vec(jnp.int32, 1),
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
@@ -407,6 +520,17 @@ class PagedLLMEngine:
         pool = self.k_pages[0]
         return pool_copies(compiled_text,
                            pool.sharding.shard_shape(pool.shape))
+
+    def state_copies(self, compiled_text: str) -> int:
+        """Whole-pool copies (`pool_copies`) at the shape of this engine's
+        scan-state pool, the large one (0 for a model without recurrent
+        state). The decode step must hold none: it updates the donated
+        pool in place, one read and one write. The convolution's window
+        pool is not counted: a few MB a layer, shifted whole every tick,
+        which the TPU compiler stages through fast memory."""
+        if self.state is None:
+            return 0
+        return pool_copies(compiled_text, self.state[0][1].shape)
 
     def _mesh_scope(self):
         """Context for jit calls: marks the serving mesh active so the
@@ -448,8 +572,15 @@ class PagedLLMEngine:
         prompt's final-position logits. Admission (page budget, prefix
         sharing) happens on the normal scheduler tick, which installs
         them where a local prefill would have finished its last chunk."""
+        self._no_recurrent("submit_prefilled")
         request._prefilled = (dense_caches, last_logits)  # type: ignore
         self.submit(request, done_callback, token_callback)
+
+    def _no_recurrent(self, what: str):
+        if self.state is not None:
+            raise NotImplementedError(
+                f"{what} ships K/V only: a model whose rows carry "
+                "recurrent state cannot be prefilled on another engine yet")
 
     def cancel(self, request_id: str) -> bool:
         """Abort a request: frees its slot+pages on the next tick if
@@ -543,10 +674,10 @@ class PagedLLMEngine:
         running batch — admission happens every tick, not per drain.
 
         The accel plane's `tick` row splits it by phase (README, "Tick
-        phases"): reap / admit / prefill / grow / stage / dispatch /
-        wait / emit / gauges tile the tick, and `between` is the time
-        since the last tick's end while work was waiting — the serving
-        loop's executor hop and whatever else held this thread."""
+        phases"): reap / admit / prefill / state / grow / stage /
+        dispatch / wait / emit / gauges tile the tick, and `between` is
+        the time since the last tick's end while work was waiting — the
+        serving loop's executor hop and whatever else held this thread."""
         entered = time.perf_counter()
         finished: List[Tuple[GenerationRequest, Any]] = []
         tick = _accel.StepTimer("tick", sink=self._tick_accum)
@@ -561,6 +692,9 @@ class PagedLLMEngine:
                 self._prefill_tick(finished)
                 active = [i for i, s in enumerate(self.seqs)
                           if s.request is not None and s.phase == "decode"]
+            if self._state_due:
+                with tick.phase("state"):
+                    self._install_states()
             if active:
                 self._decode_tick(active, tick.phase, finished)
             with tick.phase("gauges"):
@@ -597,6 +731,15 @@ class PagedLLMEngine:
         metrics.waiting.set(self._waiting_count(), tags=_GAUGE_TAGS)
         metrics.shared_pages.set(self.radix.shared_pages(),
                                  tags=_GAUGE_TAGS)
+
+    def _install_states(self):
+        """`write_state` for every prefill that finished this tick."""
+        with self._mesh_scope():
+            for slot, staged in self._state_due:
+                self.state = self._write_state(
+                    self.state, staged, jnp.asarray(slot, jnp.int32))
+                self._state_installs += 1
+        self._state_due.clear()
 
     def _reap_cancelled(self):
         """Release cancelled sequences in ANY phase (a mid-prefill
@@ -790,10 +933,13 @@ class PagedLLMEngine:
         if trace:
             chunk_t0 = time.monotonic()
             compile_t0 = self._compile_total()
+        # a scan layer must be told where the bucket's padding starts
+        valid = () if self.state is None \
+            else (jnp.asarray(take, jnp.int32),)
         with self._mesh_scope():
             logits, seq.dense_caches = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                seq.dense_caches, jnp.asarray(off, jnp.int32))
+                seq.dense_caches, jnp.asarray(off, jnp.int32), *valid)
         last = off + take == len(prompt)
         if last:
             seq.last_logits = np.asarray(logits[0, take - 1], np.float64)
@@ -804,6 +950,7 @@ class PagedLLMEngine:
             reqtrace.record(
                 seq.request.request_id, reqtrace.PREFILL_CHUNK,
                 tokens=take, bucket=chunk, fenced=last or None,
+                valid=take if valid else None,
                 dur_s=round(time.monotonic() - chunk_t0, 6),
                 compile_s=round(
                     self._compile_total() - compile_t0, 6) or None)
@@ -832,10 +979,13 @@ class PagedLLMEngine:
         request = seq.request
         prompt = seq.prompt
         write_ids = seq.pages[seq.own_from:]
+        staged = seq.dense_caches
+        if self.state is not None:
+            self._state_due.append((index, staged["state"]))
+            staged = staged["kv"]
         if write_ids:
-            self._write_owned_pages(seq.dense_caches, write_ids,
-                                    seq.own_from)
-        seq.dense_caches = None
+            self._write_owned_pages(staged, write_ids, seq.own_from)
+        seq.dense_caches = staged = None
         self._register_prefix(prompt, seq.pages)
         first_token = self._first_token(request, seq.last_logits)
         seq.last_logits = None
@@ -922,7 +1072,8 @@ class PagedLLMEngine:
         request = seq.request
         # generated-so-far becomes a prompt extension; re-admission
         # radix-matches the already-registered prompt pages, so only
-        # the generated span (plus the partial page) re-prefills
+        # the generated span (plus the partial page) re-prefills (all of
+        # it for a model with recurrent state, which registers none)
         request._resume_tokens = seq.resume + list(seq.generated)
         self._release(seq)
         self.seqs[index] = _Seq()
@@ -947,6 +1098,7 @@ class PagedLLMEngine:
         disaggregation (reference:
         llm/_internal/serve/deployments/prefill_decode_disagg/) — the KV
         ships to a decode engine's `submit_prefilled`."""
+        self._no_recurrent("prefill_only")
         seq = _Seq(prompt=list(prompt))
         self._stage_prefill_cache(seq)
         while seq.prefill_off < len(seq.prompt):
@@ -982,7 +1134,12 @@ class PagedLLMEngine:
 
     def _match_prefix(self, prompt: List[int]) -> List[int]:
         """Longest cached full-page prefix of `prompt`: refcounted page
-        ids the caller maps copy-on-write into its block table."""
+        ids the caller maps copy-on-write into its block table. Pages
+        carry no recurrent state, so a model that has it matches nothing
+        (and `_register_prefix` registers nothing)."""
+        if self.state is not None:
+            self._prefix_skipped_recurrent += 1
+            return []
         shared = self.radix.match(prompt)
         if shared:
             self._prefix_hits += 1
@@ -995,6 +1152,8 @@ class PagedLLMEngine:
     def _register_prefix(self, prompt: List[int], pages: List[int]):
         """Commit the full prompt pages for reuse; the radix enforces
         the entry budget (`RTPU_PREFIX_CACHE_ENTRIES`)."""
+        if self.state is not None:
+            return
         n_full = len(prompt) // self.config.page_size
         # re-read the flag so tests / live reconfig take effect
         self.radix.max_entries = int(CONFIG.prefix_cache_entries)
@@ -1116,9 +1275,17 @@ class PagedLLMEngine:
                                 key, jnp.asarray(temps),
                                 jnp.asarray(top_ks), jnp.asarray(top_ps))
                     with phase("dispatch"):
-                        out, self.k_pages, self.v_pages = self._decode(
-                            self.params, self.k_pages, self.v_pages,
-                            *args)
+                        if self.state is None:
+                            out, self.k_pages, self.v_pages = self._decode(
+                                self.params, self.k_pages, self.v_pages,
+                                *args)
+                        else:
+                            live = np.zeros((B,), bool)
+                            live[active] = True
+                            (out, self.k_pages, self.v_pages,
+                             self.state) = self._decode(
+                                self.params, self.k_pages, self.v_pages,
+                                self.state, jnp.asarray(live), *args)
                         # freed here, inside a phase, not after the last
                         del args
                     with phase("wait"):
@@ -1190,6 +1357,13 @@ class PagedLLMEngine:
             "prefix_hits": self._prefix_hits,
             "prefix_misses": self._prefix_misses,
             "preemptions": self._preemptions,
+            # recurrent state beside the pages (zeros for a model
+            # without it)
+            "state_bytes": sum(
+                a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(self.state)),
+            "state_installs": self._state_installs,
+            "prefix_skipped_recurrent": self._prefix_skipped_recurrent,
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
             "tp": self._tp,

@@ -375,7 +375,8 @@ class LLMServer:
         the backend's platform, device kind and count, each device's
         `memory_stats()`, the accel plane's compile and step folds, and
         (paged engine) the Pallas kernels found in the compiled decode
-        step. The caller — a driver that must stay
+        step and the whole-pool copies in it (page pools, recurrent-state
+        pools: both 0). The caller — a driver that must stay
         off JAX — learns from this whether a chip lease became a chip."""
         def probe():
             import os
@@ -396,6 +397,8 @@ class LLMServer:
                 text = self._engine.decode_program_text()
                 report["decode_kernels"] = pallas_kernels(text)
                 report["decode_pool_copies"] = self._engine.pool_copies(text)
+                report["decode_state_copies"] = \
+                    self._engine.state_copies(text)
             return report
         # off-loop: the probe compiles, and a blocked loop fails the
         # replica's health check

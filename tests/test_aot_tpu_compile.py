@@ -317,3 +317,98 @@ def test_chip_smoke_refuses_to_pass_off_chip(args):
     assert '"ok": true' not in out.stdout
     with pytest.raises(ValueError):
         json.loads(last)  # a failure prints no result object
+
+
+# ---------------------------------------------------------------------------
+# the hybrid cell's programs (a Mamba-2 mixer beside 5:1 GQA attention)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def falcon_programs(v5e):
+    """The `serve-falconh1-chat-closed128` cell's engine programs (its
+    config file's widths, rows and pool, its builder) at one layer, with
+    the shapes of their arguments on one described chip: the engine's own
+    `_recurrent_programs`, on an engine that never allocated anything."""
+    from benchmarks.harness.builders_falcon_h1 import falcon_h1_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "falcon-h1-34b-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=1)
+    engine_cfg = falcon_h1_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._recurrent_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = placed(jax.eval_shape(lambda: unbox(engine.model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    rows = engine_cfg.max_batch
+    pool = (cfg.num_kv_heads, engine_cfg.num_pages, engine_cfg.page_size,
+            cfg.head_dim)
+    return {
+        "engine": engine, "cfg": cfg, "pool": pool, "spec": spec,
+        "params": params, "rows": rows,
+        "pages": [spec(cfg.dtype, *pool)],
+        "state": placed(jax.eval_shape(lambda: cfg.init_state(rows))),
+        "staged": placed(jax.eval_shape(engine._dense_zero_caches))}
+
+
+def test_hybrid_decode_step_compiles_for_v5e_and_copies_no_pool(
+        falcon_programs, as_tpu):
+    """5:1 grouping (20 query heads on 4 kv heads) through the stock paged
+    kernel, and the donated pools, the pages' and the scan state's
+    [rows, 32, 128, 256] float32, updated in place."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = falcon_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pages"], p["pages"], p["state"],
+        spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, p["engine"].config.pages_per_seq),
+        spec(jnp.int32, rows), spec(jnp.int32, rows, 1),
+        spec(jnp.uint32, 2), spec(jnp.float32, rows),
+        spec(jnp.int32, rows), spec(jnp.float32, rows)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_attention": 1}
+    ssm = p["state"][0][1].shape
+    assert ssm == (rows, cfg.mamba_n_heads, cfg.mamba_d_head,
+                   cfg.mamba_d_state)
+    assert "f32[" + ",".join(map(str, ssm)) + "]" in text
+    assert pool_copies(text, ssm) == 0
+    assert pool_copies(text, p["pool"]) == 0
+    # both page pools (bf16) and the scan state (float32) alias in place
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * 2 * math.prod(p["pool"]) + 4 * math.prod(ssm)
+
+
+def test_hybrid_prefill_chunk_compiles_for_v5e(falcon_programs, as_tpu):
+    """The largest bucket (two chunks of the scan): the staging pytree
+    (dense K/V and one row's state) is donated and aliased, nothing of a
+    pool's shape is in it, and its temporaries stay far under what the
+    cell's memory plan leaves. (The staged caches themselves, a few MB
+    each, the compiler stages through fast memory: copies, no relayout.)"""
+    from ray_tpu.llm.paged import pool_copies
+    p = falcon_programs
+    spec = p["spec"]
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 256), spec(jnp.int32, 1, 256),
+        p["staged"], spec(jnp.int32), spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pool_copies(text, p["pool"]) == 0
+    assert pool_copies(text, p["state"][0][1].shape) == 0
+    staged_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(p["staged"]))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= staged_bytes
+    assert memory.temp_size_in_bytes < 0.5e9
