@@ -288,7 +288,10 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
         f"pool-shaped copies in the decode step {pool_copies}; "
         f"stats steps={stats['steps']} tokens={stats['tokens_generated']} "
         f"prefix_hits={stats['prefix_hits']} "
-        f"leaked_pages={stats['leaked_pages']} tp={stats['tp']}")
+        f"leaked_pages={stats['leaked_pages']} tp={stats['tp']} "
+        f"lookahead_ticks={stats['lookahead_ticks']} "
+        f"drained_by={json.dumps(stats['drained_by'])} "
+        f"discarded_tokens={stats['discarded_tokens']}")
     wait_pid_gone(report["pid"], f"serve[{label}] replica")
     check_device(plan, device, f"serve[{label}] replica")
     if not plan.rehearse:
@@ -306,6 +309,13 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
               f"no longer agree on the pool's layout")
     check(stats["leaked_pages"] == 0,
           f"{stats['leaked_pages']} leaked KV pages")
+    # the tick runs one decode step ahead of the host (PERF.md, PR 33):
+    # a run that decodes at all dispatches most steps before it reads
+    # the one before, and reads without a step behind only to end
+    check(stats["lookahead_ticks"] > 4 * stats["drained_ticks"],
+          f"the step ahead was there for {stats['lookahead_ticks']} decode "
+          f"steps and missing for {stats['drained_ticks']} "
+          f"({stats['drained_by']}): the tick is serial again")
     compiles = report["compile"]
     say(f"smoke observation: serve[{label}] one cold run: "
         f"{compiles['compiles']} compiles, "

@@ -651,7 +651,9 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
                 steps: int = 1,
                 comm_s: float = 0.0,
                 phases: Optional[Dict[str, float]] = None,
-                cpu_s: float = 0.0) -> Optional[Dict[str, float]]:
+                cpu_s: float = 0.0,
+                counters: Optional[Dict[str, float]] = None
+                ) -> Optional[Dict[str, float]]:
     """Fold one step (or ``steps`` uniform steps) into the process's
     step telemetry: step-time histogram, tokens/s EWMA gauge, MFU gauge
     (``flops`` = total FLOPs the interval performed, divided by wall
@@ -661,8 +663,10 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
     host-blocked = wall − compile − device − comm). ``phases`` (a
     StepTimer's named intervals, seconds by name) and ``cpu_s`` (the
     stepping thread's own CPU seconds) are summed into the kind's
-    ``step_summary()`` row as they come. Returns the derived numbers,
-    or None when the plane is disabled."""
+    ``step_summary()`` row as they come, and so are ``counters`` (a
+    StepTimer's ``count()``: events by name, whatever the step's owner
+    counts). Returns the derived numbers, or None when the plane is
+    disabled."""
     if accel_disabled() or wall_s <= 0:
         return None
     metrics = accel_metrics()
@@ -703,7 +707,7 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
             "steps": 0, "wall_s": 0.0, "tokens": 0,
             "compile_s": 0.0, "device_s": 0.0, "comm_s": 0.0,
             "host_s": 0.0, "tokens_per_s": 0.0, "mfu": 0.0,
-            "cpu_s": 0.0, "phases": {}})
+            "cpu_s": 0.0, "phases": {}, "counters": {}})
         agg["steps"] += steps
         agg["wall_s"] += wall_s
         agg["tokens"] += tokens
@@ -713,6 +717,7 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
         agg["host_s"] += host_s
         agg["cpu_s"] += cpu_s
         _sum_phases(agg["phases"], phases)
+        _sum_phases(agg["counters"], counters)
         if tokens_per_s is not None:
             prev = agg["tokens_per_s"]
             agg["tokens_per_s"] = tokens_per_s if not prev else \
@@ -731,7 +736,8 @@ def step_summary() -> List[Dict[str, Any]]:
     with _STEP_LOCK:
         out = []
         for kind, agg in _step_stats.items():
-            row = dict(agg, kind=kind, phases=dict(agg["phases"]))
+            row = dict(agg, kind=kind, phases=dict(agg["phases"]),
+                       counters=dict(agg["counters"]))
             steps = max(1, int(agg["steps"]))
             row["mean_step_s"] = agg["wall_s"] / steps
             out.append(row)
@@ -749,7 +755,7 @@ class StepAccumulator:
 
     __slots__ = ("kind", "every", "device_kind",
                  "_n", "_wall", "_tokens", "_device", "_compile",
-                 "_comm", "_flops", "_cpu", "_phases")
+                 "_comm", "_flops", "_cpu", "_phases", "_counters")
 
     def __init__(self, kind: str, every: int = 16,
                  device_kind: Optional[str] = None):
@@ -761,12 +767,14 @@ class StepAccumulator:
         self._comm = self._flops = self._cpu = 0.0
         self._tokens = 0
         self._phases: Dict[str, float] = {}
+        self._counters: Dict[str, float] = {}
 
     def add(self, wall_s: float, tokens: int = 0, device_s: float = 0.0,
             compile_s: float = 0.0, flops: float = 0.0,
             comm_s: float = 0.0,
             phases: Optional[Dict[str, float]] = None,
-            cpu_s: float = 0.0):
+            cpu_s: float = 0.0,
+            counters: Optional[Dict[str, float]] = None):
         self._n += 1
         self._wall += wall_s
         self._tokens += tokens
@@ -776,6 +784,7 @@ class StepAccumulator:
         self._flops += flops
         self._cpu += cpu_s
         _sum_phases(self._phases, phases)
+        _sum_phases(self._counters, counters)
         if self._n >= self.every:
             self.flush()
 
@@ -787,12 +796,14 @@ class StepAccumulator:
             self.kind, self._wall, tokens=self._tokens,
             device_s=self._device, compile_s=self._compile,
             flops=self._flops, device_kind=self.device_kind, steps=n,
-            comm_s=self._comm, phases=self._phases, cpu_s=self._cpu)
+            comm_s=self._comm, phases=self._phases, cpu_s=self._cpu,
+            counters=self._counters)
         self._n = 0
         self._wall = self._device = self._compile = 0.0
         self._comm = self._flops = self._cpu = 0.0
         self._tokens = 0
         self._phases = {}
+        self._counters = {}
         return out
 
 
@@ -840,8 +851,8 @@ class StepTimer:
     built and nothing is reported."""
 
     __slots__ = ("kind", "tokens", "flops", "device_kind", "enabled",
-                 "phases", "cpu_s", "result", "sink", "_t0", "_c0",
-                 "_cpu0", "_span")
+                 "phases", "counters", "cpu_s", "result", "sink", "_t0",
+                 "_c0", "_cpu0", "_span")
 
     def __init__(self, kind: str, tokens: int = 0, flops: float = 0.0,
                  device_kind: Optional[str] = None,
@@ -853,6 +864,7 @@ class StepTimer:
         self.sink = sink
         self.enabled = not accel_disabled()
         self.phases: Dict[str, float] = {}
+        self.counters: Dict[str, float] = {}
         self.cpu_s = 0.0
         self.result: Optional[Dict[str, float]] = None
         self._t0 = 0.0
@@ -902,6 +914,12 @@ class StepTimer:
         if self.enabled:
             self.phases[name] = self.phases.get(name, 0.0) + seconds
 
+    def count(self, name: str, n: float = 1) -> None:
+        """``n`` more events of ``name`` in this step: summed by name
+        into the kind's ``step_summary()`` row under ``counters``."""
+        if self.enabled and n:
+            self.counters[name] = self.counters.get(name, 0) + n
+
     def __exit__(self, exc_type, exc, tb):
         if not self.enabled:
             return False
@@ -916,14 +934,15 @@ class StepTimer:
             self.sink.add(wall, tokens=self.tokens,
                           device_s=self.device_s, compile_s=compile_s,
                           flops=self.flops, comm_s=self.comm_s,
-                          phases=self.phases, cpu_s=self.cpu_s)
+                          phases=self.phases, cpu_s=self.cpu_s,
+                          counters=self.counters)
         else:
             self.result = report_step(
                 self.kind, wall, tokens=self.tokens,
                 device_s=self.device_s, compile_s=compile_s,
                 flops=self.flops, device_kind=self.device_kind,
                 comm_s=self.comm_s, phases=self.phases,
-                cpu_s=self.cpu_s)
+                cpu_s=self.cpu_s, counters=self.counters)
         return False
 
 

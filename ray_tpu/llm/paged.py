@@ -19,6 +19,11 @@ tokens as a prompt extension) instead of exhausting the pool. Prefix
 reuse rides a radix tree over KV pages (`radix.py`): admission maps the
 longest cached prefix copy-on-write into the block table and prefills
 only the tail.
+
+The tick runs one decode step ahead of the host: a visit dispatches step
+n+1 before it reads step n's tokens, which stay on the device and feed
+step n+1 there, so the host's part of a tick runs while the chip
+computes (`PagedLLMEngine.step`).
 """
 
 from __future__ import annotations
@@ -112,6 +117,11 @@ def _param_init(cfg, mesh):
     return jax.jit(init_params, out_shardings=pshard), pshard
 
 
+def _no_phase(name: str):
+    """`StepTimer.phase` for a caller outside any timer."""
+    return contextlib.nullcontext()
+
+
 def pool_copies(compiled_text: str, pool_shape) -> int:
     """`copy` ops of a compiled program (`compiled.as_text()`) whose
     result has the shape of one whole pool (a page pool's, a state
@@ -121,6 +131,34 @@ def pool_copies(compiled_text: str, pool_shape) -> int:
     dims = ",".join(map(str, pool_shape))
     return len(re.findall(
         rf"= \w+\[{dims}\]\S* copy(?:-done)?\(", compiled_text))
+
+
+@jax.jit
+def logits_row(logits, index):
+    """Row `index` of a prefill chunk's logits `[1, chunk, vocab]`, as
+    `[1, vocab]` float32: what the prompt's first token is sampled from
+    (one small program a bucket; the chunk's logits are free after it)."""
+    return jax.lax.dynamic_index_in_dim(
+        logits[0], index, axis=0).astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("sampled",))
+def first_token(tokens, last, slot, rng, temperature, top_k, top_p,
+                sampled):
+    """The token a prompt's prefill ends in, from its last position's
+    logits `last` ([1, vocab]; the [1] parameter vectors are the
+    request's), into row `slot` of the engine's token vector: the first
+    token reaches the host with the next read of the vector, not through
+    a fetch of its own. `sampled`: the request has a temperature, so the
+    token is drawn as `decode_step` draws; the argmax otherwise, in a
+    program of its own (the sampler sorts the vocabulary whatever is
+    asked, and compiling that sort takes the TPU's compiler 10-35 s)."""
+    if sampled:
+        from .sampling import sample_tokens
+        token = sample_tokens(rng, last, temperature, top_k, top_p)
+    else:
+        token = jnp.argmax(last, axis=-1)
+    return tokens.at[slot].set(token[0].astype(jnp.int32))
 
 
 class PagePool:
@@ -158,7 +196,11 @@ class _Seq:
     own_from: int = 0            # pages[:own_from] are shared (prefix)
     length: int = 0              # cached tokens
     generated: List[int] = dataclasses.field(default_factory=list)
-    last_token: int = 0
+    # tokens computed or in flight for this row since admission: the one
+    # its prefill ends in, then one a dispatched decode step. `generated`
+    # holds those the host has read; `length` counts a dispatched step's
+    # token as cached already
+    dispatched: int = 0
     cancelled: bool = False
     # continuous-batching state
     phase: str = "decode"        # "prefill" until the prompt is cached
@@ -167,6 +209,7 @@ class _Seq:
     resume: List[int] = dataclasses.field(default_factory=list)
     prefill_off: int = 0         # prompt tokens cached so far
     dense_caches: Any = None     # in-flight chunked-prefill cache
+    # logits at the prompt's last position, [1, vocab] on the device
     last_logits: Any = None
     admit_at: int = 0            # admission order (preemption picks max)
 
@@ -261,6 +304,26 @@ class PagedLLMEngine:
         self._by_id: Dict[str, _Seq] = {}
         self._steps = 0
         self._tokens_generated = 0
+        # One decode step of lookahead. `_tokens` is the token vector on
+        # the device, row = slot: the last dispatched decode step's
+        # output, with the token a finished prefill ends in written at
+        # its row since. It is the next step's input as it stands and
+        # makes no trip through the host. `_unread` lists the (slot, seq)
+        # whose newest token is in it and has not been read.
+        self._tokens = jnp.zeros((config.max_batch,), jnp.int32)
+        if mesh is not None:
+            self._tokens = jax.device_put(
+                self._tokens, NamedSharding(mesh, PSpec()))
+        self._unread: List[Tuple[int, _Seq]] = []
+        # requests that ended since step() last returned
+        self._finished: List[Tuple[GenerationRequest, Any]] = []
+        # decode steps dispatched before the tokens of the step before
+        # were read / reads with nothing dispatched behind them, by reason
+        # / tokens computed for a row found finished or cancelled a tick
+        # late (dropped: never emitted, never counted)
+        self._lookahead_ticks = 0
+        self._drained_ticks: Dict[str, int] = {}
+        self._discarded_tokens = 0
         # accelerator-plane step telemetry (StepTimer on the decode
         # tick): decode forward ≈ 2 FLOPs per param per token. Checked
         # once here so a killed plane costs the tick two attribute
@@ -288,13 +351,16 @@ class PagedLLMEngine:
 
         def decode_step(params, k_pages, v_pages, block_tables, lengths,
                         tokens, rng, temperature, top_k, top_p):
+            """`tokens`: the vector this step's `out` replaces, [rows] on
+            the device: what the step before sampled for each row."""
             caches = [
                 {"k": k_pages[i], "v": v_pages[i],
                  "block_tables": block_tables, "lengths": lengths}
                 for i in range(cfg.num_layers)
             ]
             logits, new_caches = model.apply(
-                {"params": params}, tokens, positions=lengths[:, None],
+                {"params": params}, tokens[:, None],
+                positions=lengths[:, None],
                 kv_caches=caches, cache_index=None)
             last = logits[:, -1, :].astype(jnp.float32)
             from .sampling import sample_tokens
@@ -425,7 +491,8 @@ class PagedLLMEngine:
                  "block_tables": block_tables, "lengths": lengths}
                 for i in range(layers)]
             logits, new = model.apply(
-                {"params": params}, tokens, positions=lengths[:, None],
+                {"params": params}, tokens[:, None],
+                positions=lengths[:, None],
                 kv_caches=caches, cache_index=None)
             from .sampling import sample_tokens
             last = logits[:, -1, :].astype(jnp.float32)
@@ -501,7 +568,7 @@ class PagedLLMEngine:
                 [like(p) for p in self.k_pages],
                 [like(p) for p in self.v_pages], *state,
                 vec(jnp.int32, cfg.pages_per_seq), vec(jnp.int32),
-                vec(jnp.int32, 1),
+                vec(jnp.int32),
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
                 vec(jnp.float32),
                 vec(jnp.int32), vec(jnp.float32))
@@ -629,6 +696,7 @@ class PagedLLMEngine:
         for i, seq in enumerate(self.seqs):
             if seq.request is not None:
                 self._end_request(seq.request, error, index=i)
+        self._unread.clear()
         self._drain_pending()
         while self._parked:
             self._end_request(self._parked.popleft(), error)
@@ -639,9 +707,18 @@ class PagedLLMEngine:
         """The one way a request leaves the engine. `result` is what its
         waiter receives and names the outcome: the tokens (done), None
         (cancelled, `where` it was) or the exception (error). `index` is
-        the slot it holds, if any: pages released, slot reset."""
+        the slot it holds, if any: pages released, slot reset.
+
+        A row that ends with a decode step still in flight (its EOS or
+        its cancellation was seen a tick late) was computed once more:
+        that token is dropped here, and the step's stale write to the
+        released pages (and state row) lands before any new owner's,
+        because the device runs what it is handed in order and every
+        write of a new owner is dispatched after this release."""
         if index is not None:
-            self._release(self.seqs[index])
+            seq = self.seqs[index]
+            self._discarded_tokens += seq.dispatched - len(seq.generated)
+            self._release(seq)
             self.seqs[index] = _Seq()
         metrics = llm_metrics()
         if result is None:
@@ -667,39 +744,58 @@ class PagedLLMEngine:
 
     # -- scheduler tick ----------------------------------------------------
 
-    def step(self) -> List[Tuple[GenerationRequest, Any]]:
-        """One continuous-batching tick: reap cancellations, fill freed
+    def step(self) -> List[Tuple[GenerationRequest, Any]]:  # rtpu: hot-loop
+        """One visit of the host to the continuous-batching tick, one
+        decode step ahead of it. In order: grow pages for, stage and
+        dispatch decode step n+1 for the rows known to decode again
+        (`_decode_tick`); only then read step n's tokens, emit them and
+        release the rows they finish; reap cancellations, fill freed
         slots from the waiting queue (radix prefix match, tail-only
-        prefill setup), advance bounded chunked prefill, then decode the
-        running batch — admission happens every tick, not per drain.
+        prefill setup), advance bounded chunked prefill and move finished
+        prompts to the decode phase. Every host phase after the dispatch
+        runs while the device computes step n+1. A row that finishes its
+        prompt joins the next visit's dispatch: its first token is sampled
+        on the device into the token vector (`first_token`) and read with
+        that visit's tokens.
 
-        The accel plane's `tick` row splits it by phase (README, "Tick
-        phases"): reap / admit / prefill / state / grow / stage /
-        dispatch / wait / emit / gauges tile the tick, and `between` is
-        the time since the last tick's end while work was waiting — the
-        serving loop's executor hop and whatever else held this thread."""
+        What staging step n+1 needs of step n is deterministic (`length +
+        1`, the page the token lands in, the finish rules that do not
+        look at the token: `_exhausted`). What is not — EOS, a
+        cancellation that lands after the dispatch — is seen one tick
+        late, and the row's extra token dropped (`_end_request`). Whatever
+        needs a token's value on the host first reads the step in flight
+        with nothing behind it (`_drain`): page-pressure preemption, and
+        a visit with no row left to dispatch. An engine without work has
+        nothing unread.
+
+        The accel plane's `tick` row splits the visit by phase (README,
+        "Tick phases"): grow / stage / dispatch / wait / emit / reap /
+        admit / prefill / state / gauges tile it; `wait` is the fetch of
+        the step dispatched one visit earlier; `between` is the time
+        since the last visit's end while work was waiting — the serving
+        loop's executor hop and whatever else held this thread. Its
+        counters say how often the step ahead was there."""
         entered = time.perf_counter()
-        finished: List[Tuple[GenerationRequest, Any]] = []
         tick = _accel.StepTimer("tick", sink=self._tick_accum)
         if self._tick_end is not None:
             tick.outside("between", entered - self._tick_end)
+        before = self._ahead_counts()
         with tick:
+            self._decode_tick(tick.phase)
             with tick.phase("reap"):
                 self._reap_cancelled()
             with tick.phase("admit"):
-                self._admit(finished)
+                self._admit()
             with tick.phase("prefill"):
-                self._prefill_tick(finished)
-                active = [i for i, s in enumerate(self.seqs)
-                          if s.request is not None and s.phase == "decode"]
+                self._prefill_tick()
             if self._state_due:
                 with tick.phase("state"):
                     self._install_states()
-            if active:
-                self._decode_tick(active, tick.phase, finished)
             with tick.phase("gauges"):
                 self._steps += 1
                 self._set_gauges()
+            for name, count in self._ahead_counts().items():
+                tick.count(name, count - before[name])
         if self.has_work():
             self._tick_end = time.perf_counter()
         else:
@@ -707,7 +803,16 @@ class PagedLLMEngine:
             # drained: flush the partial windows so step telemetry
             # never lags an idle engine by up to `every` ticks
             self._flush_step_rows()
+        finished, self._finished = self._finished, []
         return finished
+
+    def _ahead_counts(self) -> Dict[str, int]:
+        """How the step ahead fared so far: decode steps dispatched
+        before the last one's tokens were read, reads with nothing
+        dispatched behind them, tokens dropped a tick late."""
+        return {"lookahead_ticks": self._lookahead_ticks,
+                "drained_ticks": sum(self._drained_ticks.values()),
+                "discarded_tokens": self._discarded_tokens}
 
     def _flush_step_rows(self):
         for accum in (self._step_accum, self._tick_accum):
@@ -796,7 +901,7 @@ class PagedLLMEngine:
                                 parked_s=round(parked, 6))
         return getattr(request, "_rt_park_total", 0.0)
 
-    def _admit(self, finished: List):
+    def _admit(self):
         self._drain_pending()
         for index, seq in enumerate(self.seqs):
             if seq.request is not None:
@@ -817,11 +922,13 @@ class PagedLLMEngine:
                     # where a local prefill would have finished its last
                     # chunk; after a preemption it re-prefills locally
                     del request._prefilled
-                    caches, seq.last_logits = shipped
+                    caches, last_logits = shipped
+                    seq.last_logits = jnp.asarray(
+                        last_logits, jnp.float32)[None, :]
                     seq.dense_caches = [(jnp.asarray(k), jnp.asarray(v))
                                         for (k, v) in caches]
                     seq.prefill_off = len(seq.prompt)
-                    self._finish_prefill(index, finished)
+                    self._finish_prefill(index)
             except Exception as e:  # noqa: BLE001
                 held = self.seqs[index].request is request
                 self._end_request(request, e, index if held else None)
@@ -859,7 +966,7 @@ class PagedLLMEngine:
         seq.own_from = len(shared)
         seq.length = 0
         seq.generated = []
-        seq.last_token = 0
+        seq.dispatched = 0
         seq.cancelled = False
         seq.prefill_off = len(shared) * ps
         seq.dense_caches = None
@@ -887,12 +994,12 @@ class PagedLLMEngine:
                                            dense, jnp.asarray(pad))
         seq.dense_caches = dense
 
-    def _prefill_tick(self, finished: List):
+    def _prefill_tick(self):
         """Advance at most `prefill_decode_ratio` prefill chunks,
         round-robin across prefilling sequences in admission order, so
         a long prompt never stalls the decode batch for more than one
         bounded chunk per tick."""
-        budget = max(1, int(self.config.prefill_decode_ratio))
+        budget = max(1, self.config.prefill_decode_ratio)
         order = sorted(
             (i for i, s in enumerate(self.seqs)
              if s.request is not None and s.phase == "prefill"
@@ -909,7 +1016,7 @@ class PagedLLMEngine:
             self._prefill_chunk(seq)
             budget -= 1
             if seq.prefill_off >= len(seq.prompt):
-                self._finish_prefill(i, finished)
+                self._finish_prefill(i)
             else:
                 order.append(i)
 
@@ -940,16 +1047,15 @@ class PagedLLMEngine:
             logits, seq.dense_caches = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 seq.dense_caches, jnp.asarray(off, jnp.int32), *valid)
-        last = off + take == len(prompt)
-        if last:
-            seq.last_logits = np.asarray(logits[0, take - 1], np.float64)
+        if off + take == len(prompt):
+            # stays on the device: `first_token` samples from it there
+            seq.last_logits = logits_row(logits, np.int32(take - 1))
         seq.prefill_off = off + take
         if trace and seq.request is not None:
-            # only a prompt's last chunk waits for the device (the
-            # fetch above); every other dur_s is the launch alone
+            # no chunk waits for the device: dur_s is the launch alone
             reqtrace.record(
                 seq.request.request_id, reqtrace.PREFILL_CHUNK,
-                tokens=take, bucket=chunk, fenced=last or None,
+                tokens=take, bucket=chunk,
                 valid=take if valid else None,
                 dur_s=round(time.monotonic() - chunk_t0, 6),
                 compile_s=round(
@@ -971,10 +1077,13 @@ class PagedLLMEngine:
                 jnp.asarray(ids, jnp.int32),
                 jnp.asarray(start_page * cfg.page_size, jnp.int32))
 
-    def _finish_prefill(self, index: int, finished: List):
+    def _finish_prefill(self, index: int):
         """Prompt fully cached: write the owned tail pages, commit full
         pages to the radix, sample the first token from the prefill
-        logits, and move the sequence to the decode phase."""
+        logits into the token vector ON THE DEVICE, and move the sequence
+        to the decode phase. Nothing here waits for the device: the host
+        work (the radix insert above all) runs under the decode step in
+        flight, and the token is read with the next visit's."""
         seq = self.seqs[index]
         request = seq.request
         prompt = seq.prompt
@@ -987,48 +1096,45 @@ class PagedLLMEngine:
             self._write_owned_pages(staged, write_ids, seq.own_from)
         seq.dense_caches = staged = None
         self._register_prefix(prompt, seq.pages)
-        first_token = self._first_token(request, seq.last_logits)
+        temp, top_k, top_p = self._sampling(request)
+        key = self._rng
+        if temp > 0:
+            self._rng, key = jax.random.split(self._rng)
+        self._tokens = first_token(
+            self._tokens, seq.last_logits, np.int32(index), key,
+            np.full((1,), temp, np.float32), np.full((1,), top_k, np.int32),
+            np.full((1,), top_p, np.float32), sampled=temp > 0)
         seq.last_logits = None
+        self._tokens.copy_to_host_async()
+        self._unread.append((index, seq))
         seq.phase = "decode"
         seq.length = len(prompt)
-        seq.generated = [first_token]
-        seq.last_token = first_token
-        self._tokens_generated += 1
-        submit_ts = getattr(request, "_submit_ts", None)
-        park_s = getattr(request, "_rt_park_total", 0.0)
-        if submit_ts is not None and not seq.resume:
-            ttft = time.monotonic() - submit_ts
-            llm_metrics().ttft.observe(ttft, tags=_TAGS)
-            self._recent_ttfts.append(ttft)
-            # the DECODE stamp splits a parked request's TTFT: park_s
-            # is the admission-blocked share, the rest is real prefill
-            reqtrace.record(request.request_id, reqtrace.DECODE,
-                            ttft_s=round(ttft, 6),
-                            park_s=round(park_s, 6) or None)
-        else:
-            reqtrace.record(request.request_id, reqtrace.DECODE,
-                            resumed=True,
-                            park_s=round(park_s, 6) or None)
-        self._emit_token(seq, first_token)
-        if self._finished_after(seq, first_token):
-            finished.append(self._finish(index))
+        seq.generated = []
+        seq.dispatched = 1
+
+    def _exhausted(self, seq: _Seq) -> bool:
+        """Whether the newest token computed or in flight for `seq` is
+        its last by the rules that do not look at it: the request's
+        budget (tokens from before a preemption count) or the engine's
+        length cap. Such a row is in no further decode step."""
+        return len(seq.resume) + seq.dispatched \
+            >= seq.request.max_new_tokens \
+            or seq.length >= self.config.max_len - 1
 
     def _finished_after(self, seq: _Seq, token: int) -> bool:
-        """Whether `token`, just emitted, was `seq`'s last: EOS, the
-        request's budget (tokens from before a preemption count), or
-        the engine's length cap."""
-        cfg = self.config
-        generated = len(seq.resume) + len(seq.generated)
-        return (cfg.eos_token is not None and token == cfg.eos_token) \
-            or generated >= seq.request.max_new_tokens \
-            or seq.length >= cfg.max_len - 1
+        """Whether `token`, just emitted, was `seq`'s last: EOS, or the
+        last of an exhausted row."""
+        eos = self.config.eos_token
+        return (eos is not None and token == eos) \
+            or (seq.dispatched == len(seq.generated)
+                and self._exhausted(seq))
 
-    def _finish(self, index: int) -> Tuple[GenerationRequest, List[int]]:
+    def _finish(self, index: int):
         seq = self.seqs[index]
         request = seq.request
         tokens = seq.resume + seq.generated
         self._end_request(request, tokens, index=index)
-        return request, tokens
+        self._finished.append((request, tokens))
 
     def _alloc_page(self) -> Optional[int]:
         """Allocate with radix pressure relief: cold unshared prefix
@@ -1044,32 +1150,33 @@ class PagedLLMEngine:
         exhaustion the YOUNGEST sequence is preempted (pages released,
         request parked at the queue front with its generated tokens as
         a prompt extension) until the rest fit — the continuous-batching
-        answer to OOM."""
+        answer to OOM. A preemption needs every token of its row on the
+        host, so the step in flight is read first (`_drain`), which may
+        itself end rows and free the pages that were short."""
         ps = self.config.page_size
-        alive = sorted(active, key=lambda i: self.seqs[i].admit_at)
-        for i in list(alive):
-            if i not in alive:
-                continue
-            seq = self.seqs[i]
-            while seq.request is not None \
+        rows = {i: self.seqs[i] for i in active}
+        for i in sorted(active, key=lambda i: self.seqs[i].admit_at):
+            seq = rows[i]
+            while self.seqs[i] is seq \
                     and seq.length // ps >= len(seq.pages):
                 page = self._alloc_page()
                 if page is not None:
                     seq.pages.append(page)
                     continue
-                victims = [j for j in alive
-                           if self.seqs[j].request is not None]
-                victim = max(victims,
-                             key=lambda j: self.seqs[j].admit_at)
+                if self._unread:
+                    self._drain("preempt")
+                    continue
+                victim = max((j for j in rows if self.seqs[j] is rows[j]),
+                             key=lambda j: rows[j].admit_at)
                 self._preempt(victim, reason="page_pressure")
-                alive.remove(victim)
-                if victim == i:
-                    break
-        return [i for i in alive if self.seqs[i].request is not None]
+        return [i for i in active if self.seqs[i] is rows[i]]
 
     def _preempt(self, index: int, reason: str):
+        self._drain("preempt")
         seq = self.seqs[index]
         request = seq.request
+        if request is None:
+            return   # ended by a token the drain read
         # generated-so-far becomes a prompt extension; re-admission
         # radix-matches the already-registered prompt pages, so only
         # the generated span (plus the partial page) re-prefills (all of
@@ -1107,30 +1214,7 @@ class PagedLLMEngine:
             self.config.page_size
         out = [(np.asarray(k[:, :, :n_tok]), np.asarray(v[:, :, :n_tok]))
                for (k, v) in seq.dense_caches]
-        return seq.last_logits, out
-
-    def _first_token(self, request: GenerationRequest,
-                     last_logits) -> int:
-        """First token from prefill logits (sampled when the request
-        asks for temperature > 0, mirroring the slot engine's branch —
-        engine.py:195-204 — so the two engines agree beyond greedy)."""
-        temp = request.temperature if request.temperature is not None \
-            else self.config.temperature
-        if temp > 0:
-            self._rng, key = jax.random.split(self._rng)
-            scaled = last_logits / max(temp, 1e-6)
-            # shared host-side filter (sampling.filter_logits) so the
-            # FIRST token honors the request's top_k/top_p too
-            from .sampling import filter_logits
-            scaled = filter_logits(
-                scaled, top_k=getattr(request, "top_k", None) or 0,
-                top_p=getattr(request, "top_p", None))
-            probs = np.exp(scaled - scaled.max())
-            probs /= probs.sum()
-            return int(np.random.default_rng(
-                int(jax.random.randint(key, (), 0, 2**31 - 1))
-            ).choice(len(probs), p=probs))
-        return int(np.argmax(last_logits))
+        return np.asarray(seq.last_logits[0], np.float64), out
 
     def _match_prefix(self, prompt: List[int]) -> List[int]:
         """Longest cached full-page prefix of `prompt`: refcounted page
@@ -1204,34 +1288,102 @@ class PagedLLMEngine:
             out["ttft_s"] = ttfts[len(ttfts) // 2]
         return out
 
-    def _emit_token(self, seq: _Seq, token: int):
-        callback = getattr(seq.request, "_token_callback", None)
-        if callback is not None:
-            callback(seq.request, token)
+    def _sampling(self, request: GenerationRequest):
+        """(temperature, top_k, top_p) as the sampler takes them: 0
+        disables the k filter, 1.0 the nucleus."""
+        temp = request.temperature
+        top_p = getattr(request, "top_p", None)
+        return (temp if temp is not None else self.config.temperature,
+                getattr(request, "top_k", None) or 0,
+                top_p if top_p is not None else 1.0)
+
+    def _drain(self, reason: str, phase=_no_phase):
+        """Read the step in flight with nothing dispatched behind it: the
+        exception path, taken where the host needs a token's value (or
+        has no row to dispatch) before it can go on. Counted by reason."""
+        if not self._unread:
+            return
+        self._drained_ticks[reason] = \
+            self._drained_ticks.get(reason, 0) + 1
+        unread, self._unread = self._unread, []
+        with phase("wait"):
+            values = self._fetch(self._tokens)
+        with phase("emit"):
+            self._emit_tokens(unread, values)
+
+    def _fetch(self, tokens) -> List[int]:
+        """The token vector on the host: the one wait for the device a
+        visit has (for the step dispatched a visit earlier, and for the
+        prefill chunks behind it whose first tokens the vector holds)."""
+        # behind the next step's dispatch wherever a row decodes again
+        return np.asarray(tokens).tolist()  # host-sync ok: the one fetch
+
+    def _emit_tokens(self, unread: List[Tuple[int, _Seq]],
+                     values: List[int]):
+        """Hand the tokens just read to their rows, in slot order within
+        a step and a finished prefill's after them; end the rows they
+        finish. A row that ended since its entry was made is skipped (its
+        token was counted as discarded when it was released), a row
+        cancelled since is released here and its token dropped."""
+        for slot, seq in unread:
+            if self.seqs[slot] is not seq:
+                continue
+            if seq.cancelled:
+                self._end_request(seq.request, None, index=slot,
+                                  where="decode")
+                continue
+            token = values[slot]
+            seq.generated.append(token)
+            self._tokens_generated += 1
+            if len(seq.generated) == 1:
+                self._note_first_token(seq)
+            callback = getattr(seq.request, "_token_callback", None)
+            if callback is not None:
+                callback(seq.request, token)
+            if self._finished_after(seq, token):
+                self._finish(slot)
+
+    def _note_first_token(self, seq: _Seq):
+        request = seq.request
+        submit_ts = getattr(request, "_submit_ts", None)
+        park_s = getattr(request, "_rt_park_total", 0.0)
+        if submit_ts is not None and not seq.resume:
+            ttft = time.monotonic() - submit_ts
+            llm_metrics().ttft.observe(ttft, tags=_TAGS)
+            self._recent_ttfts.append(ttft)
+            # the DECODE stamp splits a parked request's TTFT: park_s
+            # is the admission-blocked share, the rest is real prefill
+            reqtrace.record(request.request_id, reqtrace.DECODE,
+                            ttft_s=round(ttft, 6),
+                            park_s=round(park_s, 6) or None)
+        else:
+            reqtrace.record(request.request_id, reqtrace.DECODE,
+                            resumed=True,
+                            park_s=round(park_s, 6) or None)
 
     def _release(self, seq: _Seq):
         for page in seq.pages:
             self.pool.decref(page)
         self._by_id.pop(seq.request.request_id, None)
 
-    def _decode_tick(self, active: List[int], phase, finished: List):
-        """Decode one token for every row in `active`. `phase` is the
-        tick's `StepTimer.phase`; requests that end here are appended
-        to `finished`."""
+    def _decode_tick(self, phase):  # rtpu: hot-loop
+        """The decode half of a visit: dispatch the next step for every
+        row that will decode again, THEN read the tokens of the step
+        dispatched a visit earlier. `phase` is the tick's
+        `StepTimer.phase`."""
         tick_start = time.monotonic()
         cfg = self.config
         B = cfg.max_batch
         with phase("grow"):
-            # cancelled since this tick's reap: release before the step
-            for i in list(active):
-                seq = self.seqs[i]
-                if seq.cancelled:
-                    active.remove(i)
-                    self._end_request(seq.request, None, index=i,
-                                      where="decode")
-            # lazy page growth (+ preemption under pressure)
-            active = self._ensure_decode_pages(active)
+            # a row whose token in flight is its last by rule, or that was
+            # cancelled, is in no further step; lazy page growth for the
+            # rest (+ preemption under pressure)
+            active = self._ensure_decode_pages([
+                i for i, s in enumerate(self.seqs)
+                if s.request is not None and s.phase == "decode"
+                and not s.cancelled and not self._exhausted(s)])
         if not active:
+            self._drain("idle", phase)
             return
         with phase("stage"):
             trace = not reqtrace.reqtrace_disabled()
@@ -1243,7 +1395,6 @@ class PagedLLMEngine:
                 compile_t0 = self._compile_total()
             block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
             lengths = np.zeros((B,), np.int32)
-            tokens = np.zeros((B, 1), np.int32)
             temps = np.zeros((B,), np.float32)
             top_ks = np.zeros((B,), np.int32)
             top_ps = np.ones((B,), np.float32)
@@ -1251,13 +1402,11 @@ class PagedLLMEngine:
                 seq = self.seqs[i]
                 block_tables[i, :len(seq.pages)] = seq.pages
                 lengths[i] = seq.length
-                tokens[i, 0] = seq.last_token
-                temp = seq.request.temperature
-                temps[i] = temp if temp is not None else cfg.temperature
-                req_k = getattr(seq.request, "top_k", None)
-                top_ks[i] = req_k if req_k else 0
-                req_p = getattr(seq.request, "top_p", None)
-                top_ps[i] = req_p if req_p is not None else 1.0
+                temps[i], top_ks[i], top_ps[i] = \
+                    self._sampling(seq.request)
+                # this step's token, in flight from here on
+                seq.length += 1
+                seq.dispatched += 1
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
         timer = accel.StepTimer(
@@ -1271,32 +1420,41 @@ class PagedLLMEngine:
                       else contextlib.nullcontext()):
                     with phase("stage"):
                         args = (jnp.asarray(block_tables),
-                                jnp.asarray(lengths), jnp.asarray(tokens),
+                                jnp.asarray(lengths), self._tokens,
                                 key, jnp.asarray(temps),
                                 jnp.asarray(top_ks), jnp.asarray(top_ps))
                     with phase("dispatch"):
+                        unread, tokens = self._unread, self._tokens
                         if self.state is None:
-                            out, self.k_pages, self.v_pages = self._decode(
+                            (self._tokens, self.k_pages,
+                             self.v_pages) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
                                 *args)
                         else:
                             live = np.zeros((B,), bool)
                             live[active] = True
-                            (out, self.k_pages, self.v_pages,
+                            (self._tokens, self.k_pages, self.v_pages,
                              self.state) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
                                 self.state, jnp.asarray(live), *args)
+                        # the copy to the host starts when the step ends,
+                        # whatever is queued behind it by then
+                        self._tokens.copy_to_host_async()
+                        self._unread = [(i, self.seqs[i]) for i in active]
                         # freed here, inside a phase, not after the last
                         del args
-                    with phase("wait"):
-                        # fences the dispatch: the host blocks here for
-                        # this tick's un-fenced prefill chunk too
-                        out = np.asarray(out)
-            with phase("emit"):
+                    if unread:
+                        self._lookahead_ticks += 1
+                        with phase("wait"):
+                            values = self._fetch(tokens)
+            if unread:
+                with phase("emit"):
+                    self._emit_tokens(unread, values)
+            with phase("gauges"):
                 if trace:
                     compile_s = self._compile_total() - compile_t0
                     if compile_s > 1e-6:
-                        # every active request's wall clock contained
+                        # every dispatched request's wall clock contained
                         # the stall — charge it to each (why_slow's
                         # compile bucket, subtracted from its decode
                         # span)
@@ -1305,17 +1463,6 @@ class PagedLLMEngine:
                                 rid, reqtrace.COMPILE,
                                 compile_s=round(compile_s, 6),
                                 phase="decode")
-                for i in active:
-                    seq = self.seqs[i]
-                    token = int(out[i])
-                    seq.generated.append(token)
-                    seq.last_token = token
-                    seq.length += 1
-                    self._tokens_generated += 1
-                    self._emit_token(seq, token)
-                    if self._finished_after(seq, token):
-                        finished.append(self._finish(i))
-            with phase("gauges"):
                 metrics = llm_metrics()
                 metrics.token_latency.observe(
                     time.monotonic() - tick_start, tags=_TAGS)
@@ -1357,6 +1504,9 @@ class PagedLLMEngine:
             "prefix_hits": self._prefix_hits,
             "prefix_misses": self._prefix_misses,
             "preemptions": self._preemptions,
+            # the step ahead (`_ahead_counts`; `drained_by`: why)
+            **self._ahead_counts(),
+            "drained_by": dict(self._drained_ticks),
             # recurrent state beside the pages (zeros for a model
             # without it)
             "state_bytes": sum(

@@ -242,10 +242,9 @@ def _buckets(rows: List[Dict[str, Any]], end: float,
     into queue / prefill_compute / park / decode / compile / other.
     ``hi=first_token_ts`` gives the TTFT decomposition; ``hi=None`` the
     e2e one. ``prefill_compute`` sums PREFILL_CHUNK ``dur_s``: host
-    wall of the chunk's call, which waits for the device only where the
-    event says ``fenced`` (a prompt's last chunk); every other chunk
-    reports its launch, and its device time is ``chunk_prefill`` in a
-    profiler trace. Invariant: buckets sum to the clipped wall clock (other
+    wall of the chunk's call, which is its launch (no chunk waits for
+    the device; its device time is ``chunk_prefill`` in a profiler
+    trace). Invariant: buckets sum to the clipped wall clock (other
     absorbs scheduler gaps between prefill chunks and unmatched
     intervals)."""
     out = {"queue": 0.0, "prefill_compute": 0.0, "park": 0.0,
@@ -424,9 +423,8 @@ def why_slow(request_id: str,
     """Latency attribution for one request: TTFT and e2e decomposed
     into queue / prefill-compute / park / decode / compile / other
     seconds, next to the raw lifecycle events. "prefill compute" is
-    host wall of the chunk calls: launch time for every chunk but the
-    ``fenced`` one (see ``_buckets``). A request-id PREFIX is accepted
-    when unambiguous."""
+    host wall of the chunk calls: their launch time (see ``_buckets``).
+    A request-id PREFIX is accepted when unambiguous."""
     by_rid = request_events(payloads)
     rows = by_rid.get(str(request_id))
     if rows is None:

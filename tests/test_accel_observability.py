@@ -244,14 +244,20 @@ def test_train_controller_folds_step_reports():
     from ray_tpu._internal import accel
     from ray_tpu.train.controller import TrainController
 
+    def train_row():
+        return next((r for r in accel.step_summary()
+                     if r["kind"] == "train"), {"steps": 0, "tokens": 0})
+
+    # rows are per process: a train test that ran here before left some
+    before = train_row()
     controller = TrainController.__new__(TrainController)
     controller.reports = {}
     controller._fold_step_telemetry(
         {"loss": 1.0, "step_time_s": 0.5, "tokens": 100,
          "step_flops": 1e9, "device_kind": "cpu"})
-    row = next(r for r in accel.step_summary() if r["kind"] == "train")
-    assert row["steps"] == 1
-    assert row["tokens"] == 100
+    row = train_row()
+    assert row["steps"] - before["steps"] == 1
+    assert row["tokens"] - before["tokens"] == 100
     assert row["mfu"] == pytest.approx((1e9 / 0.5) / 1e12)
     # reports without timing keys are ignored, not crashed on
     controller._fold_step_telemetry({"loss": 2.0})

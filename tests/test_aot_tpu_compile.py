@@ -376,11 +376,16 @@ def test_hybrid_decode_step_compiles_for_v5e_and_copies_no_pool(
         p["params"], p["pages"], p["pages"], p["state"],
         spec(jnp.bool_, rows),
         spec(jnp.int32, rows, p["engine"].config.pages_per_seq),
-        spec(jnp.int32, rows), spec(jnp.int32, rows, 1),
+        spec(jnp.int32, rows), spec(jnp.int32, rows),
         spec(jnp.uint32, 2), spec(jnp.float32, rows),
         spec(jnp.int32, rows), spec(jnp.float32, rows)).compile()
     text = compiled.as_text()
     assert pallas_kernels(text) == {"paged_attention": 1}
+    # the sampled tokens leave as the [rows] vector the next call takes
+    # (PR 33: the step ahead is fed on the device)
+    assert compiled.output_shardings[0] is not None
+    out_tokens = jax.tree_util.tree_leaves(compiled.out_info)[0]
+    assert (out_tokens.shape, out_tokens.dtype) == ((rows,), jnp.int32)
     ssm = p["state"][0][1].shape
     assert ssm == (rows, cfg.mamba_n_heads, cfg.mamba_d_head,
                    cfg.mamba_d_state)
@@ -390,6 +395,28 @@ def test_hybrid_decode_step_compiles_for_v5e_and_copies_no_pool(
     # both page pools (bf16) and the scan state (float32) alias in place
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         2 * 2 * math.prod(p["pool"]) + 4 * math.prod(ssm)
+
+
+def test_first_token_compiles_for_v5e(falcon_programs, as_tpu):
+    """The two programs that keep a prompt's first token on the device, at
+    the hybrid cell's largest chunk (256 positions x 261,120 float32
+    logits, 0.27 GB): one reads a row of them and copies nothing else,
+    the other takes that row's argmax into the token vector and holds no
+    sort (greedy traffic never pays the sampler's compile)."""
+    from ray_tpu.llm.paged import first_token, logits_row
+    p = falcon_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    row = logits_row.lower(spec(jnp.float32, 1, 256, cfg.vocab_size),
+                           spec(jnp.int32)).compile()
+    memory = row.memory_analysis()
+    assert memory.output_size_in_bytes < 2 * 4 * cfg.vocab_size
+    assert memory.temp_size_in_bytes < 2 * 4 * cfg.vocab_size
+    greedy = first_token.lower(
+        spec(jnp.int32, rows), spec(jnp.float32, 1, cfg.vocab_size),
+        spec(jnp.int32), spec(jnp.uint32, 2), spec(jnp.float32, 1),
+        spec(jnp.int32, 1), spec(jnp.float32, 1), sampled=False).compile()
+    assert greedy.memory_analysis().output_size_in_bytes <= 1024
+    assert " sort(" not in greedy.as_text()
 
 
 def test_hybrid_prefill_chunk_compiles_for_v5e(falcon_programs, as_tpu):

@@ -421,7 +421,8 @@ def test_finish_rule_holds_for_the_first_token(cb_engines, case):
         # token is the last, and the run reads as if never preempted
         results = {}
         _submit_all(paged, prompts[:1], 5, results)
-        while len(paged.seqs[0].generated) < 4:
+        # three read, the fourth in flight: `_preempt` reads it first
+        while len(paged.seqs[0].generated) < 3:
             paged.step()
         paged._preempt(0, reason="page_pressure")
         while paged.has_work():
@@ -429,6 +430,279 @@ def test_finish_rule_holds_for_the_first_token(cb_engines, case):
         assert list(results.values()) == want[:1]
     assert not paged.has_work()
     assert paged.page_leak_check() == 0
+
+
+# ---------------------------------------------------------------------------
+# one decode step of lookahead (PR 33): the tick dispatches step n+1 before
+# it reads step n's tokens. Same tokens as a plain serial greedy decode,
+# dense and hybrid, whatever ends a row; and the order itself.
+# ---------------------------------------------------------------------------
+
+AHEAD_LEN = 96      # the serial reference pads every sequence to this
+
+
+def _ahead_engine(kind, **overrides):
+    import jax.numpy as jnp
+    if kind == "dense":
+        import dataclasses
+        model = dataclasses.replace(tiny_model(), dtype=jnp.float32)
+    else:
+        from test_falcon_h1 import tiny_model as tiny_hybrid
+        model = tiny_hybrid()
+    config = dict(model=model, max_batch=3, max_len=AHEAD_LEN, page_size=8,
+                  num_pages=64, prefill_buckets=(16, 32))
+    config.update(overrides)
+    return PagedLLMEngine(PagedEngineConfig(**config))
+
+
+@pytest.fixture(scope="module", params=["dense", "hybrid"])
+def ahead(request):
+    """(kind, params, serial): `serial(prompt, n, eos)` is the plain greedy
+    reference — the model's full-sequence forward over everything so far,
+    argmax, one token at a time, no cache, no engine."""
+    import jax
+    import jax.numpy as jnp
+    kind = request.param
+    engine = _ahead_engine(kind)
+    params, model = engine.params, engine.model
+
+    @jax.jit
+    def last_logits(tokens, n):
+        logits = model.apply({"params": params}, tokens)
+        return jax.lax.dynamic_index_in_dim(logits[0], n - 1, axis=0)
+
+    def serial(prompt, max_new, eos=None):
+        seq, out = list(prompt), []
+        while len(out) < max_new and len(seq) - 1 < AHEAD_LEN - 1:
+            padded = np.zeros((1, AHEAD_LEN), np.int32)
+            padded[0, :len(seq)] = seq
+            token = int(np.argmax(np.asarray(
+                last_logits(jnp.asarray(padded), len(seq)))))
+            seq.append(token)
+            out.append(token)
+            if token == eos:
+                break
+        return out
+
+    return kind, params, serial
+
+
+def _ahead_prompts(vocab=128):
+    rng = np.random.RandomState(33)
+    return [[int(t) for t in rng.randint(1, vocab, size=n)]
+            for n in (5, 19, 33, 9, 41, 12, 27)]
+
+
+def _run_staggered(engine, prompts, max_new, every=2, between=None):
+    """Submit one prompt every `every` visits, step to the end. Returns
+    (results by index, streamed tokens by index)."""
+    results, streamed = {}, {i: [] for i in range(len(prompts))}
+    waiting = list(enumerate(prompts))
+    visits = 0
+    while waiting or engine.has_work():
+        if waiting and visits % every == 0:
+            i, prompt = waiting.pop(0)
+            budget = max_new[i] if isinstance(max_new, list) else max_new
+            engine.submit(
+                GenerationRequest(prompt_tokens=prompt,
+                                  max_new_tokens=budget,
+                                  request_id=f"ahead-{i}"),
+                done_callback=lambda req, toks, i=i:
+                    results.__setitem__(i, toks),
+                token_callback=lambda req, tok, i=i:
+                    streamed[i].append(tok))
+        engine.step()
+        if between is not None:
+            between(engine, visits)
+        visits += 1
+        assert visits < 2000
+    return results, streamed
+
+
+def _assert_clean(engine, results, streamed):
+    assert engine.page_leak_check() == 0
+    assert not engine._unread and not engine.has_work()
+    stats = engine.stats()
+    # every token the callbacks saw is in a result, in its order, and the
+    # counter counts the emitted ones alone (a dropped token is in neither)
+    for i, tokens in results.items():
+        if tokens is not None:
+            assert streamed[i] == tokens
+    assert stats["tokens_generated"] == sum(
+        len(tokens) for tokens in streamed.values())
+
+
+def _assert_reused_slot_state_is_fresh(kind, params, engine):
+    """A slot that earlier rows (and their late, dropped steps) wrote to
+    holds, after the next prompt's install, exactly what a fresh engine's
+    slot holds after the same install."""
+    if kind != "hybrid":
+        return
+    probe = _ahead_prompts()[2]
+    fresh = _ahead_engine(kind)
+    fresh.params = params
+    for eng in (engine, fresh):
+        eng.submit(GenerationRequest(prompt_tokens=probe, max_new_tokens=4,
+                                     request_id="probe"))
+        eng.step()
+        while eng.seqs[0].phase != "decode":
+            eng.step()
+        # installed in that visit; its first decode step is the next's
+        assert eng.seqs[0].dispatched == 1
+    for used, new in zip(engine.state, fresh.state):
+        for a, b in zip(used, new):
+            np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+    while engine.has_work():
+        engine.step()
+
+
+AHEAD_CASES = ["staggered", "eos_mid_batch", "max_new_1", "max_new_2",
+               "cancel_in_flight", "prompt_ends_mid_decode", "preemption"]
+
+
+@pytest.mark.parametrize("case", AHEAD_CASES)
+def test_lookahead_tokens_match_the_serial_reference(ahead, case):
+    kind, params, serial = ahead
+    prompts = _ahead_prompts()
+    overrides = {}
+    if case == "preemption":
+        # 3 rows x up to 8 pages of 8 on 13 usable pages: growth runs dry
+        overrides = dict(num_pages=14)
+    engine = _ahead_engine(kind, **overrides)
+    engine.params = params
+    if case == "staggered":
+        budgets = [7, 12, 5, 9, 3, 11, 6]
+        results, streamed = _run_staggered(engine, prompts, budgets)
+        want = [serial(p, n) for p, n in zip(prompts, budgets)]
+        assert [results[i] for i in range(len(prompts))] == want
+        assert engine.stats()["discarded_tokens"] == 0
+    elif case == "eos_mid_batch":
+        free = [serial(p, 10) for p in prompts]
+        eos = free[1][4]        # ends request 1 at its fifth token or sooner
+        engine.config.eos_token = eos
+        results, streamed = _run_staggered(engine, prompts, 10, every=1)
+        want = [serial(p, 10, eos=eos) for p in prompts]
+        assert [results[i] for i in range(len(prompts))] == want
+        assert len(want[1]) <= 5 and want[1][-1] == eos
+        # each row that EOS ended before its budget was computed once more
+        late = sum(1 for w in want if w[-1] == eos and len(w) < 10)
+        assert engine.stats()["discarded_tokens"] == late >= 1
+    elif case in ("max_new_1", "max_new_2"):
+        n = int(case[-1])
+        results, streamed = _run_staggered(engine, prompts, n, every=1)
+        assert [results[i] for i in range(len(prompts))] == \
+            [serial(p, n) for p in prompts]
+        assert engine.stats()["discarded_tokens"] == 0
+    elif case == "cancel_in_flight":
+        def cancel(eng, visits):
+            # visit 5 left a step in flight with row 0 in it
+            if visits == 5:
+                assert any(seq.request.request_id == "ahead-0"
+                           for _slot, seq in eng._unread)
+                assert eng.cancel("ahead-0")
+        results, streamed = _run_staggered(engine, prompts[:3], 12,
+                                           every=1, between=cancel)
+        assert results[0] is None
+        assert [results[1], results[2]] == \
+            [serial(p, 12) for p in prompts[1:3]]
+        # what was emitted before the cancel is a prefix of the answer;
+        # the step in flight when it landed was dropped, not emitted
+        assert streamed[0] == serial(prompts[0], 12)[:len(streamed[0])]
+        assert engine.stats()["discarded_tokens"] == 1
+    elif case == "prompt_ends_mid_decode":
+        long_prompt = [int(t) for t in np.random.RandomState(5).randint(
+            1, 128, size=70)]        # three chunks of 32, one a visit
+        both = [prompts[0], long_prompt]
+        results, streamed = _run_staggered(engine, both, [30, 6], every=3)
+        assert [results[0], results[1]] == \
+            [serial(both[0], 30), serial(both[1], 6)]
+    else:
+        long = [p + p[:20] for p in prompts[:3]]
+        results, streamed = _run_staggered(engine, long, 40, every=1)
+        assert [results[i] for i in range(3)] == \
+            [serial(p, 40) for p in long]
+        stats = engine.stats()
+        assert stats["preemptions"] >= 1
+        assert stats["drained_by"].get("preempt", 0) >= 1
+    _assert_clean(engine, results, streamed)
+    _assert_reused_slot_state_is_fresh(kind, params, engine)
+
+
+def _recorded(engine):
+    """Wrap the decode dispatch and the fetch: ("dispatch", out) and
+    ("fetch", vector read) in the order the host made them."""
+    events = []
+    decode, fetch = engine._decode, engine._fetch
+
+    def recording_decode(*args):
+        out = decode(*args)
+        events.append(("dispatch", out[0]))
+        return out
+
+    def recording_fetch(tokens):
+        events.append(("fetch", tokens))
+        return fetch(tokens)
+
+    engine._decode, engine._fetch = recording_decode, recording_fetch
+    return events
+
+
+@pytest.mark.parametrize("case", ["steady", "late_eos", "preempt"])
+def test_step_dispatches_ahead_of_its_read(ahead, case):
+    """With rows decoding, step n+1 is in the device's queue before the
+    host reads step n; the counters say what each scenario implies."""
+    kind, params, serial = ahead
+    engine = _ahead_engine(kind)
+    engine.params = params
+    events = _recorded(engine)
+    prompts = _ahead_prompts()[:2]
+    if case == "late_eos":
+        engine.config.eos_token = serial(prompts[0], 6)[3]
+        prompts = prompts[:1]
+    results, streamed = {}, {i: [] for i in range(len(prompts))}
+    for i, prompt in enumerate(prompts):
+        engine.submit(
+            GenerationRequest(prompt_tokens=prompt, max_new_tokens=8,
+                              request_id=f"order-{i}"),
+            done_callback=lambda req, toks, i=i:
+                results.__setitem__(i, toks),
+            token_callback=lambda req, tok, i=i: streamed[i].append(tok))
+    if case == "preempt":
+        for _ in range(4):
+            engine.step()
+        engine._preempt(0, reason="page_pressure")
+    while engine.has_work():
+        engine.step()
+    stats = engine.stats()
+    dispatches = [e for e in events if e[0] == "dispatch"]
+    if case == "steady":
+        # admit+prefill, then 7 decode steps, each dispatched before the
+        # read of the vector the step before it left; one last read alone
+        assert [kind_ for kind_, _ in events] == \
+            ["dispatch", "fetch"] * 7 + ["fetch"]
+        for at in range(3, len(events) - 1, 2):
+            assert events[at][0] == "fetch"
+            assert events[at][1] is events[at - 3][1]   # step n's out ...
+            assert events[at - 1][0] == "dispatch"      # ... after n+1 left
+        assert events[-1][1] is dispatches[-1][1]
+        assert stats["lookahead_ticks"] == 7
+        assert stats["drained_by"] == {"idle": 1}
+        assert stats["discarded_tokens"] == 0
+    elif case == "late_eos":
+        want = serial(prompts[0], 8, eos=engine.config.eos_token)
+        assert results[0] == want and len(want) <= 4
+        # the step in flight when the EOS was read: computed, dropped
+        assert stats["discarded_tokens"] == 1
+        assert len(dispatches) == len(want)
+        assert stats["drained_ticks"] == 0
+    else:
+        assert stats["preemptions"] == 1
+        assert stats["drained_by"].get("preempt") == 1
+        assert stats["discarded_tokens"] == 0
+    for i, prompt in enumerate(prompts):
+        assert results[i] == serial(prompt, 8, eos=engine.config.eos_token)
+        assert streamed[i] == results[i]      # per-request callback order
+    assert engine.page_leak_check() == 0
 
 
 def test_prefix_cache_entries_flag_bounds_radix():

@@ -353,3 +353,51 @@ def test_decode_program_scatters_by_head_page_offset(engines):
     for dims in into_pool:
         assert "inserted_window_dims = [0, 1, 2]" in dims
         assert "update_window_dims = [2]" in dims
+
+
+@pytest.mark.parametrize("what", ["token_vector", "pool_copies",
+                                  "first_token"])
+def test_decode_program_runs_one_step_ahead(engines, what):
+    """The decode step of PR 33: its sampled tokens are a [rows] int32
+    vector that is the next call's token input as it stands (no trip
+    through the host), the pools are still donated and copied nowhere,
+    and a prompt's first token is written into that vector on the
+    device."""
+    import re
+    _slot, paged = engines
+    rows = paged.config.max_batch
+    lowered = paged.lower_decode()
+    if what == "token_vector":
+        text = lowered.as_text()
+        main = text[text.index("func.func public @main"):]
+        head = main[:main.index("{\n")]
+        args, results = head.split("->", 1)
+        vector = f"tensor<{rows}xi32>"
+        # block tables are [rows, pages]: lengths, tokens, top_k are the
+        # three [rows] int32 arguments; the first result is the vector
+        assert len(re.findall(re.escape(vector), args)) == 3
+        assert results.lstrip(" (").startswith(vector)
+        assert f"tensor<{rows}x1xi32>" not in args
+    elif what == "pool_copies":
+        compiled = lowered.compile()
+        assert paged.pool_copies(compiled.as_text()) == 0
+        pool = paged.k_pages[0]
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            2 * paged.config.model.num_layers * pool.size \
+            * pool.dtype.itemsize
+    else:
+        from ray_tpu.llm.paged import first_token, logits_row
+        logits = np.zeros((1, 16, paged.config.model.vocab_size),
+                          np.float32)
+        logits[0, 4, 77] = 1.0
+        want = np.arange(rows)
+        want[2] = 77
+        # greedy, and a sampler whose top_k leaves one token to draw
+        for temperature, top_k in ((0.0, 0), (2.0, 1)):
+            out = first_token(
+                np.arange(rows, dtype=np.int32),
+                logits_row(logits, np.int32(4)), np.int32(2), paged._rng,
+                np.full((1,), temperature, np.float32),
+                np.full((1,), top_k, np.int32), np.ones((1,), np.float32),
+                sampled=temperature > 0)
+            assert np.asarray(out).tolist() == want.tolist()
