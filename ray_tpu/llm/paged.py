@@ -53,6 +53,7 @@ from .radix import RadixPrefixCache
 
 if TYPE_CHECKING:
     from ..models.falcon_h1 import FalconH1Config
+    from ..models.nemotron_h import NemotronHConfig
 
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
@@ -65,8 +66,12 @@ class PagedEngineConfig:
     # heads and head size, its type, and its flax module (`module()`; a
     # LlamaConfig's is LlamaModel). One whose rows carry recurrent state
     # beside their pages says in what shape and type (`state_shapes()`)
-    # and makes it (`init_state(rows)`); `_module_of` / `_recurrent` below.
-    model: Union[LlamaConfig, "FalconH1Config"]
+    # and makes it (`init_state(rows)`). One whose layers are not all of
+    # one kind says what each keeps (`layer_caches()`), makes state for
+    # the layers that scan only, and may carry per-layer accumulators
+    # through the decode step (`init_counters()`); `_module_of` /
+    # `_recurrent` / `_layer_caches` below.
+    model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -92,6 +97,16 @@ def _recurrent(cfg) -> bool:
     """Whether a row of this model carries recurrent state (a scan
     layer's) beside its K/V pages."""
     return hasattr(cfg, "state_shapes")
+
+
+def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
+    """Per layer, what it keeps between calls: (K/V pages, recurrent
+    state, accumulators carried through a decode step). A configuration
+    that does not say has layers of one kind: all attend, and all scan if
+    its rows carry state at all."""
+    if hasattr(cfg, "layer_caches"):
+        return tuple(cfg.layer_caches())
+    return ((True, _recurrent(cfg), False),) * cfg.num_layers
 
 
 @functools.lru_cache(maxsize=8)
@@ -272,14 +287,26 @@ class PagedLLMEngine:
             if self._page_sharding is not None:
                 z = jax.device_put(z, self._page_sharding)
             return z
-        self.k_pages = [_zero_pages() for _ in range(cfg.num_layers)]
-        self.v_pages = [_zero_pages() for _ in range(cfg.num_layers)]
+        # a page pool per layer that attends
+        attending = sum(1 for attends, _, _ in _layer_caches(cfg) if attends)
+        self.k_pages = [_zero_pages() for _ in range(attending)]
+        self.v_pages = [_zero_pages() for _ in range(attending)]
         # recurrent state beside the pages, for a model that has it: per
-        # layer (conv, ssm) pools of max_batch rows, row = slot index (a
-        # slot that is not decoding is masked out of the decode step, and
-        # an install overwrites a row whole); None otherwise
+        # layer that scans, (conv, ssm) pools of max_batch rows, row = slot
+        # index (a slot that is not decoding is masked out of the decode
+        # step, and an install overwrites a row whole); None otherwise
         self.state = cfg.init_state(config.max_batch) \
             if _recurrent(cfg) else None
+        # accumulators a model carries through the decode step (an expert
+        # layer's per-expert counts): donated to it and returned by it, so
+        # only the stepping thread may touch them, and only between steps
+        # (`read_counters`); stats() reads the host copy published there.
+        # None for a model without them
+        self.counters = cfg.init_counters() \
+            if hasattr(cfg, "init_counters") else []
+        self._counters_host = jax.device_get(self.counters)
+        self._counters_at = 0          # `_steps` when that copy was made
+        self._counters_asked = False
         # (slot, staged state) of prefills finished this tick, installed
         # in the tick's `state` phase
         self._state_due: List[Tuple[int, Any]] = []
@@ -478,18 +505,65 @@ class PagedLLMEngine:
         takes and returns the state pools donated beside the page pools,
         a prefill chunk hands (conv, ssm) on in its staging pytree and is
         told how many of its tokens are real, and `write_state` installs a
-        finished prefill's state into its slot."""
+        finished prefill's state into its slot. A layer is handed, and
+        hands back, what its kind keeps (`_layer_caches`) and nothing
+        else: `k_pages` / `v_pages` hold a pool per layer that attends,
+        `state` a pair per layer that scans, `counters` a tuple per layer
+        that counts."""
         config, cfg, model = self.config, self.config.model, self.model
-        layers = cfg.num_layers
+        kinds = _layer_caches(cfg)
+
+        def by_kind(new):
+            """A model's per-layer tuples (k, v, conv, ssm, counters...),
+            each holding its kind's part only, as the four lists."""
+            nk, nv, nstate, ncount = [], [], [], []
+            for (attends, scans, counts), kept in zip(kinds, new):
+                kept = list(kept)
+                if attends:
+                    nk.append(kept.pop(0))
+                    nv.append(kept.pop(0))
+                if scans:
+                    nstate.append((kept.pop(0), kept.pop(0)))
+                if counts:
+                    ncount.append(tuple(kept))
+            return nk, nv, nstate, ncount
+
+        def decode_caches(k_pages, v_pages, state, counters, active,
+                          block_tables, lengths):
+            """What each layer is handed in a paged decode step."""
+            pools = iter(zip(k_pages, v_pages))
+            states, counts_of = iter(state), iter(counters)
+            caches = []
+            for attends, scans, counts in kinds:
+                cache = {"active": active}
+                if attends:
+                    k, v = next(pools)
+                    cache.update(k=k, v=v, block_tables=block_tables,
+                                 lengths=lengths)
+                if scans:
+                    cache["conv"], cache["ssm"] = next(states)
+                if counts:
+                    cache["pairs"], cache["steps"] = next(counts_of)
+                caches.append(cache)
+            return caches
+
+        def chunk_caches(staged):
+            """What each layer is handed in a prefill chunk."""
+            dense, states = iter(staged["kv"]), iter(staged["state"])
+            return [(tuple(next(dense)) if attends else ())
+                    + (tuple(next(states)) if scans else ())
+                    for attends, scans, _ in kinds]
+
+        # for a caller that applies the model itself (the benchmark's
+        # parity check reads logits where the engine's step returns ids)
+        self._by_kind, self._decode_caches = by_kind, decode_caches
+        self._chunk_caches = chunk_caches
 
         def decode_step(params, k_pages, v_pages, state, active,
                         block_tables, lengths, tokens, rng, temperature,
-                        top_k, top_p):
-            caches = [
-                {"k": k_pages[i], "v": v_pages[i], "conv": state[i][0],
-                 "ssm": state[i][1], "active": active,
-                 "block_tables": block_tables, "lengths": lengths}
-                for i in range(layers)]
+                        top_k, top_p, counters=()):
+            caches = decode_caches(k_pages, v_pages, state, counters,
+                                   active, block_tables, lengths)
             logits, new = model.apply(
                 {"params": params}, tokens[:, None],
                 positions=lengths[:, None],
@@ -505,24 +579,24 @@ class PagedLLMEngine:
                 lambda: sample_tokens(rng, last, temperature, top_k,
                                       top_p).astype(jnp.int32),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return (out, [c[0] for c in new],
-                    [c[1] for c in new], [(c[2], c[3]) for c in new])
+            return (out,) + by_kind(new)
 
-        self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3))
+        # `counters` is () for a model without any: no argument, no result
+        self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3, 12))
 
         def chunk_prefill(params, tokens, positions, staged, offset, valid):
-            """One prefill chunk of one row. `staged`: {"kv": per-layer
-            dense (k, v), "state": per-layer (conv, ssm)}. Attention
-            overwrites or masks the padded tail; the mixer is told
-            `valid`, the count of real tokens, and keeps the rest out of
-            the state it hands on."""
-            caches = [kv + st for kv, st
-                      in zip(staged["kv"], staged["state"])]
+            """One prefill chunk of one row. `staged`: {"kv": dense
+            (k, v) per layer that attends, "state": (conv, ssm) per layer
+            that scans}. Attention overwrites or masks the padded tail; a
+            layer that scans (or counts) is told `valid`, the count of
+            real tokens, and keeps the rest out of what it hands on."""
             logits, new = model.apply(
                 {"params": params}, tokens, positions=positions,
-                kv_caches=caches, cache_index=offset, valid=valid)
+                kv_caches=chunk_caches(staged), cache_index=offset,
+                valid=valid)
+            nk, nv, nstate, _ = by_kind(new)
             return logits.astype(jnp.float32), {
-                "kv": [c[:2] for c in new], "state": [c[2:] for c in new]}
+                "kv": list(zip(nk, nv)), "state": nstate}
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
@@ -532,7 +606,7 @@ class PagedLLMEngine:
             shape = (1, cfg.num_kv_heads, length, cfg.head_dim_)
             return {"kv": [(jnp.zeros(shape, cfg.dtype),
                             jnp.zeros(shape, cfg.dtype))
-                           for _ in range(layers)],
+                           for attends, _, _ in kinds if attends],
                     "state": cfg.init_state(1)}
 
         self._dense_zero_caches = jax.jit(_staging_zero)
@@ -562,6 +636,8 @@ class PagedLLMEngine:
 
         state = () if self.state is None else (
             jax.tree_util.tree_map(like, self.state), vec(jnp.bool_))
+        counters = () if self.state is None else (
+            jax.tree_util.tree_map(like, self.counters),)
         with self._mesh_scope():
             return self._decode.lower(
                 jax.tree_util.tree_map(like, self.params),
@@ -571,7 +647,7 @@ class PagedLLMEngine:
                 vec(jnp.int32),
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
                 vec(jnp.float32),
-                vec(jnp.int32), vec(jnp.float32))
+                vec(jnp.int32), vec(jnp.float32), *counters)
 
     def decode_program_text(self) -> str:
         """Compiled text of the decode step: the one-shot probe of what
@@ -776,6 +852,8 @@ class PagedLLMEngine:
         loop's executor hop and whatever else held this thread. Its
         counters say how often the step ahead was there."""
         entered = time.perf_counter()
+        if self._counters_asked:
+            self.read_counters()
         tick = _accel.StepTimer("tick", sink=self._tick_accum)
         if self._tick_end is not None:
             tick.outside("between", entered - self._tick_end)
@@ -803,8 +881,22 @@ class PagedLLMEngine:
             # drained: flush the partial windows so step telemetry
             # never lags an idle engine by up to `every` ticks
             self._flush_step_rows()
+            self.read_counters()
         finished, self._finished = self._finished, []
         return finished
+
+    def read_counters(self):
+        """Publish the model's accumulators to stats(): a fetch from the
+        device that waits for the step in flight. For the stepping thread
+        between steps only (the arrays are donated to every decode step,
+        and a reader on another thread would find them deleted): `step`
+        calls it when the engine drains and, once, after a stats() call
+        found the host copy older than the last step; a caller that runs
+        between steps (the benchmark's marks) may call it itself."""
+        if self.counters:
+            self._counters_host = jax.device_get(  # host-sync ok: on request
+                self.counters)
+        self._counters_at, self._counters_asked = self._steps, False
 
     def _ahead_counts(self) -> Dict[str, int]:
         """How the step ahead fared so far: decode steps dispatched
@@ -1434,9 +1526,10 @@ class PagedLLMEngine:
                             live = np.zeros((B,), bool)
                             live[active] = True
                             (self._tokens, self.k_pages, self.v_pages,
-                             self.state) = self._decode(
+                             self.state, self.counters) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
-                                self.state, jnp.asarray(live), *args)
+                                self.state, jnp.asarray(live), *args,
+                                self.counters)
                         # the copy to the host starts when the step ends,
                         # whatever is queued behind it by then
                         self._tokens.copy_to_host_async()
@@ -1488,9 +1581,15 @@ class PagedLLMEngine:
 
     def stats(self) -> Dict[str, Any]:
         self._flush_step_rows()  # surfaces the partial window
-        cache_bytes = (2 * self.config.model.num_layers *
-                       int(np.prod(self.k_pages[0].shape)) *
-                       self.k_pages[0].dtype.itemsize)
+        cache_bytes = sum(int(np.prod(pool.shape)) * pool.dtype.itemsize
+                          for pool in self.k_pages + self.v_pages)
+        kinds = _layer_caches(self.config.model)
+        # the copy `read_counters` last published; where steps have run
+        # since, the stepping thread is asked for a newer one and makes it
+        # before its next step (nothing here touches the donated arrays)
+        counters = self._counters_host
+        self._counters_asked = bool(counters) \
+            and self._counters_at != self._steps
         param_bytes = sum(
             int(np.prod(p.shape)) * p.dtype.itemsize
             for p in jax.tree_util.tree_leaves(self.params))
@@ -1514,6 +1613,14 @@ class PagedLLMEngine:
                 for a in jax.tree_util.tree_leaves(self.state)),
             "state_installs": self._state_installs,
             "prefix_skipped_recurrent": self._prefix_skipped_recurrent,
+            # what each layer keeps: p(ages), s(tate), c(ounters); and,
+            # per layer that counts, per expert held: tokens routed to it
+            # and decode steps that routed it any, as of the last
+            # `read_counters`
+            "layer_kinds": ["".join(tag for tag, kept in zip("psc", kind)
+                                    if kept) or "-" for kind in kinds],
+            "expert_pairs": [pairs.tolist() for pairs, _ in counters],
+            "expert_steps": [steps.tolist() for _, steps in counters],
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
             "tp": self._tp,

@@ -167,6 +167,26 @@ def clear():
 # ---------------------------------------------------------------------------
 
 
+# events encoded per hold of the GIL by `_payload_json`
+_FLUSH_SLICE = 128
+
+
+def _payload_json(events: List[tuple]) -> bytes:
+    """`json.dumps(payload)` of these events, encoded a slice at a time
+    with a real sleep between slices. The encoder holds the GIL for the
+    whole of one call; a full ring is over a megabyte and tens of
+    milliseconds, and this runs on the flusher's thread of a replica
+    whose engine thread would stand still for all of it (an engine tick
+    is 20-30 ms)."""
+    import json
+    parts = []
+    for at in range(0, len(events), _FLUSH_SLICE):
+        parts.append(json.dumps(events[at:at + _FLUSH_SLICE])[1:-1])
+        time.sleep(0.0005)  # long enough that a waiting thread takes over
+    return ('{"pid": %d, "events": [%s]}'
+            % (os.getpid(), ", ".join(parts))).encode()
+
+
 def flush(gcs=None, key: Optional[str] = None) -> bool:
     """Push this process's event ring into the GCS KV (ns ``reqtrace``)
     under a per-process key. Called piggyback from the metrics flusher
@@ -175,7 +195,6 @@ def flush(gcs=None, key: Optional[str] = None) -> bool:
     if reqtrace_disabled() or _RECORDER is None:
         return False
     try:
-        import json
         if gcs is None:
             from .._internal.core_worker import try_get_core_worker
             worker = try_get_core_worker()
@@ -184,8 +203,7 @@ def flush(gcs=None, key: Optional[str] = None) -> bool:
             gcs = worker.gcs
         if key is None:
             key = str(os.getpid())
-        gcs.put(REQTRACE_KV_NS, key,
-                json.dumps(_RECORDER.payload()).encode())
+        gcs.put(REQTRACE_KV_NS, key, _payload_json(_RECORDER.events()))
         return True
     except Exception:  # noqa: BLE001 — observability is best-effort
         logger.debug("reqtrace flush failed", exc_info=True)

@@ -112,3 +112,132 @@ class MoELayer(nn.Module):
         aux_loss = self.router_aux_weight * E * jnp.sum(f * p)
 
         return out.reshape(B, S, D), aux_loss
+
+
+# ---------------------------------------------------------------------------
+# A served expert layer: no capacity, no dropped pair, told which experts
+# it holds
+# ---------------------------------------------------------------------------
+
+F32 = jnp.float32
+
+
+def sigmoid_top_k(u, router, bias, k: int, scale: float):
+    """The router of a sigmoid-scored mixture (DeepSeek-V3's, which the
+    Nemotron-H family's expert layers repeat), in float32 whatever the
+    model computes in: scores s = sigmoid(u W_g) over ALL experts; the k
+    experts with the largest s + bias are chosen (the bias chooses and
+    does not weigh); their weights are scale * s / sum of the chosen s.
+    u [T, d]; router [d, E]; bias [E]. Returns (chosen [T, k] int32,
+    weights [T, k] float32, scores [T, E] float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(F32), router.astype(F32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias.astype(F32), k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights, scores
+
+
+def relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int):
+    """The part of sum_i w_i E_i(x) that the experts held here give, with
+    E_i(x) = w_out[i] relu(w_in[i] x)^2, for every token at once and with no
+    capacity: every (token, choice) pair whose expert is held is computed,
+    however the pairs fall.
+
+    x [T, l]; chosen, weights [T, k] (over all experts); mask [T] bool
+    (False: the token is padding or an idle row, and counts nowhere);
+    w_in [held, l, f], w_out [held, f, l]; the held experts are
+    first .. first + held - 1. Returns (out [T, l] float32, pairs [held]
+    int32: the tokens routed to each held expert).
+
+    ONE form for a decode step and a prefill chunk: every held expert is
+    applied to every token, and the pair weights, scattered to [T, held]
+    (0 where the expert was not chosen), sum the results. No sort, no
+    gather, one read of every held expert's matrices whatever the routing,
+    which is what a step must read anyway once every held expert has a
+    token (a balanced router at 96 rows leaves a held expert without one
+    1.5 % of the time), and 4 T held l f FLOPs that stay under that
+    read's time up to the engine's largest bucket on a v5e (PERF.md
+    section 6, PR 35: 1.92 / 2.07 ms at 96 / 256 tokens against 2.09 / 2.23
+    for sorted pairs through a grouped-matmul kernel). The cost grows with
+    T x held, not with the pairs: a caller with thousands of tokens a call
+    wants the pairs sorted into a grouped matmul instead."""
+    held = w_in.shape[0]
+    local = chosen - first
+    mine = (local >= 0) & (local < held) & mask[:, None]
+    taken = (local[..., None] == jnp.arange(held)) & mine[..., None]
+    per_expert = jnp.where(taken, weights[..., None], 0.0).sum(1)  # [T, held]
+    hidden = jnp.einsum("tl,elf->etf", x.astype(w_in.dtype), w_in,
+                        preferred_element_type=F32)
+    y = jnp.einsum("etf,efl->etl", relu2(hidden).astype(w_out.dtype), w_out,
+                   preferred_element_type=F32)
+    return jnp.einsum("te,etl->tl", per_expert, y), \
+        taken.sum((0, 1)).astype(jnp.int32)
+
+
+class RoutedExperts(nn.Module):
+    """The routed experts of one layer as ONE chip of an expert-parallel
+    deployment holds them: the router keeps its width (`num_experts`) and
+    its `experts_per_token`, the chip holds experts `held[0] ..
+    held[0] + held[1] - 1`, and the layer returns the part of the weighted
+    sum that those give. Nothing stands in for the absent chips or their
+    exchange; the shares of all chips add up to the whole layer
+    (tests/test_nemotron_h.py). No capacity: no pair is dropped at any
+    imbalance (`held_expert_sum`).
+
+    `u` [.., d] is what the router reads, `x` [.., l] what the experts
+    read and write (the same array unless the experts live in a latent
+    space). Experts are not gated: E(x) = W2 relu(W1 x)^2.
+    Returns (out [.., l] float32, pairs [held] int32)."""
+    num_experts: int
+    experts_per_token: int
+    held: Tuple[int, int]
+    mlp_dim: int
+    routed_scaling: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u, x, mask=None):
+        lead, d, width = u.shape[:-1], u.shape[-1], x.shape[-1]
+        first, held = self.held
+        router = self.param(
+            "router", _partitioned(nn.initializers.lecun_normal(),
+                                   ("embed", None)),
+            (d, self.num_experts), self.param_dtype)
+        bias = self.param(
+            "e_score_correction_bias",
+            _partitioned(nn.initializers.zeros, (None,)),
+            (self.num_experts,), F32)
+        w_in = self.param(
+            "w_in", _partitioned(nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1, batch_axis=(0,)),
+                ("expert", "embed", "mlp")),
+            (held, width, self.mlp_dim), self.param_dtype)
+        w_out = self.param(
+            "w_out", _partitioned(nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1, batch_axis=(0,)),
+                ("expert", "mlp", "embed")),
+            (held, self.mlp_dim, width), self.param_dtype)
+        u = u.reshape(-1, d)
+        x = x.reshape(-1, width)
+        mask = jnp.ones((u.shape[0],), bool) if mask is None \
+            else mask.reshape(-1)
+        with jax.named_scope("moe/route"):
+            chosen, weights, scores = sigmoid_top_k(
+                u, router, bias, self.experts_per_token,
+                self.routed_scaling)
+        # readable by an apply with mutable=["routing"] (the parity check
+        # certifies near-ties, the benchmark balances the bias); dropped
+        # at trace time by every other
+        self.sow("routing", "chosen", chosen.reshape(lead + (-1,)))
+        self.sow("routing", "scores", scores.reshape(lead + (-1,)))
+        with jax.named_scope("moe/experts"):
+            out, pairs = held_expert_sum(x, chosen, weights, mask, w_in,
+                                         w_out, first)
+        return out.reshape(lead + (width,)), pairs

@@ -439,3 +439,122 @@ def test_hybrid_prefill_chunk_compiles_for_v5e(falcon_programs, as_tpu):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= staged_bytes
     assert memory.temp_size_in_bytes < 0.5e9
+
+
+# ---------------------------------------------------------------------------
+# the Nemotron-H cell's programs (layers of three kinds, 128 held experts)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def nemotron_programs(v5e):
+    """The `serve-nemotron3-reason-closed192` cell's engine programs: its
+    config file's widths, rows and pool, its builder, its whole period of
+    11 layers, with the shapes of their arguments on one described chip,
+    on an engine that never allocated anything."""
+    from benchmarks.harness.builders_nemotron_h import nemotron_h_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "nemotron-3-super-120b-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = nemotron_h_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._recurrent_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    rows = engine_cfg.max_batch
+    return {
+        "engine": engine, "cfg": cfg, "config": config, "spec": spec,
+        "rows": rows,
+        "params": placed(jax.eval_shape(lambda: unbox(engine.model.init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((1, 8), jnp.int32))["params"]))),
+        "pool": (cfg.num_kv_heads, engine_cfg.num_pages,
+                 engine_cfg.page_size, cfg.head_dim),
+        "state": placed(jax.eval_shape(lambda: cfg.init_state(rows))),
+        "counters": placed(jax.eval_shape(cfg.init_counters)),
+        "staged": placed(jax.eval_shape(engine._dense_zero_caches))}
+
+
+# what the compiler admits of a v5e's 16 GiB (PERF.md section 4)
+V5E_BYTES_LIMIT = 16.9e9
+
+
+def test_nemotron_decode_step_compiles_for_v5e_within_memory(
+        nemotron_programs, as_tpu):
+    """96 rows: 16:1 grouping through ONE stock paged kernel (the one
+    layer that attends), every held expert on every token (plain einsums:
+    models/moe.py held_expert_sum), the page pool, the five
+    scan-state pools and the expert counters donated and updated in
+    place, and arguments + temporaries + every row's prefill staging
+    under what the chip holds: the numbers of the file's
+    `memory_analysis`."""
+    from benchmarks.harness import costs_nemotron_h
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = nemotron_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    pages = [spec(cfg.dtype, *p["pool"])]
+    compiled = p["engine"]._decode.lower(
+        p["params"], pages, pages, p["state"], spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, p["engine"].config.pages_per_seq),
+        spec(jnp.int32, rows), spec(jnp.int32, rows), spec(jnp.uint32, 2),
+        spec(jnp.float32, rows), spec(jnp.int32, rows),
+        spec(jnp.float32, rows), p["counters"]).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_attention": 1}
+    ssm = p["state"][0][1].shape
+    assert len(p["state"]) == 5 and len(p["counters"]) == 5
+    assert ssm == (rows, 128, 64, 128)
+    assert pool_copies(text, ssm) == 0
+    assert pool_copies(text, p["pool"]) == 0
+    memory = compiled.memory_analysis()
+    donated = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(
+                      (pages, pages, p["state"], p["counters"])))
+    assert memory.alias_size_in_bytes >= donated
+    recorded = p["config"]["memory_analysis"]["decode_step_batch96"]
+    assert memory.argument_size_in_bytes == recorded["argument_bytes"]
+    assert abs(memory.temp_size_in_bytes - recorded["temp_bytes"]) \
+        < 0.2 * recorded["temp_bytes"]
+    staging = rows * costs_nemotron_h.table(p["config"])[
+        "staging_bytes_per_row"]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + staging < V5E_BYTES_LIMIT
+
+
+def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
+    """The largest bucket (256 tokens, the same einsums over every held
+    expert as a decode step's: no kernel of the program's own): the
+    staging pytree holds dense K/V for the ONE layer that attends and
+    a state for each of the five that scan, is donated and aliased, and
+    nothing of a pool's shape is in the program."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = nemotron_programs
+    spec = p["spec"]
+    assert len(p["staged"]["kv"]) == 1 and len(p["staged"]["state"]) == 5
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 256), spec(jnp.int32, 1, 256),
+        p["staged"], spec(jnp.int32), spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {}
+    assert pool_copies(text, p["pool"]) == 0
+    assert pool_copies(text, p["state"][0][1].shape) == 0
+    staged_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(p["staged"]))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= staged_bytes
+    recorded = p["config"]["memory_analysis"]["chunk_prefill_256"]
+    assert memory.argument_size_in_bytes == recorded["argument_bytes"]
+    assert memory.temp_size_in_bytes < 0.5e9

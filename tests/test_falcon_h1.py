@@ -343,3 +343,19 @@ def test_a_dense_engine_owns_no_recurrent_state():
     after = {row["kind"]: dict(row.get("phases", {}))
              for row in accel.step_summary()}["tick"]
     assert after.get("state", 0.0) == before.get("state", 0.0)
+
+
+# recorded from the commit before the engine learned of layer kinds (PR 33's
+# tree, 2c580e1), by test_llm_paged.lowered_programs on `tiny_engine()`
+FALCON_PROGRAMS = {"decode_step": "73aba8d37aa6e0a4",
+                   "chunk_prefill": "afb853d4dea3960f"}
+
+
+@pytest.mark.parametrize("program", sorted(FALCON_PROGRAMS))
+def test_hybrid_engine_lowers_to_the_program_it_always_did(engine, program):
+    """A FalconH1Config engine (every layer attends AND scans) lowers to
+    the StableHLO it lowered to before a configuration could give each
+    layer a kind of its own, text for text."""
+    from test_llm_paged import lowered_programs, program_hash
+    assert program_hash(lowered_programs(engine)[program]) \
+        == FALCON_PROGRAMS[program]

@@ -401,3 +401,45 @@ def test_decode_program_runs_one_step_ahead(engines, what):
                 np.full((1,), top_k, np.int32), np.ones((1,), np.float32),
                 sampled=temperature > 0)
             assert np.asarray(out).tolist() == want.tolist()
+
+
+# -- the engine's per-layer-kind contract changes no program that did not
+# -- ask for it ---------------------------------------------------------------
+
+def lowered_programs(engine):
+    """StableHLO text of an engine's decode step and of its largest
+    prefill chunk, lowered from shapes."""
+    import jax
+    import jax.numpy as jnp
+    cfg = engine.config
+    staged = jax.eval_shape(engine._dense_zero_caches)
+    bucket = cfg.prefill_buckets[-1]
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    valid = () if engine.state is None else (i32(),)
+    return {"decode_step": engine.lower_decode().as_text(),
+            "chunk_prefill": engine._chunk_prefill.lower(
+                engine.params, i32(1, bucket), i32(1, bucket), staged,
+                i32(), *valid).as_text()}
+
+
+def program_hash(text: str) -> str:
+    import hashlib
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# recorded from the commit before the engine learned of layer kinds (PR 33's
+# tree, 2c580e1), by this very function at these very sizes
+DENSE_PROGRAMS = {"decode_step": "618df5990d42e21b",
+                  "chunk_prefill": "b087c299668649d9"}
+
+
+@pytest.mark.parametrize("program", sorted(DENSE_PROGRAMS))
+def test_dense_engine_lowers_to_the_program_it_always_did(program):
+    """A LlamaConfig engine's programs are, text for text, what they were
+    before `PagedEngineConfig.model` could say what each layer keeps. A
+    change that means to alter the dense programs re-records the hash."""
+    engine = PagedLLMEngine(PagedEngineConfig(
+        model=tiny_model(), max_batch=4, max_len=96, page_size=8,
+        num_pages=64, prefill_buckets=(16, 32)))
+    assert program_hash(lowered_programs(engine)[program]) \
+        == DENSE_PROGRAMS[program]

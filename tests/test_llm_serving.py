@@ -205,6 +205,57 @@ def test_openai_shapes_direct():
     assert asyncio.run(scenario())
 
 
+def test_streamed_tokens_reach_the_loop_once_a_step():
+    """The engine's thread hands a decode step's tokens to the replica's
+    loop in one wake-up, not one a token; every stream still gets its
+    tokens in order, and its last token before its end. The first request
+    freezes the heap (a full collection then leaves it alone)."""
+    from ray_tpu.llm.serving import LLMServer
+
+    cfg = PagedEngineConfig(model=tiny_model(), max_batch=4, max_len=96,
+                            page_size=8, num_pages=64,
+                            prefill_buckets=(16, 32))
+    server = LLMServer(cfg)
+    wakeups = []
+    push = server._push_tokens
+
+    def counted():
+        wakeups.append(len(server._emitted))
+        push()
+    server._push_tokens = counted
+    prompts = [[3 + i, 5, 7, 11 + i] for i in range(4)]
+
+    async def stream(prompt):
+        sid = await server.generate_stream_start(prompt, max_new_tokens=12)
+        tokens, done = [], False
+        while not done:
+            batch = await server.stream_next(sid, timeout_s=60)
+            tokens += batch["tokens"]
+            done = batch["done"]
+        return tokens
+
+    async def scenario():
+        streamed = await asyncio.gather(*(stream(p) for p in prompts))
+        whole = [(await server.generate(p, max_new_tokens=12))["tokens"]
+                 for p in prompts]
+        return streamed, whole
+
+    import gc
+    frozen = gc.get_freeze_count()
+    try:
+        streamed, whole = asyncio.run(scenario())
+        # the heap the server was built on is frozen when serving begins
+        assert gc.get_freeze_count() > frozen
+    finally:
+        gc.unfreeze()   # this process is pytest's, not a replica's
+    assert streamed == whole
+    assert all(len(tokens) == 12 for tokens in streamed)
+    assert not server._emitted
+    # 48 tokens; four rows decode together, so a wake-up carries several
+    assert max(wakeups) > 1
+    assert sum(1 for n in wakeups if n) < 48
+
+
 # ---------------------------------------------------------------------------
 # cluster-level: HTTP streaming through the proxy
 # ---------------------------------------------------------------------------

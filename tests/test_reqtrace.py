@@ -472,3 +472,17 @@ def test_kill_switch_record_noop_in_process():
         assert reqtrace.flush(gcs=FakeGcs()) is False
     finally:
         CONFIG.apply_system_config(old)
+
+
+@pytest.mark.parametrize("count", [0, 1, reqtrace._FLUSH_SLICE,
+                                   reqtrace._FLUSH_SLICE + 1, 300])
+def test_flush_encodes_the_whole_ring_in_slices(count):
+    """The flusher encodes the ring a slice at a time (it shares the GIL
+    with the engine's thread): what it puts is `json.dumps(payload())`."""
+    rec = reqtrace._Recorder()
+    for i in range(count):
+        rec.record(f"r{i % 7}", reqtrace.STREAMED, float(i),
+                   {"polls": i, "hold_sum_s": 0.25, "route": "/llm"})
+    sliced = json.loads(reqtrace._payload_json(rec.events()))
+    assert sliced == json.loads(json.dumps(rec.payload()))
+    assert len(sliced["events"]) == count
