@@ -289,6 +289,7 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
         f"stats steps={stats['steps']} tokens={stats['tokens_generated']} "
         f"prefix_hits={stats['prefix_hits']} "
         f"leaked_pages={stats['leaked_pages']} tp={stats['tp']} "
+        f"paged_kernel={stats['paged_kernel']} "
         f"lookahead_ticks={stats['lookahead_ticks']} "
         f"drained_by={json.dumps(stats['drained_by'])} "
         f"discarded_tokens={stats['discarded_tokens']}")
@@ -296,8 +297,10 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
     check_device(plan, device, f"serve[{label}] replica")
     if not plan.rehearse:
         # a shape the kernel refuses is an error here, not a gather run
-        check(kernels.get("paged_attention") == model.num_layers,
-              f"compiled decode step holds {kernels} — expected the "
+        check(stats["paged_kernel"] == "pallas"
+              and kernels.get("paged_attention") == model.num_layers,
+              f"compiled decode step holds {kernels} and the engine took "
+              f"the {stats['paged_kernel']} path — expected the "
               f"paged-attention tpu_custom_call once per layer "
               f"({model.num_layers})")
         # the token's K/V write and the kernel share the pool's layout:
@@ -311,8 +314,14 @@ def serve_session(plan: Plan, layers: int, mesh_config, label: str,
           f"{stats['leaked_pages']} leaked KV pages")
     # the tick runs one decode step ahead of the host (PERF.md, PR 33):
     # a run that decodes at all dispatches most steps before it reads
-    # the one before, and reads without a step behind only to end
-    check(stats["lookahead_ticks"] > 4 * stats["drained_ticks"],
+    # the one before, and reads without a step behind only when a batch
+    # empties. A serial tick reads 0 ahead and every step behind. The
+    # four-chip phase's 24 one-token requests end at their prefill, run
+    # no decode step and empty the batch once a wave, as many waves as
+    # their arrivals happen to form: 22 ahead on both sides, 5 behind on
+    # the parent and 6 on the change in one call (PR 37), so a factor of
+    # 4 passed by one wave or failed by one
+    check(stats["lookahead_ticks"] > stats["drained_ticks"],
           f"the step ahead was there for {stats['lookahead_ticks']} decode "
           f"steps and missing for {stats['drained_ticks']} "
           f"({stats['drained_by']}): the tick is serial again")
@@ -545,31 +554,42 @@ class KernelParity:
         out["flash_bwd"] = max(err(g, r) for g, r in zip(grads, grads_ref))
 
         # one Attention layer's decode step: paged branch (page scatter +
-        # paged kernel) against its dense-cache branch, same cache content
-        batch, page, per_seq = 4, config["page_size"], 16
+        # paged kernel) against its dense-cache branch, same cache content;
+        # at the model's grouping and at the hybrid and expert cells' (5
+        # and 16 query heads a kv head), with one dead row (length 0, a
+        # table of null pages: what the engine stages for a free slot)
+        batch, page, per_seq = 5, config["page_size"], 16
         span = page * per_seq
-        layer = Attention(cfg)
         x = jax.random.normal(keys[4], (batch, 1, cfg.hidden_size),
                               cfg.dtype)
-        lengths = jnp.asarray([1, page + 3, span // 2, span - 2], jnp.int32)
-        params = jax.jit(lambda r: unbox(layer.init(
-            r, x, lengths[:, None])["params"]))(keys[5])
-        ck = jax.random.normal(keys[6], (batch, kvh, span, hd), cfg.dtype)
-        cv = jax.random.normal(keys[7], (batch, kvh, span, hd), cfg.dtype)
+        lengths = jnp.asarray([0, 1, page + 3, span // 2, span - 2],
+                              jnp.int32)
         # row b's token t lives in page 1 + b*per_seq + t//page (0 = null)
         tables = 1 + jnp.arange(batch * per_seq, dtype=jnp.int32).reshape(
             batch, per_seq)
+        tables = tables.at[0].set(0)
+        for label, q_heads, kv_heads in (
+                ("paged_decode", heads, kvh),
+                ("paged_decode_5to1", 20, 4), ("paged_decode_16to1", 32, 2)):
+            layer = Attention(dataclasses.replace(
+                cfg, num_heads=q_heads, num_kv_heads=kv_heads, head_dim=hd))
+            params = jax.jit(lambda r: unbox(layer.init(
+                r, x, lengths[:, None])["params"]))(keys[5])
+            ck = jax.random.normal(keys[6], (batch, kv_heads, span, hd),
+                                   cfg.dtype)
+            cv = jax.random.normal(keys[7], (batch, kv_heads, span, hd),
+                                   cfg.dtype)
 
-        def pooled(c):
-            body = c.transpose(1, 0, 2, 3).reshape(
-                kvh, batch * per_seq, page, hd)
-            return jnp.concatenate([jnp.zeros_like(body[:, :1]), body], 1)
-        apply = jax.jit(lambda cache, index: layer.apply(
-            {"params": params}, x, lengths[:, None], cache, index)[0])
-        paged = apply({"k": pooled(ck), "v": pooled(cv),
-                       "block_tables": tables, "lengths": lengths}, None)
-        dense = apply((ck, cv), lengths)
-        out["paged_decode"] = err(paged, dense)
+            def pooled(c):
+                body = c.transpose(1, 0, 2, 3).reshape(
+                    c.shape[1], batch * per_seq, page, hd)
+                return jnp.concatenate(
+                    [jnp.zeros_like(body[:, :1]), body], 1)
+            apply = jax.jit(lambda cache, index: layer.apply(
+                {"params": params}, x, lengths[:, None], cache, index)[0])
+            paged = apply({"k": pooled(ck), "v": pooled(cv),
+                           "block_tables": tables, "lengths": lengths}, None)
+            out[label] = err(paged, apply((ck, cv), lengths))
         return out
 
 
@@ -584,7 +604,8 @@ def phase_kernel_parity(plan: Plan) -> None:
         "flash_seq": 128 if plan.rehearse else 1024,
         "page_size": plan.engine(1).page_size}), timeout=REPLICA_WAIT_S)
     ray_tpu.kill(probe)
-    errors = {k: got[k] for k in ("flash_fwd", "flash_bwd", "paged_decode")}
+    errors = {k: v for k, v in got.items()
+              if k.startswith(("flash_", "paged_decode"))}
     say(f"smoke: kernels against the jnp references on "
         f"{json.dumps(got['device'])}, max error over max value: "
         f"{json.dumps({k: round(v, 5) for k, v in errors.items()})}")

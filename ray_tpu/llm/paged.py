@@ -46,6 +46,7 @@ import numpy as np
 from .._internal import accel as _accel
 from .._internal.config import CONFIG
 from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
+from ..ops.paged_attention import paged_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
 from .engine import GenerationRequest
@@ -281,6 +282,9 @@ class PagedLLMEngine:
         self._rng = rng
         kvh, hd = cfg.num_kv_heads, cfg.head_dim_
         P, ps = config.num_pages, config.page_size
+        # the attention path the decode program is built with here
+        self._paged_kernel = paged_kernel(
+            hd, cfg.attention_impl == "reference")
         # kernel layout: [kv_heads, num_pages, page_size, head_dim]
         def _zero_pages():
             z = jnp.zeros((kvh, P, ps, hd), cfg.dtype)
@@ -1623,6 +1627,8 @@ class PagedLLMEngine:
             "expert_steps": [steps.tolist() for _, steps in counters],
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
+            # "pallas" or "gather": ops.paged_attention in `decode_step`
+            "paged_kernel": self._paged_kernel,
             "tp": self._tp,
             "hbm_cache_bytes": cache_bytes,
             # per-chip residency: pages shard on kv_heads, params on

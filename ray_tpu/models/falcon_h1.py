@@ -41,6 +41,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.paged_attention import paged_attend
 from ..ops.ssm import ssd_chunked_scan, ssm_step
 from .llama import (RMSNorm, _flash_on_mesh, _partitioned, apply_rope,
                     rope_frequencies, write_token_rows)
@@ -140,37 +141,6 @@ def _dt_bias_init(key, shape, dtype):
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
-def _paged_attend(q, kp, vp, lengths, tables, reference: bool):
-    """One query token a row over its pages. q [B, heads, hd] (unscaled);
-    kp/vp [kv_heads, pages, page_size, hd]; the token itself is already
-    written at position lengths[b]. The stock Pallas kernel on a TPU at
-    kernel-sized heads, a gather elsewhere (as models/llama.py does)."""
-    hd = q.shape[-1]
-    if (jax.default_backend() == "tpu" and not reference and hd % 128 == 0):
-        from jax.experimental.pallas.ops.tpu.paged_attention \
-            .paged_attention_kernel import paged_attention
-        n_pages = tables.shape[1]
-        ppcb = next(d for d in range(min(8, n_pages), 0, -1)
-                    if n_pages % d == 0)
-        return paged_attention(
-            (q * hd ** -0.5).astype(kp.dtype), kp, vp, lengths + 1, tables,
-            pages_per_compute_block=ppcb)
-    rows, page_size = q.shape[0], kp.shape[2]
-    span = tables.shape[1] * page_size
-
-    def gather(pool):
-        g = jnp.transpose(pool, (1, 0, 2, 3))[tables]   # [B, n, kvh, ps, hd]
-        g = jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(
-            rows, pool.shape[0], span, hd)
-        return jnp.repeat(g, q.shape[1] // pool.shape[0], axis=1).astype(F32)
-
-    logits = jnp.einsum("bhd,bhkd->bhk", q.astype(F32), gather(kp)) \
-        * hd ** -0.5
-    seen = jnp.arange(span)[None, :] <= lengths[:, None]
-    probs = jax.nn.softmax(jnp.where(seen[:, None, :], logits, -1e30), -1)
-    return jnp.einsum("bhk,bhkd->bhd", probs, gather(vp))
-
-
 class HybridAttention(nn.Module):
     """The attention half of a block: `cache` is None (whole sequence), a
     dict (paged decode) or a (k, v) pair of dense caches written at
@@ -200,8 +170,8 @@ class HybridAttention(nn.Module):
                 a[:, :, 0, :], (1, 0, 2)).astype(pool.dtype)
             kp = write_token_rows(kp, rows(k, kp), tables, lengths)
             vp = write_token_rows(vp, rows(v, vp), tables, lengths)
-            out = _paged_attend(q[:, :, 0, :], kp, vp, lengths, tables,
-                                cfg.attention_impl == "reference")
+            out = paged_attend(q[:, :, 0, :], kp, vp, lengths, tables,
+                               reference=cfg.attention_impl == "reference")
             out = out[:, :, None, :].astype(cfg.dtype)
             new_cache = (kp, vp)
         elif cache is not None:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..ops.attention import flash_attention, flash_uses_pallas
+from ..ops.paged_attention import paged_attend
 from ..parallel.mesh import constrain, current_kernel_mesh
 
 Dtype = Any
@@ -275,61 +276,16 @@ class Attention(nn.Module):
             #   k/v: [kv_heads, num_pages, page_size, head_dim]
             #   block_tables: [B, pages_per_seq] physical page ids
             #   lengths: [B] tokens already cached (this token's position)
-            # Write lands at (table[len//ps], len%ps); attention runs the
-            # Pallas paged kernel on TPU (jax.experimental.pallas.ops.tpu.
-            # paged_attention) or a gather fallback elsewhere.
+            # Write lands at (table[len//ps], len%ps); attention is
+            # ops.paged_attention.paged_attend: a Pallas kernel on TPU, a
+            # gather fallback elsewhere.
             kp, vp = kv_cache["k"], kv_cache["v"]
             block_tables = kv_cache["block_tables"]
             lengths = kv_cache["lengths"]
-            page_size = kp.shape[2]
             # k,v are [B, kvh, 1, hd] -> write [kvh, B, hd] rows
             k_rows = jnp.transpose(k[:, :, 0, :], (1, 0, 2)).astype(kp.dtype)
             v_rows = jnp.transpose(v[:, :, 0, :], (1, 0, 2)).astype(vp.dtype)
             q1 = q[:, :, 0, :]  # [B, heads, hd]
-
-            def paged_kernel(q_, kp_, vp_, lengths_, tables_):
-                """Per-shard paged attention: q_ holds LOCAL heads,
-                kp_/vp_ LOCAL kv heads (head-parallel — no collectives
-                needed). Runs unsharded when there is no tensor axis."""
-                # Pallas kernel only when asked for (attention_impl)
-                # AND the shapes meet its tiling floor — tiny test/CI
-                # configs (head_dim < 128) must take the gather path
-                # even on real TPU hardware.
-                if (jax.default_backend() == "tpu"
-                        and cfg.attention_impl != "reference"
-                        and hd % 128 == 0):
-                    from jax.experimental.pallas.ops.tpu.paged_attention \
-                        .paged_attention_kernel import paged_attention
-                    n_pages = tables_.shape[1]
-                    # kernel requires pages_per_sequence % block == 0
-                    ppcb = next(d for d in range(min(8, n_pages), 0, -1)
-                                if n_pages % d == 0)
-                    return paged_attention(
-                        (q_ * hd ** -0.5).astype(kp_.dtype), kp_, vp_,
-                        lengths_ + 1, tables_,
-                        pages_per_compute_block=ppcb)
-                # Gather fallback: materialize each row's pages densely.
-                # [B, pages_per_seq, kvh, ps, hd] -> [B, kvh, L, hd]
-                B_ = q_.shape[0]
-                gk = jnp.transpose(kp_, (1, 0, 2, 3))[tables_]
-                gv = jnp.transpose(vp_, (1, 0, 2, 3))[tables_]
-                L = tables_.shape[1] * page_size
-                gk = jnp.transpose(gk, (0, 2, 1, 3, 4)).reshape(
-                    B_, kp_.shape[0], L, hd)
-                gv = jnp.transpose(gv, (0, 2, 1, 3, 4)).reshape(
-                    B_, vp_.shape[0], L, hd)
-                groups_ = q_.shape[1] // kp_.shape[0]
-                gk = jnp.repeat(gk, groups_, axis=1)
-                gv = jnp.repeat(gv, groups_, axis=1)
-                logits = jnp.einsum(
-                    "bhd,bhkd->bhk", q_.astype(jnp.float32),
-                    gk.astype(jnp.float32)) * (hd ** -0.5)
-                kv_pos = jnp.arange(L)[None, :]
-                mask = kv_pos <= lengths_[:, None]
-                logits = jnp.where(mask[:, None, :], logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                return jnp.einsum("bhk,bhkd->bhd", probs,
-                                  gv.astype(jnp.float32))
 
             def write_then_attend(q_, kp_, vp_, k_rows_, v_rows_, lengths_,
                                   tables_):
@@ -338,8 +294,10 @@ class Attention(nn.Module):
                 the pools, then the kernel reads the pools."""
                 kp_ = write_token_rows(kp_, k_rows_, tables_, lengths_)
                 vp_ = write_token_rows(vp_, v_rows_, tables_, lengths_)
-                return (paged_kernel(q_, kp_, vp_, lengths_, tables_),
-                        kp_, vp_)
+                out_ = paged_attend(
+                    q_, kp_, vp_, lengths_, tables_,
+                    reference=cfg.attention_impl == "reference")
+                return out_, kp_, vp_
 
             # Tensor-parallel serving: when tracing under a serving mesh
             # whose `tensor` axis is >1, write and attend per-shard via

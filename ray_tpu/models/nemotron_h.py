@@ -53,7 +53,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .falcon_h1 import FalconH1Config, MambaMixer, _dense, _paged_attend
+from ..ops.paged_attention import paged_attend
+from .falcon_h1 import FalconH1Config, MambaMixer, _dense
 from .llama import RMSNorm, _flash_on_mesh, _partitioned, write_token_rows
 from .moe import RoutedExperts, relu2
 
@@ -172,8 +173,8 @@ class PositionlessAttention(nn.Module):
                 a[:, :, 0, :], (1, 0, 2)).astype(pool.dtype)
             kp = write_token_rows(kp, rows(k, kp), tables, lengths)
             vp = write_token_rows(vp, rows(v, vp), tables, lengths)
-            out = _paged_attend(q[:, :, 0, :], kp, vp, lengths, tables,
-                                cfg.attention_impl == "reference")
+            out = paged_attend(q[:, :, 0, :], kp, vp, lengths, tables,
+                               reference=cfg.attention_impl == "reference")
             out = out[:, :, None, :].astype(cfg.dtype)
             new_cache = (kp, vp)
         elif cache is not None:

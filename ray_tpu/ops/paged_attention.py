@@ -1,0 +1,253 @@
+"""Paged decode attention: one query token a row over the row's K/V pages.
+
+`paged_attend` is the one entry point of every model's paged-decode branch
+(models/llama.py, models/falcon_h1.py, models/nemotron_h.py). Two paths:
+
+1. A Pallas TPU kernel written for the engine's page pool
+   (`[kv_heads, pages, page_size, head_dim]`), taken on a TPU when
+   `head_dim % 128 == 0`. A program is a ROW; one asynchronous copy moves a
+   page for ALL local kv heads (`kv_heads` runs of `page_size * head_dim`
+   elements); the copies of a block of pages are in flight while the block
+   before is computed, across rows too. The products take the pool's
+   (bf16) operands and accumulate in float32; the softmax statistics, the
+   probabilities into `P . V` and the output accumulator are float32.
+2. A gather fallback elsewhere (the CPU, toy head sizes, a model whose
+   `attention_impl` is "reference"): each row's pages materialised densely.
+
+`paged_kernel` names the path a decode program built here will hold.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import NEG_INF, NUM_LANES, _interpret
+
+# What the kernel's four K/V buffers (two slots each) may take of a core's
+# fast memory; the block of pages a copy group moves is sized to it.
+_BUFFER_BYTES = 4 << 20
+# What a compute step's float32 logits [kv_heads, queries, tokens] may take:
+# a quarter of the vector registers. A row pays for its tokens rounded up
+# to such a chunk, not to the block.
+_LOGIT_BYTES = 64 << 10
+_SUBLANES = 8
+
+
+def paged_kernel(head_dim: int, reference: bool = False) -> str:
+    """The path `paged_attend` takes here for heads of `head_dim`:
+    "pallas" or "gather"."""
+    if (jax.default_backend() == "tpu" and not reference
+            and head_dim % NUM_LANES == 0):
+        return "pallas"
+    return "gather"
+
+
+def paged_attend(q, k_pages, v_pages, lengths, tables, *,
+                 reference: bool = False):
+    """q [rows, heads, hd], unscaled; k_pages / v_pages [kv_heads, pages,
+    page_size, hd] (LOCAL heads and kv heads under a tensor axis: attention
+    is head-parallel, no collective); lengths [rows] tokens cached BEFORE
+    this one, which is already written at position lengths[row]; tables
+    [rows, pages_per_row] physical page ids. Row b attends positions 0 ..
+    lengths[b]. Returns [rows, heads, hd]."""
+    hd = q.shape[-1]
+    if paged_kernel(hd, reference) == "pallas":
+        return _paged_attend_pallas(
+            (q * hd ** -0.5).astype(k_pages.dtype), k_pages, v_pages,
+            lengths + 1, tables)
+    # Gather fallback: materialize each row's pages densely.
+    # [B, pages_per_seq, kvh, ps, hd] -> [B, kvh, L, hd]
+    rows, page_size = q.shape[0], k_pages.shape[2]
+    gk = jnp.transpose(k_pages, (1, 0, 2, 3))[tables]
+    gv = jnp.transpose(v_pages, (1, 0, 2, 3))[tables]
+    span = tables.shape[1] * page_size
+    gk = jnp.transpose(gk, (0, 2, 1, 3, 4)).reshape(
+        rows, k_pages.shape[0], span, hd)
+    gv = jnp.transpose(gv, (0, 2, 1, 3, 4)).reshape(
+        rows, v_pages.shape[0], span, hd)
+    groups = q.shape[1] // k_pages.shape[0]
+    gk = jnp.repeat(gk, groups, axis=1)
+    gv = jnp.repeat(gv, groups, axis=1)
+    logits = jnp.einsum(
+        "bhd,bhkd->bhk", q.astype(jnp.float32),
+        gk.astype(jnp.float32)) * (hd ** -0.5)
+    kv_pos = jnp.arange(span)[None, :]
+    mask = kv_pos <= lengths[:, None]
+    logits = jnp.where(mask[:, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhk,bhkd->bhd", probs, gv.astype(jnp.float32))
+
+
+def _chunk_tokens(kv_heads: int, queries: int) -> int:
+    """Tokens a compute step takes: whole lanes of logits within
+    `_LOGIT_BYTES`, 128 to 512 (256 at 8 kv heads x 8 padded queries; on
+    the chip 128 cost chat's shapes 14 % and 1024 gained the 4 x 8 and
+    2 x 16 shapes 3 % over 512: PERF.md §6, PR 37)."""
+    fit = _LOGIT_BYTES // (4 * kv_heads * queries) // NUM_LANES * NUM_LANES
+    return max(NUM_LANES, min(4 * NUM_LANES, fit))
+
+
+def _block_pages(kv_heads: int, page_size: int, head_dim: int,
+                 pages_per_row: int, itemsize: int, chunk: int) -> int:
+    """Pages a copy group moves: as many whole compute chunks as
+    `_BUFFER_BYTES` holds for this many kv heads, and no more than a row
+    has (rounded up to whole chunks)."""
+    chunk_pages = chunk // page_size
+    chunk_bytes = 2 * 2 * kv_heads * chunk * head_dim * itemsize
+    fit = max(1, _BUFFER_BYTES // chunk_bytes)
+    need = -(-pages_per_row // chunk_pages)
+    return min(fit, need) * chunk_pages
+
+
+def _kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref, *,
+            block_pages: int, chunk: int, pages_per_row: int):
+    """One row. lengths_ref [rows] tokens to attend (>= 1), tables_ref
+    [rows * pages_per_row] in SMEM; q_ref / o_ref [kv_heads, G, hd] (the
+    row's query heads by kv head, G padded to whole sublanes); k_hbm /
+    v_hbm the pools; k_buf / v_buf [2, kv_heads, block, hd]; sems [2, 2]
+    (K / V by slot); slot_ref [1] the slot the row's first block is in."""
+    row, rows = pl.program_id(0), pl.num_programs(0)
+    page_size = k_hbm.shape[2]
+    block = block_pages * page_size
+    length = lengths_ref[row]
+
+    def copies(r, blk, slot, start: bool):
+        """Start (or wait for) the pages of block `blk` of row `r`: per
+        page ONE copy for K and one for V that covers every kv head."""
+        pages = jnp.minimum(
+            block_pages, pl.cdiv(lengths_ref[r] - blk * block, page_size))
+        first = r * pages_per_row + blk * block_pages
+
+        def one(j, carry):
+            # a wait needs the copy's shape, not its source
+            page = tables_ref[first + j] if start else 0
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for pool, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                   (v_hbm, v_buf, sems.at[1, slot])):
+                copy = pltpu.make_async_copy(
+                    pool.at[:, page], buf.at[slot, :, at], sem)
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+        jax.lax.fori_loop(0, pages, one, None)
+
+    @pl.when(row == 0)
+    def _first():
+        # a row's last chunk reads past its tokens: masked in K, times a
+        # probability of zero in V, which the buffer's first bits may not
+        # survive (0 * nan)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, start=True)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    blocks = pl.cdiv(length, block)
+
+    def attend_block(blk, slot):
+        ends = blk + 1 == blocks
+        next_row = jnp.where(ends, row + 1, row)
+
+        @pl.when(next_row < rows)
+        def _prefetch():
+            copies(next_row, jnp.where(ends, 0, blk + 1), 1 - slot,
+                   start=True)
+
+        copies(row, blk, slot, start=False)
+        here = jnp.minimum(block, length - blk * block)
+
+        def attend_chunk(c, carry):
+            # every kv head at once: one chain of products, reductions and
+            # exponentials a chunk, kv_heads wide, not kv_heads chains
+            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            seen = (blk * block + c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, chunk), 2)) < length
+            logits = jax.lax.dot_general(
+                q_ref[...], k_buf[slot, :, at, :],
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)      # [kvh, G, chunk]
+            logits = jnp.where(seen, logits, NEG_INF)
+            m_prev = m_ref[...]                              # [kvh, G, 1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * correction + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+                p, v_buf[slot, :, at, :].astype(jnp.float32),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)      # [kvh, G, hd]
+            return carry
+        jax.lax.fori_loop(0, pl.cdiv(here, chunk), attend_chunk, None)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(0, blocks, attend_block, slot_ref[0])
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_pages",))
+def _paged_attend_pallas(q, k_pages, v_pages, lengths, tables,
+                         block_pages=None):
+    """The kernel. q [rows, heads, hd] SCALED and in the pool's type;
+    lengths [rows] tokens to attend, >= 1 (a dead row: 1, on the null
+    page). `block_pages` is the tests' override of `_block_pages`. Jitted so that a model's layers share ONE trace of
+    the kernel's body: traced a layer at a time, 16 layers added 2.8 s to
+    every start of the chat cell's replica (PERF.md §6, PR 37)."""
+    rows, heads, hd = q.shape
+    kv_heads, _, page_size, _ = k_pages.shape
+    pages_per_row = tables.shape[1]
+    groups = heads // kv_heads
+    padded = -(-groups // _SUBLANES) * _SUBLANES
+    chunk = _chunk_tokens(kv_heads, padded)
+    if block_pages is None:
+        block_pages = _block_pages(kv_heads, page_size, hd, pages_per_row,
+                                   k_pages.dtype.itemsize, chunk)
+    block = block_pages * page_size
+    chunk = min(chunk, block)
+    if chunk % page_size or block % chunk:
+        raise ValueError(f"pages of {page_size} tokens do not tile chunks "
+                         f"of {chunk} in a block of {block}")
+    # by kv head, each group padded to whole sublanes: a slice per kv head
+    # is then whole tiles
+    q = jnp.pad(q.reshape(rows, kv_heads, groups, hd),
+                ((0, 0), (0, 0), (0, padded - groups), (0, 0)))
+    row_spec = pl.BlockSpec((None, kv_heads, padded, hd),
+                            lambda r, *_: (r, 0, 0, 0))
+    stat = pltpu.VMEM((kv_heads, padded, 1), jnp.float32)
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_pages=block_pages, chunk=chunk,
+                          pages_per_row=pages_per_row),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row_spec,
+            grid=(rows,),
+            scratch_shapes=[
+                pltpu.VMEM((2, kv_heads, block, hd), k_pages.dtype),
+                pltpu.VMEM((2, kv_heads, block, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                stat, stat,
+                pltpu.VMEM((kv_heads, padded, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, k_pages.dtype),
+        # a row's last block starts the next row's first: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="paged_attention",
+    # no row reads past its table, as none does in the gather fallback
+    )(jnp.minimum(lengths, pages_per_row * page_size), tables.reshape(-1),
+      q, k_pages, v_pages)
+    return out[:, :, :groups].reshape(rows, heads, hd)

@@ -107,7 +107,7 @@ def test_flash_on_a_mesh_is_shard_mapped(v5e, as_tpu):
 
 def _paged_decode_layer(mesh=None):
     """One Attention layer's paged decode step (q_len 1) through the
-    model's own branch: page scatter + the stock paged-attention kernel."""
+    model's own branch: page scatter + ops.paged_attention's kernel."""
     import dataclasses
 
     from ray_tpu.models.llama import Attention, LlamaConfig
@@ -365,7 +365,7 @@ def falcon_programs(v5e):
 
 def test_hybrid_decode_step_compiles_for_v5e_and_copies_no_pool(
         falcon_programs, as_tpu):
-    """5:1 grouping (20 query heads on 4 kv heads) through the stock paged
+    """5:1 grouping (20 query heads on 4 kv heads) through the paged
     kernel, and the donated pools, the pages' and the scan state's
     [rows, 32, 128, 256] float32, updated in place."""
     from ray_tpu.llm.paged import pool_copies
@@ -492,7 +492,7 @@ V5E_BYTES_LIMIT = 16.9e9
 
 def test_nemotron_decode_step_compiles_for_v5e_within_memory(
         nemotron_programs, as_tpu):
-    """96 rows: 16:1 grouping through ONE stock paged kernel (the one
+    """96 rows: 16:1 grouping through ONE paged kernel (the one
     layer that attends), every held expert on every token (plain einsums:
     models/moe.py held_expert_sum), the page pool, the five
     scan-state pools and the expert counters donated and updated in

@@ -346,8 +346,12 @@ def test_a_dense_engine_owns_no_recurrent_state():
 
 
 # recorded from the commit before the engine learned of layer kinds (PR 33's
-# tree, 2c580e1), by test_llm_paged.lowered_programs on `tiny_engine()`
-FALCON_PROGRAMS = {"decode_step": "73aba8d37aa6e0a4",
+# tree, 2c580e1), by test_llm_paged.lowered_programs on `tiny_engine()`;
+# `decode_step` again in PR 37 (was 73aba8d37aa6e0a4): the gather fallback
+# is now the dense model's (ops.paged_attention.paged_attend), which
+# repeats the kv heads before it casts to float32 where this model's own
+# copy cast first. The same values; `chunk_prefill` holds no paged decode
+FALCON_PROGRAMS = {"decode_step": "0d4903ff37ada98a",
                    "chunk_prefill": "afb853d4dea3960f"}
 
 
