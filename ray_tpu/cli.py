@@ -700,6 +700,34 @@ def cmd_stack(args):
           f"({sum(1 for r in rows if r.get('error'))} unreachable)")
 
 
+def _print_extents(row):
+    """A step kind's distribution of extents and its newest slow steps
+    (accel plane: `extent_hist`, `slow`), each with its largest phase and
+    the parts of it that were timed apart."""
+    from ray_tpu._internal import accel
+    hist = row["extent_hist"]
+    p50, p99, top = (accel.extent_quantile(hist, q) * 1e3
+                     for q in (0.5, 0.99, 1.0))
+    print(f"    extent p50={p50:.1f}ms p99={p99:.1f}ms max<={top:.0f}ms "
+          f"· slow {row['slow_total']} ({row['slow_seconds']:.2f}s)")
+    now = row.get("now") or time.monotonic()
+    for step in row["slow"][-5:]:
+        phase, seconds = max(step["phases"].items(), key=lambda kv: kv[1],
+                             default=("-", 0.0))
+        # the pieces of that phase timed apart (`StepTimer.part`)
+        parts = ", ".join(
+            f"{name[len(phase) + 1:-2]} {value * 1e3:.1f}"
+            for name, value in step["counters"].items()
+            if name.startswith(phase + "_") and name.endswith("_s"))
+        pauses = ", ".join(f"{p['what']} {p['seconds'] * 1e3:.0f}ms"
+                           for p in step["pauses"]) or "none stamped"
+        print(f"      {now - step['end']:>8.1f}s ago "
+              f"{step['extent_s'] * 1e3:>7.1f}ms "
+              f"(usual {step['typical_s'] * 1e3:.1f}) "
+              f"{phase}={seconds * 1e3:.1f}ms"
+              + (f" ({parts})" if parts else "") + f"  pauses: {pauses}")
+
+
 def cmd_devices(args):
     """Cluster accelerator report (the device leg of memory/profile):
     per-device HBM used/peak/limit, XLA compile totals + top compiled
@@ -739,6 +767,8 @@ def cmd_devices(args):
                   f"goodput compile/device/host="
                   f"{row['compile_s']:.2f}/{row['device_s']:.2f}/"
                   f"{row['host_s']:.2f}s")
+            if row.get("extent_hist"):
+                _print_extents(row)
     top_fns = []
     for proc in summary["processes"]:
         top_fns.extend((proc.get("compile") or {}).get("per_function", ()))

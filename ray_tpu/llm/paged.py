@@ -133,8 +133,14 @@ def _param_init(cfg, mesh):
     return jax.jit(init_params, out_shardings=pshard), pshard
 
 
-def _no_phase(name: str):
-    """`StepTimer.phase` for a caller outside any timer."""
+# Visits between two settings of the engine's gauges, as many as between two
+# flushes of the `tick` accumulator: the metrics flusher samples a gauge
+# every 5 s, and `shared_pages` walks the whole radix (0.8 ms on a full one).
+_GAUGES_EVERY = 16
+
+
+def _no_phase(*names: str):
+    """`StepTimer.phase` / `.part` for a caller outside any timer."""
     return contextlib.nullcontext()
 
 
@@ -355,6 +361,10 @@ class PagedLLMEngine:
         self._lookahead_ticks = 0
         self._drained_ticks: Dict[str, int] = {}
         self._discarded_tokens = 0
+        # what the visits dispatched (the `tick` row's counters)
+        self._decode_rows = 0
+        self._prefill_chunks = 0
+        self._prompts_finished = 0
         # accelerator-plane step telemetry (StepTimer on the decode
         # tick): decode forward ≈ 2 FLOPs per param per token. Checked
         # once here so a killed plane costs the tick two attribute
@@ -854,10 +864,13 @@ class PagedLLMEngine:
         the step dispatched one visit earlier; `between` is the time
         since the last visit's end while work was waiting — the serving
         loop's executor hop and whatever else held this thread. Its
-        counters say how often the step ahead was there."""
-        entered = time.perf_counter()
+        counters say how often the step ahead was there, and how many
+        rows and prefill chunks the visit dispatched; the row also has
+        the visits' distribution and the slow ones whole (`extent_hist`,
+        `slow`: a visit's extent is `between` + the visit)."""
         if self._counters_asked:
             self.read_counters()
+        entered = time.perf_counter()
         tick = _accel.StepTimer("tick", sink=self._tick_accum)
         if self._tick_end is not None:
             tick.outside("between", entered - self._tick_end)
@@ -869,13 +882,14 @@ class PagedLLMEngine:
             with tick.phase("admit"):
                 self._admit()
             with tick.phase("prefill"):
-                self._prefill_tick()
+                self._prefill_tick(tick.part)
             if self._state_due:
                 with tick.phase("state"):
                     self._install_states()
-            with tick.phase("gauges"):
-                self._steps += 1
-                self._set_gauges()
+            self._steps += 1
+            if self._steps % _GAUGES_EVERY == 0:
+                with tick.phase("gauges"):
+                    self._set_gauges()
             for name, count in self._ahead_counts().items():
                 tick.count(name, count - before[name])
         if self.has_work():
@@ -898,29 +912,41 @@ class PagedLLMEngine:
         found the host copy older than the last step; a caller that runs
         between steps (the benchmark's marks) may call it itself."""
         if self.counters:
-            self._counters_host = jax.device_get(  # host-sync ok: on request
-                self.counters)
+            with _accel.pause("read_counters"):
+                counters = jax.device_get(  # host-sync ok: on request
+                    self.counters)
+            self._counters_host = counters
         self._counters_at, self._counters_asked = self._steps, False
 
     def _ahead_counts(self) -> Dict[str, int]:
         """How the step ahead fared so far: decode steps dispatched
         before the last one's tokens were read, reads with nothing
-        dispatched behind them, tokens dropped a tick late."""
+        dispatched behind them, tokens dropped a tick late; and what the
+        visits dispatched: decode rows, prefill chunks, and the prompts
+        they finished."""
         return {"lookahead_ticks": self._lookahead_ticks,
                 "drained_ticks": sum(self._drained_ticks.values()),
-                "discarded_tokens": self._discarded_tokens}
+                "discarded_tokens": self._discarded_tokens,
+                "decode_rows": self._decode_rows,
+                "prefill_chunks": self._prefill_chunks,
+                "prompts_finished": self._prompts_finished}
 
     def _flush_step_rows(self):
+        """When the engine drains and in `stats()`: the accumulators hand
+        their partial window to the accel plane, and the gauges are set
+        (`step` sets them every `_GAUGES_EVERY` visits besides)."""
         for accum in (self._step_accum, self._tick_accum):
             if accum is not None:
                 accum.flush()
+        self._set_gauges()
 
     def _waiting_count(self) -> int:
         return self._pending.qsize() + len(self._parked)
 
     def _set_gauges(self):
         metrics = llm_metrics()
-        metrics.queue_depth.set(self._waiting_count(), tags=_GAUGE_TAGS)
+        waiting = self._waiting_count()
+        metrics.queue_depth.set(waiting, tags=_GAUGE_TAGS)
         metrics.running.set(
             sum(1 for s in self.seqs if s.request is not None),
             tags=_GAUGE_TAGS)
@@ -929,7 +955,7 @@ class PagedLLMEngine:
             1.0 - free / max(1, self.config.num_pages), tags=_GAUGE_TAGS)
         metrics.kv_occupancy.set(self.config.num_pages - 1 - free,
                                  tags=_GAUGE_TAGS)
-        metrics.waiting.set(self._waiting_count(), tags=_GAUGE_TAGS)
+        metrics.waiting.set(waiting, tags=_GAUGE_TAGS)
         metrics.shared_pages.set(self.radix.shared_pages(),
                                  tags=_GAUGE_TAGS)
 
@@ -1090,11 +1116,15 @@ class PagedLLMEngine:
                                            dense, jnp.asarray(pad))
         seq.dense_caches = dense
 
-    def _prefill_tick(self):
+    def _prefill_tick(self, part=_no_phase):
         """Advance at most `prefill_decode_ratio` prefill chunks,
         round-robin across prefilling sequences in admission order, so
         a long prompt never stalls the decode batch for more than one
-        bounded chunk per tick."""
+        bounded chunk per tick. `part` (`StepTimer.part`) times the two
+        halves of the `prefill` phase apart, in the visits that run them:
+        a chunk's staging and dispatch (`prefill_chunk_s`), and what a
+        finished prompt costs the stepping thread (`prefill_finish_s`:
+        the page write, the radix insert, `first_token`)."""
         budget = max(1, self.config.prefill_decode_ratio)
         order = sorted(
             (i for i, s in enumerate(self.seqs)
@@ -1109,10 +1139,14 @@ class PagedLLMEngine:
         while budget > 0 and order:
             i = order.pop(0)
             seq = self.seqs[i]
-            self._prefill_chunk(seq)
+            with part("prefill", "chunk"):
+                self._prefill_chunk(seq)
+            self._prefill_chunks += 1
             budget -= 1
             if seq.prefill_off >= len(seq.prompt):
-                self._finish_prefill(i)
+                with part("prefill", "finish"):
+                    self._finish_prefill(i)
+                self._prompts_finished += 1
             else:
                 order.append(i)
 
@@ -1402,7 +1436,7 @@ class PagedLLMEngine:
         self._drained_ticks[reason] = \
             self._drained_ticks.get(reason, 0) + 1
         unread, self._unread = self._unread, []
-        with phase("wait"):
+        with phase("wait"), _accel.pause("drain/" + reason):
             values = self._fetch(self._tokens)
         with phase("emit"):
             self._emit_tokens(unread, values)
@@ -1503,6 +1537,7 @@ class PagedLLMEngine:
                 # this step's token, in flight from here on
                 seq.length += 1
                 seq.dispatched += 1
+            self._decode_rows += len(active)
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
         timer = accel.StepTimer(

@@ -203,7 +203,10 @@ def flush(gcs=None, key: Optional[str] = None) -> bool:
             gcs = worker.gcs
         if key is None:
             key = str(os.getpid())
-        gcs.put(REQTRACE_KV_NS, key, _payload_json(_RECORDER.events()))
+        from .._internal import accel
+        with accel.pause("reqtrace_encode"):
+            payload = _payload_json(_RECORDER.events())
+        gcs.put(REQTRACE_KV_NS, key, payload)
         return True
     except Exception:  # noqa: BLE001 — observability is best-effort
         logger.debug("reqtrace flush failed", exc_info=True)

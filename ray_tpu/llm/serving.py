@@ -161,6 +161,10 @@ class LLMServer:
             # spread of a serving rate)
             gc.collect()
             gc.freeze()
+            # what the collector still costs is stamped: a pass over 1 ms
+            # is in the slow visit it stopped (accel `tick` row, `slow`)
+            from .._internal import accel
+            accel.watch_gc()
         if self._loop_task is None or self._loop_task.done():
             self._wake = asyncio.Event()
             self._loop_task = asyncio.ensure_future(self._drive())

@@ -226,24 +226,29 @@ def flush_now(gcs=None, key: Optional[str] = None) -> bool:
             if key is None:
                 key = worker.worker_id.hex() if isinstance(
                     worker.worker_id, bytes) else str(worker.worker_id)
-        # transport-observatory piggyback: fold the hot-path
-        # accumulators (wire bytes, in-flight) and the native-ring
-        # stats into the registry BEFORE snapshotting so this flush
-        # carries them. sys.modules-guarded like the reqtrace hook
-        # below — processes that never imported the RPC metrics module
-        # pay nothing.
         import sys
-        rpcm = sys.modules.get("ray_tpu._internal.rpc_metrics")
-        if rpcm is not None:
-            rpcm.export_transport()
-        gcs.put(METRICS_KV_NS, key, snapshot_all_json())
-        # request-observatory piggyback (steptrace pattern): the serve
-        # plane's lifecycle rings ride the same flush cadence. Guarded
-        # via sys.modules so processes that never imported the serve
-        # plane pay nothing (and never import it from here).
-        mod = sys.modules.get("ray_tpu.llm.reqtrace")
-        if mod is not None:
-            mod.flush(gcs=gcs, key=key)
+        from .._internal import accel
+        # the encodes below hold the GIL on this thread: stamped, so that
+        # a step of this process which a flush slowed says so (accel
+        # plane, `slow[*].pauses`)
+        with accel.pause("metrics_flush"):
+            # transport-observatory piggyback: fold the hot-path
+            # accumulators (wire bytes, in-flight) and the native-ring
+            # stats into the registry BEFORE snapshotting so this flush
+            # carries them. sys.modules-guarded like the reqtrace hook
+            # below — processes that never imported the RPC metrics
+            # module pay nothing.
+            rpcm = sys.modules.get("ray_tpu._internal.rpc_metrics")
+            if rpcm is not None:
+                rpcm.export_transport()
+            gcs.put(METRICS_KV_NS, key, snapshot_all_json())
+            # request-observatory piggyback (steptrace pattern): the
+            # serve plane's lifecycle rings ride the same flush cadence.
+            # Guarded via sys.modules so processes that never imported
+            # the serve plane pay nothing (and never import it from here).
+            mod = sys.modules.get("ray_tpu.llm.reqtrace")
+            if mod is not None:
+                mod.flush(gcs=gcs, key=key)
         return True
     except Exception:  # noqa: BLE001
         return False

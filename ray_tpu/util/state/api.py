@@ -1060,8 +1060,11 @@ def accel_summary(force_local_jax: bool = True,
                 pid=report.get("pid"),
                 worker_id=report.get("worker_id")))
         for row in report.get("steps", ()):
+            # `now`: the process's monotonic clock when it reported, for
+            # the ages of its `slow` steps
             steps.append(dict(row, node_id=report.get("node_id"),
-                              pid=report.get("pid")))
+                              pid=report.get("pid"),
+                              now=report.get("now")))
         comp = report.get("compile") or {}
         compiles += comp.get("compiles", 0)
         compile_seconds += comp.get("compile_seconds", 0.0)
