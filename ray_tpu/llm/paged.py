@@ -155,21 +155,46 @@ def pool_copies(compiled_text: str, pool_shape) -> int:
         rf"= \w+\[{dims}\]\S* copy(?:-done)?\(", compiled_text))
 
 
-@jax.jit
-def logits_row(logits, index):
-    """Row `index` of a prefill chunk's logits `[1, chunk, vocab]`, as
-    `[1, vocab]` float32: what the prompt's first token is sampled from
-    (one small program a bucket; the chunk's logits are free after it)."""
-    return jax.lax.dynamic_index_in_dim(
-        logits[0], index, axis=0).astype(jnp.float32)
+def array_shapes(compiled_text: str, shape) -> int:
+    """Arrays of `shape` (whatever their type and layout) named anywhere
+    in a compiled program's text. A prefill chunk that applies the head
+    to one row holds none of `[chunk, vocab]`."""
+    dims = ",".join(map(str, shape))
+    return len(re.findall(rf"\w+\[{dims}\]", compiled_text))
+
+
+def chunk_logits(model, params, hidden, last):
+    """The head in a prefill chunk, over the final norm's output `hidden`
+    [1, chunk, hidden size]. `last`, an int32 scalar, is the row of the
+    prompt's final token if this chunk holds it and -1 otherwise: the
+    logits of that one row, [1, vocab] float32 (the first token is
+    sampled from them), and zeros from a chunk that finishes nothing,
+    which then neither runs the head nor reads its weights (a `cond`:
+    one program a bucket either way). `last=None` is the form before PR
+    40, logits at every position [1, chunk, vocab] float32, which the
+    tick no longer runs; both forms share every line in front of the
+    head and differ in the rows that meet it."""
+    def head(rows):
+        return model.apply({"params": params}, rows,
+                           method="head").astype(jnp.float32)
+
+    if last is None:
+        return head(hidden)
+    row = jax.lax.dynamic_slice_in_dim(
+        hidden, jnp.maximum(last, 0), 1, axis=1)
+    return jax.lax.cond(
+        last >= 0, lambda: head(row)[:, 0],
+        lambda: jnp.zeros((hidden.shape[0], model.config.vocab_size),
+                          jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("sampled",))
 def first_token(tokens, last, slot, rng, temperature, top_k, top_p,
                 sampled):
     """The token a prompt's prefill ends in, from its last position's
-    logits `last` ([1, vocab]; the [1] parameter vectors are the
-    request's), into row `slot` of the engine's token vector: the first
+    logits `last` ([1, vocab], as the prompt's last `chunk_prefill`
+    returned them; the [1] parameter vectors are the request's), into
+    row `slot` of the engine's token vector: the first
     token reaches the host with the next read of the vector, not through
     a fetch of its own. `sampled`: the request has a temperature, so the
     token is drawn as `decode_step` draws; the argmax otherwise, in a
@@ -231,7 +256,9 @@ class _Seq:
     resume: List[int] = dataclasses.field(default_factory=list)
     prefill_off: int = 0         # prompt tokens cached so far
     dense_caches: Any = None     # in-flight chunked-prefill cache
-    # logits at the prompt's last position, [1, vocab] on the device
+    # logits at the prompt's last position, [1, vocab] float32 on the
+    # device: what the chunk that finished the prompt returned (or another
+    # engine shipped); None until then and after `first_token` took it
     last_logits: Any = None
     admit_at: int = 0            # admission order (preemption picks max)
 
@@ -364,6 +391,7 @@ class PagedLLMEngine:
         # what the visits dispatched (the `tick` row's counters)
         self._decode_rows = 0
         self._prefill_chunks = 0
+        self._prefill_heads = 0     # chunks that ran the head (on one row)
         self._prompts_finished = 0
         # accelerator-plane step telemetry (StepTimer on the decode
         # tick): decode forward ≈ 2 FLOPs per param per token. Checked
@@ -419,17 +447,22 @@ class PagedLLMEngine:
 
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
 
-        def chunk_prefill(params, tokens, positions, dense_caches, offset):
+        def chunk_prefill(params, tokens, positions, dense_caches, offset,
+                          last=None):
             """One prefill chunk: write K/V for `tokens` into the dense
             caches at `offset`, attend causally over everything cached so
             far. Chunked prefill lifts the prompt cap to max_len — any
             prompt runs as ceil(n/bucket) chunks of one compiled shape
             per bucket (reference: vLLM chunked prefill, delegated by
-            llm/_internal/serve/deployments/llm/vllm/)."""
-            logits, new_caches = model.apply(
+            llm/_internal/serve/deployments/llm/vllm/). Returns the
+            logits of row `last` alone, [1, vocab] float32 (`chunk_logits`:
+            zeros, and no head, at -1); called without `last`, the logits
+            of every position [1, chunk, vocab], a specialisation of its
+            own that only the benchmark's parity check still compiles."""
+            hidden, new_caches = model.apply(
                 {"params": params}, tokens, positions=positions,
-                kv_caches=dense_caches, cache_index=offset)
-            return logits.astype(jnp.float32), new_caches
+                kv_caches=dense_caches, cache_index=offset, head=False)
+            return chunk_logits(model, params, hidden, last), new_caches
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
@@ -598,18 +631,21 @@ class PagedLLMEngine:
         # `counters` is () for a model without any: no argument, no result
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3, 12))
 
-        def chunk_prefill(params, tokens, positions, staged, offset, valid):
+        def chunk_prefill(params, tokens, positions, staged, offset, valid,
+                          last=None):
             """One prefill chunk of one row. `staged`: {"kv": dense
             (k, v) per layer that attends, "state": (conv, ssm) per layer
             that scans}. Attention overwrites or masks the padded tail; a
             layer that scans (or counts) is told `valid`, the count of
-            real tokens, and keeps the rest out of what it hands on."""
-            logits, new = model.apply(
+            real tokens, and keeps the rest out of what it hands on.
+            `last` and the logits returned: as the dense `chunk_prefill`'s
+            (`chunk_logits`)."""
+            hidden, new = model.apply(
                 {"params": params}, tokens, positions=positions,
                 kv_caches=chunk_caches(staged), cache_index=offset,
-                valid=valid)
+                valid=valid, head=False)
             nk, nv, nstate, _ = by_kind(new)
-            return logits.astype(jnp.float32), {
+            return chunk_logits(model, params, hidden, last), {
                 "kv": list(zip(nk, nv)), "state": nstate}
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
@@ -922,13 +958,14 @@ class PagedLLMEngine:
         """How the step ahead fared so far: decode steps dispatched
         before the last one's tokens were read, reads with nothing
         dispatched behind them, tokens dropped a tick late; and what the
-        visits dispatched: decode rows, prefill chunks, and the prompts
-        they finished."""
+        visits dispatched: decode rows, prefill chunks, those of them that
+        ran the head, and the prompts they finished."""
         return {"lookahead_ticks": self._lookahead_ticks,
                 "drained_ticks": sum(self._drained_ticks.values()),
                 "discarded_tokens": self._discarded_tokens,
                 "decode_rows": self._decode_rows,
                 "prefill_chunks": self._prefill_chunks,
+                "prefill_heads": self._prefill_heads,
                 "prompts_finished": self._prompts_finished}
 
     def _flush_step_rows(self):
@@ -1140,7 +1177,7 @@ class PagedLLMEngine:
             i = order.pop(0)
             seq = self.seqs[i]
             with part("prefill", "chunk"):
-                self._prefill_chunk(seq)
+                self._prefill_heads += self._prefill_chunk(seq)
             self._prefill_chunks += 1
             budget -= 1
             if seq.prefill_off >= len(seq.prompt):
@@ -1150,10 +1187,12 @@ class PagedLLMEngine:
             else:
                 order.append(i)
 
-    def _prefill_chunk(self, seq: _Seq):
+    def _prefill_chunk(self, seq: _Seq) -> bool:
         """One bucket-rounded chunk of `seq`'s remaining prompt into its
         dense cache — one compiled shape per bucket, whatever the
-        prompt's length."""
+        prompt's length. Returns whether the chunk ran the head: only the
+        chunk that holds the prompt's final token does, on that row, and
+        leaves its logits in `seq.last_logits`."""
         cfg = self.config
         prompt = seq.prompt
         largest = cfg.prefill_buckets[-1]
@@ -1173,13 +1212,18 @@ class PagedLLMEngine:
         # a scan layer must be told where the bucket's padding starts
         valid = () if self.state is None \
             else (jnp.asarray(take, jnp.int32),)
+        # the row the first token is sampled from, if this chunk holds it:
+        # the program applies the head to that row and to nothing else
+        finishes = off + take == len(prompt)
+        last = take - 1 if finishes else -1
         with self._mesh_scope():
             logits, seq.dense_caches = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                seq.dense_caches, jnp.asarray(off, jnp.int32), *valid)
-        if off + take == len(prompt):
+                seq.dense_caches, jnp.asarray(off, jnp.int32), *valid,
+                jnp.asarray(last, jnp.int32))
+        if finishes:
             # stays on the device: `first_token` samples from it there
-            seq.last_logits = logits_row(logits, np.int32(take - 1))
+            seq.last_logits = logits
         seq.prefill_off = off + take
         if trace and seq.request is not None:
             # no chunk waits for the device: dur_s is the launch alone
@@ -1192,6 +1236,7 @@ class PagedLLMEngine:
                     self._compile_total() - compile_t0, 6) or None)
         # counts COMPUTED tokens only — a radix-shared span costs zero
         llm_metrics().prefill_tokens.inc(take, tags=_TAGS)
+        return finishes
 
     def _write_owned_pages(self, dense_caches, write_ids, start_page):
         """Commit owned prompt pages from a dense prefill cache to the
@@ -1209,9 +1254,10 @@ class PagedLLMEngine:
 
     def _finish_prefill(self, index: int):
         """Prompt fully cached: write the owned tail pages, commit full
-        pages to the radix, sample the first token from the prefill
-        logits into the token vector ON THE DEVICE, and move the sequence
-        to the decode phase. Nothing here waits for the device: the host
+        pages to the radix, sample the first token from the one row of
+        logits the last chunk returned (`seq.last_logits`) into the token
+        vector ON THE DEVICE, and move the sequence to the decode phase.
+        Nothing here waits for the device: the host
         work (the radix insert above all) runs under the decode step in
         flight, and the token is read with the next visit's."""
         seq = self.seqs[index]

@@ -341,12 +341,14 @@ class ParallelBlock(nn.Module):
 class FalconH1Model(nn.Module):
     """tokens -> logits; with `kv_caches`, (logits, per-layer (k, v, conv,
     ssm)) — k/v pools and state pools in paged decode, dense caches and
-    one row's state in a prefill chunk."""
+    one row's state in a prefill chunk. `head=False` and the method `head`
+    as `LlamaModel`'s: the final norm's output in place of the logits, and
+    the head alone."""
     config: FalconH1Config
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None,
-                 cache_index=None, valid=None):
+                 cache_index=None, valid=None, head=True):
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -364,8 +366,14 @@ class FalconH1Model(nn.Module):
                 x, positions, cache, cache_index, valid)
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        logits = _dense(cfg.vocab_size, ("embed", "vocab"), "lm_head",
-                        cfg)(x) * cfg.lm_head_multiplier
+        out = self.head(x) if head else x
         if kv_caches is not None:
-            return logits, new_caches
-        return logits
+            return out, new_caches
+        return out
+
+    @nn.compact
+    def head(self, x):
+        """Logits of the final norm's output `x` [batch, rows, hidden]."""
+        cfg = self.config
+        return _dense(cfg.vocab_size, ("embed", "vocab"), "lm_head",
+                      cfg)(x) * cfg.lm_head_multiplier

@@ -430,12 +430,18 @@ class DecoderBlock(nn.Module):
 
 
 class LlamaModel(nn.Module):
-    """Causal LM: tokens -> logits. `kv_caches` enables decode mode."""
+    """Causal LM: tokens -> logits. `kv_caches` enables decode mode.
+
+    The head alone is `head` (`model.apply(variables, x, method="head")`),
+    and `head=False` stops a call in front of it and hands back the final
+    norm's output where the logits would be: a caller that needs logits
+    at few of its positions (a prefill chunk needs one, or none) applies
+    the head to those rows itself."""
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None,
-                 cache_index=None):
+                 cache_index=None, head=True):
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -460,18 +466,23 @@ class LlamaModel(nn.Module):
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         x = _pin(x, _STREAM, kv_caches is None)
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x,
-                                embed.astype(cfg.dtype))
-        else:
-            logits = nn.DenseGeneral(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="lm_head",
-                kernel_init=_partitioned(nn.initializers.lecun_normal(),
-                                         ("embed", "vocab")))(x)
+        out = self.head(x) if head else x
         if kv_caches is not None:
-            return logits, new_caches
-        return constrain(logits, _LOGITS)
+            return out, new_caches
+        return constrain(out, _LOGITS) if head else out
+
+    @nn.compact
+    def head(self, x):
+        """Logits of the final norm's output `x` [batch, rows, hidden]."""
+        cfg = self.config
+        if cfg.tie_embeddings:
+            embed = nn.unbox(self.get_variable("params", "embed"))
+            return jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
+        return nn.DenseGeneral(
+            cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="lm_head",
+            kernel_init=_partitioned(nn.initializers.lecun_normal(),
+                                     ("embed", "vocab")))(x)
 
 
 def init_kv_caches(config: LlamaConfig, batch: int, max_len: int,

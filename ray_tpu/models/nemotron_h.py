@@ -272,12 +272,14 @@ class Block(nn.Module):
 class NemotronHModel(nn.Module):
     """tokens -> logits; with `kv_caches`, (logits, per-layer tuples of
     what the layer's kind carries: (k, v), (conv, ssm), (pairs, steps) in
-    paged decode and () in a prefill chunk for an E layer)."""
+    paged decode and () in a prefill chunk for an E layer). `head=False`
+    and the method `head` as `LlamaModel`'s: the final norm's output in
+    place of the logits, and the head alone."""
     config: NemotronHConfig
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None,
-                 cache_index=None, valid=None):
+                 cache_index=None, valid=None, head=True):
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -294,8 +296,13 @@ class NemotronHModel(nn.Module):
                 x, positions, cache, cache_index, valid)
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        logits = _dense(cfg.vocab_size, ("embed", "vocab"), "lm_head",
-                        cfg)(x)
+        out = self.head(x) if head else x
         if kv_caches is not None:
-            return logits, new_caches
-        return logits
+            return out, new_caches
+        return out
+
+    @nn.compact
+    def head(self, x):
+        """Logits of the final norm's output `x` [batch, rows, hidden]."""
+        cfg = self.config
+        return _dense(cfg.vocab_size, ("embed", "vocab"), "lm_head", cfg)(x)

@@ -398,19 +398,13 @@ def test_hybrid_decode_step_compiles_for_v5e_and_copies_no_pool(
 
 
 def test_first_token_compiles_for_v5e(falcon_programs, as_tpu):
-    """The two programs that keep a prompt's first token on the device, at
-    the hybrid cell's largest chunk (256 positions x 261,120 float32
-    logits, 0.27 GB): one reads a row of them and copies nothing else,
-    the other takes that row's argmax into the token vector and holds no
-    sort (greedy traffic never pays the sampler's compile)."""
-    from ray_tpu.llm.paged import first_token, logits_row
+    """The program that keeps a prompt's first token on the device: it
+    takes the one row of logits the prompt's last chunk returned and
+    writes that row's argmax into the token vector, and holds no sort
+    (greedy traffic never pays the sampler's compile)."""
+    from ray_tpu.llm.paged import first_token
     p = falcon_programs
     spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
-    row = logits_row.lower(spec(jnp.float32, 1, 256, cfg.vocab_size),
-                           spec(jnp.int32)).compile()
-    memory = row.memory_analysis()
-    assert memory.output_size_in_bytes < 2 * 4 * cfg.vocab_size
-    assert memory.temp_size_in_bytes < 2 * 4 * cfg.vocab_size
     greedy = first_token.lower(
         spec(jnp.int32, rows), spec(jnp.float32, 1, cfg.vocab_size),
         spec(jnp.int32), spec(jnp.uint32, 2), spec(jnp.float32, 1),
@@ -420,25 +414,34 @@ def test_first_token_compiles_for_v5e(falcon_programs, as_tpu):
 
 
 def test_hybrid_prefill_chunk_compiles_for_v5e(falcon_programs, as_tpu):
-    """The largest bucket (two chunks of the scan): the staging pytree
+    """The largest bucket (two chunks of the scan), as the tick runs it
+    (`last`: the head on one row, under a `cond`): the staging pytree
     (dense K/V and one row's state) is donated and aliased, nothing of a
     pool's shape is in it, and its temporaries stay far under what the
     cell's memory plan leaves. (The staged caches themselves, a few MB
-    each, the compiler stages through fast memory: copies, no relayout.)"""
-    from ray_tpu.llm.paged import pool_copies
+    each, the compiler stages through fast memory: copies, no relayout.)
+    What it returns is the staging and ONE row of float32 logits, 9.0 MB
+    at this one layer (the logits of all 256 positions would be 267 MB
+    more), and no array of [256, vocab] is in the program."""
+    from ray_tpu.llm.paged import array_shapes, pool_copies
     p = falcon_programs
-    spec = p["spec"]
+    spec, vocab = p["spec"], p["cfg"].vocab_size
     compiled = p["engine"]._chunk_prefill.lower(
         p["params"], spec(jnp.int32, 1, 256), spec(jnp.int32, 1, 256),
-        p["staged"], spec(jnp.int32), spec(jnp.int32)).compile()
+        p["staged"], spec(jnp.int32), spec(jnp.int32),
+        spec(jnp.int32)).compile()
     text = compiled.as_text()
     assert pool_copies(text, p["pool"]) == 0
     assert pool_copies(text, p["state"][0][1].shape) == 0
+    assert array_shapes(text, (256, vocab)) == 0
+    assert array_shapes(text, (1, 256, vocab)) == 0
     staged_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
                        for a in jax.tree_util.tree_leaves(p["staged"]))
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= staged_bytes
     assert memory.temp_size_in_bytes < 0.5e9
+    assert memory.output_size_in_bytes < 50e6
+    assert memory.output_size_in_bytes < staged_bytes + 2 * 4 * vocab
 
 
 # ---------------------------------------------------------------------------
@@ -546,7 +549,8 @@ def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
     assert len(p["staged"]["kv"]) == 1 and len(p["staged"]["state"]) == 5
     compiled = p["engine"]._chunk_prefill.lower(
         p["params"], spec(jnp.int32, 1, 256), spec(jnp.int32, 1, 256),
-        p["staged"], spec(jnp.int32), spec(jnp.int32)).compile()
+        p["staged"], spec(jnp.int32), spec(jnp.int32),
+        spec(jnp.int32)).compile()
     text = compiled.as_text()
     assert pallas_kernels(text) == {}
     assert pool_copies(text, p["pool"]) == 0
@@ -555,6 +559,8 @@ def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
                        for a in jax.tree_util.tree_leaves(p["staged"]))
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= staged_bytes
+    # the config file's record is of the call without `last`: one scalar
     recorded = p["config"]["memory_analysis"]["chunk_prefill_256"]
-    assert memory.argument_size_in_bytes == recorded["argument_bytes"]
+    assert 0 < memory.argument_size_in_bytes \
+        - recorded["argument_bytes"] <= 512
     assert memory.temp_size_in_bytes < 0.5e9

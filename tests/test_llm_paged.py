@@ -386,17 +386,25 @@ def test_decode_program_runs_one_step_ahead(engines, what):
             2 * paged.config.model.num_layers * pool.size \
             * pool.dtype.itemsize
     else:
-        from ray_tpu.llm.paged import first_token, logits_row
-        logits = np.zeros((1, 16, paged.config.model.vocab_size),
-                          np.float32)
-        logits[0, 4, 77] = 1.0
+        import jax.numpy as jnp
+        from ray_tpu.llm.paged import first_token
+        # the one row of logits a chunk that finishes a prompt returns
+        # (`last` names the row)
+        tokens = np.arange(1, 17, dtype=np.int32)[None]
+        with paged._mesh_scope():
+            row, _caches = paged._chunk_prefill(
+                paged.params, jnp.asarray(tokens), jnp.asarray(tokens - 1),
+                paged._dense_zero_caches(), jnp.int32(0), jnp.int32(4))
+        assert row.shape == (1, paged.config.model.vocab_size)
+        assert row.dtype == jnp.float32
         want = np.arange(rows)
-        want[2] = 77
+        want[2] = int(np.argmax(np.asarray(row)[0]))
+        assert np.ptp(np.asarray(row)) > 0
         # greedy, and a sampler whose top_k leaves one token to draw
         for temperature, top_k in ((0.0, 0), (2.0, 1)):
             out = first_token(
                 np.arange(rows, dtype=np.int32),
-                logits_row(logits, np.int32(4)), np.int32(2), paged._rng,
+                row, np.int32(2), paged._rng,
                 np.full((1,), temperature, np.float32),
                 np.full((1,), top_k, np.int32), np.ones((1,), np.float32),
                 sampled=temperature > 0)

@@ -512,8 +512,11 @@ def test_prefill_parts_lie_inside_the_phase_and_are_no_phase(five_prompts):
 
 
 @pytest.mark.parametrize("name,count", [("prompts_finished", 5),
-                                        ("prefill_chunks", 10)])
+                                        ("prefill_chunks", 10),
+                                        ("prefill_heads", 5)])
 def test_each_finished_prompt_is_counted_once(five_prompts, name, count):
+    """`prefill_heads` (PR 40): the chunks that ran the head, one a
+    finished prompt: half of these ten ran none."""
     assert five_prompts["counters"][name] == count
     assert five_prompts["stats"][name] >= count
 

@@ -297,7 +297,9 @@ def test_collective_counts_reads_pairs_and_clones_once():
 
 def _serve_programs(tensor):
     """Lowered text of a tiny paged engine's decode_step and one
-    chunk_prefill bucket, from shapes."""
+    chunk_prefill bucket as the tick calls it (`last`: the head on one
+    row under a `cond`, inside which a vocabulary-sharded head's
+    collective sits), from shapes."""
     import jax
     from ray_tpu.llm import PagedEngineConfig, PagedLLMEngine
     from ray_tpu.models.llama import LlamaConfig
@@ -318,6 +320,7 @@ def _serve_programs(tensor):
         prefill = engine._chunk_prefill.lower(
             jax.tree_util.tree_map(like, engine.params), ints, ints,
             jax.tree_util.tree_map(like, engine._dense_zero_caches()),
+            jax.ShapeDtypeStruct((), jax.numpy.int32),
             jax.ShapeDtypeStruct((), jax.numpy.int32))
     return engine.lower_decode().as_text(), prefill.as_text()
 
