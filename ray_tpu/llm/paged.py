@@ -53,6 +53,7 @@ from .engine import GenerationRequest
 from .radix import RadixPrefixCache
 
 if TYPE_CHECKING:
+    from ..models.evabyte import EvaByteConfig
     from ..models.falcon_h1 import FalconH1Config
     from ..models.nemotron_h import NemotronHConfig
 
@@ -71,8 +72,20 @@ class PagedEngineConfig:
     # one kind says what each keeps (`layer_caches()`), makes state for
     # the layers that scan only, and may carry per-layer accumulators
     # through the decode step (`init_counters()`); `_module_of` /
-    # `_recurrent` / `_layer_caches` below.
-    model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig"]
+    # `_recurrent` / `_layer_caches` below. One whose rows do not keep the
+    # K/V of their whole context says what they keep instead (`_windowed`):
+    # the rows of its pages a row of n positions holds and its next token
+    # attends (`cache_rows(n)`, which its own decode path applies to
+    # `lengths`), the pages that makes (`pages_held(n, page_size)`), the
+    # most a row holds on its way to n (`prefill_pages`: the admission
+    # budget, and at the longest row the block table's width), whether a
+    # position closes a window (`window_closes(n)`: the engine then runs
+    # `compress_window_pages` on the row's open window and takes back the
+    # pages it emptied), and which page sizes and buckets it can live
+    # with (`check_pages`). Its prefill chunk reads and writes the row's
+    # pages directly; nothing of a row is staged densely.
+    model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig",
+                 "EvaByteConfig"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -87,6 +100,10 @@ class PagedEngineConfig:
 
     @property
     def pages_per_seq(self) -> int:
+        if _windowed(self.model):
+            # a padded last chunk may run a bucket past max_len
+            return self.model.prefill_pages(
+                self.max_len + self.prefill_buckets[-1], self.page_size)
         return -(-self.max_len // self.page_size)
 
 
@@ -98,6 +115,13 @@ def _recurrent(cfg) -> bool:
     """Whether a row of this model carries recurrent state (a scan
     layer's) beside its K/V pages."""
     return hasattr(cfg, "state_shapes")
+
+
+def _windowed(cfg) -> bool:
+    """Whether a row of this model keeps something other than the K/V of
+    its whole context in its pages (summaries of closed windows beside the
+    open one), so that pages leave a row while it lives."""
+    return hasattr(cfg, "window_closes")
 
 
 def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
@@ -288,6 +312,15 @@ class PagedLLMEngine:
             raise NotImplementedError(
                 "recurrent state over a tensor mesh is not built: the "
                 "state pool is not sharded")
+        # rows whose pages hold summaries of closed windows (`_windowed`)
+        self._windowed = _windowed(cfg)
+        if self._windowed:
+            if self._tp > 1:
+                raise NotImplementedError(
+                    "compressed windows over a tensor mesh are not built: "
+                    "the chunk over pages and the compression are not "
+                    "mapped over the heads")
+            cfg.check_pages(config.page_size, config.prefill_buckets)
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -349,6 +382,15 @@ class PagedLLMEngine:
         self._state_due: List[Tuple[int, Any]] = []
         self._state_installs = 0
         self._prefix_skipped_recurrent = 0
+        # windows compressed (by the phase the row was in), the pages that
+        # gave back, prompts whose prefix was not looked up because a page
+        # of this model is no prefix's K/V once its window has closed, and
+        # the rows of each kind the decode steps attended (`_windowed`)
+        self._window_closes = {"prefill": 0, "decode": 0}
+        self._pages_released = 0
+        self._prefix_skipped_compressed = 0
+        self._summary_rows = 0
+        self._window_rows = 0
         self.pool = PagePool(P)
         self.radix = RadixPrefixCache(
             self.pool, ps, max_entries=int(CONFIG.prefix_cache_entries))
@@ -545,6 +587,75 @@ class PagedLLMEngine:
         self._gather_pages = jax.jit(gather_pages, donate_argnums=(2,))
         if self.state is not None:
             self._recurrent_programs()
+        if self._windowed:
+            self._window_programs()
+
+    def _window_programs(self):
+        """The programs of a model whose rows keep summaries of closed
+        windows (`_windowed`). The decode step is the dense one (the model
+        turns `lengths` into rows of the table itself). A prefill chunk
+        takes the page pools donated and the row's block table in place of
+        a dense cache, and `compress_window` turns one row's full window
+        into summaries in every layer. `_dense_zero_caches`,
+        `_write_pages` and `_gather_pages` stay what the dense engine
+        builds and are never called."""
+        model = self.model
+
+        def chunk_prefill(params, tokens, positions, pools, offset, table,
+                          last=None):
+            """One prefill chunk of one row over its pages. `pools`: (k
+            pools, v pools), a pair a layer; `table` [pages_per_seq] the
+            row's page ids, the null page where it holds none. The
+            chunk's K/V rows are written into the row's pages and
+            attended there with what the row already keeps. `last` and
+            the logits returned: as the dense `chunk_prefill`'s."""
+            k_pages, v_pages = pools
+            hidden, new = model.apply(
+                {"params": params}, tokens, positions=positions,
+                kv_caches=[{"k": k, "v": v, "table": table}
+                           for k, v in zip(k_pages, v_pages)],
+                cache_index=offset, head=False)
+            return chunk_logits(model, params, hidden, last), (
+                [c["k"] for c in new], [c["v"] for c in new])
+
+        self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
+
+        def compress_window(params, k_pages, v_pages, pages):
+            """`pages` [window / page_size]: the page ids of one row's
+            full window, in order. Its summaries replace the first of
+            them in every layer's pools."""
+            return self.config.model.compress_window_pages(
+                params, k_pages, v_pages, pages)
+
+        self._compress_window = jax.jit(compress_window,
+                                        donate_argnums=(1, 2))
+
+    def lower_chunk(self, bucket: Optional[int] = None):
+        """The prefill chunk of a `_windowed` model lowered at this
+        engine's shapes (the largest bucket's unless told), from shapes
+        alone, in the form the tick runs (`last` given)."""
+        cfg = self.config
+        bucket = bucket or cfg.prefill_buckets[-1]
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        params, k_pages, v_pages = self._shapes()
+        return self._chunk_prefill.lower(
+            params, i32(1, bucket), i32(1, bucket), (k_pages, v_pages),
+            i32(), i32(cfg.pages_per_seq), i32())
+
+    def lower_compress(self):
+        """`compress_window` lowered at this engine's shapes."""
+        cfg = self.config
+        return self._compress_window.lower(
+            *self._shapes(), jax.ShapeDtypeStruct(
+                (cfg.model.window_size // cfg.page_size,), jnp.int32))
+
+    def _shapes(self):
+        """(weights, k pools, v pools) as shapes: what `lower_chunk` and
+        `lower_compress` lower from."""
+        like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+        return (jax.tree_util.tree_map(like, self.params),
+                [like(p) for p in self.k_pages],
+                [like(p) for p in self.v_pages])
 
     def _recurrent_programs(self):
         """The programs of a model whose rows carry recurrent state, in
@@ -774,6 +885,11 @@ class PagedLLMEngine:
             raise NotImplementedError(
                 f"{what} ships K/V only: a model whose rows carry "
                 "recurrent state cannot be prefilled on another engine yet")
+        if self._windowed:
+            raise NotImplementedError(
+                f"{what} ships the K/V of a whole prompt: a model whose "
+                "rows keep summaries of closed windows in their pages "
+                "cannot be prefilled on another engine yet")
 
     def cancel(self, request_id: str) -> bool:
         """Abort a request: frees its slot+pages on the next tick if
@@ -960,13 +1076,24 @@ class PagedLLMEngine:
         dispatched behind them, tokens dropped a tick late; and what the
         visits dispatched: decode rows, prefill chunks, those of them that
         ran the head, and the prompts they finished."""
-        return {"lookahead_ticks": self._lookahead_ticks,
-                "drained_ticks": sum(self._drained_ticks.values()),
-                "discarded_tokens": self._discarded_tokens,
-                "decode_rows": self._decode_rows,
-                "prefill_chunks": self._prefill_chunks,
-                "prefill_heads": self._prefill_heads,
-                "prompts_finished": self._prompts_finished}
+        counts = {"lookahead_ticks": self._lookahead_ticks,
+                  "drained_ticks": sum(self._drained_ticks.values()),
+                  "discarded_tokens": self._discarded_tokens,
+                  "decode_rows": self._decode_rows,
+                  "prefill_chunks": self._prefill_chunks,
+                  "prefill_heads": self._prefill_heads,
+                  "prompts_finished": self._prompts_finished}
+        if self._windowed:
+            # windows compressed and the pages that gave back; the rows of
+            # each kind the decode steps attended, from lengths alone
+            counts.update(
+                window_closes_prefill=self._window_closes["prefill"],
+                window_closes_decode=self._window_closes["decode"],
+                pages_released=self._pages_released,
+                summary_rows=self._summary_rows,
+                window_rows=self._window_rows,
+                prefix_skipped_compressed=self._prefix_skipped_compressed)
+        return counts
 
     def _flush_step_rows(self):
         """When the engine drains and in `stats()`: the accumulators hand
@@ -1075,7 +1202,8 @@ class PagedLLMEngine:
                     return
                 shipped = getattr(request, "_prefilled", None)
                 if shipped is None:
-                    self._stage_prefill_cache(seq)
+                    if not self._windowed:
+                        self._stage_prefill_cache(seq)
                 else:
                     # prefilled elsewhere (`submit_prefilled`): enters
                     # where a local prefill would have finished its last
@@ -1105,6 +1233,14 @@ class PagedLLMEngine:
         shared = self._match_prefix(prompt)
         n_prompt_pages = -(-len(prompt) // ps)
         tail_pages = n_prompt_pages - len(shared)
+        if self._windowed:
+            # the budget is the most the row holds on its way through the
+            # prompt; the chunks take their pages as they come to them
+            # (`_chunk_pages`), so a window's are back before the next's
+            if self.pool.num_free() \
+                    < self.config.model.prefill_pages(len(prompt), ps):
+                return False
+            tail_pages = 0
         if self.pool.num_free() < tail_pages:
             self.radix.evict_pages(tail_pages - self.pool.num_free())
             if self.pool.num_free() < tail_pages:
@@ -1176,6 +1312,9 @@ class PagedLLMEngine:
         while budget > 0 and order:
             i = order.pop(0)
             seq = self.seqs[i]
+            if self._windowed and not self._chunk_pages(i, seq):
+                budget -= 1
+                continue     # parked again: the pool is short
             with part("prefill", "chunk"):
                 self._prefill_heads += self._prefill_chunk(seq)
             self._prefill_chunks += 1
@@ -1187,6 +1326,64 @@ class PagedLLMEngine:
             else:
                 order.append(i)
 
+    def _chunk_size(self, seq: _Seq) -> Tuple[int, int]:
+        """(bucket, real tokens) of `seq`'s next prefill chunk."""
+        rem = len(seq.prompt) - seq.prefill_off
+        chunk = self._bucket(min(rem, self.config.prefill_buckets[-1]))
+        return chunk, min(rem, chunk)
+
+    def _chunk_pages(self, index: int, seq: _Seq) -> bool:
+        """The pages `seq`'s next chunk writes its real tokens into, for a
+        model whose chunks write the row's pages (`_windowed`). Admission
+        found the row's budget free, but the rows beside it have grown
+        since: where the pool is short now, the row goes back to the front
+        of the queue with what it had (False) and is admitted again when
+        its budget is free."""
+        cfg = self.config
+        _, take = self._chunk_size(seq)
+        # rows, not positions: a chunk that fills its window is written
+        # whole before the window is compressed
+        need = -(-(cfg.model.cache_rows(seq.prefill_off) + take)
+                 // cfg.page_size)
+        while len(seq.pages) < need:
+            page = self._alloc_page()
+            if page is None:
+                self._preempt(index, reason="page_pressure")
+                return False
+            seq.pages.append(page)
+        return True
+
+    def _row_table(self, seq: _Seq):
+        """One row's block table: its pages, then the null page."""
+        table = np.zeros((self.config.pages_per_seq,), np.int32)
+        table[:len(seq.pages)] = seq.pages
+        return table
+
+    def _close_window(self, seq: _Seq, where: str):
+        """`seq`'s open window is full: `compress_window` turns its pages
+        into summaries in every layer, in place in the first of them, and
+        the rest go back to the pool. Dispatched in stream order behind
+        the step (or chunk) that wrote the window's last position; the
+        host knows from the row's length alone that it is due, so nothing
+        is read and the step ahead stays ahead. A page released here is
+        written by its next owner only in a program dispatched later."""
+        cfg = self.config
+        model = cfg.model
+        full = model.window_size // cfg.page_size
+        kept = model.window_summaries // cfg.page_size
+        base = len(seq.pages) - full
+        assert base >= 0 and base % kept == 0, (base, len(seq.pages))
+        window = np.zeros((full,), np.int32)
+        window[:] = seq.pages[base:]
+        with self._mesh_scope():
+            self.k_pages, self.v_pages = self._compress_window(
+                self.params, self.k_pages, self.v_pages, window)
+        for page in seq.pages[base + kept:]:
+            self.pool.decref(page)
+        del seq.pages[base + kept:]
+        self._window_closes[where] += 1
+        self._pages_released += full - kept
+
     def _prefill_chunk(self, seq: _Seq) -> bool:
         """One bucket-rounded chunk of `seq`'s remaining prompt into its
         dense cache — one compiled shape per bucket, whatever the
@@ -1195,11 +1392,8 @@ class PagedLLMEngine:
         leaves its logits in `seq.last_logits`."""
         cfg = self.config
         prompt = seq.prompt
-        largest = cfg.prefill_buckets[-1]
         off = seq.prefill_off
-        rem = len(prompt) - off
-        chunk = self._bucket(min(rem, largest))
-        take = min(rem, chunk)
+        chunk, take = self._chunk_size(seq)
         tokens = np.zeros((1, chunk), np.int32)
         tokens[0, :take] = prompt[off:off + take]
         positions = np.minimum(
@@ -1216,11 +1410,26 @@ class PagedLLMEngine:
         # the program applies the head to that row and to nothing else
         finishes = off + take == len(prompt)
         last = take - 1 if finishes else -1
+        if self._windowed:
+            # chunks start at multiples of the largest bucket, which
+            # divides the window: none straddles a close
+            window = cfg.model.window_size
+            assert off // window == (off + chunk - 1) // window, (off, chunk)
+            staged = (self.k_pages, self.v_pages)
+            extra = (self._row_table(seq),)
+        else:
+            staged, extra = seq.dense_caches, valid
         with self._mesh_scope():
-            logits, seq.dense_caches = self._chunk_prefill(
+            logits, staged = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                seq.dense_caches, jnp.asarray(off, jnp.int32), *valid,
+                staged, jnp.asarray(off, jnp.int32), *extra,
                 jnp.asarray(last, jnp.int32))
+        if self._windowed:
+            self.k_pages, self.v_pages = staged
+            if cfg.model.window_closes(off + take):
+                self._close_window(seq, "prefill")
+        else:
+            seq.dense_caches = staged
         if finishes:
             # stays on the device: `first_token` samples from it there
             seq.last_logits = logits
@@ -1263,7 +1472,8 @@ class PagedLLMEngine:
         seq = self.seqs[index]
         request = seq.request
         prompt = seq.prompt
-        write_ids = seq.pages[seq.own_from:]
+        # a `_windowed` model's chunks wrote the row's pages themselves
+        write_ids = [] if self._windowed else seq.pages[seq.own_from:]
         staged = seq.dense_caches
         if self.state is not None:
             self._state_due.append((index, staged["state"]))
@@ -1330,11 +1540,15 @@ class PagedLLMEngine:
         host, so the step in flight is read first (`_drain`), which may
         itself end rows and free the pages that were short."""
         ps = self.config.page_size
+        # the row of its pages a row's next token lands in: its length,
+        # unless the model's rows keep something else than their context
+        at = self.config.model.cache_rows if self._windowed \
+            else (lambda length: length)
         rows = {i: self.seqs[i] for i in active}
         for i in sorted(active, key=lambda i: self.seqs[i].admit_at):
             seq = rows[i]
             while self.seqs[i] is seq \
-                    and seq.length // ps >= len(seq.pages):
+                    and at(seq.length) // ps >= len(seq.pages):
                 page = self._alloc_page()
                 if page is not None:
                     seq.pages.append(page)
@@ -1396,9 +1610,13 @@ class PagedLLMEngine:
         """Longest cached full-page prefix of `prompt`: refcounted page
         ids the caller maps copy-on-write into its block table. Pages
         carry no recurrent state, so a model that has it matches nothing
-        (and `_register_prefix` registers nothing)."""
+        (and `_register_prefix` registers nothing); nor does one whose
+        pages stop being a prefix's K/V when their window closes."""
         if self.state is not None:
             self._prefix_skipped_recurrent += 1
+            return []
+        if self._windowed:
+            self._prefix_skipped_compressed += 1
             return []
         shared = self.radix.match(prompt)
         if shared:
@@ -1412,7 +1630,7 @@ class PagedLLMEngine:
     def _register_prefix(self, prompt: List[int], pages: List[int]):
         """Commit the full prompt pages for reuse; the radix enforces
         the entry budget (`RTPU_PREFIX_CACHE_ENTRIES`)."""
-        if self.state is not None:
+        if self.state is not None or self._windowed:
             return
         n_full = len(prompt) // self.config.page_size
         # re-read the flag so tests / live reconfig take effect
@@ -1580,6 +1798,10 @@ class PagedLLMEngine:
                 lengths[i] = seq.length
                 temps[i], top_ks[i], top_ps[i] = \
                     self._sampling(seq.request)
+                if self._windowed:
+                    summary, window = cfg.model.attended_rows(seq.length)
+                    self._summary_rows += summary
+                    self._window_rows += window
                 # this step's token, in flight from here on
                 seq.length += 1
                 seq.dispatched += 1
@@ -1621,6 +1843,13 @@ class PagedLLMEngine:
                         self._unread = [(i, self.seqs[i]) for i in active]
                         # freed here, inside a phase, not after the last
                         del args
+                    if self._windowed:
+                        with phase("compress"):
+                            for i in active:
+                                if cfg.model.window_closes(
+                                        self.seqs[i].length):
+                                    self._close_window(self.seqs[i],
+                                                       "decode")
                     if unread:
                         self._lookahead_ticks += 1
                         with phase("wait"):

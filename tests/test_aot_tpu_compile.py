@@ -564,3 +564,133 @@ def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
     assert 0 < memory.argument_size_in_bytes \
         - recorded["argument_bytes"] <= 512
     assert memory.temp_size_in_bytes < 0.5e9
+
+
+# ---------------------------------------------------------------------------
+# the EvaByte cell's programs (EVA attention: pages that leave a living row)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def evabyte_programs(v5e):
+    """The `serve-evabyte-doc-closed80` cell's engine programs: its config
+    file's widths, rows and pool, its builder, all 8 layers, with the
+    shapes of their arguments on one described chip. The decode step of
+    this model is the dense engine's, built in `__init__`: so a real
+    engine, with shapes for weights and a pool of 8 pages, whose programs
+    are then lowered at the file's pool."""
+    import dataclasses
+    from benchmarks.harness.builders_evabyte import evabyte_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "evabyte-6.5b-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = evabyte_engine(config, seed=0)
+    cfg = engine_cfg.model
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = placed(jax.eval_shape(lambda: unbox(cfg.module().init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    engine = PagedLLMEngine(dataclasses.replace(engine_cfg, num_pages=8),
+                            params=params)
+    pool = (cfg.num_kv_heads, engine_cfg.num_pages, engine_cfg.page_size,
+            cfg.head_dim)
+    return {"engine": engine, "cfg": cfg, "engine_cfg": engine_cfg,
+            "config": config, "pool": pool, "spec": spec, "params": params,
+            "rows": engine_cfg.max_batch,
+            "pages": [spec(cfg.dtype, *pool)] * cfg.num_layers}
+
+
+def _within(read: int, recorded: int, share: float = 0.02) -> bool:
+    return abs(read - recorded) <= share * recorded
+
+
+def test_evabyte_decode_step_compiles_for_v5e_within_memory(
+        evabyte_programs, as_tpu):
+    """44 rows, a block table 160 wide (not ceil(10496 / 16) = 656), 32
+    kv heads with one query each through the paged kernel, a kernel a
+    layer, every pool donated and updated in place, and arguments +
+    temporaries as the file's `memory_analysis` records them."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = evabyte_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    recorded = p["config"]["memory_analysis"]["decode_step_batch44"]
+    assert (rows, width) == (44, 160) == (44, recorded["block_table_width"])
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pages"], p["pages"], spec(jnp.int32, rows, width),
+        spec(jnp.int32, rows), spec(jnp.int32, rows), spec(jnp.uint32, 2),
+        spec(jnp.float32, rows), spec(jnp.int32, rows),
+        spec(jnp.float32, rows)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_attention": cfg.num_layers}
+    assert pool_copies(text, p["pool"]) == 0
+    memory = compiled.memory_analysis()
+    pools = 2 * cfg.num_layers * 2 * math.prod(p["pool"])
+    assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
+    assert memory.alias_size_in_bytes >= pools
+    assert _within(memory.argument_size_in_bytes,
+                   recorded["argument_bytes"])
+    assert _within(memory.argument_size_in_bytes
+                   + memory.temp_size_in_bytes,
+                   recorded["argument_bytes"] + recorded["temp_bytes"])
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < V5E_BYTES_LIMIT - 1.5e9
+
+
+def test_evabyte_prefill_chunk_compiles_for_v5e_over_the_pools(
+        evabyte_programs, as_tpu):
+    """The largest bucket, as the tick runs it: the pools donated and
+    aliased, the row's table in place of a dense cache (no argument of
+    [1, 32, >= 2048, 128]), no pool relaid out, temporaries as
+    recorded."""
+    from ray_tpu.llm.paged import pool_copies
+    p = evabyte_programs
+    spec, cfg = p["spec"], p["cfg"]
+    lowered = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 256), spec(jnp.int32, 1, 256),
+        (p["pages"], p["pages"]), spec(jnp.int32),
+        spec(jnp.int32, p["engine_cfg"].pages_per_seq), spec(jnp.int32))
+    dense = [a for a in jax.tree_util.tree_leaves(lowered.args_info)
+             if len(a.shape) == 4 and a.shape[0] == 1
+             and a.shape[2] >= cfg.window_size]
+    assert not dense
+    compiled = lowered.compile()
+    assert pool_copies(compiled.as_text(), p["pool"]) == 0
+    memory = compiled.memory_analysis()
+    recorded = p["config"]["memory_analysis"]["chunk_prefill_256"]
+    assert memory.alias_size_in_bytes >= recorded["alias_bytes"]
+    assert _within(memory.argument_size_in_bytes
+                   + memory.temp_size_in_bytes,
+                   recorded["argument_bytes"] + recorded["temp_bytes"])
+    assert memory.temp_size_in_bytes < 0.25e9
+
+
+def test_evabyte_compress_window_compiles_for_v5e_in_place(
+        evabyte_programs, as_tpu):
+    """One row's 128 window pages of every layer into 8 pages of
+    summaries, in the donated pools, with no copy of a pool."""
+    from ray_tpu.llm.paged import pool_copies
+    p = evabyte_programs
+    spec, cfg = p["spec"], p["cfg"]
+    compiled = p["engine"]._compress_window.lower(
+        p["params"], p["pages"], p["pages"],
+        spec(jnp.int32, cfg.window_size // p["engine_cfg"].page_size)
+    ).compile()
+    assert pool_copies(compiled.as_text(), p["pool"]) == 0
+    memory = compiled.memory_analysis()
+    recorded = p["config"]["memory_analysis"]["compress_window"]
+    assert memory.alias_size_in_bytes >= recorded["alias_bytes"]
+    assert _within(memory.argument_size_in_bytes
+                   + memory.temp_size_in_bytes,
+                   recorded["argument_bytes"] + recorded["temp_bytes"])
+    assert memory.temp_size_in_bytes < 16e6

@@ -423,3 +423,18 @@ def test_a_shared_prefix_is_not_reused(engine):
     stats = engine.stats()
     assert stats["prefix_skipped_recurrent"] - before == 2
     assert stats["prefix_entries"] == 0
+
+
+# recorded on the parent of PR 42 (676249f) by test_llm_paged's
+# lowered_programs on `tiny_engine()`, and read again on PR 42's tree: the
+# contract for rows that keep summaries of closed windows changed no
+# program of a model that did not ask for it
+NEMOTRON_PROGRAMS = {"decode_step": "6ef3901514180479",
+                     "chunk_prefill": "daa7529285a45f19"}
+
+
+@pytest.mark.parametrize("program", sorted(NEMOTRON_PROGRAMS))
+def test_the_engine_lowers_to_the_program_it_always_did(engine, program):
+    from test_llm_paged import lowered_programs, program_hash
+    assert program_hash(lowered_programs(engine)[program]) \
+        == NEMOTRON_PROGRAMS[program]
