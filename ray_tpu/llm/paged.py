@@ -51,6 +51,7 @@ from . import reqtrace
 from ._metrics import llm_metrics
 from .engine import GenerationRequest
 from .radix import RadixPrefixCache
+from .sampling import SAMPLER_TIERS, sample_tokens, sampler_tier
 
 if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
@@ -222,10 +223,10 @@ def first_token(tokens, last, slot, rng, temperature, top_k, top_p,
     token reaches the host with the next read of the vector, not through
     a fetch of its own. `sampled`: the request has a temperature, so the
     token is drawn as `decode_step` draws; the argmax otherwise, in a
-    program of its own (the sampler sorts the vocabulary whatever is
-    asked, and compiling that sort takes the TPU's compiler 10-35 s)."""
+    program of its own (the sampler's program holds a sort of the
+    vocabulary even where no batch takes that branch, and compiling that
+    sort takes the TPU's compiler 10-35 s)."""
     if sampled:
-        from .sampling import sample_tokens
         token = sample_tokens(rng, last, temperature, top_k, top_p)
     else:
         token = jnp.argmax(last, axis=-1)
@@ -432,6 +433,9 @@ class PagedLLMEngine:
         self._discarded_tokens = 0
         # what the visits dispatched (the `tick` row's counters)
         self._decode_rows = 0
+        # decode steps dispatched, by the branch `sample_tokens` takes in
+        # each (`sampling.SAMPLER_TIERS`)
+        self._sampler_steps = [0] * len(SAMPLER_TIERS)
         self._prefill_chunks = 0
         self._prefill_heads = 0     # chunks that ran the head (on one row)
         self._prompts_finished = 0
@@ -474,7 +478,6 @@ class PagedLLMEngine:
                 positions=lengths[:, None],
                 kv_caches=caches, cache_index=None)
             last = logits[:, -1, :].astype(jnp.float32)
-            from .sampling import sample_tokens
             out = sample_tokens(rng, last, temperature, top_k, top_p)
             nk = [c["k"] for c in new_caches]
             nv = [c["v"] for c in new_caches]
@@ -726,18 +729,9 @@ class PagedLLMEngine:
                 {"params": params}, tokens[:, None],
                 positions=lengths[:, None],
                 kv_caches=caches, cache_index=None)
-            from .sampling import sample_tokens
             last = logits[:, -1, :].astype(jnp.float32)
-            # sample_tokens sorts the vocabulary whatever is asked (17.8 ms
-            # of a 37.8 ms step at 48 rows x 261,120: PERF.md, PR 32) and
-            # returns the argmax for a row whose temperature is 0: skip it
-            # when every row's is
-            out = jax.lax.cond(
-                jnp.any(temperature > 0),
-                lambda: sample_tokens(rng, last, temperature, top_k,
-                                      top_p).astype(jnp.int32),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return (out,) + by_kind(new)
+            out = sample_tokens(rng, last, temperature, top_k, top_p)
+            return (out.astype(jnp.int32),) + by_kind(new)
 
         # `counters` is () for a model without any: no argument, no result
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3, 12))
@@ -1075,11 +1069,14 @@ class PagedLLMEngine:
         before the last one's tokens were read, reads with nothing
         dispatched behind them, tokens dropped a tick late; and what the
         visits dispatched: decode rows, prefill chunks, those of them that
-        ran the head, and the prompts they finished."""
+        ran the head, the prompts they finished, and the decode steps by
+        the sampler's branch."""
         counts = {"lookahead_ticks": self._lookahead_ticks,
                   "drained_ticks": sum(self._drained_ticks.values()),
                   "discarded_tokens": self._discarded_tokens,
                   "decode_rows": self._decode_rows,
+                  **{f"sampler_{tier}_steps": steps for tier, steps
+                     in zip(SAMPLER_TIERS, self._sampler_steps)},
                   "prefill_chunks": self._prefill_chunks,
                   "prefill_heads": self._prefill_heads,
                   "prompts_finished": self._prompts_finished}
@@ -1806,6 +1803,7 @@ class PagedLLMEngine:
                 seq.length += 1
                 seq.dispatched += 1
             self._decode_rows += len(active)
+            self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
         timer = accel.StepTimer(
@@ -1920,6 +1918,7 @@ class PagedLLMEngine:
             # the step ahead (`_ahead_counts`; `drained_by`: why)
             **self._ahead_counts(),
             "drained_by": dict(self._drained_ticks),
+            "sampler": dict(zip(SAMPLER_TIERS, self._sampler_steps)),
             # recurrent state beside the pages (zeros for a model
             # without it)
             "state_bytes": sum(

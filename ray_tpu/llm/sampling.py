@@ -5,8 +5,13 @@ here the same contract as ONE vectorized XLA program over the batch).
 
 TPU notes: per-slot parameters arrive as [B] arrays so one compiled
 program serves heterogeneous requests (no per-request recompiles).
-The top-p mask needs a descending sort of the vocab — O(V log V) on
-rows of 32k is microseconds on the VPU next to the decode matmuls."""
+The top-p mask needs a descending sort of the vocab, and that sort is
+not cheap on the chip: 0.86 ms of a 13.4 ms decode step at 32 rows x
+32,768 (PERF_LEDGER.jsonl, PR 42: `decode_step/sort`), 17.8 ms of
+37.8 at 48 x 261,120 (PERF.md, PR 32). So `sample_tokens` branches ON
+THE DEVICE, on the parameters it is handed, to the least work that
+gives the same tokens (`sampler_tier`); a batch that asks for no
+filter sorts nothing, one that asks for no sample draws nothing."""
 
 from __future__ import annotations
 
@@ -15,16 +20,36 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+# what `sampler_tier` returns, by name (the engine's counters use them)
+SAMPLER_TIERS = ("greedy", "plain", "filtered")
 
-def sample_tokens(rng, logits, temperature, top_k, top_p):
-    """One token per row.
 
-    logits: [B, V] float32. temperature/top_k/top_p: [B] — per slot:
-    temperature <= 0 means greedy (top_k/top_p ignored); top_k <= 0
-    disables the k filter; top_p >= 1 disables the nucleus filter.
-    Filters compose the standard way: restrict to the top-k set, then
-    to the smallest prefix of the (sorted) distribution whose mass
-    reaches top_p, renormalize implicitly via categorical."""
+def sampler_tier(temperature, top_k, top_p):
+    """The work a batch's [B] parameters ask of `sample_tokens`: 0, no
+    row samples (an argmax); 1, some row samples and no SAMPLING row
+    filters (a categorical draw, no sort); 2, some sampling row has a
+    top-k or a nucleus. Written with operators and methods that numpy
+    and jax arrays share: the device takes its branch by it, and the
+    host counts, with the same arrays, the branch each step will take."""
+    sampling = temperature > 0
+    filtering = sampling & ((top_k > 0) | (top_p < 1.0))
+    return sampling.any().astype("int32") + filtering.any().astype("int32")
+
+
+def _greedy(rng, logits, temperature, top_k, top_p):
+    return jnp.argmax(logits, axis=-1)
+
+
+def _plain(rng, logits, temperature, top_k, top_p):
+    """`_filtered` where no sampling row has a filter: both thresholds
+    are NEG_INF there, the mask keeps every logit, and the draw under
+    the same key is the same draw."""
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    sampled = jax.random.categorical(rng, scaled)
+    return jnp.where(temperature > 0, sampled, jnp.argmax(logits, axis=-1))
+
+
+def _filtered(rng, logits, temperature, top_k, top_p):
     greedy = jnp.argmax(logits, axis=-1)
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
 
@@ -55,6 +80,24 @@ def sample_tokens(rng, logits, temperature, top_k, top_p):
     masked = jnp.where(scaled >= thresh[:, None], scaled, NEG_INF)
     sampled = jax.random.categorical(rng, masked)
     return jnp.where(temperature > 0, sampled, greedy)
+
+
+def sample_tokens(rng, logits, temperature, top_k, top_p):
+    """One token per row.
+
+    logits: [B, V] float32. temperature/top_k/top_p: [B] — per slot:
+    temperature <= 0 means greedy (top_k/top_p ignored); top_k <= 0
+    disables the k filter; top_p >= 1 disables the nucleus filter.
+    Filters compose the standard way: restrict to the top-k set, then
+    to the smallest prefix of the (sorted) distribution whose mass
+    reaches top_p, renormalize implicitly via categorical.
+
+    Every tier returns what `_filtered`, the whole contract, returns for
+    the same arguments and key; the switch only leaves out work whose
+    result no row of this batch reads."""
+    return jax.lax.switch(sampler_tier(temperature, top_k, top_p),
+                          (_greedy, _plain, _filtered),
+                          rng, logits, temperature, top_k, top_p)
 
 
 def filter_logits(logits, top_k=0, top_p=None):

@@ -392,8 +392,10 @@ def test_named_scopes_reach_the_compiled_steps(engine):
 
 
 # recorded by this very function at these very sizes (PR 42); a change
-# that means to alter these programs re-records them
-EVA_PROGRAMS = {"decode_step": "ed29019092c0e93a",
+# that means to alter these programs re-records them. `decode_step` again in
+# PR 43 (was ed29019092c0e93a): `sample_tokens` is now a switch over three
+# branches where it was the sorting one alone
+EVA_PROGRAMS = {"decode_step": "440d3530e50c21bf",
                 "chunk_prefill": "ae19d8c4f25ce3fc",
                 "compress_window": "bd8cbac8a25691c6"}
 

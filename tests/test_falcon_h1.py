@@ -350,8 +350,10 @@ def test_a_dense_engine_owns_no_recurrent_state():
 # `decode_step` again in PR 37 (was 73aba8d37aa6e0a4): the gather fallback
 # is now the dense model's (ops.paged_attention.paged_attend), which
 # repeats the kv heads before it casts to float32 where this model's own
-# copy cast first. The same values; `chunk_prefill` holds no paged decode
-FALCON_PROGRAMS = {"decode_step": "0d4903ff37ada98a",
+# copy cast first. The same values; `chunk_prefill` holds no paged decode.
+# `decode_step` again in PR 43 (was 0d4903ff37ada98a): the step's own
+# `lax.cond` around the sampler went; `sample_tokens` holds the switch
+FALCON_PROGRAMS = {"decode_step": "34f2c4ce8d947c2f",
                    "chunk_prefill": "afb853d4dea3960f"}
 
 

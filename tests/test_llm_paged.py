@@ -437,7 +437,11 @@ def program_hash(text: str) -> str:
 
 # recorded from the commit before the engine learned of layer kinds (PR 33's
 # tree, 2c580e1), by this very function at these very sizes
-DENSE_PROGRAMS = {"decode_step": "618df5990d42e21b",
+# `decode_step` again in PR 43 (was 618df5990d42e21b): `sample_tokens`, the
+# step's last call, is now a switch over three branches (argmax / draw /
+# sort and draw) where it was the third alone; `chunk_prefill` calls no
+# sampler and reads as before
+DENSE_PROGRAMS = {"decode_step": "315a3b48a436eb30",
                   "chunk_prefill": "b087c299668649d9"}
 
 

@@ -428,8 +428,10 @@ def test_a_shared_prefix_is_not_reused(engine):
 # recorded on the parent of PR 42 (676249f) by test_llm_paged's
 # lowered_programs on `tiny_engine()`, and read again on PR 42's tree: the
 # contract for rows that keep summaries of closed windows changed no
-# program of a model that did not ask for it
-NEMOTRON_PROGRAMS = {"decode_step": "6ef3901514180479",
+# program of a model that did not ask for it. `decode_step` again in PR 43
+# (was 6ef3901514180479): the step's own `lax.cond` around the sampler
+# went; `sample_tokens` holds the switch
+NEMOTRON_PROGRAMS = {"decode_step": "36d4f1ec9cc8d44b",
                      "chunk_prefill": "daa7529285a45f19"}
 
 
