@@ -37,6 +37,14 @@ def main():
     logging.basicConfig(
         level=logging.INFO,
         format="[worker %(process)d] %(levelname)s %(name)s: %(message)s")
+    if "tpu" in os.environ.get("JAX_PLATFORMS", "").split(","):
+        # leased chips (the raylet names the platform): the process that
+        # held them last may still be tearing the device down
+        from ..accelerators.tpu import wait_for_free_chips
+        waited = wait_for_free_chips()
+        if waited >= 1.0:
+            logging.getLogger(__name__).warning(
+                "waited %.1f s for the TPU device files to be free", waited)
     # `kill -USR2 <pid>` dumps every thread's stack to stderr (reference:
     # the dashboard's on-demand py-spy; this is the dependency-free
     # always-on variant for debugging wedged workers).

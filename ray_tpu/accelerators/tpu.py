@@ -18,10 +18,12 @@ Valid chip counts per worker mirror the reference: {1, 2, 4, 8}.
 
 from __future__ import annotations
 
+import errno
 import glob as _glob_module
 import logging
 import os
 import re
+import time
 from typing import Dict, List, Optional
 
 logger = logging.getLogger(__name__)
@@ -71,6 +73,62 @@ def autodetect_num_chips(glob=_glob_module.glob) -> int:
     if vfio:
         return len(vfio)
     return 0
+
+
+def chip_device_files(glob=_glob_module.glob, env=os.environ) -> List[str]:
+    """The device files of the chips this process may open: /dev/accel<n>
+    and the numbered /dev/vfio/<n>. All of them where `TPU_VISIBLE_CHIPS`
+    is not set or names as many chips as the host has files (the lease is
+    the whole host's); else only the files it names by number, and none if
+    it names none of them (a /dev/vfio number is an IOMMU group, not
+    always the chip's index: a file that is not positively this worker's
+    may be another worker's, and is left alone)."""
+    files = sorted(glob("/dev/accel*")) + sorted(glob("/dev/vfio/[0-9]*"))
+    visible = {c for c in env.get(TPU_VISIBLE_CHIPS_ENV, "").split(",") if c}
+    if not visible or len(visible) >= len(files):
+        return files
+    return [f for f in files if re.search(r"(\d+)$", f).group(1) in visible]
+
+
+def wait_for_free_chips(timeout_s: float = 60.0, poll_s: float = 0.25,
+                        glob=_glob_module.glob, env=os.environ,
+                        opener=os.open, closer=os.close, sleep=time.sleep,
+                        clock=time.monotonic) -> float:
+    """Wait, bounded, until no other process holds this worker's chip
+    device files (`chip_device_files`); returns the seconds waited. For a
+    worker that was leased chips, before it first touches JAX: a device
+    file is opened by one process at a time, and the process that held it
+    last may have exited as a thread-group leader (state Z, which a `wait`
+    takes for gone) while its runtime threads still tear the device down:
+    JAX's backend then fails with `open(/dev/vfio/0): Device or resource
+    busy` some seconds before the device is free (PERF.md section 7: two
+    PRs' checks ended on it). Each file is opened and closed until none
+    answers EBUSY; a free device costs one `open`. The first EBUSY is
+    logged, so a worker that waits says so while it does. After
+    `timeout_s` the worker goes on and fails as it would have; any other
+    error (no such file, no permission) is the backend's to report, not
+    waited on."""
+    start = clock()
+    files = chip_device_files(glob, env)
+    said = False
+
+    def held_elsewhere(path: str) -> bool:
+        try:
+            closer(opener(path, os.O_RDWR))
+        except OSError as e:
+            return e.errno == errno.EBUSY
+        return False
+
+    while True:
+        busy = [f for f in files if held_elsewhere(f)]
+        if not busy or clock() - start >= timeout_s:
+            break
+        if not said:
+            logger.warning("%s held by another process: waiting up to "
+                           "%.0f s for it", ", ".join(busy), timeout_s)
+            said = True
+        sleep(poll_s)
+    return clock() - start
 
 
 def compile_cache_dir(env=os.environ) -> str:

@@ -46,6 +46,7 @@ import numpy as np
 from .._internal import accel as _accel
 from .._internal.config import CONFIG
 from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
+from ..ops.latent_attention import latent_kernel
 from ..ops.paged_attention import paged_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
@@ -57,6 +58,7 @@ if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
     from ..models.falcon_h1 import FalconH1Config
     from ..models.nemotron_h import NemotronHConfig
+    from ..models.sarvam_mla import SarvamMLAConfig
 
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
@@ -84,9 +86,15 @@ class PagedEngineConfig:
     # `compress_window_pages` on the row's open window and takes back the
     # pages it emptied), and which page sizes and buckets it can live
     # with (`check_pages`). Its prefill chunk reads and writes the row's
-    # pages directly; nothing of a row is staged densely.
+    # pages directly; nothing of a row is staged densely. One whose layers
+    # cache ONE latent row a token, key and value at once, says how wide
+    # (`latent_cache()`: lanes of a row in the pool, lanes of it that are
+    # the value; `_latent`): a layer then keeps one pool `[1, pages,
+    # page_size, lanes]` and no V pool, its prefill chunks go straight into
+    # the row's pages through its table as a `_windowed` model's do, and a
+    # radix-shared prefix is mapped in place and never copied.
     model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig",
-                 "EvaByteConfig"]
+                 "EvaByteConfig", "SarvamMLAConfig"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -123,6 +131,12 @@ def _windowed(cfg) -> bool:
     its whole context in its pages (summaries of closed windows beside the
     open one), so that pages leave a row while it lives."""
     return hasattr(cfg, "window_closes")
+
+
+def _latent(cfg) -> bool:
+    """Whether a layer of this model caches one latent row a token that is
+    its key and its value (multi-head latent attention): one pool a layer."""
+    return hasattr(cfg, "latent_cache")
 
 
 def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
@@ -322,6 +336,12 @@ class PagedLLMEngine:
                     "the chunk over pages and the compression are not "
                     "mapped over the heads")
             cfg.check_pages(config.page_size, config.prefill_buckets)
+        # layers that cache one latent row a token (`_latent`)
+        self._latent = _latent(cfg)
+        if self._latent and self._tp > 1:
+            raise NotImplementedError(
+                "latent attention over a tensor mesh is not built: the "
+                "heads would be split and the latent pool replicated")
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -350,8 +370,10 @@ class PagedLLMEngine:
         kvh, hd = cfg.num_kv_heads, cfg.head_dim_
         P, ps = config.num_pages, config.page_size
         # the attention path the decode program is built with here
-        self._paged_kernel = paged_kernel(
-            hd, cfg.attention_impl == "reference")
+        reference = cfg.attention_impl == "reference"
+        self._paged_kernel = latent_kernel(
+            cfg.latent_cache()[1], reference) if self._latent \
+            else paged_kernel(hd, reference)
         # kernel layout: [kv_heads, num_pages, page_size, head_dim]
         def _zero_pages():
             z = jnp.zeros((kvh, P, ps, hd), cfg.dtype)
@@ -361,7 +383,9 @@ class PagedLLMEngine:
         # a page pool per layer that attends
         attending = sum(1 for attends, _, _ in _layer_caches(cfg) if attends)
         self.k_pages = [_zero_pages() for _ in range(attending)]
-        self.v_pages = [_zero_pages() for _ in range(attending)]
+        # a latent row is key and value at once: no second pool
+        self.v_pages = [] if self._latent \
+            else [_zero_pages() for _ in range(attending)]
         # recurrent state beside the pages, for a model that has it: per
         # layer that scans, (conv, ssm) pools of max_batch rows, row = slot
         # index (a slot that is not decoding is masked out of the decode
@@ -392,6 +416,19 @@ class PagedLLMEngine:
         self._prefix_skipped_compressed = 0
         self._summary_rows = 0
         self._window_rows = 0
+        # what the latent path did (`_latent`): cached rows the decode
+        # steps attended; pages the decoding rows held a step, counted a
+        # row and counted once (rows on one document share pages); prompt
+        # tokens mapped from the radix and computed; cached rows the
+        # prefill chunks attended; radix nodes evicted
+        self._latent_rows_attended = 0
+        self._latent_pages_rowwise = 0
+        self._latent_pages_distinct = 0
+        self._page_seen = np.zeros((P,), bool)
+        self._prefix_shared_tokens = 0
+        self._prefill_computed_tokens = 0
+        self._prefill_ctx_rows = 0
+        self._radix_evictions = 0
         self.pool = PagePool(P)
         self.radix = RadixPrefixCache(
             self.pool, ps, max_entries=int(CONFIG.prefix_cache_entries))
@@ -592,6 +629,70 @@ class PagedLLMEngine:
             self._recurrent_programs()
         if self._windowed:
             self._window_programs()
+        if self._latent:
+            self._latent_programs()
+
+    def _latent_programs(self):
+        """The programs of a model whose layers cache one latent row a
+        token (`_latent`): `k_pages` holds the one pool a layer and
+        `v_pages` nothing. The decode step takes the pools and the expert
+        counters donated; a prefill chunk takes the pools donated and the
+        row's block table in place of a dense cache, and is told how many
+        of its tokens are real. `_dense_zero_caches`, `_write_pages` and
+        `_gather_pages` stay what the dense engine builds and are never
+        called: a shared prefix is attended where it lies."""
+        model = self.model
+        kinds = _layer_caches(self.config.model)
+
+        def decode_caches(pools, counters, active, block_tables, lengths):
+            """What each layer is handed in a paged decode step."""
+            counts_of = iter(counters)
+            caches = []
+            for pool, (_, _, counts) in zip(pools, kinds):
+                cache = {"pool": pool, "active": active,
+                         "block_tables": block_tables, "lengths": lengths}
+                if counts:
+                    cache["pairs"], cache["steps"] = next(counts_of)
+                caches.append(cache)
+            return caches
+
+        def by_kind(new):
+            """A model's per-layer tuples (pool, counters...) as the
+            pools and the counters of the layers that count."""
+            return ([kept[0] for kept in new],
+                    [tuple(kept[1:]) for kept in new if len(kept) > 1])
+
+        def decode_step(params, pools, active, block_tables, lengths,
+                        tokens, rng, temperature, top_k, top_p,
+                        counters=()):
+            logits, new = model.apply(
+                {"params": params}, tokens[:, None],
+                positions=lengths[:, None],
+                kv_caches=decode_caches(pools, counters, active,
+                                        block_tables, lengths),
+                cache_index=None)
+            last = logits[:, -1, :].astype(jnp.float32)
+            out = sample_tokens(rng, last, temperature, top_k, top_p)
+            return (out.astype(jnp.int32),) + by_kind(new)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(1, 10))
+
+        def chunk_prefill(params, tokens, positions, pools, offset, table,
+                          valid, last=None):
+            """One prefill chunk of one row over its pages. `pools`: the
+            latent pool of every layer; `table` [pages_per_seq] the row's
+            page ids, shared prefix pages first, the null page where it
+            holds none. The chunk's first `valid` rows are written into
+            the row's pages and attended there with everything cached
+            before them, a block of pages at a time. `last` and the logits
+            returned: as the dense `chunk_prefill`'s."""
+            hidden, new = model.apply(
+                {"params": params}, tokens, positions=positions,
+                kv_caches=[{"pool": pool, "table": table} for pool in pools],
+                cache_index=offset, valid=valid, head=False)
+            return chunk_logits(model, params, hidden, last), by_kind(new)[0]
+
+        self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
     def _window_programs(self):
         """The programs of a model whose rows keep summaries of closed
@@ -634,13 +735,17 @@ class PagedLLMEngine:
                                         donate_argnums=(1, 2))
 
     def lower_chunk(self, bucket: Optional[int] = None):
-        """The prefill chunk of a `_windowed` model lowered at this
-        engine's shapes (the largest bucket's unless told), from shapes
+        """The prefill chunk of a `_windowed` or `_latent` model lowered at
+        this engine's shapes (the largest bucket's unless told), from shapes
         alone, in the form the tick runs (`last` given)."""
         cfg = self.config
         bucket = bucket or cfg.prefill_buckets[-1]
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
         params, k_pages, v_pages = self._shapes()
+        if self._latent:
+            return self._chunk_prefill.lower(
+                params, i32(1, bucket), i32(1, bucket), k_pages, i32(),
+                i32(cfg.pages_per_seq), i32(), i32())
         return self._chunk_prefill.lower(
             params, i32(1, bucket), i32(1, bucket), (k_pages, v_pages),
             i32(), i32(cfg.pages_per_seq), i32())
@@ -791,13 +896,15 @@ class PagedLLMEngine:
 
         state = () if self.state is None else (
             jax.tree_util.tree_map(like, self.state), vec(jnp.bool_))
-        counters = () if self.state is None else (
+        counters = () if self.state is None and not self._latent else (
             jax.tree_util.tree_map(like, self.counters),)
+        # one pool a layer and the rows that decode, for a latent model
+        pools = ([like(p) for p in self.k_pages], vec(jnp.bool_)) \
+            if self._latent else ([like(p) for p in self.k_pages],
+                                  [like(p) for p in self.v_pages])
         with self._mesh_scope():
             return self._decode.lower(
-                jax.tree_util.tree_map(like, self.params),
-                [like(p) for p in self.k_pages],
-                [like(p) for p in self.v_pages], *state,
+                jax.tree_util.tree_map(like, self.params), *pools, *state,
                 vec(jnp.int32, cfg.pages_per_seq), vec(jnp.int32),
                 vec(jnp.int32),
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
@@ -884,6 +991,11 @@ class PagedLLMEngine:
                 f"{what} ships the K/V of a whole prompt: a model whose "
                 "rows keep summaries of closed windows in their pages "
                 "cannot be prefilled on another engine yet")
+        if self._latent:
+            raise NotImplementedError(
+                f"{what} ships dense K/V: a model whose layers cache "
+                "latent rows in their pages cannot be prefilled on "
+                "another engine yet")
 
     def cancel(self, request_id: str) -> bool:
         """Abort a request: frees its slot+pages on the next tick if
@@ -1090,6 +1202,15 @@ class PagedLLMEngine:
                 summary_rows=self._summary_rows,
                 window_rows=self._window_rows,
                 prefix_skipped_compressed=self._prefix_skipped_compressed)
+        if self._latent:
+            counts.update(
+                latent_rows_attended=self._latent_rows_attended,
+                latent_pages_rowwise=self._latent_pages_rowwise,
+                latent_pages_distinct=self._latent_pages_distinct,
+                prefix_shared_tokens=self._prefix_shared_tokens,
+                prefill_computed_tokens=self._prefill_computed_tokens,
+                prefill_ctx_rows=self._prefill_ctx_rows,
+                radix_evictions=self._radix_evictions)
         return counts
 
     def _flush_step_rows(self):
@@ -1199,7 +1320,8 @@ class PagedLLMEngine:
                     return
                 shipped = getattr(request, "_prefilled", None)
                 if shipped is None:
-                    if not self._windowed:
+                    # chunks of these go straight into the row's pages
+                    if not self._windowed and not self._latent:
                         self._stage_prefill_cache(seq)
                 else:
                     # prefilled elsewhere (`submit_prefilled`): enters
@@ -1239,7 +1361,8 @@ class PagedLLMEngine:
                 return False
             tail_pages = 0
         if self.pool.num_free() < tail_pages:
-            self.radix.evict_pages(tail_pages - self.pool.num_free())
+            self._radix_evictions += self.radix.evict_pages(
+                tail_pages - self.pool.num_free())
             if self.pool.num_free() < tail_pages:
                 for page in shared:
                     self.pool.decref(page)
@@ -1261,6 +1384,7 @@ class PagedLLMEngine:
         seq.dispatched = 0
         seq.cancelled = False
         seq.prefill_off = len(shared) * ps
+        self._prefix_shared_tokens += len(shared) * ps
         seq.dense_caches = None
         seq.last_logits = None
         self._admit_clock += 1
@@ -1414,6 +1538,10 @@ class PagedLLMEngine:
             assert off // window == (off + chunk - 1) // window, (off, chunk)
             staged = (self.k_pages, self.v_pages)
             extra = (self._row_table(seq),)
+        elif self._latent:
+            staged = self.k_pages
+            extra = (self._row_table(seq), jnp.asarray(take, jnp.int32))
+            self._prefill_ctx_rows += off + take
         else:
             staged, extra = seq.dense_caches, valid
         with self._mesh_scope():
@@ -1425,6 +1553,8 @@ class PagedLLMEngine:
             self.k_pages, self.v_pages = staged
             if cfg.model.window_closes(off + take):
                 self._close_window(seq, "prefill")
+        elif self._latent:
+            self.k_pages = staged
         else:
             seq.dense_caches = staged
         if finishes:
@@ -1442,6 +1572,7 @@ class PagedLLMEngine:
                     self._compile_total() - compile_t0, 6) or None)
         # counts COMPUTED tokens only — a radix-shared span costs zero
         llm_metrics().prefill_tokens.inc(take, tags=_TAGS)
+        self._prefill_computed_tokens += take
         return finishes
 
     def _write_owned_pages(self, dense_caches, write_ids, start_page):
@@ -1469,8 +1600,9 @@ class PagedLLMEngine:
         seq = self.seqs[index]
         request = seq.request
         prompt = seq.prompt
-        # a `_windowed` model's chunks wrote the row's pages themselves
-        write_ids = [] if self._windowed else seq.pages[seq.own_from:]
+        # a `_windowed` or `_latent` model's chunks wrote the row's pages
+        write_ids = [] if self._windowed or self._latent \
+            else seq.pages[seq.own_from:]
         staged = seq.dense_caches
         if self.state is not None:
             self._state_due.append((index, staged["state"]))
@@ -1524,6 +1656,7 @@ class PagedLLMEngine:
         pages are reclaimed before giving up."""
         page = self.pool.alloc()
         if page is None and self.radix.evict_pages(1):
+            self._radix_evictions += 1
             page = self.pool.alloc()
         return page
 
@@ -1633,7 +1766,9 @@ class PagedLLMEngine:
         # re-read the flag so tests / live reconfig take effect
         self.radix.max_entries = int(CONFIG.prefix_cache_entries)
         if n_full:
-            self.radix.insert(prompt, pages[:n_full])
+            before = self.radix.entries
+            added = self.radix.insert(prompt, pages[:n_full])
+            self._radix_evictions += before + added - self.radix.entries
         llm_metrics().prefix_entries.set(self.radix.entries,
                                          tags=_GAUGE_TAGS)
 
@@ -1799,9 +1934,19 @@ class PagedLLMEngine:
                     summary, window = cfg.model.attended_rows(seq.length)
                     self._summary_rows += summary
                     self._window_rows += window
+                if self._latent:
+                    self._latent_rows_attended += seq.length + 1
+                    self._latent_pages_rowwise += len(seq.pages)
                 # this step's token, in flight from here on
                 seq.length += 1
                 seq.dispatched += 1
+            if self._latent:
+                # the pages those rows hold, each once
+                seen = self._page_seen
+                seen[block_tables[active].ravel()] = True
+                seen[0] = False
+                self._latent_pages_distinct += np.count_nonzero(seen)
+                seen[:] = False
             self._decode_rows += len(active)
             self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
             self._rng, key = jax.random.split(self._rng)
@@ -1822,14 +1967,22 @@ class PagedLLMEngine:
                                 jnp.asarray(top_ks), jnp.asarray(top_ps))
                     with phase("dispatch"):
                         unread, tokens = self._unread, self._tokens
-                        if self.state is None:
+                        if self._latent or self.state is not None:
+                            # the rows that decode, for the layers that
+                            # count or scan
+                            live = np.zeros((B,), bool)
+                            live[active] = True
+                        if self._latent:
+                            (self._tokens, self.k_pages,
+                             self.counters) = self._decode(
+                                self.params, self.k_pages,
+                                jnp.asarray(live), *args, self.counters)
+                        elif self.state is None:
                             (self._tokens, self.k_pages,
                              self.v_pages) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
                                 *args)
                         else:
-                            live = np.zeros((B,), bool)
-                            live[active] = True
                             (self._tokens, self.k_pages, self.v_pages,
                              self.state, self.counters) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
@@ -1936,8 +2089,11 @@ class PagedLLMEngine:
             "expert_steps": [steps.tolist() for _, steps in counters],
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
-            # "pallas" or "gather": ops.paged_attention in `decode_step`
-            "paged_kernel": self._paged_kernel,
+            # "pallas" or "gather": the path `decode_step` holds, of
+            # ops.latent_attention for a model whose layers cache latent
+            # rows, else of ops.paged_attention
+            ("latent_kernel" if self._latent else "paged_kernel"):
+                self._paged_kernel,
             "tp": self._tp,
             "hbm_cache_bytes": cache_bytes,
             # per-chip residency: pages shard on kv_heads, params on

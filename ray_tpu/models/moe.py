@@ -143,11 +143,15 @@ def relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
-def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int):
+def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
+                    w_gate=None):
     """The part of sum_i w_i E_i(x) that the experts held here give, with
-    E_i(x) = w_out[i] relu(w_in[i] x)^2, for every token at once and with no
-    capacity: every (token, choice) pair whose expert is held is computed,
-    however the pairs fall.
+    E_i(x) = w_out[i] relu(w_in[i] x)^2, or, where `w_gate` [held, l, f] is
+    given, the gated E_i(x) = w_out[i] (silu(w_gate[i] x) * w_in[i] x)
+    (SwiGLU, three matrices an expert: `w_in` is then the up projection),
+    for every token at once and with no capacity: every (token, choice)
+    pair whose expert is held is computed, however the pairs fall.
+    `w_gate=None` is static and leaves the two-matrix program as it was.
 
     x [T, l]; chosen, weights [T, k] (over all experts); mask [T] bool
     (False: the token is padding or an idle row, and counts nowhere);
@@ -174,7 +178,13 @@ def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int):
     per_expert = jnp.where(taken, weights[..., None], 0.0).sum(1)  # [T, held]
     hidden = jnp.einsum("tl,elf->etf", x.astype(w_in.dtype), w_in,
                         preferred_element_type=F32)
-    y = jnp.einsum("etf,efl->etl", relu2(hidden).astype(w_out.dtype), w_out,
+    if w_gate is None:
+        hidden = relu2(hidden)
+    else:
+        hidden = jax.nn.silu(jnp.einsum(
+            "tl,elf->etf", x.astype(w_gate.dtype), w_gate,
+            preferred_element_type=F32)) * hidden
+    y = jnp.einsum("etf,efl->etl", hidden.astype(w_out.dtype), w_out,
                    preferred_element_type=F32)
     return jnp.einsum("te,etl->tl", per_expert, y), \
         taken.sum((0, 1)).astype(jnp.int32)
@@ -192,7 +202,9 @@ class RoutedExperts(nn.Module):
 
     `u` [.., d] is what the router reads, `x` [.., l] what the experts
     read and write (the same array unless the experts live in a latent
-    space). Experts are not gated: E(x) = W2 relu(W1 x)^2.
+    space). Experts are not gated, E(x) = W2 relu(W1 x)^2, unless `gated`:
+    E(x) = W_d (silu(W_g x) * W_u x), with a third matrix `w_gate` an
+    expert (`w_in` is W_u, `w_out` W_d).
     Returns (out [.., l] float32, pairs [held] int32)."""
     num_experts: int
     experts_per_token: int
@@ -201,6 +213,7 @@ class RoutedExperts(nn.Module):
     routed_scaling: float = 1.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
+    gated: bool = False
 
     @nn.compact
     def __call__(self, u, x, mask=None):
@@ -224,6 +237,12 @@ class RoutedExperts(nn.Module):
                 in_axis=-2, out_axis=-1, batch_axis=(0,)),
                 ("expert", "mlp", "embed")),
             (held, self.mlp_dim, width), self.param_dtype)
+        w_gate = self.param(
+            "w_gate", _partitioned(nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1, batch_axis=(0,)),
+                ("expert", "embed", "mlp")),
+            (held, width, self.mlp_dim), self.param_dtype) \
+            if self.gated else None
         u = u.reshape(-1, d)
         x = x.reshape(-1, width)
         mask = jnp.ones((u.shape[0],), bool) if mask is None \
@@ -239,5 +258,5 @@ class RoutedExperts(nn.Module):
         self.sow("routing", "scores", scores.reshape(lead + (-1,)))
         with jax.named_scope("moe/experts"):
             out, pairs = held_expert_sum(x, chosen, weights, mask, w_in,
-                                         w_out, first)
+                                         w_out, first, w_gate)
         return out.reshape(lead + (width,)), pairs
