@@ -46,7 +46,8 @@ import numpy as np
 from .._internal import accel as _accel
 from .._internal.config import CONFIG
 from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
-from ..ops.latent_attention import latent_kernel
+from ..ops.latent_attention import (latent_kernel, pages_spared,
+                                    share_schedule)
 from ..ops.paged_attention import paged_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
@@ -418,12 +419,15 @@ class PagedLLMEngine:
         self._window_rows = 0
         # what the latent path did (`_latent`): cached rows the decode
         # steps attended; pages the decoding rows held a step, counted a
-        # row and counted once (rows on one document share pages); prompt
+        # row, counted once (rows on one document share pages) and as the
+        # kernel's schedule has it copy them (a group's shared span once:
+        # between the two); prompt
         # tokens mapped from the radix and computed; cached rows the
         # prefill chunks attended; radix nodes evicted
         self._latent_rows_attended = 0
         self._latent_pages_rowwise = 0
         self._latent_pages_distinct = 0
+        self._latent_pages_copied = 0
         self._page_seen = np.zeros((P,), bool)
         self._prefix_shared_tokens = 0
         self._prefill_computed_tokens = 0
@@ -645,12 +649,18 @@ class PagedLLMEngine:
         kinds = _layer_caches(self.config.model)
 
         def decode_caches(pools, counters, active, block_tables, lengths):
-            """What each layer is handed in a paged decode step."""
+            """What each layer is handed in a paged decode step: the rows
+            that attend a shared document together are found ONCE, for
+            every layer's kernel."""
+            with jax.named_scope("mla/attend"):
+                schedule = share_schedule(block_tables, lengths,
+                                          self.config.page_size)
             counts_of = iter(counters)
             caches = []
             for pool, (_, _, counts) in zip(pools, kinds):
                 cache = {"pool": pool, "active": active,
-                         "block_tables": block_tables, "lengths": lengths}
+                         "block_tables": block_tables, "lengths": lengths,
+                         "schedule": schedule}
                 if counts:
                     cache["pairs"], cache["steps"] = next(counts_of)
                 caches.append(cache)
@@ -1207,6 +1217,7 @@ class PagedLLMEngine:
                 latent_rows_attended=self._latent_rows_attended,
                 latent_pages_rowwise=self._latent_pages_rowwise,
                 latent_pages_distinct=self._latent_pages_distinct,
+                latent_pages_copied=self._latent_pages_copied,
                 prefix_shared_tokens=self._prefix_shared_tokens,
                 prefill_computed_tokens=self._prefill_computed_tokens,
                 prefill_ctx_rows=self._prefill_ctx_rows,
@@ -1937,6 +1948,7 @@ class PagedLLMEngine:
                 if self._latent:
                     self._latent_rows_attended += seq.length + 1
                     self._latent_pages_rowwise += len(seq.pages)
+                    self._latent_pages_copied += len(seq.pages)
                 # this step's token, in flight from here on
                 seq.length += 1
                 seq.dispatched += 1
@@ -1947,6 +1959,12 @@ class PagedLLMEngine:
                 seen[0] = False
                 self._latent_pages_distinct += np.count_nonzero(seen)
                 seen[:] = False
+                # and what the kernel does not copy of them: a group's
+                # shared span for every member but one, by the schedule
+                # the device makes of the same arrays
+                self._latent_pages_copied -= int(  # host-sync ok: numpy
+                    pages_spared(share_schedule(
+                        block_tables, lengths, cfg.page_size)))
             self._decode_rows += len(active)
             self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
             self._rng, key = jax.random.split(self._rng)

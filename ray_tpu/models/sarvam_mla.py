@@ -266,7 +266,8 @@ class LatentAttention(nn.Module):
                     attended = latent_attend(
                         q_abs[:, 0], pool, cache["lengths"],
                         cache["block_tables"], value_dim=rank,
-                        reference=cfg.attention_impl == "reference")[:, None]
+                        reference=cfg.attention_impl == "reference",
+                        schedule=cache.get("schedule"))[:, None]
                 else:
                     attended = latent_attend_chunk(
                         q_abs[0], pool, cache["table"], cache_index,

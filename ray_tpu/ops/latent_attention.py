@@ -16,15 +16,25 @@ below it, a prefill chunk's: an XLA loop over blocks of pages). Two paths,
 as ops/paged_attention.py:
 
 1. A Pallas TPU kernel over the engine's latent pool `[1, pages, page_size,
-   width]`. A program is a ROW; a page is copied ONCE and serves as key
-   (all `width` lanes) and as value (the first `value_dim`); the copies of
-   a block of pages are in flight while the block before is computed, across
-   rows too. Two products a chunk with every query head against the one kv
-   head: bf16 operands (the pool's type), float32 accumulation; the softmax
-   statistics and the output accumulator are float32; the probabilities
-   enter `P . C` in the pool's type.
+   width]`. Rows whose block tables begin with the same pages (a document
+   the radix mapped in place for each of them) form a GROUP of at most
+   `_GROUP_ROWS` (`share_schedule`, from the tables and the lengths alone),
+   and the group's shared span is copied ONCE and attended with the
+   members' queries stacked, `[members * heads, width]` against the one
+   copy of a page block; after it each member attends its own remaining
+   pages with its own queries and its own slice of the statistics. A row
+   that shares nothing is a group of one: no shared span. A page copied
+   serves as key (all `width` lanes) and as value (the first
+   `value_dim`); the copies of a block of pages are in flight while the
+   block before is computed, across spans, rows and groups. Two products a
+   chunk against the one kv head: bf16 operands (the pool's type), float32
+   accumulation; the softmax statistics and the output accumulator are
+   float32; the probabilities enter `P . C` in the pool's type. A shared
+   span is whole compute chunks, so a row's chunks fall where they would
+   alone and its sums are taken in the same order.
 2. A gather fallback elsewhere (the CPU, a model whose `attention_impl` is
-   "reference"): each row's pages materialised densely, float32.
+   "reference"): each row's pages materialised densely, float32; it takes
+   no notice of groups.
 
 `latent_kernel` names the path a decode program built here will hold.
 """
@@ -32,9 +42,11 @@ as ops/paged_attention.py:
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -43,9 +55,12 @@ from .attention import NEG_INF, NUM_LANES, _interpret
 F32 = jnp.float32
 # What the kernel's page buffer (two slots) may take of a core's fast memory.
 _BUFFER_BYTES = 4 << 20
-# Tokens a compute step takes: [heads, chunk] float32 logits, 64 KB at 64
-# heads.
+# Tokens a compute step takes: [heads, chunk] float32 logits a row, 64 KB at
+# 64 heads.
 _CHUNK_TOKENS = 256
+# Rows that attend a shared span together at most: their queries stacked
+# are one product's rows (PERF.md section 6, PR 46: the probe that chose it).
+_GROUP_ROWS = 4
 
 
 def latent_kernel(value_dim: int, reference: bool = False) -> str:
@@ -58,17 +73,91 @@ def latent_kernel(value_dim: int, reference: bool = False) -> str:
     return "gather"
 
 
+def _chunk_pages(page_size: int) -> int:
+    """Whole pages a compute chunk takes."""
+    return max(1, _CHUNK_TOKENS // page_size)
+
+
+class Schedule(NamedTuple):
+    """`share_schedule`'s answer: four int32 [rows], by position in the
+    order the kernel takes the rows."""
+    order: jax.Array
+    lead: jax.Array
+    size: jax.Array
+    shared: jax.Array
+
+
+def share_schedule(tables, lengths, page_size: int,
+                   group: int = _GROUP_ROWS) -> Schedule:
+    """Which rows of a decode step attend a shared span together. tables
+    [rows, pages_per_row] page ids, lengths [rows] tokens cached before
+    this one: numpy arrays or jax's (one body: the decode program calls it
+    once a step, the engine on the arrays it stages, for its counter).
+
+    Rows are ordered so that equal leading pages stand together (by the
+    ids at positions 0, 1, 2, 4, 8, ... of their whole pages); a LINK is
+    the count of leading pages two neighbours hold in common, whole pages
+    that both attend in full (`lengths // page_size`: a dead row's null
+    pages and a row's own tail never link), rounded down to whole compute
+    chunks. A row is cut from the row before it where that link is weaker
+    than either neighbour's other link (nested prefixes: of A, B on 270
+    pages and C on their first 120, A and B go together), so every link
+    inside a run is the same count, which all the run's rows hold in
+    common; a run longer than `group` is cut into even parts of at most
+    `group`. Returns, by position in that order: `order` the caller's row,
+    `lead` the position of its group's first row, `size` its group's rows,
+    `shared` the leading pages the group attends once (0 alone)."""
+    xp = jnp if isinstance(tables, jax.Array) else np
+    rows, width = tables.shape
+    unit = _chunk_pages(page_size)
+    full = lengths // page_size
+    probes = sorted({0} | {1 << k for k in range(width.bit_length())
+                           if 1 << k < width})
+    keys = xp.where(xp.asarray(probes)[None, :] < full[:, None],
+                    tables[:, probes], 0)
+    order = xp.lexsort(keys.T[::-1])
+    held, full = tables[order], full[order]
+    differ = held[1:] != held[:-1]
+    common = xp.where(differ.any(axis=1), differ.argmax(axis=1), width)
+    link = xp.minimum(common, xp.minimum(full[1:], full[:-1])) // unit * unit
+    none = xp.zeros((1,), link.dtype)
+    before = xp.concatenate([none, link])       # with the row before
+    after = xp.concatenate([link, none])        # with the row after
+    cut = (before == 0) | (before < after) \
+        | (before < xp.concatenate([none, before[:-1]]))
+    run = cut.cumsum()
+    at = xp.arange(rows)
+    same = run[:, None] == run[None, :]
+    length = same.sum(axis=1)
+    place = (same & (at[None, :] < at[:, None])).sum(axis=1)
+    part = place * (-(-length // group)) // length
+    mates = same & (part[:, None] == part[None, :])
+    lead, size = mates.argmax(axis=1), mates.sum(axis=1)
+    shared = xp.where(size > 1, after[lead], 0)
+    return Schedule(*(a.astype("int32")
+                      for a in (order, lead, size, shared)))
+
+
+def pages_spared(schedule: Schedule):
+    """The page copies a step's schedule spares the kernel in one layer:
+    a group's shared span once for every member but its first."""
+    follows = schedule.lead != np.arange(len(schedule.lead))
+    return (schedule.shared * follows).sum()
+
+
 def latent_attend(q, pool, lengths, tables, *, value_dim: int,
-                  reference: bool = False):
+                  reference: bool = False, schedule: Schedule = None):
     """q [rows, heads, width], absorbed and SCALED, float32 or the pool's
     type; pool [1, pages, page_size, width]; lengths [rows] tokens cached
     BEFORE this one, whose row is already written at position
-    lengths[row]; tables [rows, pages_per_row] physical page ids. Row b
-    attends positions 0 .. lengths[b]. Returns [rows, heads, value_dim]
-    float32: the attended latent, in front of the value up-projection."""
+    lengths[row]; tables [rows, pages_per_row] physical page ids;
+    `schedule` what `share_schedule` made of the two (a caller with many
+    layers makes it once; made here if None). Row b attends positions
+    0 .. lengths[b]. Returns [rows, heads, value_dim] float32: the attended
+    latent, in front of the value up-projection."""
     if latent_kernel(value_dim, reference) == "pallas":
         return _latent_attend_pallas(
-            q.astype(pool.dtype), pool, lengths + 1, tables,
+            q.astype(pool.dtype), pool, lengths + 1, tables, schedule,
             value_dim=value_dim).astype(F32)
     rows, page_size = q.shape[0], pool.shape[2]
     span = tables.shape[1] * page_size
@@ -93,140 +182,223 @@ def _block_pages(page_size: int, width: int, pages_per_row: int,
     return min(fit, need) * chunk_pages
 
 
-def _kernel(lengths_ref, tables_ref, q_ref, pool_hbm, o_ref, buf, sems,
-            slot_ref, m_ref, l_ref, acc_ref, *, block_pages: int,
-            chunk: int, pages_per_row: int, value_dim: int):
-    """One row. lengths_ref [rows] tokens to attend (>= 1), tables_ref
-    [rows * pages_per_row] in SMEM; q_ref [heads, width], o_ref [heads,
-    value_dim]; pool_hbm the pool; buf [2, block, width]; sems [2] (by
-    slot); slot_ref [1] the slot the row's first block is in."""
-    row, rows = pl.program_id(0), pl.num_programs(0)
+def _kernel(lengths_ref, tables_ref, order_ref, lead_ref, size_ref,
+            shared_ref, q_ref, pool_hbm, o_ref, buf, sems, slot_ref, qs_ref,
+            m_ref, l_ref, acc_ref, *, block_pages: int, chunk: int,
+            pages_per_row: int, value_dim: int, group: int):
+    """One row, by its position in the schedule's order; a group's first
+    row attends the group's shared span for every member before its own.
+    lengths_ref [rows] tokens to attend (>= 1), tables_ref [rows *
+    pages_per_row], order_ref / lead_ref / size_ref / shared_ref [rows] the
+    schedule, in SMEM; q_ref [rows, heads, width] every row's queries,
+    o_ref [heads, value_dim] this row's; pool_hbm the pool; buf [2, block,
+    width]; sems [2] (by slot); slot_ref [1] the slot the next block to
+    compute is in; qs_ref [group * heads, width] a group's queries
+    stacked; m_ref, l_ref [group * heads, 1] and acc_ref [group * heads,
+    value_dim] the statistics of a group's members, one after another:
+    the shared span fills them, and each member's own pages go on in the
+    FIRST member's rows, its own moved there first."""
+    at, rows = pl.program_id(0), pl.num_programs(0)
+    heads = q_ref.shape[1]
     page_size = pool_hbm.shape[2]
-    block = block_pages * page_size
+    row = order_ref[at]
     length = lengths_ref[row]
+    pages = pl.cdiv(length, page_size)
+    shared = shared_ref[at]
+    leads = (lead_ref[at] == at) & (shared > 0)
 
-    def copies(r, blk, slot, start: bool):
-        """Start (or wait for) the pages of block `blk` of row `r`: ONE
-        copy a page, which is its keys and its values."""
-        pages = jnp.minimum(
-            block_pages, pl.cdiv(lengths_ref[r] - blk * block, page_size))
-        first = r * pages_per_row + blk * block_pages
+    def copies(r, first, count, slot, start: bool):
+        """Start (or wait for) pages first .. first + count of row `r`'s
+        table: ONE copy a page, which is its keys and its values."""
+        base = r * pages_per_row + first
 
         def one(j, carry):
             # a wait needs the copy's shape, not its source
-            page = tables_ref[first + j] if start else 0
-            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            page = tables_ref[base + j] if start else 0
+            to = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
             copy = pltpu.make_async_copy(
-                pool_hbm.at[0, page], buf.at[slot, at], sems.at[slot])
+                pool_hbm.at[0, page], buf.at[slot, to], sems.at[slot])
             if start:
                 copy.start()
             else:
                 copy.wait()
             return carry
-        jax.lax.fori_loop(0, pages, one, None)
+        jax.lax.fori_loop(0, count, one, None)
 
-    @pl.when(row == 0)
+    def first_block(p):
+        """(row, first page, pages) of the first block program `p` takes:
+        of its group's shared span if it leads one, else of its own pages
+        behind what its group's leader attended for it."""
+        r, span = order_ref[p], shared_ref[p]
+        leader = lead_ref[p] == p
+        first = jnp.where(leader, 0, span)
+        end = jnp.where(leader & (span > 0), span,
+                        pl.cdiv(lengths_ref[r], page_size))
+        return r, first, jnp.minimum(block_pages, end - first)
+
+    @pl.when(at == 0)
     def _first():
         # a row's last chunk reads past its tokens: masked as keys, times
         # a probability of zero as values, which the buffer's first bits
         # may not survive (0 * nan)
         buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        copies(0, 0, 0, start=True)
+        copies(*first_block(0), 0, start=True)
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    blocks = pl.cdiv(length, block)
+    def attend_span(first, end, limit, stats, then):
+        """Pages first .. end of this row's table, `limit` tokens of the
+        row seen (None: every token of the span by every member), for the
+        rows `stats` of the stacked queries and of the statistics; `then`
+        says what to copy while the span's last block is computed: (is
+        there one, row, first page, pages)."""
+        blocks = pl.cdiv(end - first, block_pages)
 
-    def attend_block(blk, slot):
-        ends = blk + 1 == blocks
-        next_row = jnp.where(ends, row + 1, row)
+        def attend_block(b, slot):
+            begin = first + b * block_pages
+            count = jnp.minimum(block_pages, end - begin)
+            ends = b + 1 == blocks
+            follows, r_next, first_next, count_next = then
 
-        @pl.when(next_row < rows)
-        def _prefetch():
-            copies(next_row, jnp.where(ends, 0, blk + 1), 1 - slot,
-                   start=True)
+            @pl.when(jnp.logical_not(ends) | follows)
+            def _prefetch():
+                copies(jnp.where(ends, r_next, row),
+                       jnp.where(ends, first_next, begin + block_pages),
+                       jnp.where(ends, count_next, jnp.minimum(
+                           block_pages, end - begin - block_pages)),
+                       1 - slot, start=True)
 
-        copies(row, blk, slot, start=False)
-        here = jnp.minimum(block, length - blk * block)
+            copies(row, begin, count, slot, start=False)
+            here = count * page_size if limit is None else jnp.minimum(
+                count * page_size, limit - begin * page_size)
 
-        def attend_chunk(c, carry):
-            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-            held = buf[slot, at, :]                        # [chunk, width]
-            seen = (blk * block + c * chunk + jax.lax.broadcasted_iota(
-                jnp.int32, (1, chunk), 1)) < length
-            logits = jax.lax.dot_general(
-                q_ref[...], held, (((1,), (1,)), ((), ())),
-                preferred_element_type=F32)               # [heads, chunk]
-            logits = jnp.where(seen, logits, NEG_INF)
-            m_prev = m_ref[...]                            # [heads, 1]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(logits, axis=-1, keepdims=True))
-            p = jnp.exp(logits - m_new)
-            correction = jnp.exp(m_prev - m_new)
-            m_ref[...] = m_new
-            l_ref[...] = l_ref[...] * correction + jnp.sum(
-                p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-                p.astype(held.dtype), held[:, :value_dim],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=F32)           # [heads, value_dim]
-            return carry
-        jax.lax.fori_loop(0, pl.cdiv(here, chunk), attend_chunk, None)
-        return 1 - slot
+            def attend_chunk(c, carry):
+                to = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                held = buf[slot, to, :]                    # [chunk, width]
+                logits = jax.lax.dot_general(
+                    qs_ref[stats, :], held, (((1,), (1,)), ((), ())),
+                    preferred_element_type=F32)        # [queries, chunk]
+                if limit is not None:
+                    seen = (begin * page_size + c * chunk
+                            + jax.lax.broadcasted_iota(
+                                jnp.int32, (1, chunk), 1)) < limit
+                    logits = jnp.where(seen, logits, NEG_INF)
+                m_prev = m_ref[stats, :]                   # [queries, 1]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(logits, axis=-1, keepdims=True))
+                p = jnp.exp(logits - m_new)
+                correction = jnp.exp(m_prev - m_new)
+                m_ref[stats, :] = m_new
+                l_ref[stats, :] = l_ref[stats, :] * correction + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_ref[stats, :] = acc_ref[stats, :] * correction \
+                    + jax.lax.dot_general(
+                        p.astype(held.dtype), held[:, :value_dim],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=F32)  # [queries, value_dim]
+                return carry
+            jax.lax.fori_loop(0, pl.cdiv(here, chunk), attend_chunk, None)
+            return 1 - slot
 
-    slot_ref[0] = jax.lax.fori_loop(0, blocks, attend_block, slot_ref[0])
-    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        slot_ref[0] = jax.lax.fori_loop(0, blocks, attend_block,
+                                        slot_ref[0])
+
+    def reset(members):
+        stats = pl.ds(0, members * heads)
+        m_ref[stats, :] = jnp.full((members * heads, 1), NEG_INF, F32)
+        l_ref[stats, :] = jnp.zeros((members * heads, 1), F32)
+        acc_ref[stats, :] = jnp.zeros((members * heads, value_dim), F32)
+
+    own = pl.ds(0, heads)
+    qs_ref[own, :] = q_ref[row]
+    # a product takes as many query rows as the group has members
+    for members in range(2, group + 1):
+        @pl.when(leads & (size_ref[at] == members))
+        def _shared_span(members=members):
+            stats = pl.ds(0, members * heads)
+            for j in range(1, members):
+                qs_ref[pl.ds(j * heads, heads), :] = q_ref[order_ref[at + j]]
+            reset(members)
+            attend_span(
+                0, shared, None, stats,
+                (True, row, shared,
+                 jnp.minimum(block_pages, pages - shared)))
+
+    @pl.when(shared == 0)
+    def _alone():
+        reset(1)
+
+    @pl.when(lead_ref[at] != at)
+    def _follows():
+        # what the group's first row made of the shared span for this one
+        made = pl.ds(pl.multiple_of((at - lead_ref[at]) * heads, heads),
+                     heads)
+        m_ref[own, :] = m_ref[made, :]
+        l_ref[own, :] = l_ref[made, :]
+        acc_ref[own, :] = acc_ref[made, :]
+
+    after = jnp.minimum(at + 1, rows - 1)
+    attend_span(shared, pages, length, own,
+                (at + 1 < rows,) + first_block(after))
+    o_ref[...] = (acc_ref[own, :] / l_ref[own, :]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("value_dim", "block_pages"))
-def _latent_attend_pallas(q, pool, lengths, tables, *, value_dim: int,
-                          block_pages=None):
+@functools.partial(jax.jit,
+                   static_argnames=("value_dim", "block_pages", "group"))
+def _latent_attend_pallas(q, pool, lengths, tables, schedule=None, *,
+                          value_dim: int, block_pages=None,
+                          group: int = _GROUP_ROWS):
     """The kernel. q [rows, heads, width] SCALED and in the pool's type;
     lengths [rows] tokens to attend, >= 1 (a dead row: 1, on the null
-    page). `block_pages` is the tests' override of `_block_pages`. Jitted
-    so that a model's layers share ONE trace of the kernel's body. Returns
-    [rows, heads, value_dim] float32."""
+    page); `schedule` what `share_schedule` made of the tables and of
+    `lengths - 1` for groups of `group` at most (made here if None).
+    `block_pages` is the tests' override of `_block_pages`. Jitted so that
+    a model's layers share ONE trace of the kernel's body. Returns [rows,
+    heads, value_dim] float32, in the caller's order."""
     rows, heads, width = q.shape
     _, _, page_size, _ = pool.shape
     pages_per_row = tables.shape[1]
-    chunk = max(page_size, _CHUNK_TOKENS)
+    unit = _chunk_pages(page_size) * page_size
     if block_pages is None:
         block_pages = _block_pages(page_size, width, pages_per_row,
-                                   pool.dtype.itemsize, chunk)
+                                   pool.dtype.itemsize, unit)
     block = block_pages * page_size
-    chunk = min(chunk, block)
-    if chunk % page_size or block % chunk:
+    chunk = min(unit, block)
+    # (a shared span is whole `unit`s, whatever the override made of them)
+    if block % chunk or unit % chunk:
         raise ValueError(f"pages of {page_size} tokens do not tile chunks "
                          f"of {chunk} in a block of {block}")
-    stat = pltpu.VMEM((heads, 1), F32)
+    if schedule is None:
+        schedule = share_schedule(tables, lengths - 1, page_size, group)
+    stat = pltpu.VMEM((group * heads, 1), F32)
     return pl.pallas_call(
         functools.partial(_kernel, block_pages=block_pages, chunk=chunk,
-                          pages_per_row=pages_per_row, value_dim=value_dim),
+                          pages_per_row=pages_per_row, value_dim=value_dim,
+                          group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            in_specs=[pl.BlockSpec((None, heads, width),
-                                   lambda r, *_: (r, 0, 0)),
+            num_scalar_prefetch=6,
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                       pl.BlockSpec(memory_space=pl.ANY)],
+            # the schedule's order in, the caller's order out
             out_specs=pl.BlockSpec((None, heads, value_dim),
-                                   lambda r, *_: (r, 0, 0)),
+                                   lambda at, n, t, order, *_:
+                                   (order[at], 0, 0)),
             grid=(rows,),
             scratch_shapes=[
                 pltpu.VMEM((2, block, width), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((group * heads, width), pool.dtype),
                 stat, stat,
-                pltpu.VMEM((heads, value_dim), F32)]),
+                pltpu.VMEM((group * heads, value_dim), F32)]),
         out_shape=jax.ShapeDtypeStruct((rows, heads, value_dim), F32),
-        # a row's last block starts the next row's first: in order
+        # a span's last block starts the next span's first: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
         name="latent_attention",
     # no row reads past its table, as none does in the gather fallback
     )(jnp.minimum(lengths, pages_per_row * page_size), tables.reshape(-1),
-      q, pool)
+      *schedule, q, pool)
 
 
 # Cached tokens a step of `latent_attend_chunk`'s loop takes.

@@ -375,7 +375,10 @@ def test_the_yarn_table_follows_the_written_out_rule_beyond_4096():
 PAGE, WIDTH, VALUE = 16, 256, 128
 DEAD = -1
 # id: (heads, tokens cached before this one per row (DEAD: a dead row on
-# the null page), table width in pages, block override, pool type)
+# the null page), table width in pages, block override, pool type[, the
+# documents: (rows, leading pages those rows' tables have in common), a
+# later one over an earlier one's]). A shared span is whole compute chunks:
+# 16 pages here (4 under a block override of 4, where a chunk is the block).
 KERNEL_CASES = {
     "dead-short-long": (8, [DEAD, 40, 150], 10, 4, jnp.float32),
     "page-less-one-token": (8, [PAGE - 2, 70], 8, 4, jnp.float32),
@@ -390,15 +393,40 @@ KERNEL_CASES = {
     "every-row-dead": (8, [DEAD, DEAD], 6, 4, jnp.float32),
     "derived-block": (64, [1100, 511, DEAD], 96, None, jnp.float32),
     "bf16-pool": (8, [DEAD, 100, 300], 24, None, jnp.bfloat16),
+    # rows on one document: its pages copied once for a group
+    "two-rows-tails-of-two-lengths": (8, [400, 330], 40, 4, jnp.float32,
+                                      [((0, 1), 20)]),
+    "three-rows": (8, [500, 300, 420], 40, 4, jnp.float32,
+                   [((0, 1, 2), 18)]),
+    "more-rows-than-a-group": (8, [300, 310, 290, 350, 280], 24, 4,
+                               jnp.float32, [((0, 1, 2, 3, 4), 17)]),
+    "nested-prefixes": (8, [700, 660, 400], 48, 4, jnp.float32,
+                        [((0, 1, 2), 20), ((0, 1), 40)]),
+    "span-ends-inside-a-copy-block": (8, [700, 650], 48, 32, jnp.float32,
+                                      [((0, 1), 20)]),
+    "span-ends-on-a-chunk-edge": (8, [600, 530], 40, 32, jnp.float32,
+                                  [((0, 1), 32)]),
+    "a-member-ends-with-the-span": (8, [16 * PAGE, 400], 32, 4, jnp.float32,
+                                    [((0, 1), 16)]),
+    "dead-row-between-members": (8, [400, DEAD, 380], 32, 4, jnp.float32,
+                                 [((0, 2), 20)]),
+    "two-dead-rows-do-not-group": (8, [DEAD, 300, DEAD, 310], 24, 4,
+                                   jnp.float32, [((1, 3), 18)]),
+    "members-out-of-order": (8, [300, 420, 350, 290, 330], 32, 4,
+                             jnp.float32, [((4, 0, 2), 17), ((3, 1), 16)]),
+    "no-row-shares": (8, [300, 420, 350], 32, None, jnp.float32),
+    "bf16-pool-shared": (16, [DEAD, 400, 330, 600], 40, None, jnp.bfloat16,
+                         [((1, 2, 3), 20)]),
+    "derived-block-shared": (64, [1100, 900], 96, None, jnp.float32,
+                             [((0, 1), 40)]),
 }
 
 
-@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_the_latent_kernel_matches_the_gather_path(case):
-    """`_latent_attend_pallas` under the TPU interpreter against the
-    gather fallback in float32: every page random (a read of a wrong page
-    shows), rows' pages scattered, unused table entries on the null page."""
-    heads, cached, width, block_pages, dtype = KERNEL_CASES[case]
+def kernel_case(case):
+    """(q, pool, lengths, tables, documents) of a case: every page random
+    (a read of a wrong page shows), rows' pages scattered, unused table
+    entries on the null page, a document's pages its first row's."""
+    heads, cached, width, _, dtype, *documents = KERNEL_CASES[case]
     rows = len(cached)
     rng = np.random.RandomState(len(case))
     pages = 1 + rows * width
@@ -412,6 +440,30 @@ def test_the_latent_kernel_matches_the_gather_path(case):
             held = n // PAGE + 1
             tables[r, :held] = free[r * width:r * width + held]
             lengths[r] = n
+    documents = documents[0] if documents else []
+    for members, leading in documents:
+        for r in members[1:]:
+            tables[r, :leading] = tables[members[0], :leading]
+    return q, pool, lengths, tables, documents
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_latent_kernel_matches_the_gather_path(case):
+    """`_latent_attend_pallas` under the TPU interpreter against the
+    gather fallback in float32, and against the kernel of a program a row
+    (PR 45's) bit for bit: a group's shared span is whole chunks, so every
+    row's sums are taken in the order they were."""
+    from latent_rowwise_kernel import rowwise_latent_attend
+    heads, cached, _, block_pages, dtype = KERNEL_CASES[case][:5]
+    q, pool, lengths, tables, documents = kernel_case(case)
+    rows = len(cached)
+    # a row goes with the rows of the longest document it is on
+    longest = {r: d for d, (members, _) in enumerate(documents)
+               for r in members}
+    together = [r for r, d in longest.items()
+                if list(longest.values()).count(d) > 1]
+    schedule = la.share_schedule(tables, lengths, PAGE)
+    assert sorted(schedule.order[schedule.size > 1]) == sorted(together)
     got = la._latent_attend_pallas(
         q, pool, jnp.asarray(lengths + 1), jnp.asarray(tables),
         value_dim=VALUE, block_pages=block_pages)
@@ -424,6 +476,10 @@ def test_the_latent_kernel_matches_the_gather_path(case):
     assert got.shape == (rows, heads, VALUE) and got.dtype == jnp.float32
     assert np.abs(np.asarray(got) - np.asarray(want))[live].max(initial=0) \
         < tolerance
+    alone = rowwise_latent_attend(
+        q, pool, jnp.asarray(lengths + 1), jnp.asarray(tables),
+        value_dim=VALUE, block_pages=block_pages)
+    assert np.array_equal(np.asarray(got)[live], np.asarray(alone)[live])
 
 
 def test_the_engine_keeps_one_latent_pool_a_layer_and_no_v_pool(engine):
@@ -473,14 +529,111 @@ def test_generation_through_the_tick_matches_the_reference_and_counts():
     assert tick["counters"]["latent_rows_attended"] > 0
 
 
+def seeded_tables(seed, rows_on, rows=12, width=40, page=64, whole=False):
+    """(tables, lengths) of `rows` rows: a document for each entry of
+    `rows_on` (the rows on it), 8 to 24 pages (whole chunks if `whole`),
+    every row with a tail of its own; the rest dead; rows shuffled."""
+    rng = np.random.default_rng(seed)
+    free = list(1 + rng.permutation(rows * width))
+    tables = np.zeros((rows, width), np.int32)
+    lengths = np.zeros((rows,), np.int32)
+    at = list(rng.permutation(rows))
+    for n in rows_on:
+        leading = int(rng.integers(2, 7)) * 4 if whole \
+            else int(rng.integers(8, 25))
+        document = [free.pop() for _ in range(leading)]
+        for _ in range(n):
+            r = at.pop()
+            lengths[r] = leading * page + int(rng.integers(1, 5 * page))
+            held = lengths[r] // page + 1
+            tables[r, :leading] = document
+            tables[r, leading:held] = [free.pop()
+                                       for _ in range(held - leading)]
+    return tables, lengths
+
+
+def page_counts(tables, lengths, page=64, group=la._GROUP_ROWS):
+    """(a row, once, as the schedule has the kernel copy them)."""
+    rowwise = int(((lengths // page + 1) * (lengths > 0)).sum())
+    distinct = len(set(tables.ravel().tolist()) - {0})
+    schedule = la.share_schedule(tables, lengths, page, group)
+    return rowwise, distinct, rowwise - int(la.pages_spared(schedule))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_schedule_is_one_from_numpy_and_from_jax(seed):
+    """What the decode program makes of its arguments on the device is
+    what the engine counts from the arrays it staged; a group holds at
+    most `_GROUP_ROWS`, its rows stand together behind its first, and its
+    shared span is whole chunks of pages that every member holds, the same
+    ids, and attends in full."""
+    rows_on = [[3, 2, 1, 1], [5, 1, 1], [2, 2, 2, 2, 2], [9], [1] * 7,
+               [4, 4, 3]][seed]
+    tables, lengths = seeded_tables(seed, rows_on)
+    host = la.share_schedule(tables, lengths, 64)
+    device = jax.jit(lambda t, n: la.share_schedule(t, n, 64))(
+        jnp.asarray(tables), jnp.asarray(lengths))
+    for ours, theirs in zip(host, device):
+        assert ours.dtype == np.int32
+        assert np.array_equal(ours, np.asarray(theirs))
+    assert sorted(host.order) == list(range(len(lengths)))
+    for at, (lead, size, shared) in enumerate(zip(*host[1:])):
+        assert lead <= at < lead + size <= len(lengths)
+        assert 1 <= size <= la._GROUP_ROWS
+        assert (host.lead[lead], host.size[lead], host.shared[lead]) \
+            == (lead, size, shared)
+        assert (shared > 0) == (size > 1) and shared % 4 == 0
+        assert shared <= lengths[host.order[at]] // 64
+        assert np.array_equal(tables[host.order[at], :shared],
+                              tables[host.order[lead], :shared])
+    rowwise, distinct, copied = page_counts(tables, lengths)
+    assert distinct <= copied <= rowwise
+    assert (copied < rowwise) == (max(rows_on) > 1)
+
+
+@pytest.mark.parametrize("rows_on,spares", [
+    ([1] * 8, "nothing"), ([2], "all"), ([3], "all"), ([4], "all"),
+    ([2, 3, 4], "all"), ([6], "some")])
+def test_the_pages_copied_lie_between_once_and_a_row(rows_on, spares):
+    """No sharing: a page a row, as before. At most `_GROUP_ROWS` rows on
+    a document of whole chunks: every page once. More: once a group."""
+    tables, lengths = seeded_tables(len(rows_on), rows_on, whole=True)
+    rowwise, distinct, copied = page_counts(tables, lengths)
+    assert distinct <= copied <= rowwise
+    if spares == "nothing":
+        assert copied == rowwise == distinct
+    elif spares == "all":
+        assert copied == distinct < rowwise
+    else:
+        assert distinct < copied < rowwise
+        assert page_counts(tables, lengths, group=6)[2] == distinct
+
+
 def test_rows_on_one_document_share_its_pages():
-    engine = tiny_engine()
-    document = prompt_of(31, 64).tolist()
+    """A document of 33 pages (32 of them whole chunks of the kernel's):
+    the two rows that ask it together hold its pages once, and the schedule
+    has the kernel copy them once."""
+    engine = PagedLLMEngine(PagedEngineConfig(
+        model=tiny_model(), max_batch=3, max_len=320, page_size=8,
+        num_pages=160, prefill_buckets=(16, 32)))
+    document = prompt_of(31, 264).tolist()
     engine.generate([document + [3]], max_new_tokens=2)
+    before = engine.stats()
+    assert before["latent_pages_copied"] == before["latent_pages_rowwise"] \
+        == before["latent_pages_distinct"] > 0
     engine.generate([document + [5, 6], document + [7]], max_new_tokens=8)
     stats = engine.stats()
     assert stats["prefix_hits"] == 2 and stats["leaked_pages"] == 0
     assert stats["latent_pages_rowwise"] > stats["latent_pages_distinct"]
+    assert stats["latent_pages_distinct"] <= stats["latent_pages_copied"] \
+        < stats["latent_pages_rowwise"]
+    # seven steps of two rows, 32 pages spared in each
+    assert stats["latent_pages_rowwise"] - stats["latent_pages_copied"] \
+        == 7 * 32
+    from ray_tpu._internal import accel
+    tick = next(r for r in accel.step_summary() if r["kind"] == "tick")
+    assert 0 < tick["counters"]["latent_pages_copied"] \
+        < tick["counters"]["latent_pages_rowwise"]
 
 
 def test_decode_step_donates_and_aliases_the_pools_and_the_counters(engine):
