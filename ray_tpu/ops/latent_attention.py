@@ -26,12 +26,20 @@ as ops/paged_attention.py:
    that shares nothing is a group of one: no shared span. A page copied
    serves as key (all `width` lanes) and as value (the first
    `value_dim`); the copies of a block of pages are in flight while the
-   block before is computed, across spans, rows and groups. Two products a
-   chunk against the one kv head: bf16 operands (the pool's type), float32
-   accumulation; the softmax statistics and the output accumulator are
-   float32; the probabilities enter `P . C` in the pool's type. A shared
-   span is whole compute chunks, so a row's chunks fall where they would
-   alone and its sums are taken in the same order.
+   block before is computed, across spans, rows and groups. A COMPUTE
+   STEP takes `_CHUNK_TOKENS` tokens: `q . row^T`, the softmax's max, two
+   `exp` and a sum, `P . C` and the update of the statistics and of the
+   accumulator each wait for the one before and nothing of the next step
+   runs under them, so the chain is paid once a step and the step is
+   long (PERF.md section 6, PR 47: the probe that chose it). Two products
+   a step against the one kv head: bf16 operands (the pool's type),
+   float32 accumulation; the logits `[members * heads, step]`, the
+   softmax statistics and the output accumulator are float32; the
+   probabilities enter `P . C` in the pool's type. ONE step length for
+   every group size, and a shared span is whole steps: a row's steps
+   then fall where they would alone, its sums are taken in one order
+   whatever rows share its document, and its output does not depend on
+   its neighbours in the batch.
 2. A gather fallback elsewhere (the CPU, a model whose `attention_impl` is
    "reference"): each row's pages materialised densely, float32; it takes
    no notice of groups.
@@ -55,9 +63,13 @@ from .attention import NEG_INF, NUM_LANES, _interpret
 F32 = jnp.float32
 # What the kernel's page buffer (two slots) may take of a core's fast memory.
 _BUFFER_BYTES = 4 << 20
-# Tokens a compute step takes: [heads, chunk] float32 logits a row, 64 KB at
-# 64 heads.
-_CHUNK_TOKENS = 256
+# Tokens a compute step takes, whatever the group's size: float32 logits
+# [members * heads, step], 256 KB at 64 heads alone and 1 MB at four members
+# (past a core's vector registers either way: they stand in fast memory). The
+# chain inside a step is paid once for so many tokens: 256 cost a call half as
+# much again (PERF.md section 6, PR 47). A group's shared span is rounded down
+# to whole steps (`share_schedule`), so a longer step shares a little less.
+_CHUNK_TOKENS = 1024
 # Rows that attend a shared span together at most: their queries stacked
 # are one product's rows (PERF.md section 6, PR 46: the probe that chose it).
 _GROUP_ROWS = 4
@@ -171,9 +183,11 @@ def latent_attend(q, pool, lengths, tables, *, value_dim: int,
 
 def _block_pages(page_size: int, width: int, pages_per_row: int,
                  itemsize: int, chunk: int) -> int:
-    """Pages a copy group moves: as many whole compute chunks as
-    `_BUFFER_BYTES` holds in two slots (a row's lanes rounded up to whole
-    tiles), and no more than a row has."""
+    """Pages a copy group moves: as many whole compute steps of `chunk`
+    tokens as `_BUFFER_BYTES` holds in two slots (a row's lanes rounded up
+    to whole tiles; never fewer than one step), and no more than a row
+    has. At 640 lanes of bf16 a step of 1024 tokens is 2.5 MiB in two
+    slots: the block is ONE step, 16 pages of 64 tokens."""
     chunk_pages = max(1, chunk // page_size)
     lanes = -(-width // NUM_LANES) * NUM_LANES
     chunk_bytes = 2 * chunk_pages * page_size * lanes * itemsize

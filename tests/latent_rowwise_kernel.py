@@ -1,7 +1,9 @@
 """The latent decode kernel as it stood before rows on one document
 attended it together (PR 45): a program a ROW, every page of every row
-copied. What `tests/test_sarvam_mla.py` holds the grouped kernel against,
-bit for bit; nothing else imports it."""
+copied; its compute step's length is the module's (`_CHUNK_TOKENS`, and
+the loop body is the grouped kernel's), so a row's sums are taken in the
+grouped kernel's order. What `tests/test_sarvam_mla.py` holds the grouped
+kernel against, bit for bit; nothing else imports it."""
 
 import functools
 

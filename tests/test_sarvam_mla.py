@@ -374,11 +374,16 @@ def test_the_yarn_table_follows_the_written_out_rule_beyond_4096():
 
 PAGE, WIDTH, VALUE = 16, 256, 128
 DEAD = -1
+# Pages and tokens of one compute step of the kernel (`la._CHUNK_TOKENS`): a
+# shared span is whole steps.
+STEP = la._chunk_pages(PAGE)
+TOKENS = STEP * PAGE
 # id: (heads, tokens cached before this one per row (DEAD: a dead row on
 # the null page), table width in pages, block override, pool type[, the
 # documents: (rows, leading pages those rows' tables have in common), a
-# later one over an earlier one's]). A shared span is whole compute chunks:
-# 16 pages here (4 under a block override of 4, where a chunk is the block).
+# later one over an earlier one's]). Under a block override shorter than a
+# step the kernel's compute step is the block (4 pages: 64 tokens); the
+# span's unit stays STEP.
 KERNEL_CASES = {
     "dead-short-long": (8, [DEAD, 40, 150], 10, 4, jnp.float32),
     "page-less-one-token": (8, [PAGE - 2, 70], 8, 4, jnp.float32),
@@ -386,39 +391,66 @@ KERNEL_CASES = {
     "exactly-one-block": (8, [4 * PAGE - 1, 70], 8, 4, jnp.float32),
     "block-and-one-token": (8, [4 * PAGE, 70], 8, 4, jnp.float32),
     "full-table": (8, [8 * PAGE - 1, 8 * PAGE - 1], 8, 4, jnp.float32),
-    "chunk-edges-inside-a-block": (16, [254, 255, 256, 600], 40, 32,
-                                   jnp.float32),
+    # a block of two steps: a row attends cached + 1 tokens
+    "chunk-edges-inside-a-block": (
+        16, [TOKENS - 2, TOKENS - 1, TOKENS, 2 * TOKENS + 350],
+        2 * STEP + 32, 2 * STEP, jnp.float32),
+    "one-step-less-one-token": (8, [TOKENS - 2, 70], STEP + 8, None,
+                                jnp.float32),
+    "exactly-one-step": (8, [TOKENS - 1, 70], STEP + 8, None, jnp.float32),
+    "one-step-and-one-token": (8, [TOKENS, 70], STEP + 8, None,
+                               jnp.float32),
     "table-not-whole-blocks": (8, [10 * PAGE - 1, 9 * PAGE + 3], 10, 4,
                                jnp.float32),
     "every-row-dead": (8, [DEAD, DEAD], 6, 4, jnp.float32),
-    "derived-block": (64, [1100, 511, DEAD], 96, None, jnp.float32),
+    "derived-block": (64, [TOKENS + 76, TOKENS // 2 - 1, DEAD], STEP + 32,
+                      None, jnp.float32),
     "bf16-pool": (8, [DEAD, 100, 300], 24, None, jnp.bfloat16),
     # rows on one document: its pages copied once for a group
-    "two-rows-tails-of-two-lengths": (8, [400, 330], 40, 4, jnp.float32,
-                                      [((0, 1), 20)]),
-    "three-rows": (8, [500, 300, 420], 40, 4, jnp.float32,
-                   [((0, 1, 2), 18)]),
-    "more-rows-than-a-group": (8, [300, 310, 290, 350, 280], 24, 4,
-                               jnp.float32, [((0, 1, 2, 3, 4), 17)]),
-    "nested-prefixes": (8, [700, 660, 400], 48, 4, jnp.float32,
-                        [((0, 1, 2), 20), ((0, 1), 40)]),
-    "span-ends-inside-a-copy-block": (8, [700, 650], 48, 32, jnp.float32,
-                                      [((0, 1), 20)]),
-    "span-ends-on-a-chunk-edge": (8, [600, 530], 40, 32, jnp.float32,
-                                  [((0, 1), 32)]),
-    "a-member-ends-with-the-span": (8, [16 * PAGE, 400], 32, 4, jnp.float32,
-                                    [((0, 1), 16)]),
-    "dead-row-between-members": (8, [400, DEAD, 380], 32, 4, jnp.float32,
-                                 [((0, 2), 20)]),
-    "two-dead-rows-do-not-group": (8, [DEAD, 300, DEAD, 310], 24, 4,
-                                   jnp.float32, [((1, 3), 18)]),
-    "members-out-of-order": (8, [300, 420, 350, 290, 330], 32, 4,
-                             jnp.float32, [((4, 0, 2), 17), ((3, 1), 16)]),
+    "two-rows-tails-of-two-lengths": (
+        8, [TOKENS + 400, TOKENS + 130], STEP + 32, 4, jnp.float32,
+        [((0, 1), STEP + 6)]),
+    "three-rows": (8, [TOKENS + 500, TOKENS + 100, TOKENS + 220],
+                   STEP + 32, 4, jnp.float32, [((0, 1, 2), STEP + 2)]),
+    "more-rows-than-a-group": (
+        8, [TOKENS + n for n in (70, 80, 60, 120, 50)], STEP + 16, 4,
+        jnp.float32, [((0, 1, 2, 3, 4), STEP + 1)]),
+    "nested-prefixes": (
+        8, [2 * TOKENS + 250, 2 * TOKENS + 150, TOKENS + 280],
+        2 * STEP + 32, 4, jnp.float32,
+        [((0, 1, 2), STEP + 6), ((0, 1), 2 * STEP + 2)]),
+    "span-ends-inside-a-copy-block": (
+        8, [2 * TOKENS + 250, 2 * TOKENS + 50], 2 * STEP + 32, 2 * STEP,
+        jnp.float32, [((0, 1), STEP + 6)]),
+    "span-ends-on-a-chunk-edge": (
+        8, [2 * TOKENS + 150, 2 * TOKENS + 50], 2 * STEP + 16, 2 * STEP,
+        jnp.float32, [((0, 1), STEP)]),
+    "a-member-ends-with-the-span": (
+        8, [TOKENS, TOKENS + 400], STEP + 32, 4, jnp.float32,
+        [((0, 1), STEP)]),
+    "tail-shorter-than-a-page-after-a-shared-span": (
+        8, [TOKENS + 5, TOKENS + 11], STEP + 8, None, jnp.float32,
+        [((0, 1), STEP)]),
+    # one page short of a step: nothing is shared, each row alone
+    "shared-span-rounds-down-to-no-step": (
+        8, [TOKENS + 100, TOKENS + 40], STEP + 8, None, jnp.float32,
+        [((0, 1), STEP - 1)]),
+    "dead-row-between-members": (
+        8, [TOKENS + 400, DEAD, TOKENS + 380], STEP + 32, 4, jnp.float32,
+        [((0, 2), STEP + 6)]),
+    "two-dead-rows-do-not-group": (
+        8, [DEAD, TOKENS + 70, DEAD, TOKENS + 80], STEP + 16, 4,
+        jnp.float32, [((1, 3), STEP + 2)]),
+    "members-out-of-order": (
+        8, [TOKENS + n for n in (70, 190, 120, 60, 100)], STEP + 16, 4,
+        jnp.float32, [((4, 0, 2), STEP + 1), ((3, 1), STEP)]),
     "no-row-shares": (8, [300, 420, 350], 32, None, jnp.float32),
-    "bf16-pool-shared": (16, [DEAD, 400, 330, 600], 40, None, jnp.bfloat16,
-                         [((1, 2, 3), 20)]),
-    "derived-block-shared": (64, [1100, 900], 96, None, jnp.float32,
-                             [((0, 1), 40)]),
+    "bf16-pool-shared": (
+        16, [DEAD, TOKENS + 400, TOKENS + 130, TOKENS + 600], STEP + 40,
+        None, jnp.bfloat16, [((1, 2, 3), STEP + 6)]),
+    "derived-block-shared": (
+        64, [2 * TOKENS + 250, TOKENS + 900], 2 * STEP + 32, None,
+        jnp.float32, [((0, 1), STEP + 36)]),
 }
 
 
@@ -451,19 +483,25 @@ def kernel_case(case):
 def test_the_latent_kernel_matches_the_gather_path(case):
     """`_latent_attend_pallas` under the TPU interpreter against the
     gather fallback in float32, and against the kernel of a program a row
-    (PR 45's) bit for bit: a group's shared span is whole chunks, so every
-    row's sums are taken in the order they were."""
+    (PR 45's, at this module's step length) bit for bit: a group's shared
+    span is whole steps, so every row's sums are taken in the order they
+    are when it attends alone."""
     from latent_rowwise_kernel import rowwise_latent_attend
     heads, cached, _, block_pages, dtype = KERNEL_CASES[case][:5]
     q, pool, lengths, tables, documents = kernel_case(case)
     rows = len(cached)
-    # a row goes with the rows of the longest document it is on
-    longest = {r: d for d, (members, _) in enumerate(documents)
-               for r in members}
+    # a row goes with the rows of the longest document it is on, if that
+    # is a whole step at least
+    longest = {r: d for d, (members, leading) in enumerate(documents)
+               if leading >= STEP for r in members}
     together = [r for r, d in longest.items()
                 if list(longest.values()).count(d) > 1]
+    assert bool(together) == (
+        bool(documents) and case != "shared-span-rounds-down-to-no-step")
     schedule = la.share_schedule(tables, lengths, PAGE)
     assert sorted(schedule.order[schedule.size > 1]) == sorted(together)
+    assert set(schedule.shared[schedule.size > 1]) <= {
+        leading // STEP * STEP for _, leading in documents}
     got = la._latent_attend_pallas(
         q, pool, jnp.asarray(lengths + 1), jnp.asarray(tables),
         value_dim=VALUE, block_pages=block_pages)
@@ -529,18 +567,21 @@ def test_generation_through_the_tick_matches_the_reference_and_counts():
     assert tick["counters"]["latent_rows_attended"] > 0
 
 
-def seeded_tables(seed, rows_on, rows=12, width=40, page=64, whole=False):
+def seeded_tables(seed, rows_on, rows=12, page=64, whole=False):
     """(tables, lengths) of `rows` rows: a document for each entry of
-    `rows_on` (the rows on it), 8 to 24 pages (whole chunks if `whole`),
-    every row with a tail of its own; the rest dead; rows shuffled."""
+    `rows_on` (the rows on it), two to six compute steps of pages (whole
+    steps if `whole`), every row with a tail of its own; the rest dead;
+    rows shuffled."""
     rng = np.random.default_rng(seed)
+    step = la._chunk_pages(page)
+    width = 6 * step + 8
     free = list(1 + rng.permutation(rows * width))
     tables = np.zeros((rows, width), np.int32)
     lengths = np.zeros((rows,), np.int32)
     at = list(rng.permutation(rows))
     for n in rows_on:
-        leading = int(rng.integers(2, 7)) * 4 if whole \
-            else int(rng.integers(8, 25))
+        leading = int(rng.integers(2, 7)) * step if whole \
+            else int(rng.integers(2 * step, 6 * step + 1))
         document = [free.pop() for _ in range(leading)]
         for _ in range(n):
             r = at.pop()
@@ -565,8 +606,8 @@ def test_the_schedule_is_one_from_numpy_and_from_jax(seed):
     """What the decode program makes of its arguments on the device is
     what the engine counts from the arrays it staged; a group holds at
     most `_GROUP_ROWS`, its rows stand together behind its first, and its
-    shared span is whole chunks of pages that every member holds, the same
-    ids, and attends in full."""
+    shared span is whole compute steps of pages that every member holds,
+    the same ids, and attends in full."""
     rows_on = [[3, 2, 1, 1], [5, 1, 1], [2, 2, 2, 2, 2], [9], [1] * 7,
                [4, 4, 3]][seed]
     tables, lengths = seeded_tables(seed, rows_on)
@@ -582,7 +623,8 @@ def test_the_schedule_is_one_from_numpy_and_from_jax(seed):
         assert 1 <= size <= la._GROUP_ROWS
         assert (host.lead[lead], host.size[lead], host.shared[lead]) \
             == (lead, size, shared)
-        assert (shared > 0) == (size > 1) and shared % 4 == 0
+        assert (shared > 0) == (size > 1)
+        assert shared % la._chunk_pages(64) == 0
         assert shared <= lengths[host.order[at]] // 64
         assert np.array_equal(tables[host.order[at], :shared],
                               tables[host.order[lead], :shared])
@@ -596,7 +638,8 @@ def test_the_schedule_is_one_from_numpy_and_from_jax(seed):
     ([2, 3, 4], "all"), ([6], "some")])
 def test_the_pages_copied_lie_between_once_and_a_row(rows_on, spares):
     """No sharing: a page a row, as before. At most `_GROUP_ROWS` rows on
-    a document of whole chunks: every page once. More: once a group."""
+    a document of whole compute steps: every page once. More: once a
+    group."""
     tables, lengths = seeded_tables(len(rows_on), rows_on, whole=True)
     rowwise, distinct, copied = page_counts(tables, lengths)
     assert distinct <= copied <= rowwise
@@ -610,13 +653,15 @@ def test_the_pages_copied_lie_between_once_and_a_row(rows_on, spares):
 
 
 def test_rows_on_one_document_share_its_pages():
-    """A document of 33 pages (32 of them whole chunks of the kernel's):
-    the two rows that ask it together hold its pages once, and the schedule
-    has the kernel copy them once."""
+    """A document of one compute step of the kernel's and a page: the
+    two rows that ask it together hold its pages once, and the schedule
+    has the kernel copy the step's pages once."""
+    step = la._chunk_pages(8)
+    tokens = (step + 1) * 8
     engine = PagedLLMEngine(PagedEngineConfig(
-        model=tiny_model(), max_batch=3, max_len=320, page_size=8,
-        num_pages=160, prefill_buckets=(16, 32)))
-    document = prompt_of(31, 264).tolist()
+        model=tiny_model(), max_batch=3, max_len=tokens + 56, page_size=8,
+        num_pages=3 * step + 64, prefill_buckets=(16, 32)))
+    document = prompt_of(31, tokens).tolist()
     engine.generate([document + [3]], max_new_tokens=2)
     before = engine.stats()
     assert before["latent_pages_copied"] == before["latent_pages_rowwise"] \
@@ -627,9 +672,9 @@ def test_rows_on_one_document_share_its_pages():
     assert stats["latent_pages_rowwise"] > stats["latent_pages_distinct"]
     assert stats["latent_pages_distinct"] <= stats["latent_pages_copied"] \
         < stats["latent_pages_rowwise"]
-    # seven steps of two rows, 32 pages spared in each
+    # seven steps of two rows, a compute step's pages spared in each
     assert stats["latent_pages_rowwise"] - stats["latent_pages_copied"] \
-        == 7 * 32
+        == 7 * step
     from ray_tpu._internal import accel
     tick = next(r for r in accel.step_summary() if r["kind"] == "tick")
     assert 0 < tick["counters"]["latent_pages_copied"] \
