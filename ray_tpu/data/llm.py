@@ -27,14 +27,10 @@ def build_llm_processor(engine_config, *, concurrency: int = 1,
 
     class _EngineWorker:
         def __init__(self):
-            from ..llm.engine import EngineConfig, LLMEngine
             from ..llm.paged import PagedEngineConfig, PagedLLMEngine
-            if isinstance(engine_config, PagedEngineConfig):
-                self.engine = PagedLLMEngine(engine_config, params=params)
-            elif isinstance(engine_config, EngineConfig):
-                self.engine = LLMEngine(engine_config, params=params)
-            else:
+            if not isinstance(engine_config, PagedEngineConfig):
                 raise TypeError(type(engine_config).__name__)
+            self.engine = PagedLLMEngine(engine_config, params=params)
 
         def __call__(self, batch: Dict[str, Any]) -> Dict[str, Any]:
             import numpy as np

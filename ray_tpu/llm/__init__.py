@@ -5,19 +5,19 @@ serve/llm/__init__.py:92 build_llm_deployment / :168 build_openai_app).
 
 The reference delegates the engine to vLLM (CUDA); no such engine exists
 for TPU, so this package IS the engine (SURVEY §7 step 8): a
-continuous-batching decode loop over slot-structured KV caches, jitted
-once per shape bucket, deployed behind ray_tpu.serve.
+continuous-batching decode loop over paged KV caches (pages shared
+through a radix tree of prefixes, prefill in chunks jitted once per
+bucket, one jitted decode step for the whole batch), deployed behind
+ray_tpu.serve.
 
-Exports resolve lazily (PEP 562): the engines pull in jax at import
+Exports resolve lazily (PEP 562): the engine pulls in jax at import
 time, but jax-free processes — the serve proxy stamping request-trace
 events, the dashboard folding `reqtrace` payloads — must be able to
 import this package (and its light submodules) without paying the jax
 import."""
 
 _EXPORTS = {
-    "EngineConfig": ".engine",
-    "GenerationRequest": ".engine",
-    "LLMEngine": ".engine",
+    "GenerationRequest": ".paged",
     "PagedEngineConfig": ".paged",
     "PagedLLMEngine": ".paged",
     "LLMServer": ".serving",

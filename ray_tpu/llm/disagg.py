@@ -49,8 +49,6 @@ class PDDecodeServer(LLMServer):
 
     def __init__(self, engine_config, params=None, prefill_handle=None):
         super().__init__(engine_config, params=params)
-        if not self._paged:
-            raise TypeError("PD-disagg requires the paged engine")
         if prefill_handle is None:
             raise ValueError("PDDecodeServer needs a prefill_handle")
         self._prefill_handle = prefill_handle

@@ -5,10 +5,10 @@ the prefix-aware machinery in serve/request_router/; re-designed
 TPU-native: page pools in the Pallas paged-attention kernel's layout,
 one jitted decode step for the whole active batch).
 
-vs the slot engine (`engine.py`): HBM scales with tokens-in-flight
-(`num_pages x page_size`), not `max_batch x max_len`; full prompt pages
-shared byte-identically across requests via a prefix hash (system
-prompts stored once); admission blocks on page budget, not slot shape.
+HBM scales with tokens-in-flight (`num_pages x page_size`), not
+`max_batch x max_len`; full prompt pages shared byte-identically across
+requests via a prefix hash (system prompts stored once); admission
+blocks on page budget, not on a row's shape.
 
 Scheduling is continuous (iteration-level): every tick fills
 freed slots from the waiting queue, advances at most
@@ -51,7 +51,6 @@ from ..ops.latent_attention import (latent_kernel, pages_spared,
 from ..ops.paged_attention import paged_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
-from .engine import GenerationRequest
 from .radix import RadixPrefixCache
 from .sampling import SAMPLER_TIERS, sample_tokens, sampler_tier
 
@@ -64,6 +63,23 @@ if TYPE_CHECKING:
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
 _GAUGE_TAGS = {"engine": "paged", "pid": str(os.getpid())}
+
+
+@dataclasses.dataclass
+class GenerationRequest:
+    prompt_tokens: List[int]
+    max_new_tokens: int = 32
+    request_id: str = ""
+    temperature: Optional[float] = None
+    # 0/None = no k filter; 1.0/None = no nucleus filter (vLLM-style
+    # SamplingParams; applied inside the jitted decode, sampling.py)
+    top_k: Optional[int] = None
+    top_p: Optional[float] = None
+    # request-observatory labels: propagated by the serve proxy
+    # (X-RTPU-Tenant, matched route prefix) down to the engine and
+    # folded into per-tenant/per-route percentiles (llm/reqtrace.py)
+    tenant: Optional[str] = None
+    route: Optional[str] = None
 
 
 @dataclasses.dataclass
@@ -304,8 +320,8 @@ class _Seq:
 
 
 class PagedLLMEngine:
-    """Same external surface as LLMEngine (submit/step/generate/stats)
-    plus cancel() and per-token streaming callbacks.
+    """The serve path's engine: submit/step/generate/stats, cancel() and
+    per-token streaming callbacks.
 
     Tensor parallelism: pass `mesh` (a jax Mesh with a `tensor` axis) and
     params + KV pages are sharded over it — params by their flax logical

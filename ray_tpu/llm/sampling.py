@@ -98,25 +98,3 @@ def sample_tokens(rng, logits, temperature, top_k, top_p):
     return jax.lax.switch(sampler_tier(temperature, top_k, top_p),
                           (_greedy, _plain, _filtered),
                           rng, logits, temperature, top_k, top_p)
-
-
-def filter_logits(logits, top_k=0, top_p=None):
-    """Host-side (numpy) mirror of sample_tokens' top-k/top-p filters —
-    the single implementation both engines' prefill first-token sampling
-    uses, so host and jit paths stay in lockstep. top_p <= 0 keeps the
-    top token (never an empty nucleus)."""
-    import numpy as np
-    scaled = np.asarray(logits, np.float64)
-    sorted_desc = np.sort(scaled)[::-1]
-    thresh = -np.inf
-    if top_k and top_k > 0:
-        thresh = max(thresh,
-                     sorted_desc[min(int(top_k), len(sorted_desc)) - 1])
-    if top_p is not None and top_p < 1.0:
-        p = max(float(top_p), 1e-6)
-        sp = np.exp(sorted_desc - sorted_desc.max())
-        sp /= sp.sum()
-        cum_before = np.cumsum(sp) - sp
-        nucleus = sorted_desc[cum_before < p]  # cum_before[0]=0 < p
-        thresh = max(thresh, nucleus[-1])
-    return np.where(scaled >= thresh, scaled, -1e30)

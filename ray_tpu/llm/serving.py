@@ -76,38 +76,25 @@ class _Stream:
 class LLMServer:
     """The replica callable (wrapped by serve.deployment).
 
-    `engine_config` picks the engine: a `PagedEngineConfig` runs the
-    paged-KV continuous-batching engine (the default TPU serving path —
-    prefix page sharing, chunked prefill to max_len); an `EngineConfig`
-    runs the static-slot engine.
+    `engine_config` is a `PagedEngineConfig`: the replica runs the
+    paged-KV continuous-batching engine (prefix page sharing, chunked
+    prefill to max_len, streaming, cancel).
 
     `mesh_config` (a `parallel.MeshConfig`, e.g. tensor=4) shards the
-    paged engine's params + KV pages over the replica's chips — the
+    engine's params + KV pages over the replica's chips — the
     tensor-parallel analog of the reference's TP×PP engine-worker
     bundles (vllm_models.py:169-178,251)."""
 
     def __init__(self, engine_config, params=None, mesh_config=None):
-        from .engine import EngineConfig, LLMEngine
         from .paged import PagedEngineConfig, PagedLLMEngine
-        mesh = None
-        if mesh_config is not None:
-            if not isinstance(engine_config, PagedEngineConfig):
-                raise ValueError(
-                    "mesh_config requires the paged engine "
-                    "(PagedEngineConfig) — the static-slot engine does "
-                    "not shard")
-            mesh = self._build_mesh(mesh_config)
-        if isinstance(engine_config, PagedEngineConfig):
-            self._engine = PagedLLMEngine(engine_config, params=params,
-                                          mesh=mesh)
-            self._paged = True
-        elif isinstance(engine_config, EngineConfig):
-            self._engine = LLMEngine(engine_config, params=params)
-            self._paged = False
-        else:
+        if not isinstance(engine_config, PagedEngineConfig):
             raise TypeError(
-                f"engine_config must be PagedEngineConfig or EngineConfig, "
+                f"engine_config must be a PagedEngineConfig, "
                 f"got {type(engine_config).__name__}")
+        mesh = None if mesh_config is None \
+            else self._build_mesh(mesh_config)
+        self._engine = PagedLLMEngine(engine_config, params=params,
+                                      mesh=mesh)
         self._loop_task: Optional[asyncio.Task] = None
         self._wake: Optional[asyncio.Event] = None
         self._streams: Dict[str, _Stream] = {}
@@ -205,11 +192,8 @@ class LLMServer:
         # async so subclasses can do remote work first (PD-disagg fetches
         # the prefilled KV from the prefill deployment here)
         self._ensure_loop()
-        if self._paged:
-            self._engine.submit(request, done_callback=done_callback,
-                                token_callback=token_callback)
-        else:
-            self._engine.submit(request, done_callback=done_callback)
+        self._engine.submit(request, done_callback=done_callback,
+                            token_callback=token_callback)
         self._wake.set()
 
     # -- one-shot generation ----------------------------------------------
@@ -222,7 +206,7 @@ class LLMServer:
                        request_id: Optional[str] = None,
                        tenant: Optional[str] = None,
                        route: Optional[str] = None) -> Dict[str, Any]:
-        from .engine import GenerationRequest
+        from .paged import GenerationRequest
         loop = asyncio.get_running_loop()
         future = loop.create_future()
 
@@ -288,9 +272,7 @@ class LLMServer:
             route: Optional[str] = None) -> str:
         """Begin a streamed generation; returns a stream id the caller
         polls with `stream_next` (the proxy relays it as chunked HTTP)."""
-        from .engine import GenerationRequest
-        if not self._paged:
-            raise RuntimeError("streaming requires the paged engine")
+        from .paged import GenerationRequest
         loop = asyncio.get_running_loop()
         request_id = request_id or self._context_request_id() \
             or uuid.uuid4().hex
@@ -369,9 +351,7 @@ class LLMServer:
         return await self.cancel(stream.request_id)
 
     async def cancel(self, request_id: str) -> bool:
-        """Abort a running or queued request (paged engine only)."""
-        if not self._paged:
-            return False
+        """Abort a running or queued request."""
         ok = self._engine.cancel(request_id)
         if self._wake is not None:
             self._wake.set()
@@ -408,10 +388,10 @@ class LLMServer:
     async def device_report(self) -> Dict[str, Any]:
         """What this replica really runs on, read in its own process:
         the backend's platform, device kind and count, each device's
-        `memory_stats()`, the accel plane's compile and step folds, and
-        (paged engine) the Pallas kernels found in the compiled decode
-        step and the whole-pool copies in it (page pools, recurrent-state
-        pools: both 0). The caller — a driver that must stay
+        `memory_stats()`, the accel plane's compile and step folds, the
+        Pallas kernels found in the compiled decode step and the
+        whole-pool copies in it (page pools, recurrent-state pools: both
+        0). The caller — a driver that must stay
         off JAX — learns from this whether a chip lease became a chip."""
         def probe():
             import os
@@ -428,12 +408,10 @@ class LLMServer:
                 "compile": accel.compile_summary(),
                 "steps": accel.step_summary(),
             }
-            if self._paged:
-                text = self._engine.decode_program_text()
-                report["decode_kernels"] = pallas_kernels(text)
-                report["decode_pool_copies"] = self._engine.pool_copies(text)
-                report["decode_state_copies"] = \
-                    self._engine.state_copies(text)
+            text = self._engine.decode_program_text()
+            report["decode_kernels"] = pallas_kernels(text)
+            report["decode_pool_copies"] = self._engine.pool_copies(text)
+            report["decode_state_copies"] = self._engine.state_copies(text)
             return report
         # off-loop: the probe compiles, and a blocked loop fails the
         # replica's health check
@@ -444,10 +422,7 @@ class LLMServer:
         """Replica autoscaling hook (replica.get_metrics() folds this
         into the controller's closed loop): the engine's waiting-queue
         depth, median TTFT, and KV page occupancy."""
-        hook = getattr(self._engine, "autoscaling_metrics", None)
-        if hook is None:
-            return {}
-        return dict(hook())
+        return dict(self._engine.autoscaling_metrics())
 
 
 def build_llm_deployment(engine_config, *, name: str = "LLMServer",
@@ -455,7 +430,8 @@ def build_llm_deployment(engine_config, *, name: str = "LLMServer",
                          max_ongoing_requests: int = 64,
                          mesh_config=None,
                          ray_actor_options: Optional[Dict[str, Any]] = None):
-    """Serve application for the engine
+    """Serve application for the paged engine: `engine_config` is a
+    `PagedEngineConfig`
     (reference: serve/llm/__init__.py:92 build_llm_deployment).
     `ray_actor_options={"num_tpus": n}` gives each replica n chips: its
     worker then opens the TPU backend or dies with the backend's error."""

@@ -324,23 +324,14 @@ class Attention(nn.Module):
             new_cache = dict(kv_cache, k=kp, v=vp)
             out = out1[:, :, None, :].astype(cfg.dtype)
         elif kv_cache is not None:
-            # Decode: write new K/V at cache_index, attend over the cache.
-            # cache_index may be a scalar (whole batch at one position —
-            # single-sequence decode / prefill) or a [batch] vector (each
-            # slot at its own position — continuous batching, where the
-            # write is a per-row one-hot blend; q_len is 1 there).
+            # Dense cache: write new K/V at cache_index (a scalar: the
+            # whole batch at one position — a prefill chunk, or a
+            # single-sequence decode), attend over the cache.
             ck, cv = kv_cache
-            idx = jnp.asarray(cache_index)
-            if idx.ndim == 0:
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    ck, k.astype(ck.dtype), cache_index, axis=2)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cv, v.astype(cv.dtype), cache_index, axis=2)
-            else:
-                onehot = jax.nn.one_hot(idx, ck.shape[2],
-                                        dtype=ck.dtype)[:, None, :, None]
-                ck = ck * (1 - onehot) + k.astype(ck.dtype) * onehot
-                cv = cv * (1 - onehot) + v.astype(cv.dtype) * onehot
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                ck, k.astype(ck.dtype), cache_index, axis=2)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cv, v.astype(cv.dtype), cache_index, axis=2)
             new_cache = (ck, cv)
             groups = cfg.num_heads // cfg.num_kv_heads
             kk = jnp.repeat(ck, groups, axis=1)

@@ -68,18 +68,34 @@ def ray_start_cluster():
     cluster.shutdown()
 
 
-@pytest.fixture
-def llm_cluster():
-    """Cluster for LLM serving tests (serve shut down before the node)."""
+def _serve_cluster(num_cpus, object_store_mb):
+    """A one-node cluster with serve started on a port the kernel picks:
+    xdist runs several serve test files side by side, and the product's
+    default (127.0.0.1:8000) is one port for all of them. Tests ask
+    `serve.get_http_address()` for what the proxy bound. Serve is shut
+    down before the node."""
     import ray_tpu
-    ray_tpu.init(num_cpus=4, object_store_memory=300 * 1024 * 1024)
+    from ray_tpu import serve
+    ray_tpu.init(num_cpus=num_cpus,
+                 object_store_memory=object_store_mb * 1024 * 1024)
+    serve.start(serve.HTTPOptions(port=0))
     yield
     try:
-        from ray_tpu import serve
         serve.shutdown()
     except Exception:
         pass
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def serve_cluster():
+    yield from _serve_cluster(8, 200)
+
+
+@pytest.fixture
+def llm_cluster():
+    """Cluster for LLM serving tests."""
+    yield from _serve_cluster(4, 300)
 
 
 def raw_http(host, port, method, path, body):

@@ -17,9 +17,8 @@ import numpy as np
 import pytest
 
 from ray_tpu._internal.config import CONFIG
-from ray_tpu.llm import (EngineConfig, GenerationRequest, LLMEngine,
-                         PagedEngineConfig, PagedLLMEngine,
-                         RadixPrefixCache)
+from ray_tpu.llm import (GenerationRequest, PagedEngineConfig,
+                         PagedLLMEngine, RadixPrefixCache)
 from ray_tpu.llm.paged import PagePool
 from ray_tpu.models.llama import LlamaConfig
 
@@ -196,14 +195,10 @@ def test_radix_insert_idempotent_refcounts():
 
 
 @pytest.fixture(scope="module")
-def cb_engines():
-    model = tiny_model()
-    slot = LLMEngine(EngineConfig(model=model, max_batch=4, max_len=128,
-                                  prefill_buckets=(16, 32, 64)))
-    paged = PagedLLMEngine(PagedEngineConfig(
-        model=model, max_batch=4, max_len=128, page_size=8, num_pages=128,
-        prefill_buckets=(16, 32, 64)), params=slot.params)
-    return slot, paged
+def paged():
+    return PagedLLMEngine(PagedEngineConfig(
+        model=tiny_model(), max_batch=4, max_len=128, page_size=8,
+        num_pages=128, prefill_buckets=(16, 32, 64)))
 
 
 def _submit_all(engine, prompts, max_new, results, token_cb=None):
@@ -217,11 +212,10 @@ def _submit_all(engine, prompts, max_new, results, token_cb=None):
         engine.submit(req, done_callback=on_done, token_callback=token_cb)
 
 
-def test_per_tick_admission_fills_freed_slots(cb_engines):
+def test_per_tick_admission_fills_freed_slots(paged):
     """Admission is per decode tick: the engine never runs more than
     max_batch, later requests join as earlier ones finish WITHIN one
     drain, and the batch is never starved below min(waiting, slots)."""
-    _slot, paged = cb_engines
     rng = np.random.RandomState(1)
     prompts = [list(rng.randint(1, 128, size=rng.randint(4, 12)))
                for _ in range(10)]
@@ -243,11 +237,10 @@ def test_per_tick_admission_fills_freed_slots(cb_engines):
     assert paged.stats()["pending"] == 0
 
 
-def test_prefill_interleaves_with_decode(cb_engines):
+def test_prefill_interleaves_with_decode(paged):
     """A long prompt admitted mid-decode prefills one chunk per tick
     (prefill_decode_ratio=1) while the running sequence keeps
     generating — no decode stall for the whole prefill."""
-    _slot, paged = cb_engines
     results = {}
     rng = np.random.RandomState(2)
     _submit_all(paged, [list(rng.randint(1, 128, size=6))], 24, results)
@@ -326,12 +319,11 @@ def test_preempted_stream_replays_no_duplicate_tokens():
     assert engine.page_leak_check() == 0
 
 
-def test_cancel_mid_decode_and_mid_prefill_page_balance(cb_engines):
+def test_cancel_mid_decode_and_mid_prefill_page_balance(paged):
     """Cancelling a sequence mid-decode AND one mid-chunked-prefill
     returns every page (including gathered shared-prefix refs) — the
     pool ledger stays balanced (PR 17 satellite: the old release path
     only handled decode-phase slots)."""
-    _slot, paged = cb_engines
     rng = np.random.RandomState(5)
     results = {}
     _submit_all(paged, [list(rng.randint(1, 128, size=10))], 40, results)
@@ -355,10 +347,9 @@ def test_cancel_mid_decode_and_mid_prefill_page_balance(cb_engines):
     assert all(s.request is None for s in paged.seqs)
 
 
-def test_cancel_parked_request(cb_engines):
+def test_cancel_parked_request(paged):
     """A request parked by admission pressure (or still queued) cancels
     cleanly without ever owning pages."""
-    _slot, paged = cb_engines
     rng = np.random.RandomState(6)
     results = {}
     prompts = [list(rng.randint(1, 128, size=6)) for _ in range(6)]
@@ -378,10 +369,9 @@ def test_cancel_parked_request(cb_engines):
     assert paged.page_leak_check() == 0
 
 
-def test_fail_all_releases_every_phase(cb_engines):
+def test_fail_all_releases_every_phase(paged):
     """fail_all mid-flight (decoding + prefilling + parked) errors every
     callback and frees every page."""
-    _slot, paged = cb_engines
     rng = np.random.RandomState(7)
     results = {}
     prompts = [list(rng.randint(1, 128, size=6)) for _ in range(4)]
@@ -398,11 +388,10 @@ def test_fail_all_releases_every_phase(cb_engines):
 
 
 @pytest.mark.parametrize("case", ["one_token", "eos_first", "resumed"])
-def test_finish_rule_holds_for_the_first_token(cb_engines, case):
+def test_finish_rule_holds_for_the_first_token(paged, case):
     """EOS, `max_new_tokens` and `max_len` end a sequence on the token
     the prefill emits as on any token of a decode tick, for a fresh
     sequence as for one resumed after a preemption."""
-    _slot, paged = cb_engines
     rng = np.random.RandomState(11)
     prompts = [list(rng.randint(1, 128, size=n)) for n in (5, 19, 33)]
     want = paged.generate(prompts, max_new_tokens=5)
@@ -794,8 +783,7 @@ def test_continuous_metrics_exposition():
 # ---------------------------------------------------------------------------
 
 
-def test_engine_autoscaling_metrics(cb_engines):
-    _slot, paged = cb_engines
+def test_engine_autoscaling_metrics(paged):
     metrics = paged.autoscaling_metrics()
     assert set(metrics) >= {"queued", "kv_occupancy"}
     assert metrics["queued"] == 0

@@ -8,6 +8,7 @@ eight prediction heads, unit-offset norms, W / C = 8 with a window of two
 of the largest bucket and two pages of summaries."""
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -17,7 +18,8 @@ import pytest
 
 from benchmarks.harness import parity_evabyte
 from benchmarks.reference import evabyte_ref
-from ray_tpu.llm.engine import GenerationRequest
+from plain_greedy import plain_greedy
+from ray_tpu.llm import GenerationRequest
 from ray_tpu.llm.paged import PagedEngineConfig, PagedLLMEngine
 from ray_tpu.models.evabyte import EvaByteConfig
 
@@ -61,16 +63,10 @@ def _plain_logits(params, tokens):
 
 
 def _plain_greedy(engine, prompt, new):
-    """The whole-sequence model, nothing cached, token by token (one
-    compiled length: the model is causal, so what is padded behind a
-    position does not reach it)."""
-    tokens = list(prompt)
-    for _ in range(new):
-        padded = np.zeros((1, PLAIN_LENGTH), np.int32)
-        padded[0, :len(tokens)] = tokens
-        logits = _plain_logits(engine.params, padded)
-        tokens.append(int(logits[0, len(tokens) - 1].argmax()))
-    return tokens[len(prompt):]
+    """The whole-sequence model, nothing cached, token by token, at one
+    length for every call of the file (one compile)."""
+    return plain_greedy(functools.partial(_plain_logits, engine.params),
+                        [prompt], new, length=PLAIN_LENGTH)[0]
 
 
 # -- the model against the reference ------------------------------------------

@@ -19,8 +19,9 @@ from benchmarks.harness.builders_falcon_h1 import falcon_h1_model  # noqa: E402
 from benchmarks.harness.parity_falcon_h1 import (engine_logits,  # noqa: E402
                                                  state_errors)
 from benchmarks.reference import falcon_h1_ref  # noqa: E402
+from plain_greedy import plain_greedy, rowwise  # noqa: E402
 from ray_tpu.llm import reqtrace  # noqa: E402
-from ray_tpu.llm.engine import GenerationRequest  # noqa: E402
+from ray_tpu.llm import GenerationRequest  # noqa: E402
 from ray_tpu.llm.paged import PagedEngineConfig, PagedLLMEngine  # noqa: E402
 from ray_tpu.models.falcon_h1 import FalconH1Config  # noqa: E402
 from ray_tpu.ops.ssm import ssd_chunked_scan, ssm_step  # noqa: E402
@@ -200,10 +201,8 @@ def _generate_alone(engine, prompt, max_new):
 
 
 def _reference_greedy(params, prompt, max_new):
-    seq = list(prompt)
-    for _ in range(max_new):
-        seq.append(int(reference_logits(params, seq)[-1].argmax()))
-    return seq[len(prompt):]
+    return plain_greedy(rowwise(lambda row: reference_logits(params, row)),
+                        [prompt], max_new)[0]
 
 
 def test_requests_admitted_mid_decode_do_not_disturb_each_other(engine):

@@ -446,7 +446,8 @@ def test_rolling_restart_e2e(tmp_path):
                 "scheduling_strategy": NodeAffinitySchedulingStrategy(
                     head_id, soft=True)})
         serve.run(streamer.bind(chunks, 0.3), name="soak",
-                  route_prefix="/soak")
+                  route_prefix="/soak",
+                  http_options=serve.HTTPOptions(port=0))
         addr = serve.api.get_http_address()
         host, port = addr.rsplit("://", 1)[-1].rsplit(":", 1)
 

@@ -1,4 +1,4 @@
-"""Paged-KV engine: equivalence vs the slot engine, page-bounded HBM,
+"""Paged-KV engine: equivalence vs the plain forward, page-bounded HBM,
 prefix sharing, and continuous-batching behavior under pressure
 (VERDICT r2 item 5; reference: vLLM PagedAttention as delegated by
 llm/_internal/serve/deployments/llm/vllm/, prefix reuse a la
@@ -10,8 +10,9 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from ray_tpu.llm import (EngineConfig, GenerationRequest, LLMEngine,
-                         PagedEngineConfig, PagedLLMEngine)
+from plain_greedy import model_forward, plain_greedy
+from ray_tpu.llm import (GenerationRequest, PagedEngineConfig,
+                         PagedLLMEngine)
 from ray_tpu.models.llama import LlamaConfig
 
 
@@ -23,46 +24,40 @@ def tiny_model():
 
 
 @pytest.fixture(scope="module")
-def engines():
-    model = tiny_model()
-    slot = LLMEngine(EngineConfig(model=model, max_batch=4, max_len=128,
-                                  prefill_buckets=(16, 32, 64)))
-    paged = PagedLLMEngine(PagedEngineConfig(
-        model=model, max_batch=4, max_len=128, page_size=8, num_pages=128,
-        prefill_buckets=(16, 32, 64)), params=slot.params)
-    return slot, paged
+def paged():
+    return PagedLLMEngine(PagedEngineConfig(
+        model=tiny_model(), max_batch=4, max_len=128, page_size=8,
+        num_pages=128, prefill_buckets=(16, 32, 64)))
 
 
 @pytest.mark.parametrize("seed,n_prompts,max_new", [
     (0, 16, 12),  # 4x max_batch of 4
     (8, 12, 10),  # the mix the legacy scheduler was held to (PR 17)
 ])
-def test_greedy_equivalence_under_load(engines, seed, n_prompts, max_new):
-    """Identical outputs vs the slot engine with queue depth 3-4x
+def test_greedy_equivalence_under_load(paged, seed, n_prompts, max_new):
+    """Identical outputs vs the no-cache forward with queue depth 3-4x
     max_batch (the VERDICT's acceptance bar)."""
-    slot, paged = engines
     rng = np.random.RandomState(seed)
     prompts = [list(rng.randint(1, 128, size=rng.randint(4, 30)))
                for _ in range(n_prompts)]
-    out_slot = slot.generate(prompts, max_new_tokens=max_new)
-    out_paged = paged.generate(prompts, max_new_tokens=max_new)
-    assert out_slot == out_paged
+    want = plain_greedy(model_forward(paged.model, paged.params), prompts,
+                        max_new)
+    assert paged.generate(prompts, max_new_tokens=max_new) == want
 
 
 def test_hbm_scales_with_pages_not_max_len():
     """Pool bytes are num_pages x page_size, independent of
-    max_len x max_batch (the slot engine's footprint)."""
+    max_len x max_batch (a dense cache's footprint: a row a request, each
+    max_len long)."""
     model = tiny_model()
     paged = PagedLLMEngine(PagedEngineConfig(
         model=model, max_batch=8, max_len=128, page_size=8, num_pages=32,
         prefill_buckets=(16,)))
-    slot = LLMEngine(EngineConfig(model=model, max_batch=8, max_len=128,
-                                  prefill_buckets=(16,)))
     paged_bytes = paged.stats()["hbm_cache_bytes"]
-    ck, _cv = slot.kv_caches[0]
-    slot_bytes = 2 * len(slot.kv_caches) * ck.size * ck.dtype.itemsize
-    # 32 pages x 8 tokens = 256 cached tokens vs 8 slots x 128 = 1024
-    assert paged_bytes * 3 < slot_bytes
+    dense_bytes = 2 * model.num_layers * 8 * model.num_kv_heads * 128 \
+        * model.head_dim_ * np.dtype(model.dtype).itemsize
+    # 32 pages x 8 tokens = 256 cached tokens vs 8 rows x 128 = 1024
+    assert paged_bytes * 3 < dense_bytes
     # and the engine still completes work under that budget
     out = paged.generate([[1, 2, 3, 4]] * 12, max_new_tokens=4)
     assert len(out) == 12
@@ -173,8 +168,7 @@ def test_prefix_cache_metrics_exposition():
             f'pid="{os.getpid()}"}}') in text
 
 
-def test_streaming_and_cancellation(engines):
-    _slot, paged = engines
+def test_streaming_and_cancellation(paged):
     streamed = []
     done = []
 
@@ -335,14 +329,13 @@ def test_paged_branch_writes_its_k_and_v_rows(kv_heads):
         assert (got[:, 7, 0] != pools[name][:, 7, 0]).all()
 
 
-def test_decode_program_scatters_by_head_page_offset(engines):
+def test_decode_program_scatters_by_head_page_offset(paged):
     """The engine's decode step, lowered from shapes: every scatter into
     a page pool indexes kv head, page and offset, so its window is
     head_dim alone. Indexed by (page, offset) with the kv heads in the
     window, XLA:TPU relays every layer's whole pool out and back around
     the scatter each tick (PERF.md, PR 29)."""
     import re
-    _slot, paged = engines
     text = paged.lower_decode().as_text()
     pool = "x".join(map(str, paged.k_pages[0].shape)) + "xbf16"
     scatters = re.findall(
@@ -357,14 +350,13 @@ def test_decode_program_scatters_by_head_page_offset(engines):
 
 @pytest.mark.parametrize("what", ["token_vector", "pool_copies",
                                   "first_token"])
-def test_decode_program_runs_one_step_ahead(engines, what):
+def test_decode_program_runs_one_step_ahead(paged, what):
     """The decode step of PR 33: its sampled tokens are a [rows] int32
     vector that is the next call's token input as it stands (no trip
     through the host), the pools are still donated and copied nowhere,
     and a prompt's first token is written into that vector on the
     device."""
     import re
-    _slot, paged = engines
     rows = paged.config.max_batch
     lowered = paged.lower_decode()
     if what == "token_vector":

@@ -75,7 +75,7 @@ def _tiny_dense_engine():
 def test_paged_engine_top_k_one_matches_greedy():
     """End-to-end: the paged engine with temperature>0 but top_k=1 must
     reproduce the greedy generation exactly."""
-    from ray_tpu.llm.engine import GenerationRequest
+    from ray_tpu.llm import GenerationRequest
 
     engine = _tiny_dense_engine()
     prompt = [3, 14, 15, 9, 2, 6]
@@ -102,15 +102,9 @@ def test_paged_engine_top_k_one_matches_greedy():
 
 
 def test_top_p_zero_keeps_top_token():
-    """top_p<=0 must behave like top-1, never crash or go uniform —
-    both in the jitted sampler and the host-side filter."""
-    from ray_tpu.llm.sampling import filter_logits
-
+    """top_p<=0 must behave like top-1, never crash or go uniform."""
     row = np.asarray([1.0, 3.0, 2.0, -1.0])
     assert set(_sample_many(row, 2.0, 0, 0.0)) == {1}
-    filtered = filter_logits(row, top_k=0, top_p=0.0)
-    assert np.argmax(filtered) == 1
-    assert np.sum(filtered > -1e29) == 1
 
 
 def reference_sample_tokens(rng, logits, temperature, top_k, top_p):
@@ -236,7 +230,7 @@ def test_engine_counts_the_tier_of_every_dispatched_step(make_engine):
     filtered once a `top_p = 0.9` request is live; and the `tick` row's
     counters say the same."""
     from ray_tpu._internal import accel
-    from ray_tpu.llm.engine import GenerationRequest
+    from ray_tpu.llm import GenerationRequest
 
     engine = make_engine()
     prompt = [3, 14, 15, 9, 2, 6]

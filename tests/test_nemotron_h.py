@@ -21,7 +21,8 @@ from benchmarks.harness.builders_nemotron_h import (  # noqa: E402
     nemotron_h_model)
 from benchmarks.harness.parity_falcon_h1 import state_errors  # noqa: E402
 from benchmarks.reference import nemotron_h_ref  # noqa: E402
-from ray_tpu.llm.engine import GenerationRequest  # noqa: E402
+from plain_greedy import plain_greedy, rowwise  # noqa: E402
+from ray_tpu.llm import GenerationRequest  # noqa: E402
 from ray_tpu.llm.paged import (PagedEngineConfig, PagedLLMEngine,  # noqa: E402
                                pool_copies)
 from ray_tpu.models import moe  # noqa: E402
@@ -297,13 +298,11 @@ def test_the_engine_keeps_a_pool_per_layer_of_its_kind(engine):
     assert engine.k_pages[0].shape == (1, cfg.num_pages, cfg.page_size, 8)
 
 
-def _reference_greedy(params, model_cfg, prompt, max_new):
-    tokens = list(prompt)
-    for _ in range(max_new):
-        logits = nemotron_h_ref.logits(params, np.asarray(tokens),
-                                       keys_of(model_cfg))
-        tokens.append(int(np.asarray(logits[-1]).argmax()))
-    return tokens[len(prompt):]
+def _reference_greedy(params, model_cfg, prompts, max_new):
+    keys = keys_of(model_cfg)
+    return plain_greedy(
+        rowwise(lambda row: nemotron_h_ref.logits(params, row, keys)),
+        prompts, max_new)
 
 
 def test_generation_through_the_tick_matches_the_reference_and_counts(
@@ -317,10 +316,8 @@ def test_generation_through_the_tick_matches_the_reference_and_counts(
     before = engine.stats()
     prompts = [prompt_of(20 + i, n).tolist()
                for i, n in enumerate((9, 33, 17, 40, 5))]
-    got = engine.generate(prompts, max_new_tokens=6)
-    for prompt, tokens in zip(prompts, got):
-        assert tokens == _reference_greedy(engine.params,
-                                           engine.config.model, prompt, 6)
+    assert engine.generate(prompts, max_new_tokens=6) == _reference_greedy(
+        engine.params, engine.config.model, prompts, 6)
     after = engine.stats()
     assert after["leaked_pages"] == 0
     assert after["state_installs"] - before["state_installs"] == 5

@@ -426,6 +426,7 @@ def test_llm_serving_flight_recorder(tmp_path, monkeypatch, capsys):
                                 prefill_buckets=(8, 16))
         app = build_llm_deployment(cfg)
         handle = serve.run(app, name="llm", route_prefix="/llm",
+                           http_options=serve.HTTPOptions(port=0),
                            wait_for_ready_timeout_s=240)
 
         # One normal task too, so the timeline has a LEASED phase row.

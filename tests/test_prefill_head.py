@@ -1,7 +1,7 @@
 """A prefill chunk applies the head to the one row a prompt's finish needs
 (PR 40): `chunk_prefill(..., last)` against the all-positions form it
 replaced in the tick, for the three model families the engine runs, and
-the engine's greedy outputs against the plain `LLMEngine`'s. Float32 on the
+the engine's greedy outputs against the plain model's. Float32 on the
 CPU: the two forms share every line in front of the head, and a row of a
 product is summed in the order the whole product sums it."""
 
@@ -13,8 +13,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm import (EngineConfig, LLMEngine, PagedEngineConfig,
-                         PagedLLMEngine)
+from plain_greedy import model_forward, plain_greedy
+from ray_tpu.llm import PagedEngineConfig, PagedLLMEngine
 from ray_tpu.llm.paged import array_shapes
 from ray_tpu.models.llama import LlamaConfig
 
@@ -28,10 +28,10 @@ def tiny_llama(tie_embeddings=False) -> LlamaConfig:
         tie_embeddings=tie_embeddings)
 
 
-def llama_engine(tie_embeddings=False, params=None) -> PagedLLMEngine:
+def llama_engine(tie_embeddings=False) -> PagedLLMEngine:
     return PagedLLMEngine(PagedEngineConfig(
         model=tiny_llama(tie_embeddings), max_batch=3, max_len=160,
-        page_size=8, num_pages=96, prefill_buckets=(16, 32)), params=params)
+        page_size=8, num_pages=96, prefill_buckets=(16, 32)))
 
 
 def falcon_engine() -> PagedLLMEngine:
@@ -138,25 +138,21 @@ PROMPTS = {"one_chunk": 11, "whole_buckets": 64, "padded_tail": 77}
 
 
 @pytest.fixture(scope="module")
-def dense_pair():
-    slot = LLMEngine(EngineConfig(model=tiny_llama(), max_batch=3,
-                                  max_len=160,
-                                  prefill_buckets=(16, 32, 64, 128)))
-    return slot, llama_engine(params=slot.params)
+def paged():
+    return llama_engine()
 
 
 @pytest.mark.parametrize("kind", sorted(PROMPTS))
-def test_greedy_outputs_equal_the_plain_engines(dense_pair, kind):
+def test_greedy_outputs_equal_the_plain_model(paged, kind):
     """A prompt of one chunk, one that is an exact multiple of the largest
     bucket (its last row is its last chunk's last), and one of several
-    chunks with a padded tail: the tokens the plain `LLMEngine` gives."""
-    slot, paged = dense_pair
+    chunks with a padded tail: the tokens the no-cache forward gives."""
     length = PROMPTS[kind]
     prompts = [np.random.default_rng(seed).integers(
         1, 256, size=length).tolist() for seed in (length, length + 1)]
     before = paged.stats()
-    assert paged.generate(prompts, max_new_tokens=6) \
-        == slot.generate(prompts, max_new_tokens=6)
+    assert paged.generate(prompts, max_new_tokens=6) == plain_greedy(
+        model_forward(paged.model, paged.params), prompts, 6)
     after = paged.stats()
     chunks = -(-length // 32) * len(prompts)
     assert after["prefill_chunks"] - before["prefill_chunks"] == chunks

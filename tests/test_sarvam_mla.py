@@ -20,7 +20,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks.harness.builders_sarvam_mla import (  # noqa: E402
     sarvam_mla_model)
 from benchmarks.reference import sarvam_mla_ref  # noqa: E402
-from ray_tpu.llm.engine import GenerationRequest  # noqa: E402
+from plain_greedy import plain_greedy, rowwise  # noqa: E402
+from ray_tpu.llm import GenerationRequest  # noqa: E402
 from ray_tpu.llm.paged import (PagedEngineConfig, PagedLLMEngine,  # noqa: E402
                                pool_copies)
 from ray_tpu.models import sarvam_mla  # noqa: E402
@@ -534,22 +535,18 @@ def test_the_engine_keeps_one_latent_pool_a_layer_and_no_v_pool(engine):
     assert cfg.pages_per_seq == 20
 
 
-def _reference_greedy(params, prompt, max_new):
-    sequence = list(prompt)
-    for _ in range(max_new):
-        lg = sarvam_mla_ref.logits(params, np.asarray(sequence), keys_of(),
-                                   rows=[len(sequence) - 1])
-        sequence.append(int(np.asarray(lg[0]).argmax()))
-    return sequence[len(prompt):]
+def _reference_greedy(params, prompts, max_new):
+    return plain_greedy(
+        rowwise(lambda row: sarvam_mla_ref.logits(params, row, keys_of())),
+        prompts, max_new)
 
 
 def test_generation_through_the_tick_matches_the_reference_and_counts():
     engine = tiny_engine()
     prompts = [prompt_of(21, 37).tolist(), prompt_of(22, 5).tolist(),
                prompt_of(23, 70).tolist()]
-    out = engine.generate(prompts, max_new_tokens=6)
-    for prompt, tokens in zip(prompts, out):
-        assert tokens == _reference_greedy(engine.params, prompt, 6)
+    assert engine.generate(prompts, max_new_tokens=6) \
+        == _reference_greedy(engine.params, prompts, 6)
     stats = engine.stats()
     assert stats["leaked_pages"] == 0 and stats["preemptions"] == 0
     assert stats["prefill_computed_tokens"] == 37 + 5 + 70

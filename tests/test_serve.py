@@ -10,19 +10,7 @@ import urllib.request
 
 import pytest
 
-import ray_tpu
 from ray_tpu import serve
-
-
-@pytest.fixture
-def serve_cluster():
-    ray_tpu.init(num_cpus=8, object_store_memory=200 * 1024 * 1024)
-    yield
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
 
 
 def _http_get(url, timeout=10):

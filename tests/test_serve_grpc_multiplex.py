@@ -5,19 +5,7 @@ import asyncio
 
 import pytest
 
-import ray_tpu
 from ray_tpu import serve
-
-
-@pytest.fixture
-def serve_cluster():
-    ray_tpu.init(num_cpus=4, object_store_memory=200 * 1024 * 1024)
-    yield
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
 
 
 def test_multiplex_wrapper_lru_no_cluster():

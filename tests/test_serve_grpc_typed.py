@@ -6,19 +6,7 @@ compile (tests/hello.proto -> tests/hello_pb2.py via protoc)."""
 
 import pytest
 
-import ray_tpu
 from ray_tpu import serve
-
-
-@pytest.fixture
-def serve_cluster():
-    ray_tpu.init(num_cpus=4, object_store_memory=200 * 1024 * 1024)
-    yield
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
 
 
 def test_api_method_table_matches_proto():

@@ -9,7 +9,7 @@ import pytest
 from ray_tpu._internal import accel
 from ray_tpu._internal.config import CONFIG
 from ray_tpu.llm import PagedEngineConfig, PagedLLMEngine, reqtrace
-from ray_tpu.llm.engine import GenerationRequest
+from ray_tpu.llm import GenerationRequest
 from ray_tpu.models.llama import LlamaConfig
 
 # ISSUE 24's table: these tile a tick; `between` lies outside its wall
