@@ -49,6 +49,7 @@ from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
 from ..ops.latent_attention import (latent_kernel, pages_spared,
                                     share_schedule)
 from ..ops.paged_attention import paged_kernel
+from ..ops.sparse_attention import sparse_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
 from .radix import RadixPrefixCache
@@ -57,6 +58,7 @@ from .sampling import SAMPLER_TIERS, sample_tokens, sampler_tier
 if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
     from ..models.falcon_h1 import FalconH1Config
+    from ..models.keye_dsa import KeyeDSAConfig
     from ..models.nemotron_h import NemotronHConfig
     from ..models.sarvam_mla import SarvamMLAConfig
 
@@ -109,9 +111,19 @@ class PagedEngineConfig:
     # the value; `_latent`): a layer then keeps one pool `[1, pages,
     # page_size, lanes]` and no V pool, its prefill chunks go straight into
     # the row's pages through its table as a `_windowed` model's do, and a
-    # radix-shared prefix is mapped in place and never copied.
+    # radix-shared prefix is mapped in place and never copied. One whose
+    # layers SELECT what they attend by an indexer's scores says how wide
+    # an index key stands in its pool (`index_cache()`: lanes; `_indexed`)
+    # and how many tokens a query selects (`index_topk`): a layer then
+    # keeps an index-key pool `[1, pages, page_size, lanes]` beside its K
+    # and V pools, which stand token-major (`[1, pages, page_size, kv_heads
+    # * head_dim]`: a selected token's K is one row), all three addressed by
+    # the same page ids and block table; its prefill chunks write all three
+    # straight into the row's pages, and a radix-shared prefix maps K, V and
+    # index pages in place (a radix node stays a page id; nothing is
+    # copied).
     model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig",
-                 "EvaByteConfig", "SarvamMLAConfig"]
+                 "EvaByteConfig", "SarvamMLAConfig", "KeyeDSAConfig"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -154,6 +166,13 @@ def _latent(cfg) -> bool:
     """Whether a layer of this model caches one latent row a token that is
     its key and its value (multi-head latent attention): one pool a layer."""
     return hasattr(cfg, "latent_cache")
+
+
+def _indexed(cfg) -> bool:
+    """Whether a layer of this model scores its cached tokens by an indexer
+    and attends the selected ones alone (learned sparse attention): an
+    index-key pool a layer beside its K and V pools."""
+    return hasattr(cfg, "index_cache")
 
 
 def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
@@ -359,6 +378,16 @@ class PagedLLMEngine:
             raise NotImplementedError(
                 "latent attention over a tensor mesh is not built: the "
                 "heads would be split and the latent pool replicated")
+        # layers that keep an index-key pool beside K and V (`_indexed`)
+        self._indexed = _indexed(cfg)
+        if self._indexed and self._tp > 1:
+            raise NotImplementedError(
+                "sparse attention over a tensor mesh is not built: the "
+                "selection is a row's, and the token-major pools are not "
+                "split over the kv heads")
+        # models whose prefill chunks write the row's pages themselves, and
+        # whose decode step takes `_row_pools` and the counters donated
+        self._in_place = self._latent or self._indexed
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -390,10 +419,13 @@ class PagedLLMEngine:
         reference = cfg.attention_impl == "reference"
         self._paged_kernel = latent_kernel(
             cfg.latent_cache()[1], reference) if self._latent \
+            else sparse_kernel(reference) if self._indexed \
             else paged_kernel(hd, reference)
-        # kernel layout: [kv_heads, num_pages, page_size, head_dim]
+        # kernel layout: [kv_heads, num_pages, page_size, head_dim]; the
+        # selected tokens' gather wants a token's kv heads in one row
+        shape = (1, P, ps, kvh * hd) if self._indexed else (kvh, P, ps, hd)
         def _zero_pages():
-            z = jnp.zeros((kvh, P, ps, hd), cfg.dtype)
+            z = jnp.zeros(shape, cfg.dtype)
             if self._page_sharding is not None:
                 z = jax.device_put(z, self._page_sharding)
             return z
@@ -403,6 +435,10 @@ class PagedLLMEngine:
         # a latent row is key and value at once: no second pool
         self.v_pages = [] if self._latent \
             else [_zero_pages() for _ in range(attending)]
+        # and an index key a token beside them, for a model that selects
+        self.index_pages = [
+            jnp.zeros((1, P, ps, cfg.index_cache()), cfg.dtype)
+            for _ in range(attending)] if self._indexed else []
         # recurrent state beside the pages, for a model that has it: per
         # layer that scans, (conv, ssm) pools of max_batch rows, row = slot
         # index (a slot that is not decoding is masked out of the decode
@@ -444,6 +480,16 @@ class PagedLLMEngine:
         self._latent_pages_rowwise = 0
         self._latent_pages_distinct = 0
         self._latent_pages_copied = 0
+        # what the sparse path did (`_indexed`): cached index keys the
+        # decode steps scored (a row a step, whatever the layers); pages of
+        # them counted a row and once (rows on one document score the same
+        # pages); tokens the steps selected, sum of min(context, topk), and
+        # the contexts they selected from
+        self._index_rows_scanned = 0
+        self._index_pages_rowwise = 0
+        self._index_pages_distinct = 0
+        self._sparse_rows_selected = 0
+        self._sparse_rows_context = 0
         self._page_seen = np.zeros((P,), bool)
         self._prefix_shared_tokens = 0
         self._prefill_computed_tokens = 0
@@ -651,6 +697,76 @@ class PagedLLMEngine:
             self._window_programs()
         if self._latent:
             self._latent_programs()
+        if self._indexed:
+            self._indexed_programs()
+
+    @property
+    def _row_pools(self):
+        """What an `_in_place` model's programs take donated and hand
+        back: a latent model's one pool a layer, an indexed model's three."""
+        if self._indexed:
+            return (self.k_pages, self.v_pages, self.index_pages)
+        return self.k_pages
+
+    @_row_pools.setter
+    def _row_pools(self, pools):
+        if self._indexed:
+            self.k_pages, self.v_pages, self.index_pages = pools
+        else:
+            self.k_pages = pools
+
+    def _indexed_programs(self):
+        """The programs of a model whose layers keep an index-key pool
+        beside K and V (`_indexed`). The decode step takes the three pools
+        a layer and the expert counters donated; a prefill chunk takes the
+        pools donated and the row's block table in place of a dense cache,
+        and is told how many of its tokens are real. `_dense_zero_caches`,
+        `_write_pages` and `_gather_pages` stay what the dense engine
+        builds and are never called: a shared prefix is scored and attended
+        where it lies."""
+        model = self.model
+
+        def by_kind(new):
+            """A model's per-layer tuples (k, v, index, counters...) as
+            the three lists of pools and the counters."""
+            return (tuple([kept[i] for kept in new] for i in range(3)),
+                    [tuple(kept[3:]) for kept in new if len(kept) > 3])
+
+        def decode_step(params, pools, active, block_tables, lengths,
+                        tokens, rng, temperature, top_k, top_p,
+                        counters=()):
+            caches = [
+                {"k": k, "v": v, "index": index, "active": active,
+                 "block_tables": block_tables, "lengths": lengths,
+                 "pairs": pairs, "steps": steps}
+                for k, v, index, (pairs, steps) in zip(*pools, counters)]
+            logits, new = model.apply(
+                {"params": params}, tokens[:, None],
+                positions=lengths[:, None], kv_caches=caches,
+                cache_index=None)
+            last = logits[:, -1, :].astype(jnp.float32)
+            out = sample_tokens(rng, last, temperature, top_k, top_p)
+            return (out.astype(jnp.int32),) + by_kind(new)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(1, 10))
+
+        def chunk_prefill(params, tokens, positions, pools, offset, table,
+                          valid, last=None):
+            """One prefill chunk of one row over its pages. `pools`: (k,
+            v, index) pools, a list a kind; `table` [pages_per_seq] the
+            row's page ids, shared prefix pages first, the null page where
+            it holds none. The chunk's first `valid` rows are written into
+            the row's pages, scored against everything cached before them
+            and attended under each query's threshold. `last` and the
+            logits returned: as the dense `chunk_prefill`'s."""
+            hidden, new = model.apply(
+                {"params": params}, tokens, positions=positions,
+                kv_caches=[{"k": k, "v": v, "index": index, "table": table}
+                           for k, v, index in zip(*pools)],
+                cache_index=offset, valid=valid, head=False)
+            return chunk_logits(model, params, hidden, last), by_kind(new)[0]
+
+        self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
     def _latent_programs(self):
         """The programs of a model whose layers cache one latent row a
@@ -761,16 +877,19 @@ class PagedLLMEngine:
                                         donate_argnums=(1, 2))
 
     def lower_chunk(self, bucket: Optional[int] = None):
-        """The prefill chunk of a `_windowed` or `_latent` model lowered at
+        """The prefill chunk of a `_windowed` or `_in_place` model lowered at
         this engine's shapes (the largest bucket's unless told), from shapes
         alone, in the form the tick runs (`last` given)."""
         cfg = self.config
         bucket = bucket or cfg.prefill_buckets[-1]
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
         params, k_pages, v_pages = self._shapes()
-        if self._latent:
+        if self._in_place:
+            pools = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                self._row_pools)
             return self._chunk_prefill.lower(
-                params, i32(1, bucket), i32(1, bucket), k_pages, i32(),
+                params, i32(1, bucket), i32(1, bucket), pools, i32(),
                 i32(cfg.pages_per_seq), i32(), i32())
         return self._chunk_prefill.lower(
             params, i32(1, bucket), i32(1, bucket), (k_pages, v_pages),
@@ -922,12 +1041,13 @@ class PagedLLMEngine:
 
         state = () if self.state is None else (
             jax.tree_util.tree_map(like, self.state), vec(jnp.bool_))
-        counters = () if self.state is None and not self._latent else (
+        counters = () if self.state is None and not self._in_place else (
             jax.tree_util.tree_map(like, self.counters),)
-        # one pool a layer and the rows that decode, for a latent model
-        pools = ([like(p) for p in self.k_pages], vec(jnp.bool_)) \
-            if self._latent else ([like(p) for p in self.k_pages],
-                                  [like(p) for p in self.v_pages])
+        # the row's pools and the rows that decode, for a model in place
+        pools = (jax.tree_util.tree_map(like, self._row_pools),
+                 vec(jnp.bool_)) \
+            if self._in_place else ([like(p) for p in self.k_pages],
+                                    [like(p) for p in self.v_pages])
         with self._mesh_scope():
             return self._decode.lower(
                 jax.tree_util.tree_map(like, self.params), *pools, *state,
@@ -947,10 +1067,11 @@ class PagedLLMEngine:
 
     def pool_copies(self, compiled_text: str) -> int:
         """Whole-pool copies (`pool_copies`) at this engine's pool shape
-        as one device holds it. The decode step must hold none."""
-        pool = self.k_pages[0]
-        return pool_copies(compiled_text,
-                           pool.sharding.shard_shape(pool.shape))
+        as one device holds it (and at the index-key pool's, for a model
+        that keeps one). The decode step must hold none."""
+        return sum(pool_copies(compiled_text,
+                               pool.sharding.shard_shape(pool.shape))
+                   for pool in self.k_pages[:1] + self.index_pages[:1])
 
     def state_copies(self, compiled_text: str) -> int:
         """Whole-pool copies (`pool_copies`) at the shape of this engine's
@@ -1022,6 +1143,11 @@ class PagedLLMEngine:
                 f"{what} ships dense K/V: a model whose layers cache "
                 "latent rows in their pages cannot be prefilled on "
                 "another engine yet")
+        if self._indexed:
+            raise NotImplementedError(
+                f"{what} ships dense K/V: a model whose layers keep index "
+                "keys beside them cannot be prefilled on another engine "
+                "yet")
 
     def cancel(self, request_id: str) -> bool:
         """Abort a request: frees its slot+pages on the next tick if
@@ -1233,7 +1359,17 @@ class PagedLLMEngine:
                 latent_rows_attended=self._latent_rows_attended,
                 latent_pages_rowwise=self._latent_pages_rowwise,
                 latent_pages_distinct=self._latent_pages_distinct,
-                latent_pages_copied=self._latent_pages_copied,
+                latent_pages_copied=self._latent_pages_copied)
+        if self._indexed:
+            counts.update(
+                index_rows_scanned=self._index_rows_scanned,
+                index_pages_rowwise=self._index_pages_rowwise,
+                index_pages_distinct=self._index_pages_distinct,
+                sparse_rows_selected=self._sparse_rows_selected,
+                sparse_rows_context=self._sparse_rows_context)
+        if self._in_place:
+            # what the radix gave and what the chunks computed and attended
+            counts.update(
                 prefix_shared_tokens=self._prefix_shared_tokens,
                 prefill_computed_tokens=self._prefill_computed_tokens,
                 prefill_ctx_rows=self._prefill_ctx_rows,
@@ -1348,7 +1484,7 @@ class PagedLLMEngine:
                 shipped = getattr(request, "_prefilled", None)
                 if shipped is None:
                     # chunks of these go straight into the row's pages
-                    if not self._windowed and not self._latent:
+                    if not self._windowed and not self._in_place:
                         self._stage_prefill_cache(seq)
                 else:
                     # prefilled elsewhere (`submit_prefilled`): enters
@@ -1565,8 +1701,8 @@ class PagedLLMEngine:
             assert off // window == (off + chunk - 1) // window, (off, chunk)
             staged = (self.k_pages, self.v_pages)
             extra = (self._row_table(seq),)
-        elif self._latent:
-            staged = self.k_pages
+        elif self._in_place:
+            staged = self._row_pools
             extra = (self._row_table(seq), jnp.asarray(take, jnp.int32))
             self._prefill_ctx_rows += off + take
         else:
@@ -1580,8 +1716,8 @@ class PagedLLMEngine:
             self.k_pages, self.v_pages = staged
             if cfg.model.window_closes(off + take):
                 self._close_window(seq, "prefill")
-        elif self._latent:
-            self.k_pages = staged
+        elif self._in_place:
+            self._row_pools = staged
         else:
             seq.dense_caches = staged
         if finishes:
@@ -1627,8 +1763,8 @@ class PagedLLMEngine:
         seq = self.seqs[index]
         request = seq.request
         prompt = seq.prompt
-        # a `_windowed` or `_latent` model's chunks wrote the row's pages
-        write_ids = [] if self._windowed or self._latent \
+        # a `_windowed` or `_in_place` model's chunks wrote the row's pages
+        write_ids = [] if self._windowed or self._in_place \
             else seq.pages[seq.own_from:]
         staged = seq.dense_caches
         if self.state is not None:
@@ -1965,16 +2101,26 @@ class PagedLLMEngine:
                     self._latent_rows_attended += seq.length + 1
                     self._latent_pages_rowwise += len(seq.pages)
                     self._latent_pages_copied += len(seq.pages)
+                if self._indexed:
+                    self._index_rows_scanned += seq.length + 1
+                    self._index_pages_rowwise += len(seq.pages)
+                    self._sparse_rows_context += seq.length + 1
+                    self._sparse_rows_selected += min(
+                        seq.length + 1, cfg.model.index_topk)
                 # this step's token, in flight from here on
                 seq.length += 1
                 seq.dispatched += 1
-            if self._latent:
+            if self._in_place:
                 # the pages those rows hold, each once
                 seen = self._page_seen
                 seen[block_tables[active].ravel()] = True
                 seen[0] = False
-                self._latent_pages_distinct += np.count_nonzero(seen)
+                if self._latent:
+                    self._latent_pages_distinct += np.count_nonzero(seen)
+                else:
+                    self._index_pages_distinct += np.count_nonzero(seen)
                 seen[:] = False
+            if self._latent:
                 # and what the kernel does not copy of them: a group's
                 # shared span for every member but one, by the schedule
                 # the device makes of the same arrays
@@ -2001,15 +2147,15 @@ class PagedLLMEngine:
                                 jnp.asarray(top_ks), jnp.asarray(top_ps))
                     with phase("dispatch"):
                         unread, tokens = self._unread, self._tokens
-                        if self._latent or self.state is not None:
+                        if self._in_place or self.state is not None:
                             # the rows that decode, for the layers that
                             # count or scan
                             live = np.zeros((B,), bool)
                             live[active] = True
-                        if self._latent:
-                            (self._tokens, self.k_pages,
+                        if self._in_place:
+                            (self._tokens, self._row_pools,
                              self.counters) = self._decode(
-                                self.params, self.k_pages,
+                                self.params, self._row_pools,
                                 jnp.asarray(live), *args, self.counters)
                         elif self.state is None:
                             (self._tokens, self.k_pages,
@@ -2079,9 +2225,21 @@ class PagedLLMEngine:
         return [results[i] for i in range(len(prompts))]
 
     def stats(self) -> Dict[str, Any]:
+        """The engine's running sums and sizes. Beside the keys of every
+        model, those of `_ahead_counts` for the model's contract; an
+        `_indexed` model's are `index_rows_scanned` (cached index keys the
+        decode steps scored, a row a step), `index_pages_rowwise` /
+        `index_pages_distinct` (pages of them counted a row / once a
+        step), `sparse_rows_selected` (sum of min(context, topk)) and
+        `sparse_rows_context` (sum of the contexts), `prefix_shared_tokens`,
+        `prefill_computed_tokens`, `prefill_ctx_rows`, `radix_evictions`,
+        and `index_cache_bytes` / `sparse_kernel`."""
         self._flush_step_rows()  # surfaces the partial window
-        cache_bytes = sum(int(np.prod(pool.shape)) * pool.dtype.itemsize
-                          for pool in self.k_pages + self.v_pages)
+        index_bytes = sum(int(np.prod(pool.shape)) * pool.dtype.itemsize
+                          for pool in self.index_pages)
+        cache_bytes = index_bytes + sum(
+            int(np.prod(pool.shape)) * pool.dtype.itemsize
+            for pool in self.k_pages + self.v_pages)
         kinds = _layer_caches(self.config.model)
         # the copy `read_counters` last published; where steps have run
         # since, the stepping thread is asked for a newer one and makes it
@@ -2126,8 +2284,9 @@ class PagedLLMEngine:
             # "pallas" or "gather": the path `decode_step` holds, of
             # ops.latent_attention for a model whose layers cache latent
             # rows, else of ops.paged_attention
-            ("latent_kernel" if self._latent else "paged_kernel"):
-                self._paged_kernel,
+            ("latent_kernel" if self._latent else "sparse_kernel"
+             if self._indexed else "paged_kernel"): self._paged_kernel,
+            "index_cache_bytes": index_bytes,
             "tp": self._tp,
             "hbm_cache_bytes": cache_bytes,
             # per-chip residency: pages shard on kv_heads, params on

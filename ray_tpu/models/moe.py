@@ -139,6 +139,19 @@ def sigmoid_top_k(u, router, bias, k: int, scale: float):
     return chosen.astype(jnp.int32), weights, scores
 
 
+def softmax_top_k(u, router, k: int):
+    """The router of a softmax-scored mixture with renormalised weights
+    (`norm_topk_prob`), in float32 whatever the model computes in: the map
+    `MoELayer` routes by (`_top_k_routing`), over logits u W_r taken at the
+    highest precision. u [T, d]; router [d, E]. Returns (chosen [T, k]
+    int32, weights [T, k] float32 that sum to 1, probabilities [T, E]
+    float32)."""
+    probs, weights, chosen = _top_k_routing(jnp.dot(
+        u.astype(F32), router.astype(F32),
+        precision=jax.lax.Precision.HIGHEST), k)
+    return chosen.astype(jnp.int32), weights, probs
+
+
 def relu2(h):
     return jnp.square(jax.nn.relu(h))
 
@@ -204,7 +217,9 @@ class RoutedExperts(nn.Module):
     read and write (the same array unless the experts live in a latent
     space). Experts are not gated, E(x) = W2 relu(W1 x)^2, unless `gated`:
     E(x) = W_d (silu(W_g x) * W_u x), with a third matrix `w_gate` an
-    expert (`w_in` is W_u, `w_out` W_d).
+    expert (`w_in` is W_u, `w_out` W_d). `scoring` is the router's map:
+    "sigmoid" (`sigmoid_top_k`, with its bias and `routed_scaling`) or
+    "softmax" (`softmax_top_k`: no bias, weights that sum to 1).
     Returns (out [.., l] float32, pairs [held] int32)."""
     num_experts: int
     experts_per_token: int
@@ -214,6 +229,7 @@ class RoutedExperts(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     gated: bool = False
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, u, x, mask=None):
@@ -226,7 +242,7 @@ class RoutedExperts(nn.Module):
         bias = self.param(
             "e_score_correction_bias",
             _partitioned(nn.initializers.zeros, (None,)),
-            (self.num_experts,), F32)
+            (self.num_experts,), F32) if self.scoring == "sigmoid" else None
         w_in = self.param(
             "w_in", _partitioned(nn.initializers.lecun_normal(
                 in_axis=-2, out_axis=-1, batch_axis=(0,)),
@@ -248,9 +264,13 @@ class RoutedExperts(nn.Module):
         mask = jnp.ones((u.shape[0],), bool) if mask is None \
             else mask.reshape(-1)
         with jax.named_scope("moe/route"):
-            chosen, weights, scores = sigmoid_top_k(
-                u, router, bias, self.experts_per_token,
-                self.routed_scaling)
+            if self.scoring == "sigmoid":
+                chosen, weights, scores = sigmoid_top_k(
+                    u, router, bias, self.experts_per_token,
+                    self.routed_scaling)
+            else:
+                chosen, weights, scores = softmax_top_k(
+                    u, router, self.experts_per_token)
         # readable by an apply with mutable=["routing"] (the parity check
         # certifies near-ties, the benchmark balances the bias); dropped
         # at trace time by every other

@@ -1,0 +1,349 @@
+"""Learned sparse attention over a paged cache: an indexer scores every
+cached token of a row, the `top_k` largest scores are selected, and the
+softmax attends the selected tokens alone (DeepSeek-Sparse-Attention's
+lightning indexer on a grouped-query layer).
+
+    I[t, s] = sum_h w[t, h] relu(qI[t, h] . kI[s])          s <= t
+    S_t     = the top_k positions of largest I[t, .], ties to the earlier
+              position; every position while t < top_k
+    o[t, h] = sum_{s in S_t} softmax_s(q[t, h] . k[s, g(h)]) v[s, g(h)]
+
+Three pools a layer, all addressed by one block table: K and V token-major,
+`[1, pages, page_size, kv_heads * head_dim]` (a token's K is ONE contiguous
+row, so a gather of selected tokens moves one piece a token and not one a
+kv head), and the index keys `[1, pages, page_size, lanes]`, `lanes` the
+index key in whole 128-lane tiles (the pad lanes zero in keys and queries).
+
+What is here, each with a `reference=True` form in plain whole-array jnp
+where the two differ:
+
+(a) `paged_index_scores`: a decode batch's scores over each row's pages, a
+    block of pages at a time, as many blocks as the longest row reaches.
+(b) `select_top_k`: the exact selection of a decode batch. The `top_k`-th
+    largest score of a row is found by bisection on the scores' bit
+    patterns (32 counting passes, no sort), ties at it are kept in order of
+    position, and the kept positions are compacted by a running count.
+    Exact: `approx_max_k` would be another model.
+(c) `sparse_attend`: the selected tokens' K and V gathered from the pools a
+    token a piece, and the softmax over them.
+(d) `sparse_attend_chunk`: a prefill chunk of one row. A chunk's queries
+    cannot gather `top_k` tokens each (chunk x top_k x 2 KB), so the chunk
+    is scored against the row's pages, each query's `top_k`-th score is its
+    threshold, and the pages are attended in blocks under that mask with
+    running softmax statistics.
+
+All of it is XLA: one program for rows of any length (a row under `top_k`
+tokens selects all it has, by data)."""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+NUM_LANES = 128
+NEG_INF = -1e30
+# cached tokens a loop step takes: of index keys, and of K and V under a
+# chunk's mask (whose logits [chunk, heads, block] stand whole)
+_SCORE_BLOCK_TOKENS = 2048
+_ATTEND_BLOCK_TOKENS = 1024
+
+
+def sparse_kernel(reference: bool) -> str:
+    """The path a decode step built with `reference` holds."""
+    return "reference" if reference else "xla"
+
+
+def _pad_pages(tables, block_pages: int):
+    """Whole blocks of pages along the last axis (the null page behind):
+    a slice that ran past the table would be moved back."""
+    pad = -tables.shape[-1] % block_pages
+    return jnp.pad(tables, [(0, 0)] * (tables.ndim - 1) + [(0, pad)])
+
+
+def _weighted_relu(products, w):
+    """sum_h w[.., h] relu(products[.., h, s]) -> [.., s], float32."""
+    return (jax.nn.relu(products) * w[..., None]).sum(-2)
+
+
+def paged_index_scores(q, w, pool, lengths, tables, *,
+                       reference: bool = False):
+    """(a) q [rows, heads, lanes], w [rows, heads] float32 (the scale
+    folded in), pool [1, pages, page_size, lanes], lengths [rows] the
+    cached tokens a row scores (its newest among them), tables [rows,
+    pages_per_row]. Returns [rows, ctx] float32, ctx the table in whole
+    blocks; what stands at positions >= lengths means nothing (the
+    selection is told the lengths). The products take the pool's type and
+    accumulate in float32."""
+    rows, _, lanes = q.shape
+    page_size = pool.shape[2]
+    block_pages = max(1, _SCORE_BLOCK_TOKENS // page_size)
+    block = block_pages * page_size
+    tables = _pad_pages(tables, block_pages)
+    q = q.astype(pool.dtype)
+
+    def scores_of(ids):
+        keys = pool[0][ids].reshape(rows, -1, lanes)
+        return _weighted_relu(jnp.einsum(
+            "rhd,rtd->rht", q, keys, preferred_element_type=F32), w)
+
+    if reference:
+        return scores_of(tables)
+
+    def score_block(b, scores):
+        ids = jax.lax.dynamic_slice_in_dim(tables, b * block_pages,
+                                           block_pages, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            scores, scores_of(ids), b * block, axis=1)
+
+    return jax.lax.fori_loop(
+        0, (lengths.max() + block - 1) // block, score_block,
+        jnp.zeros((rows, tables.shape[1] * page_size), F32))
+
+
+def ordered_bits(scores):
+    """float32 -> uint32 that orders as the floats do (0.0 and -0.0 alike),
+    above 0 for every finite score: 0 is left for "no candidate"."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(F32), jnp.int32)
+    bits = jnp.where(scores == 0, 0, bits)
+    bits = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def kth_largest(u, k: int):
+    """u [n, m] uint32, 0 where there is no candidate. The largest T with
+    count(u >= T) >= k, bit by bit from the top: 32 passes that compare
+    and count; 0 for a row with fewer than k candidates. [n] uint32."""
+    def settle_bit(i, t):
+        bit = jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        enough = (u >= (t | bit)[:, None]).sum(-1) >= k
+        return jnp.where(enough, t | bit, t)
+
+    return jax.lax.fori_loop(0, 32, settle_bit,
+                             jnp.zeros(u.shape[:1], jnp.uint32))
+
+
+def _group_counts(flags):
+    """bool [n, m] in groups of 128 lanes: (the inclusive running count
+    within each group [n, groups, 128], the groups' totals [n, groups]),
+    float32. A product with a triangle of ones (exact: the operands are 0
+    and 1, the sums float32 under 2^24): the TPU has no fast scan over 66k
+    lanes."""
+    n, m = flags.shape
+    groups = jnp.pad(flags, ((0, 0), (0, -m % NUM_LANES))).reshape(
+        n, -1, NUM_LANES)
+    triangle = (jnp.arange(NUM_LANES)[:, None]
+                <= jnp.arange(NUM_LANES)[None, :]).astype(jnp.bfloat16)
+    within = jnp.einsum("ngl,lm->ngm", groups.astype(jnp.bfloat16),
+                        triangle, preferred_element_type=F32)
+    return within, within[..., -1]
+
+
+def running_count(flags):
+    """Inclusive running count of bool [n, m] along m, int32."""
+    within, totals = _group_counts(flags)
+    before = jnp.cumsum(totals, axis=-1) - totals
+    return (within + before[..., None]).astype(jnp.int32).reshape(
+        flags.shape[0], -1)[:, :flags.shape[1]]
+
+
+class Threshold(NamedTuple):
+    """A row's selection as a rule over its scores' bits: keep u > t, and
+    of those with u == t the `need` earliest."""
+    t: jax.Array        # [n, 1] uint32
+    need: jax.Array     # [n, 1] int32
+
+
+def threshold_of(u, k: int) -> Threshold:
+    t = kth_largest(u, k)[:, None]
+    return Threshold(t, k - (u > t).sum(-1, keepdims=True).astype(jnp.int32))
+
+
+def kept(u, rule: Threshold, ties_before=0):
+    """The candidates the rule keeps, bool like u; `ties_before` [n, 1]:
+    candidates at the threshold that stand before u's first column. The
+    running count is made only where some row has more ties than it
+    needs."""
+    tied = (u == rule.t) & (u > 0)
+    spare = tied.sum(-1, keepdims=True) + ties_before > rule.need
+    return (u > rule.t) | jax.lax.cond(
+        spare.any(),
+        lambda: tied & (running_count(tied) + ties_before <= rule.need),
+        lambda: tied)
+
+
+def candidate_bits(scores, lengths):
+    """`ordered_bits` of a decode batch's scores [rows, ctx], 0 at and
+    behind each row's length."""
+    seen = jnp.arange(scores.shape[1])[None, :] < lengths[:, None]
+    return jnp.where(seen, ordered_bits(scores), jnp.uint32(0))
+
+
+def positions_of(keep, k: int):
+    """The first k kept columns of bool [n, m] as positions [n, k] int32,
+    ascending; m where a row keeps fewer. Slot j's group of 128 lanes is
+    the count of groups whose running total is <= j, its lane the count of
+    that group's lanes whose running count is <= j's rank in the group:
+    comparisons, sums and ONE gather of a 128-lane row a slot (a binary
+    search a slot gathers single elements, 17 times, and took 17 ms a
+    layer on the chip where this takes under one)."""
+    n, m = keep.shape
+    within, totals = _group_counts(keep)
+    upto = jnp.cumsum(totals, axis=-1)                       # [n, groups]
+    groups = upto.shape[1]
+    slot = jnp.arange(k, dtype=F32)[None, :, None]
+    passed = upto[:, None, :] <= slot                        # [n, k, groups]
+    group = jnp.minimum(passed.sum(-1), groups - 1)
+    rank = slot[..., 0] - jnp.where(passed, totals[:, None, :], 0).sum(-1)
+    rows = within.reshape(n * groups, NUM_LANES)[
+        group + jnp.arange(n)[:, None] * groups]             # [n, k, 128]
+    at = group * NUM_LANES + (rows <= rank[..., None]).sum(-1)
+    return jnp.where(slot[..., 0] < upto[:, -1:], at, m).astype(jnp.int32)
+
+
+def select_top_k(scores, lengths, k: int, *, reference: bool = False):
+    """(b) scores [rows, ctx] float32, lengths [rows] the positions that
+    are candidates. Returns (positions [rows, k'] int32 ascending, count
+    [rows] int32), k' = min(k, ctx): the `count` = min(length, k) selected
+    positions first, 0 behind them."""
+    rows, ctx = scores.shape
+    k = min(k, ctx)
+    count = jnp.minimum(lengths, k).astype(jnp.int32)
+    slot = jnp.arange(k)[None, :] < count[:, None]
+    if reference:
+        seen = jnp.arange(ctx)[None, :] < lengths[:, None]
+        # `top_k` puts the lower index first among equals; the candidates
+        # come before the -inf of a row shorter than k
+        _, at = jax.lax.top_k(jnp.where(
+            seen, jnp.where(scores == 0, 0.0, scores), -jnp.inf), k)
+        at = jnp.sort(jnp.where(slot, at, ctx), axis=-1)
+    else:
+        u = candidate_bits(scores, lengths)
+        at = positions_of(kept(u, threshold_of(u, k)), k)
+    return jnp.where(slot, at, 0).astype(jnp.int32), count
+
+
+def sparse_attend(q, k_pool, v_pool, positions, count, tables, *,
+                  kv_heads: int):
+    """(c) q [rows, heads, head_dim] scaled; pools [1, pages, page_size,
+    kv_heads * head_dim]; positions [rows, k] of which the first `count`
+    [rows] are the row's selection; tables [rows, pages_per_row]. Each
+    selected token's K and V rows are gathered whole from the pools and
+    the softmax runs over them (float32; the products take the pools' type
+    and accumulate in float32). Returns [rows, heads, head_dim] float32."""
+    rows, heads, head_dim = q.shape
+    page_size = k_pool.shape[2]
+    page = jnp.take_along_axis(tables, positions // page_size, axis=1)
+    token = page * page_size + positions % page_size          # [rows, k]
+
+    def gathered(pool):
+        return pool.reshape(-1, pool.shape[-1])[token].reshape(
+            rows, -1, kv_heads, head_dim)
+
+    queries = q.reshape(rows, kv_heads, heads // kv_heads,
+                        head_dim).astype(k_pool.dtype)
+    logits = jnp.einsum("rgjd,rkgd->rgjk", queries, gathered(k_pool),
+                        preferred_element_type=F32)
+    live = jnp.arange(positions.shape[1])[None, :] < count[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(live[:, None, None, :], logits, NEG_INF), axis=-1)
+    return jnp.einsum("rgjk,rkgd->rgjd", probs.astype(v_pool.dtype),
+                      gathered(v_pool), preferred_element_type=F32
+                      ).reshape(rows, heads, head_dim)
+
+
+def chunk_candidates(qi, w, index_pool, table, start):
+    """A prefill chunk's scores against its row's pages, as candidate
+    bits: qi [chunk, heads, lanes], w [chunk, heads] float32, table
+    [pages_per_row], query i at position start + i (its own key already
+    written). Returns u [chunk, ctx] uint32, `ordered_bits` of I[i, s] at
+    s <= start + i and 0 elsewhere; a block of pages at a time, as many as
+    the chunk's last position reaches."""
+    chunk, _, lanes = qi.shape
+    page_size = index_pool.shape[2]
+    block_pages = max(1, _SCORE_BLOCK_TOKENS // page_size)
+    block = block_pages * page_size
+    table = _pad_pages(table, block_pages)
+    qi = qi.astype(index_pool.dtype)
+    at = start + jnp.arange(chunk)[:, None]
+
+    def score_block(b, u):
+        ids = jax.lax.dynamic_slice_in_dim(table, b * block_pages,
+                                           block_pages)
+        keys = index_pool[0][ids].reshape(block, lanes)
+        scores = _weighted_relu(jnp.einsum(
+            "qhd,td->qht", qi, keys, preferred_element_type=F32), w)
+        seen = (b * block + jnp.arange(block))[None, :] <= at
+        return jax.lax.dynamic_update_slice_in_dim(
+            u, jnp.where(seen, ordered_bits(scores), jnp.uint32(0)),
+            b * block, axis=1)
+
+    return jax.lax.fori_loop(
+        0, (start + chunk + block - 1) // block, score_block,
+        jnp.zeros((chunk, table.shape[0] * page_size), jnp.uint32))
+
+
+def sparse_attend_chunk(q, u, k_pool, v_pool, table, start, *, top_k: int,
+                        kv_heads: int):
+    """(d) One prefill chunk of ONE row over its pages. q [chunk, heads,
+    head_dim] scaled, query i at position start + i, its own K, V and
+    index rows already written; u [chunk, ctx] its candidate bits
+    (`chunk_candidates`). Query i attends the `top_k` candidates of largest
+    score (`threshold_of`: its top_k-th score the threshold, ties to the
+    earlier position; every candidate while it has no more than top_k). K
+    and V are taken a block of pages at a time under that mask with
+    running softmax statistics (float32): nothing gathered a query, no
+    logits of the whole context. Returns [chunk, heads, head_dim]
+    float32."""
+    chunk, heads, head_dim = q.shape
+    page_size = k_pool.shape[2]
+    block_pages = max(1, _ATTEND_BLOCK_TOKENS // page_size)
+    block = block_pages * page_size
+    table = _pad_pages(table, block_pages)
+    # (the candidates' width is the table's in whole scoring blocks)
+    u = jnp.pad(u, ((0, 0), (0, -u.shape[1] % block)))
+    rule = threshold_of(u, top_k)
+    group = heads // kv_heads
+    queries = q.reshape(chunk, kv_heads, group, head_dim).astype(k_pool.dtype)
+
+    def attend_block(b, carry):
+        m, l, acc, ties = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, b * block_pages,
+                                           block_pages)
+        keys = k_pool[0][ids].reshape(block, kv_heads, head_dim)
+        values = v_pool[0][ids].reshape(block, kv_heads, head_dim)
+        here = jax.lax.dynamic_slice_in_dim(u, b * block, block, axis=1)
+        keep = kept(here, rule, ties)[:, None, None, :]
+        logits = jnp.where(keep, jnp.einsum(
+            "qgjd,tgd->qgjt", queries, keys, preferred_element_type=F32),
+            NEG_INF)
+        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(logits - m_new), 0.0)
+        correction = jnp.exp(m - m_new)
+        tied = ((here == rule.t) & (here > 0)).sum(-1, keepdims=True)
+        return (m_new, l * correction + p.sum(-1, keepdims=True),
+                acc * correction + jnp.einsum(
+                    "qgjt,tgd->qgjd", p.astype(v_pool.dtype), values,
+                    preferred_element_type=F32),
+                ties + tied.astype(jnp.int32))
+
+    stats = (chunk, kv_heads, group, 1)
+    _, l, acc, _ = jax.lax.fori_loop(
+        0, (start + chunk + block - 1) // block, attend_block,
+        (jnp.full(stats, NEG_INF, F32), jnp.zeros(stats, F32),
+         jnp.zeros((chunk, kv_heads, group, head_dim), F32),
+         jnp.zeros((chunk, 1), jnp.int32)))
+    return (acc / l).reshape(chunk, heads, head_dim)
+
+
+def dense_selection(scores, k: int):
+    """A whole sequence with nothing cached: scores [s, s] float32, query
+    t's candidates the positions <= t. The mask [s, s] of what each query
+    attends, by the same rule."""
+    s = scores.shape[0]
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    u = jnp.where(causal, ordered_bits(scores), jnp.uint32(0))
+    return kept(u, threshold_of(u, k))

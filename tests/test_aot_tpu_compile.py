@@ -805,3 +805,118 @@ def test_sarvam_prefill_chunk_compiles_for_v5e_over_the_pools(
     assert _within(memory.temp_size_in_bytes, recorded["temp_bytes"], 0.2)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < V5E_BYTES_LIMIT - 1.0e9
+
+
+# ---------------------------------------------------------------------------
+# the Keye-VL-2.0 cell's programs (sparse attention: three pools a layer)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def keye_programs(v5e):
+    """The `serve-keye-longdoc-closed96` cell's engine programs: its config
+    file's widths, rows and pools, its builder, its six layers, with the
+    shapes of their arguments on one described chip, on an engine that
+    never allocated anything."""
+    from benchmarks.harness.builders_keye_dsa import keye_dsa_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "keye-vl-2.0-30b-a3b-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = keye_dsa_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._indexed_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    kv = (1, engine_cfg.num_pages, engine_cfg.page_size,
+          cfg.num_kv_heads * cfg.head_dim)
+    index = kv[:3] + (cfg.index_cache(),)
+    return {"engine": engine, "engine_cfg": engine_cfg, "cfg": cfg,
+            "config": config, "spec": spec, "kv": kv, "index": index,
+            "params": placed(jax.eval_shape(lambda: unbox(engine.model.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32))["params"]))),
+            "rows": engine_cfg.max_batch,
+            "pools": ([spec(cfg.dtype, *kv)] * cfg.num_layers,
+                      [spec(cfg.dtype, *kv)] * cfg.num_layers,
+                      [spec(cfg.dtype, *index)] * cfg.num_layers),
+            "counters": placed(jax.eval_shape(cfg.init_counters))}
+
+
+def _keye_pool_bytes(p) -> int:
+    return p["cfg"].num_layers * 2 * (2 * math.prod(p["kv"])
+                                      + math.prod(p["index"]))
+
+
+def test_keye_decode_step_compiles_for_v5e_within_memory(
+        keye_programs, as_tpu):
+    """48 rows, a block table 1036 wide: every row's index keys scored over
+    its pages, the exact top-2048, the selected tokens' K and V gathered
+    from token-major pools, every held expert on every token; the three
+    pools a layer and the expert counters donated and updated in place, NO
+    pool copied, and arguments + temporaries as the file's
+    `memory_analysis` records them, the fullest device over 60 % full."""
+    from ray_tpu.llm.paged import pool_copies
+    p = keye_programs
+    spec, rows = p["spec"], p["rows"]
+    width = p["engine_cfg"].pages_per_seq
+    recorded = p["config"]["memory_analysis"]["decode_step_batch48"]
+    assert (rows, width) == (48, 1036) == (48, recorded["block_table_width"])
+    assert p["kv"] == (1, 13312, 64, 512) and p["index"] == (1, 13312, 64, 128)
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pools"], spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, width), spec(jnp.int32, rows),
+        spec(jnp.int32, rows), spec(jnp.uint32, 2), spec(jnp.float32, rows),
+        spec(jnp.int32, rows), spec(jnp.float32, rows),
+        p["counters"]).compile()
+    text = compiled.as_text()
+    assert pool_copies(text, p["kv"]) == pool_copies(text, p["index"]) == 0
+    assert len(p["counters"]) == 6
+    memory = compiled.memory_analysis()
+    pools = _keye_pool_bytes(p)
+    assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
+    assert memory.alias_size_in_bytes >= pools
+    assert _within(memory.argument_size_in_bytes,
+                   recorded["argument_bytes"])
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert _within(held, recorded["argument_bytes"] + recorded["temp_bytes"])
+    assert 0.6 * V5E_BYTES_LIMIT < held < V5E_BYTES_LIMIT - 1.5e9
+
+
+def test_keye_prefill_chunk_compiles_for_v5e_over_the_pools(
+        keye_programs, as_tpu):
+    """The largest bucket (512 tokens, `q_chunk_size`): the chunk's K, V
+    and index rows go into the row's pages through its table, are scored
+    there, and attended in blocks under each query's threshold (no dense
+    cache of a row, nothing of [chunk, vocab], no pool copied); the pools
+    are donated and aliased."""
+    from ray_tpu.llm.paged import array_shapes, pool_copies
+    p = keye_programs
+    spec, cfg = p["spec"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    assert p["engine_cfg"].prefill_buckets[-1] == 512
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 512), spec(jnp.int32, 1, 512),
+        p["pools"], spec(jnp.int32), spec(jnp.int32, width),
+        spec(jnp.int32), spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pool_copies(text, p["kv"]) == pool_copies(text, p["index"]) == 0
+    assert array_shapes(text, (512, cfg.vocab_size)) == 0
+    memory = compiled.memory_analysis()
+    recorded = p["config"]["memory_analysis"]["chunk_prefill_512"]
+    assert memory.alias_size_in_bytes >= _keye_pool_bytes(p)
+    assert _within(memory.argument_size_in_bytes,
+                   recorded["argument_bytes"])
+    assert _within(memory.temp_size_in_bytes, recorded["temp_bytes"], 0.2)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < V5E_BYTES_LIMIT - 1.5e9
