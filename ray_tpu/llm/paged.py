@@ -419,7 +419,8 @@ class PagedLLMEngine:
         reference = cfg.attention_impl == "reference"
         self._paged_kernel = latent_kernel(
             cfg.latent_cache()[1], reference) if self._latent \
-            else sparse_kernel(reference) if self._indexed \
+            else sparse_kernel(reference, ps, cfg.index_cache()) \
+            if self._indexed \
             else paged_kernel(hd, reference)
         # kernel layout: [kv_heads, num_pages, page_size, head_dim]; the
         # selected tokens' gather wants a token's kv heads in one row

@@ -17,8 +17,16 @@ index key in whole 128-lane tiles (the pad lanes zero in keys and queries).
 What is here, each with a `reference=True` form in plain whole-array jnp
 where the two differ:
 
-(a) `paged_index_scores`: a decode batch's scores over each row's pages, a
-    block of pages at a time, as many blocks as the longest row reaches.
+(a) `paged_index_scores`: a decode batch's scores over each row's pages.
+    On the chip ONE Pallas kernel, a program a row: it walks the row's
+    block table as far as the row's own length, copies the pages of a
+    block from the pool in HBM into one of two slots of fast memory (a
+    copy a page; the next block's in flight while this one is scored,
+    across blocks and across rows) and scores the block where it lands,
+    `q . keys^T` a step of `_SCORE_BLOCK_TOKENS` with the tokens on the
+    lanes, so a row's scores are written lane-dense. Elsewhere (the CPU)
+    an XLA loop over blocks of gathered pages, as many as the longest row
+    reaches. `sparse_kernel` names the path a decode program holds.
 (b) `select_top_k`: the exact selection of a decode batch. The `top_k`-th
     largest score of a row is found by bisection on the scores' bit
     patterns (32 counting passes, no sort), ties at it are kept in order of
@@ -32,28 +40,53 @@ where the two differ:
     threshold, and the pages are attended in blocks under that mask with
     running softmax statistics.
 
-All of it is XLA: one program for rows of any length (a row under `top_k`
-tokens selects all it has, by data)."""
+All but (a)'s kernel is XLA: one program for rows of any length (a row
+under `top_k` tokens selects all it has, by data)."""
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import _interpret
 
 F32 = jnp.float32
 NUM_LANES = 128
 NEG_INF = -1e30
-# cached tokens a loop step takes: of index keys, and of K and V under a
-# chunk's mask (whose logits [chunk, heads, block] stand whole)
+# cached tokens a step takes: of index keys (the XLA loop's block and the
+# kernel's product: float32 scores [heads, step], 32 vector registers at 16
+# heads), and of K and V under a chunk's mask (whose logits [chunk, heads,
+# block] stand whole)
 _SCORE_BLOCK_TOKENS = 2048
 _ATTEND_BLOCK_TOKENS = 1024
+# Scoring steps a copy block of the kernel holds in one of its two slots
+# (1 MB a slot at 128 lanes of bf16; PERF.md section 6, PR 50: the probe
+# that chose it).
+_COPY_BLOCK_STEPS = 2
+# Rows of a packed bf16 tile: a page lands on whole tiles of a slot, and
+# the queries' heads are whole tiles.
+_PACKED_ROWS = 16
+# Rows of a float32 tile: so many rows' scores leave the kernel together.
+_SUBLANES = 8
 
 
-def sparse_kernel(reference: bool) -> str:
-    """The path a decode step built with `reference` holds."""
-    return "reference" if reference else "xla"
+def sparse_kernel(reference: bool, page_size: int, lanes: int) -> str:
+    """The path a decode step built with `reference` holds for index pools
+    of such pages: "reference", "pallas" or "xla". The kernel copies whole
+    pages into whole tiles and scores them in steps of
+    `_SCORE_BLOCK_TOKENS`."""
+    if reference:
+        return "reference"
+    if (jax.default_backend() == "tpu" and lanes % NUM_LANES == 0
+            and page_size % _PACKED_ROWS == 0
+            and _SCORE_BLOCK_TOKENS % page_size == 0):
+        return "pallas"
+    return "xla"
 
 
 def _pad_pages(tables, block_pages: int):
@@ -83,6 +116,8 @@ def paged_index_scores(q, w, pool, lengths, tables, *,
     block = block_pages * page_size
     tables = _pad_pages(tables, block_pages)
     q = q.astype(pool.dtype)
+    if sparse_kernel(reference, page_size, lanes) == "pallas":
+        return _index_scores_pallas(q, w, pool, lengths, tables)
 
     def scores_of(ids):
         keys = pool[0][ids].reshape(rows, -1, lanes)
@@ -101,6 +136,155 @@ def paged_index_scores(q, w, pool, lengths, tables, *,
     return jax.lax.fori_loop(
         0, (lengths.max() + block - 1) // block, score_block,
         jnp.zeros((rows, tables.shape[1] * page_size), F32))
+
+
+def _scores_kernel(lengths_ref, tables_ref, q_ref, w_ref, pool_hbm, o_ref,
+                   buf, sems, slot_ref, *, block_pages: int, step: int,
+                   pages_per_row: int):
+    """One row. lengths_ref [rows] tokens to score (>= 1), tables_ref [rows *
+    pages_per_row] in SMEM; q_ref [heads, lanes] and w_ref [heads, 1] the
+    row's; pool_hbm the pool; o_ref [8, ctx] the scores of the eight rows
+    this one stands among (it stays in fast memory while they run: a row
+    writes its own sublane, the first of them zeroes the eight); buf [2,
+    block, lanes]; sems [2] (by slot); slot_ref [1] the slot the row's first
+    block is in."""
+    row, rows = pl.program_id(0), pl.num_programs(0)
+    page_size = pool_hbm.shape[2]
+    block = block_pages * page_size
+    own = pl.ds(row % _SUBLANES, 1)
+
+    def pages_of(r):
+        return pl.cdiv(lengths_ref[r], page_size)
+
+    pages = pages_of(row)
+    blocks = pl.cdiv(pages, block_pages)
+
+    def page_copy(page, j, slot):
+        to = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+        return pltpu.make_async_copy(pool_hbm.at[0, page], buf.at[slot, to],
+                                     sems.at[slot])
+
+    def each_page(count, one):
+        """`one(j)` for the pages 0 .. count of a block; a whole block's
+        unrolled, so that its copies are issued back to back."""
+        @pl.when(count == block_pages)
+        def _whole():
+            jax.lax.fori_loop(0, block_pages, one, None, unroll=True)
+
+        @pl.when(count != block_pages)
+        def _part():
+            jax.lax.fori_loop(0, count, one, None)
+
+    def start(r, first, count, slot):
+        """Start the copies of pages first .. first + count of row `r`'s
+        table."""
+        base = r * pages_per_row + first
+        each_page(count, lambda j, _: page_copy(
+            tables_ref[base + j], j, slot).start())
+
+    def wait(count, slot):
+        # (a wait needs the copy's size, not its source: a whole block's
+        # copies are waited for as one of a slot's size)
+        @pl.when(count == block_pages)
+        def _whole():
+            pltpu.make_async_copy(buf.at[1 - slot], buf.at[slot],
+                                  sems.at[slot]).wait()
+
+        @pl.when(count != block_pages)
+        def _part():
+            jax.lax.fori_loop(
+                0, count, lambda j, _: page_copy(0, j, slot).wait(), None)
+
+    @pl.when(row == 0)
+    def _first():
+        # a row's last step reads past its pages: what stands there is
+        # written as scores that mean nothing, and must be finite
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start(0, 0, jnp.minimum(block_pages, pages), 0)
+
+    @pl.when(row % _SUBLANES == 0)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    q, w = q_ref[...], w_ref[...]
+    after = jnp.minimum(row + 1, rows - 1)
+    follows = row + 1 < rows
+    count_after = jnp.minimum(block_pages, pages_of(after))
+
+    def score_block(b, slot):
+        begin = b * block_pages
+        count = jnp.minimum(block_pages, pages - begin)
+        ends = b + 1 == blocks
+
+        @pl.when(jnp.logical_not(ends) | follows)
+        def _prefetch():
+            start(jnp.where(ends, after, row),
+                  jnp.where(ends, 0, begin + block_pages),
+                  jnp.where(ends, count_after, jnp.minimum(
+                      block_pages, pages - begin - block_pages)),
+                  1 - slot)
+
+        wait(count, slot)
+
+        def score_step(c, carry):
+            at = pl.multiple_of(c * step, step)
+            products = jax.lax.dot_general(
+                q, buf[slot, pl.ds(at, step), :], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)                # [heads, step]
+            o_ref[own, pl.ds(pl.multiple_of(b * block + at, step), step)] = (
+                jnp.maximum(products, 0.0) * w).sum(0, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(count * page_size, step), score_step,
+                          None)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(0, blocks, score_block, slot_ref[0])
+
+
+@jax.jit
+def _index_scores_pallas(q, w, pool, lengths, tables):
+    """The kernel. q [rows, heads, lanes] in the pool's type, w [rows,
+    heads] float32, lengths [rows] tokens to score, tables [rows,
+    pages_per_row] in whole scoring blocks of pages. Jitted so that a
+    model's layers share ONE trace of the kernel's body. Returns [rows,
+    pages_per_row * page_size] float32: zero behind a row's last step."""
+    rows, heads, lanes = q.shape
+    page_size = pool.shape[2]
+    pages_per_row = tables.shape[1]
+    ctx = pages_per_row * page_size
+    block = _COPY_BLOCK_STEPS * _SCORE_BLOCK_TOKENS
+    # (heads in whole tiles: a head of zeros with a weight of zero adds 0)
+    pad = ((0, 0), (0, -heads % _PACKED_ROWS))
+    q, w = jnp.pad(q, pad + ((0, 0),)), jnp.pad(w.astype(F32), pad)
+    heads = q.shape[1]
+    return pl.pallas_call(
+        functools.partial(_scores_kernel, block_pages=block // page_size,
+                          step=_SCORE_BLOCK_TOKENS,
+                          pages_per_row=pages_per_row),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=[pl.BlockSpec((None, heads, lanes),
+                                   lambda r, *_: (r, 0, 0)),
+                      pl.BlockSpec((None, heads, 1), lambda r, *_: (r, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((_SUBLANES, ctx),
+                                   lambda r, *_: (r // _SUBLANES, 0)),
+            grid=(rows,),
+            scratch_shapes=[pltpu.VMEM((2, block, lanes), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(
+            (-(-rows // _SUBLANES) * _SUBLANES, ctx), F32),
+        # a row's last block starts the next row's first: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="dsa_index_scores",
+    # no row reads past its table, as none does in the XLA loop
+    )(jnp.clip(lengths, 1, ctx), tables.reshape(-1), q, w[..., None],
+      pool)[:rows]
 
 
 def ordered_bits(scores):

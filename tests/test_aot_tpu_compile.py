@@ -861,12 +861,14 @@ def _keye_pool_bytes(p) -> int:
 def test_keye_decode_step_compiles_for_v5e_within_memory(
         keye_programs, as_tpu):
     """48 rows, a block table 1036 wide: every row's index keys scored over
-    its pages, the exact top-2048, the selected tokens' K and V gathered
-    from token-major pools, every held expert on every token; the three
-    pools a layer and the expert counters donated and updated in place, NO
-    pool copied, and arguments + temporaries as the file's
-    `memory_analysis` records them, the fullest device over 60 % full."""
+    its pages by the scoring kernel (a kernel a layer), the exact top-2048,
+    the selected tokens' K and V gathered from token-major pools, every
+    held expert on every token; the three pools a layer and the expert
+    counters donated and updated in place, NO pool copied, and arguments +
+    temporaries as the file's `memory_analysis` records them, the fullest
+    device over 60 % full."""
     from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
     p = keye_programs
     spec, rows = p["spec"], p["rows"]
     width = p["engine_cfg"].pages_per_seq
@@ -880,6 +882,7 @@ def test_keye_decode_step_compiles_for_v5e_within_memory(
         spec(jnp.int32, rows), spec(jnp.float32, rows),
         p["counters"]).compile()
     text = compiled.as_text()
+    assert pallas_kernels(text) == {"dsa_index_scores": p["cfg"].num_layers}
     assert pool_copies(text, p["kv"]) == pool_copies(text, p["index"]) == 0
     assert len(p["counters"]) == 6
     memory = compiled.memory_analysis()
@@ -891,6 +894,32 @@ def test_keye_decode_step_compiles_for_v5e_within_memory(
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert _within(held, recorded["argument_bytes"] + recorded["temp_bytes"])
     assert 0.6 * V5E_BYTES_LIMIT < held < V5E_BYTES_LIMIT - 1.5e9
+
+
+@pytest.mark.parametrize("rows,heads,lanes,page_size,dtype", [
+    (48, 16, 128, 64, jnp.bfloat16),        # the cell's
+    (5, 20, 256, 16, jnp.bfloat16),         # heads padded, two lane tiles
+    (9, 16, 128, 2048, jnp.bfloat16),       # a page a scoring step
+    (4, 8, 128, 32, jnp.float32)])
+def test_the_scoring_kernel_compiles_for_v5e_at_the_shapes_it_takes(
+        v5e, as_tpu, rows, heads, lanes, page_size, dtype):
+    """What `sparse_kernel` answers "pallas" for, the chip's compiler
+    takes: pages that fill whole tiles and divide a scoring step, lanes in
+    whole tiles, any head count."""
+    from ray_tpu.ops import sparse_attention as sa
+    from ray_tpu.ops.attention import pallas_kernels
+    assert sa.sparse_kernel(False, page_size, lanes) == "pallas"
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(kind, *shape):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one)
+
+    width = 2 * sa._COPY_BLOCK_STEPS * sa._SCORE_BLOCK_TOKENS // page_size
+    compiled = sa._index_scores_pallas.lower(
+        spec(dtype, rows, heads, lanes), spec(jnp.float32, rows, heads),
+        spec(dtype, 1, 4 * width, page_size, lanes), spec(jnp.int32, rows),
+        spec(jnp.int32, rows, width)).compile()
+    assert pallas_kernels(compiled.as_text()) == {"dsa_index_scores": 1}
 
 
 def test_keye_prefill_chunk_compiles_for_v5e_over_the_pools(

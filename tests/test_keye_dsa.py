@@ -290,21 +290,129 @@ def test_the_running_count_is_a_cumsum(m):
                           np.cumsum(flags, axis=-1))
 
 
-def test_the_blocked_scores_are_the_whole_gathers():
-    """(a) a block of pages at a time = the whole table at once."""
-    rng = np.random.default_rng(5)
-    pool = jnp.asarray(rng.normal(size=(1, 80, 64, 128)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(3, 2, 128)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(3, 2)), jnp.float32)
-    tables = jnp.asarray(rng.permutation(np.arange(1, 80))[:3 * 26].reshape(
-        3, 26), jnp.int32)
-    lengths = jnp.asarray([5, 2100, 4300], jnp.int32)
-    whole = sa.paged_index_scores(q, w, pool, lengths, tables,
-                                  reference=True)
-    blocked = sa.paged_index_scores(q, w, pool, lengths, tables)
+# (page size, each row's tokens to score, the table's pages a row): what
+# the non-reference scoring paths must give as the whole gather does. DEAD
+# is a row the engine holds no request in: it scores one token, on the
+# null page.
+STEP = sa._SCORE_BLOCK_TOKENS
+BLOCK = sa._COPY_BLOCK_STEPS * STEP
+DEAD = 0
+SCORE_CASES = {
+    "three-rows-over-blocks": (64, [5, 2100, 4300], 80),
+    "a-row-under-one-page": (64, [37], 40),
+    "a-row-ends-on-a-steps-edge": (64, [STEP, 2 * STEP], 70),
+    "a-row-ends-on-a-copy-blocks-edge": (64, [BLOCK, 700], 70),
+    "one-token-beside-many-blocks": (64, [1, 2 * BLOCK + STEP + 70], 170),
+    "a-dead-row-between": (64, [BLOCK + 9, DEAD, 300], 80),
+    "a-table-padded-with-the-null-page": (64, [130, 64 * 33 + 1], 100),
+    "pages-of-16": (16, [15, STEP + 17, BLOCK + 300], 300),
+    "pages-of-16-one-token": (16, [1, 16, 17], 140),
+    "nine-rows-two-groups-of-eight": (
+        64, [40, 70, 1, 200, 64, 65, 128, 2049, 90], 40),
+}
+
+
+def score_case(case, dtype=jnp.float32):
+    """(q, w, pool, lengths, tables) of a case: every page random (a read
+    of a wrong page shows), rows' pages scattered, the entries of a table
+    behind a row's pages on the null page, which is zeros."""
+    page_size, tokens, width = SCORE_CASES[case]
+    rows = len(tokens)
+    rng = np.random.default_rng(len(case))
+    held = [-(-n // page_size) for n in tokens]
+    pool = rng.normal(size=(1, 1 + sum(held), page_size, 128))
+    pool[0, 0] = 0
+    free = 1 + rng.permutation(sum(held))
+    tables = np.zeros((rows, width), np.int32)
+    for r, pages in enumerate(held):
+        tables[r, :pages] = free[:pages]
+        free = free[pages:]
+    return (jnp.asarray(rng.normal(size=(rows, 2, 128)), dtype),
+            jnp.asarray(rng.normal(size=(rows, 2)), jnp.float32),
+            jnp.asarray(pool, dtype),
+            jnp.asarray(np.maximum(tokens, 1), jnp.int32),
+            jnp.asarray(tables))
+
+
+def scores_by(path, q, w, pool, lengths, tables):
+    """A decode batch's scores by a non-reference path: "xla" the loop the
+    CPU's programs hold, "pallas" the chip's kernel under the TPU
+    interpreter."""
+    if path == "xla":
+        return sa.paged_index_scores(q, w, pool, lengths, tables)
+    pages = max(1, STEP // pool.shape[2])
+    return sa._index_scores_pallas(q.astype(pool.dtype), w, pool, lengths,
+                                   sa._pad_pages(tables, pages))
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(SCORE_CASES))
+def test_the_blocked_scores_are_the_whole_gathers(case, path):
+    """(a) a block of pages at a time = the whole table at once: every
+    cached token's score, finite and of one width whatever the path."""
+    q, w, pool, lengths, tables = score_case(case)
+    whole = np.asarray(sa.paged_index_scores(q, w, pool, lengths, tables,
+                                             reference=True))
+    blocked = np.asarray(scores_by(path, q, w, pool, lengths, tables))
+    assert blocked.dtype == np.float32 and np.isfinite(blocked).all()
+    assert blocked.shape == (len(lengths), -(-whole.shape[1] // STEP) * STEP)
     for row, n in enumerate(np.asarray(lengths)):
-        assert np.abs(np.asarray(blocked[row, :n])
-                      - np.asarray(whole[row, :n])).max() < 1e-4
+        assert np.abs(blocked[row, :n] - whole[row, :n]).max() < 1e-4
+    if path == "pallas":
+        # nothing is scored behind a row's last step, and nothing is read
+        # past a row's own pages but the slot's leftovers
+        for row, n in enumerate(np.asarray(lengths)):
+            assert not blocked[row, -(-n // STEP) * STEP:].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_rows_scores_do_not_depend_on_the_rows_beside_it(dtype):
+    """The same row alone, first and last in a batch: bit for bit, over
+    the tokens it has."""
+    q, w, pool, lengths, tables = score_case("three-rows-over-blocks", dtype)
+    n = int(lengths[2])
+
+    def batch(*order):
+        at = np.asarray(order)
+        return np.asarray(scores_by("pallas", q[at], w[at], pool,
+                                    lengths[at], tables[at]))
+
+    alone = batch(2)[0, :n]
+    assert np.abs(alone).max() > 1
+    assert np.array_equal(batch(2, 0, 1)[0, :n], alone)
+    assert np.array_equal(batch(0, 1, 2)[2, :n], alone)
+    assert np.array_equal(batch(1, 2, 0, 1, 1, 0, 0, 1, 2)[8, :n], alone)
+
+
+def test_the_kernel_takes_the_products_in_the_pools_type():
+    """bf16 operands, float32 sums: against the same products in float32
+    on the bf16 values, and told apart from float32 operands."""
+    q, w, pool, lengths, tables = score_case("three-rows-over-blocks",
+                                             jnp.bfloat16)
+    got = np.asarray(scores_by("pallas", q, w, pool, lengths, tables))
+    as_f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    want = np.asarray(sa.paged_index_scores(
+        as_f32(q), w, as_f32(pool), lengths, tables, reference=True))
+    exact = np.asarray(sa.paged_index_scores(
+        *score_case("three-rows-over-blocks")[:3], lengths, tables,
+        reference=True))
+    n = int(lengths[2])
+    assert np.abs(got[2, :n] - want[2, :n]).max() < 1e-3
+    assert np.abs(got[2, :n] - exact[2, :n]).max() > 1e-2
+
+
+def test_the_scoring_path_is_named_by_backend_and_shape(monkeypatch):
+    """`sparse_kernel`: the XLA loop off the chip; on it the kernel for
+    pages that fill whole tiles and divide a scoring step, by the shapes."""
+    assert sa.sparse_kernel(False, 64, 128) == "xla"
+    assert sa.sparse_kernel(True, 64, 128) == "reference"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sa.sparse_kernel(False, 64, 128) == "pallas"
+    assert sa.sparse_kernel(False, 16, 256) == "pallas"
+    assert sa.sparse_kernel(True, 64, 128) == "reference"
+    for page_size, lanes in ((8, 128), (48, 128), (4096, 128), (64, 64),
+                             (64, 192)):
+        assert sa.sparse_kernel(False, page_size, lanes) == "xla"
 
 
 # -- (iv) topk >= context: the sparse path is dense paged attention ------
