@@ -218,7 +218,8 @@ class SparseAttention(nn.Module):
         self.sow("intermediates", "selected", (chosen, count))
         with jax.named_scope("dsa/attend"):
             out = sparse_attend(q[:, 0], pools[0], pools[1], chosen, count,
-                                tables, kv_heads=cfg.num_kv_heads)
+                                tables, kv_heads=cfg.num_kv_heads,
+                                reference=reference)
         return out[:, None], pools
 
     def _chunk(self, q, qi, w, rows, pools, table, start, valid):

@@ -33,7 +33,16 @@ where the two differ:
     position, and the kept positions are compacted by a running count.
     Exact: `approx_max_k` would be another model.
 (c) `sparse_attend`: the selected tokens' K and V gathered from the pools a
-    token a piece, and the softmax over them.
+    token a piece, `[rows, top_k, kv_heads * head_dim]`, and the softmax
+    over them with a kv head read as a slice of `head_dim` lanes of the
+    rows as the gather leaves them: the gathered rows are never split into
+    `[rows, top_k, kv_heads, head_dim]`, which the chip would relay out,
+    a whole gathered pool a layer. XLA gathers, not a kernel: seen as
+    `[tokens, kv_heads * head_dim]` a bf16 pool stands in HBM in tiles of
+    8 token rows, the chip's compiler refuses a copy of one row, and a
+    kernel that copies the aligned 8 rows a selected token lies in (8 KB
+    for 1 KB wanted, 20 ns a copy) is slower than the gather (PERF.md
+    section 6, PR 51: the probe).
 (d) `sparse_attend_chunk`: a prefill chunk of one row. A chunk's queries
     cannot gather `top_k` tokens each (chunk x top_k x 2 KB), so the chunk
     is scored against the row's pages, each query's `top_k`-th score is its
@@ -79,7 +88,8 @@ def sparse_kernel(reference: bool, page_size: int, lanes: int) -> str:
     """The path a decode step built with `reference` holds for index pools
     of such pages: "reference", "pallas" or "xla". The kernel copies whole
     pages into whole tiles and scores them in steps of
-    `_SCORE_BLOCK_TOKENS`."""
+    `_SCORE_BLOCK_TOKENS`. It is the scoring's path: the gather-and-attend
+    behind it is the same XLA form on every backend."""
     if reference:
         return "reference"
     if (jax.default_backend() == "tpu" and lanes % NUM_LANES == 0
@@ -411,32 +421,52 @@ def select_top_k(scores, lengths, k: int, *, reference: bool = False):
 
 
 def sparse_attend(q, k_pool, v_pool, positions, count, tables, *,
-                  kv_heads: int):
+                  kv_heads: int, reference: bool = False):
     """(c) q [rows, heads, head_dim] scaled; pools [1, pages, page_size,
     kv_heads * head_dim]; positions [rows, k] of which the first `count`
-    [rows] are the row's selection; tables [rows, pages_per_row]. Each
-    selected token's K and V rows are gathered whole from the pools and
-    the softmax runs over them (float32; the products take the pools' type
-    and accumulate in float32). Returns [rows, heads, head_dim] float32."""
+    [rows] are the row's selection (what stands behind them takes no part;
+    a row of `count` 0 gives finite numbers); tables [rows, pages_per_row].
+    Each selected token's K and V rows are gathered whole from the pools,
+    `[rows, k, kv_heads * head_dim]`, and the softmax runs over them
+    (float32; the products take the pools' type and accumulate in float32).
+    A kv head is read as a slice of `head_dim` lanes of the rows as the
+    gather leaves them; `reference` splits the rows into `[rows, k,
+    kv_heads, head_dim]` for ONE product over all kv heads instead, which
+    on the chip relays out every gathered row (PERF.md section 6, PR 51).
+    The same sums either way. Returns [rows, heads, head_dim] float32."""
     rows, heads, head_dim = q.shape
     page_size = k_pool.shape[2]
     page = jnp.take_along_axis(tables, positions // page_size, axis=1)
     token = page * page_size + positions % page_size          # [rows, k]
-
-    def gathered(pool):
-        return pool.reshape(-1, pool.shape[-1])[token].reshape(
-            rows, -1, kv_heads, head_dim)
-
+    keys, values = (pool.reshape(-1, pool.shape[-1])[token]
+                    for pool in (k_pool, v_pool))
     queries = q.reshape(rows, kv_heads, heads // kv_heads,
                         head_dim).astype(k_pool.dtype)
-    logits = jnp.einsum("rgjd,rkgd->rgjk", queries, gathered(k_pool),
-                        preferred_element_type=F32)
     live = jnp.arange(positions.shape[1])[None, :] < count[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(live[:, None, None, :], logits, NEG_INF), axis=-1)
-    return jnp.einsum("rgjk,rkgd->rgjd", probs.astype(v_pool.dtype),
-                      gathered(v_pool), preferred_element_type=F32
-                      ).reshape(rows, heads, head_dim)
+
+    def probs_of(logits, live):
+        return jax.nn.softmax(jnp.where(live, logits, NEG_INF),
+                              axis=-1).astype(v_pool.dtype)
+
+    if reference:
+        keys, values = (x.reshape(rows, -1, kv_heads, head_dim)
+                        for x in (keys, values))
+        probs = probs_of(jnp.einsum("rgjd,rkgd->rgjk", queries, keys,
+                                    preferred_element_type=F32),
+                         live[:, None, None])
+        out = jnp.einsum("rgjk,rkgd->rgjd", probs, values,
+                         preferred_element_type=F32)
+    else:
+        def head(g):
+            lanes = slice(g * head_dim, (g + 1) * head_dim)
+            probs = probs_of(jnp.einsum(
+                "rjd,rkd->rjk", queries[:, g], keys[..., lanes],
+                preferred_element_type=F32), live[:, None])
+            return jnp.einsum("rjk,rkd->rjd", probs, values[..., lanes],
+                              preferred_element_type=F32)
+
+        out = jnp.stack([head(g) for g in range(kv_heads)], axis=1)
+    return out.reshape(rows, heads, head_dim)
 
 
 def chunk_candidates(qi, w, index_pool, table, start):

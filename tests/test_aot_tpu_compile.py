@@ -862,12 +862,14 @@ def test_keye_decode_step_compiles_for_v5e_within_memory(
         keye_programs, as_tpu):
     """48 rows, a block table 1036 wide: every row's index keys scored over
     its pages by the scoring kernel (a kernel a layer), the exact top-2048,
-    the selected tokens' K and V gathered from token-major pools, every
-    held expert on every token; the three pools a layer and the expert
-    counters donated and updated in place, NO pool copied, and arguments +
-    temporaries as the file's `memory_analysis` records them, the fullest
+    the selected tokens' K and V gathered from token-major pools and read
+    as they are gathered (nothing of `[rows, k, kv heads, head dim]`: the
+    chip would relay out every gathered row for it), every held expert on
+    every token; the three pools a layer and the expert counters donated
+    and updated in place, NO pool copied, and arguments + temporaries as
+    the file's `memory_analysis` records them and not higher, the fullest
     device over 60 % full."""
-    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.llm.paged import array_shapes, pool_copies
     from ray_tpu.ops.attention import pallas_kernels
     p = keye_programs
     spec, rows = p["spec"], p["rows"]
@@ -884,6 +886,10 @@ def test_keye_decode_step_compiles_for_v5e_within_memory(
     text = compiled.as_text()
     assert pallas_kernels(text) == {"dsa_index_scores": p["cfg"].num_layers}
     assert pool_copies(text, p["kv"]) == pool_copies(text, p["index"]) == 0
+    cfg = p["cfg"]
+    assert array_shapes(text, (rows, cfg.index_topk, cfg.num_kv_heads,
+                               cfg.head_dim)) == 0
+    assert array_shapes(text, (rows, cfg.index_topk, p["kv"][3])) > 0
     assert len(p["counters"]) == 6
     memory = compiled.memory_analysis()
     pools = _keye_pool_bytes(p)
@@ -893,7 +899,93 @@ def test_keye_decode_step_compiles_for_v5e_within_memory(
                    recorded["argument_bytes"])
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert _within(held, recorded["argument_bytes"] + recorded["temp_bytes"])
+    assert memory.temp_size_in_bytes <= recorded["temp_bytes"]
     assert 0.6 * V5E_BYTES_LIMIT < held < V5E_BYTES_LIMIT - 1.5e9
+
+
+@pytest.mark.parametrize("rows,k,kv_heads,group,page_size,dtype", [
+    (48, 2048, 4, 8, 64, jnp.bfloat16),     # the cell's
+    (5, 512, 1, 8, 16, jnp.bfloat16),       # one kv head
+    (9, 1024, 8, 1, 64, jnp.bfloat16),      # a query a kv head
+    (4, 256, 2, 4, 32, jnp.float32)])
+def test_the_gathered_rows_are_attended_as_they_lie_on_v5e(
+        v5e, as_tpu, rows, k, kv_heads, group, page_size, dtype):
+    """`sparse_attend` compiled for the chip: the selected rows of each
+    pool gathered once, `[rows, k, width]`, and no array of `[rows, k, kv
+    heads, 128]` (or of any other split of the rows) beside them; the
+    reference form holds the split rows, which is what the chip relays
+    out."""
+    from ray_tpu.llm.paged import array_shapes
+    from ray_tpu.ops import sparse_attention as sa
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(kind, *shape):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one)
+
+    width, table = kv_heads * 128, 4 * k // page_size
+    args = (spec(jnp.float32, rows, kv_heads * group, 128),
+            spec(dtype, 1, rows * table, page_size, width),
+            spec(dtype, 1, rows * table, page_size, width),
+            spec(jnp.int32, rows, k), spec(jnp.int32, rows),
+            spec(jnp.int32, rows, table))
+
+    def text(**how):
+        return jax.jit(lambda *a: sa.sparse_attend(
+            *a, kv_heads=kv_heads, **how)).lower(*args).compile().as_text()
+
+    split, as_they_lie = (rows, k, kv_heads, 128), text()
+    assert array_shapes(as_they_lie, split) == 0
+    assert array_shapes(as_they_lie, (rows, k, width)) > 0
+    if kv_heads > 1:
+        assert array_shapes(text(reference=True), split) > 0
+
+
+def _row_piece_copy(pool_shape, piece_rows: int):
+    """A kernel that copies `piece_rows` token rows, from an aligned
+    offset it is told, out of a pool of `pool_shape` in HBM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(at_ref, pool_hbm, o_ref, sem):
+        copy = pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(pl.multiple_of(at_ref[0] * piece_rows,
+                                             piece_rows), piece_rows)],
+            o_ref, sem.at[0])
+        copy.start()
+        copy.wait()
+
+    piece = (piece_rows,) + tuple(pool_shape[1:])
+    return lambda at, pool: pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(piece, lambda i, *_: (0,) * len(piece)),
+            grid=(1,), scratch_shapes=[pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=jax.ShapeDtypeStruct(piece, jnp.bfloat16))(at, pool)
+
+
+def test_the_compiler_refuses_a_one_row_piece_of_a_token_major_pool(
+        v5e, as_tpu):
+    """Why no kernel gathers the selected rows from the K and V pools as
+    they stand (PERF.md section 6, PR 51): seen as `[tokens, 512]` bf16 a
+    pool stands in HBM in tiles of 8 token rows, and a copy of ONE row is
+    refused; the aligned group of 8 that holds it (8 KB for 1 KB wanted)
+    is taken, and so is one token of a pool `[tokens, 8, 128]` (a whole
+    tile a token: ROADMAP S14(a)'s fused row). The day the first stops
+    being refused, a kernel may gather a row a piece."""
+    one = SingleDeviceSharding(v5e[0])
+
+    def compiles(pool_shape, piece_rows):
+        jax.jit(_row_piece_copy(pool_shape, piece_rows)).lower(
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct(pool_shape, jnp.bfloat16,
+                                 sharding=one)).compile()
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compiles((13312 * 64, 512), 1)
+    compiles((13312 * 64, 512), 8)
+    compiles((13312 * 64, 8, 128), 1)
 
 
 @pytest.mark.parametrize("rows,heads,lanes,page_size,dtype", [

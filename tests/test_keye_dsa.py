@@ -415,6 +415,146 @@ def test_the_scoring_path_is_named_by_backend_and_shape(monkeypatch):
         assert sa.sparse_kernel(False, page_size, lanes) == "xla"
 
 
+# -- (iii') the gather-and-attend reads the rows as the gather leaves them --
+
+# page_size, kv heads, queries a kv head, k, and per row (context, count,
+# the positions selected: None = a random `count` of the context)
+EVERY_RESIDUE = [9 * i for i in range(16)]            # 9 i % 8 = i % 8
+ATTEND_CASES = {
+    "count-is-k": (16, 2, 2, 32, [(40, 32, None), (200, 32, None),
+                                  (33, 32, None)]),
+    "count-under-k-zeros-behind": (16, 2, 2, 32, [
+        (5, 5, None), (31, 31, None), (200, 32, None)]),
+    "a-dead-row-between": (16, 2, 2, 32, [(90, 32, None), (0, 0, None),
+                                          (50, 32, None)]),
+    "every-residue-of-a-group-of-eight-both-halves-of-a-packed-pair": (
+        16, 2, 2, 16, [(150, 16, EVERY_RESIDUE),
+                       (150, 16, [p + 3 for p in EVERY_RESIDUE])]),
+    "two-selected-tokens-in-one-group": (16, 2, 2, 8, [
+        (64, 8, [16, 17, 18, 23, 40, 41, 62, 63])]),
+    "a-tables-first-page-and-its-last": (16, 2, 2, 8, [
+        (12 * 16, 8, [0, 1, 15, 16, 100, 176, 190, 191])]),
+    "pages-of-64": (64, 2, 2, 32, [(700, 32, None), (64, 32, None),
+                                   (65, 32, None)]),
+    "pages-of-8": (8, 2, 2, 32, [(90, 32, None), (17, 17, None)]),
+    "one-kv-head": (16, 1, 8, 32, [(100, 32, None), (20, 20, None)]),
+    "four-kv-heads-eight-queries-each": (16, 4, 8, 32, [
+        (100, 32, None), (20, 20, None)]),
+    "one-query-a-kv-head": (16, 4, 1, 32, [(100, 32, None), (7, 7, None)]),
+}
+
+
+def attend_case(case, dtype=jnp.float32, head_dim=128):
+    """(q, k_pool, v_pool, positions, count, tables), kv heads: every page
+    random (a read of a wrong token shows), rows' pages scattered, 0
+    behind a row's `count` positions."""
+    page_size, kv_heads, group, k, rows_of = ATTEND_CASES[case]
+    rows = len(rows_of)
+    rng = np.random.default_rng(len(case))
+    width = max(1, max(-(-n // page_size) for n, _, _ in rows_of))
+    pages = 1 + rows * width
+    tables = (1 + rng.permutation(pages - 1)).reshape(rows, width)
+    positions = np.zeros((rows, k), np.int32)
+    for r, (context, count, chosen) in enumerate(rows_of):
+        if chosen is None:
+            chosen = np.sort(rng.choice(context, count, replace=False))
+        positions[r, :count] = chosen
+    pools = [jnp.asarray(rng.normal(size=(1, pages, page_size,
+                                          kv_heads * head_dim)), dtype)
+             for _ in range(2)]
+    q = rng.normal(size=(rows, kv_heads * group, head_dim)) * head_dim ** -0.5
+    return (jnp.asarray(q, jnp.float32), *pools, jnp.asarray(positions),
+            jnp.asarray([count for _, count, _ in rows_of], jnp.int32),
+            jnp.asarray(tables, jnp.int32)), kv_heads
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(ATTEND_CASES))
+def test_the_rows_read_as_gathered_give_the_split_rows_softmax(case, dtype):
+    """(c) a kv head as a slice of lanes of the gathered rows = the rows
+    split into kv heads (`reference=True`): the same sums, over exactly a
+    row's `count` selected tokens, finite where a row selects none."""
+    args, kv_heads = attend_case(case, dtype)
+    got = np.asarray(sa.sparse_attend(*args, kv_heads=kv_heads))
+    want = np.asarray(sa.sparse_attend(*args, kv_heads=kv_heads,
+                                       reference=True))
+    assert got.dtype == np.float32 and got.shape == args[0].shape
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.1
+    assert np.abs(got - want).max() < 1e-5
+    # and what stands behind a row's count took no part in it
+    positions, count = np.asarray(args[3]), np.asarray(args[4])
+    behind = np.arange(positions.shape[1])[None, :] >= count[:, None]
+    moved = jnp.asarray(np.where(behind, 3, positions))
+    again = sa.sparse_attend(*args[:3], moved, *args[4:], kv_heads=kv_heads)
+    live = count > 0
+    assert np.array_equal(np.asarray(again)[live], got[live])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_rows_attention_does_not_depend_on_the_rows_beside_it(dtype):
+    """The same row alone, first and last in a batch: bit for bit."""
+    (q, k_pool, v_pool, positions, count, tables), kv_heads = attend_case(
+        "count-under-k-zeros-behind", dtype)
+
+    def batch(*order):
+        at = np.asarray(order)
+        return np.asarray(sa.sparse_attend(
+            q[at], k_pool, v_pool, positions[at], count[at], tables[at],
+            kv_heads=kv_heads))
+
+    alone = batch(1)[0]
+    assert np.abs(alone).max() > 0.1
+    assert np.array_equal(batch(1, 0, 2)[0], alone)
+    assert np.array_equal(batch(0, 2, 1)[2], alone)
+    assert np.array_equal(batch(2, 1, 0, 2, 2, 0, 0, 2, 1)[8], alone)
+
+
+def test_the_attention_takes_its_products_in_the_pools_type():
+    """bf16 operands and bf16 probabilities, float32 sums: against the
+    same arithmetic spelled out in float32 on the rounded values, and told
+    apart from float32 operands."""
+    case = "four-kv-heads-eight-queries-each"
+    page_size, _, group, k, _ = ATTEND_CASES[case]
+    (q, k_pool, v_pool, positions, count, tables), kv_heads = attend_case(
+        case, jnp.bfloat16)
+    got = np.asarray(sa.sparse_attend(q, k_pool, v_pool, positions, count,
+                                      tables, kv_heads=kv_heads))
+    rounded = lambda a: np.asarray(  # noqa: E731
+        a.astype(jnp.bfloat16).astype(jnp.float32))
+    at = np.asarray(positions)
+    token = np.take_along_axis(np.asarray(tables), at // page_size,
+                               axis=1) * page_size + at % page_size
+    keys, values = (rounded(pool).reshape(-1, kv_heads, 128)[token]
+                    for pool in (k_pool, v_pool))     # [rows, k, g, d]
+    logits = np.einsum("rgjd,rkgd->rgjk",
+                       rounded(q).reshape(-1, kv_heads, group, 128), keys)
+    live = np.arange(k)[None, :] < np.asarray(count)[:, None]
+    probs = jax.nn.softmax(jnp.where(live[:, None, None], logits,
+                                     sa.NEG_INF), axis=-1)
+    want = np.einsum("rgjk,rkgd->rgjd", rounded(probs), values).reshape(
+        got.shape)
+    exact = np.asarray(sa.sparse_attend(
+        *attend_case(case)[0], kv_heads=kv_heads, reference=True))
+    assert np.abs(got - want).max() < 2e-5
+    assert np.abs(got - exact).max() > 3e-3
+
+
+def test_no_gathered_row_is_split_into_kv_heads():
+    """The decode path holds nothing of shape [rows, k, kv heads, head
+    dim] (what the chip relays out, a whole gathered pool a layer); the
+    reference form does."""
+    args, kv_heads = attend_case("four-kv-heads-eight-queries-each")
+    split = "tensor<2x32x4x128x"
+
+    def lowered(**how):
+        return jax.jit(lambda *a: sa.sparse_attend(
+            *a, kv_heads=kv_heads, **how)).lower(*args).as_text()
+
+    as_they_lie = lowered()
+    assert split not in as_they_lie and split in lowered(reference=True)
+    assert "tensor<2x32x512x" in as_they_lie
+
+
 # -- (iv) topk >= context: the sparse path is dense paged attention ------
 
 def test_with_topk_over_the_context_it_is_paged_attention():
