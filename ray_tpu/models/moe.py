@@ -183,7 +183,14 @@ def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
     section 6, PR 35: 1.92 / 2.07 ms at 96 / 256 tokens against 2.09 / 2.23
     for sorted pairs through a grouped-matmul kernel). The cost grows with
     T x held, not with the pairs: a caller with thousands of tokens a call
-    wants the pairs sorted into a grouped matmul instead."""
+    wants the pairs sorted into a grouped matmul instead. With EVERY expert
+    of a layer held (64 gated experts 3584 x 1024, 4 a token: PERF.md
+    section 6, PR 52) a layer reads 1.41 GB, 1.72 ms at the chip's
+    bandwidth: at 48 rows the form takes 2.0 ms a layer, within a sixth of
+    that read; at a 512-token chunk 4.3 ms, the 721 GFLOP of T x held at
+    85 % of the MXU's peak, where the chosen pairs' 45 GFLOP and the same
+    read would want 1.8: the case for the sorted form, which ROADMAP S15
+    queues with these sizes."""
     held = w_in.shape[0]
     local = chosen - first
     mine = (local >= 0) & (local < held) & mask[:, None]

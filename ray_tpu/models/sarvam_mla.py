@@ -1,6 +1,8 @@
 """Decoder with multi-head latent attention beside routed SwiGLU experts (the
 Sarvam-105B family, `model_type: sarvam_mla`; equations from the published
-config's keys, which the field names below repeat).
+config's keys, which the field names below repeat). `LatentAttention`,
+`GatedMLP`, `SharedAndRouted`, the rotary table and the chunk's row write
+are also what models/xing_mhc.py builds its blocks from.
 
     x = E[token]
     x = x + MLA(N(x)) ;  x = x + F(N(x))         N: RMSNorm, eps 1e-6
@@ -8,7 +10,10 @@ config's keys, which the field names below repeat).
 
 MLA (h = N(x) of one token at position t; H heads):
 
-    q_i = W_q,i h = [q_i^nope (128) ; q_i^rope (64)]          no query latent
+    q_i = W_q,i h = [q_i^nope (128) ; q_i^rope (64)]
+        `q_lora_rank=None`: no query latent (this file's model, Sarvam-105B,
+        whose config gives none). With a rank (models/xing_mhc.py, 768):
+        c_q = RMSNorm(W_dq h; g_q) ;  q_i = W_uq,i c_q
     [c~ (512) ; k~^rope (64)] = W_kva h
     c = RMSNorm(c~; g_kv)            `use_qk_norm`, read as the norm on the
                                      compressed latent (see `assumed` in the
@@ -64,7 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -213,8 +218,11 @@ class LatentAttention(nn.Module):
     """`cache` is None (whole sequence, expanded), a dict with `lengths`
     (paged decode, a token a row) or a dict with `table` (a prefill chunk
     of one row whose first position is `cache_index` and whose first
-    `valid` tokens are real); the last two absorbed, over the pool."""
+    `valid` tokens are real); the last two absorbed, over the pool.
+    `q_lora_rank`: the width of the query latent, `q = W_uq RMSNorm(W_dq
+    u)`; None (static) is one `W_q` and leaves that program as it was."""
     config: SarvamMLAConfig
+    q_lora_rank: Optional[int] = None
 
     @nn.compact
     def __call__(self, u, rotary, cache=None, cache_index=None, valid=None):
@@ -223,8 +231,16 @@ class LatentAttention(nn.Module):
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
         with jax.named_scope("mla/q"):
-            q = _dense((heads, nope + rope), ("embed", "heads", "head_dim"),
-                       "q_proj", cfg)(u)                # [b, s, heads, 192]
+            if self.q_lora_rank is None:
+                q = _dense((heads, nope + rope),
+                           ("embed", "heads", "head_dim"), "q_proj", cfg)(u)
+            else:
+                c_q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_a_norm")(
+                    _dense(self.q_lora_rank, ("embed", None), "q_a_proj",
+                           cfg)(u))
+                q = _dense((heads, nope + rope),
+                           (None, "heads", "head_dim"), "q_b_proj", cfg)(c_q)
+            # [b, s, heads, 192]
             q_nope = q[..., :nope]
             q_rope = _rotate(q[..., nope:], *rotary)
         with jax.named_scope("mla/latent"):

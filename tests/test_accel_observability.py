@@ -64,6 +64,10 @@ def test_compile_capture_around_fresh_jit():
     from ray_tpu._internal import accel
 
     assert accel.ensure_installed()
+    # the summary lists the 50 slowest sites of the process: start from none,
+    # or a worker that ran model files first has 50 slower than this test's
+    with accel._TRACKER.lock:
+        accel._TRACKER.per_function.clear()
     before = accel.compile_summary()
 
     def my_unique_compile_site(x):

@@ -1041,3 +1041,125 @@ def test_keye_prefill_chunk_compiles_for_v5e_over_the_pools(
     assert _within(memory.temp_size_in_bytes, recorded["temp_bytes"], 0.2)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < V5E_BYTES_LIMIT - 1.5e9
+
+
+# ---------------------------------------------------------------------------
+# the Xing4.0 cell's programs (four residual streams mixed by
+# hyper-connections; latent attention with a query latent, 32 heads; every
+# expert held)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def xing_programs(v5e):
+    """The `serve-xing-longin-closed64` cell's engine programs: its config
+    file's widths, rows and pool, its builder, its six layers, with the
+    shapes of their arguments on one described chip, on an engine that
+    never allocated anything."""
+    from benchmarks.harness.builders_xing_mhc import xing_mhc_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "xing4.0-29b-a4b-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = xing_mhc_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._latent_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = (1, engine_cfg.num_pages, engine_cfg.page_size,
+            cfg.latent_cache()[0])
+    return {"engine": engine, "engine_cfg": engine_cfg, "cfg": cfg,
+            "config": config, "spec": spec, "pool": pool,
+            "params": placed(jax.eval_shape(lambda: unbox(engine.model.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32))["params"]))),
+            "rows": engine_cfg.max_batch,
+            "pools": [spec(cfg.dtype, *pool)] * cfg.num_layers,
+            "counters": placed(jax.eval_shape(cfg.init_counters))}
+
+
+def _xing_memory(p, compiled, recorded):
+    """Arguments and temporaries as the file's `memory_analysis` states
+    them, the pools aliased, and the rule of the cut: 80 % of the chip in
+    arguments, 1.5 GB free beside the program."""
+    memory = compiled.memory_analysis()
+    pools = p["cfg"].num_layers * 2 * math.prod(p["pool"])
+    assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
+    assert memory.alias_size_in_bytes >= pools
+    assert _within(memory.argument_size_in_bytes, recorded["argument_bytes"])
+    assert _within(memory.temp_size_in_bytes, recorded["temp_bytes"], 0.2)
+    assert memory.argument_size_in_bytes >= 0.8 * V5E_BYTES_LIMIT
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < V5E_BYTES_LIMIT - 1.5e9
+
+
+def test_xing_decode_step_compiles_for_v5e_within_memory(
+        xing_programs, as_tpu):
+    """48 rows, a block table 152 wide, 32 heads against ONE latent kv head
+    through the latent kernel (its blocks are functions of `heads`), a
+    kernel a layer and ONE pool a layer, all 64 experts on every token, the
+    pools and the expert counters donated and updated in place; the model's
+    parameters are the issue's 4.79 B."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = xing_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    recorded = p["config"]["memory_analysis"]["decode_step_batch48"]
+    assert (rows, width) == (48, 152) == (48, recorded["block_table_width"])
+    assert p["pool"] == (1, p["engine_cfg"].num_pages, 64, 640)
+    assert p["engine_cfg"].num_pages >= 48 * width
+    table = p["config"]["memory_analysis"]["table"]
+    leaves = jax.tree_util.tree_leaves(p["params"])
+    assert sum(math.prod(a.shape) for a in leaves) \
+        == table["weights_params"] == 4792669828
+    assert sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves) \
+        == table["weights_bytes"]
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pools"], spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, width), spec(jnp.int32, rows),
+        spec(jnp.int32, rows), spec(jnp.uint32, 2), spec(jnp.float32, rows),
+        spec(jnp.int32, rows), spec(jnp.float32, rows),
+        p["counters"]).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"latent_attention": cfg.num_layers}
+    assert pool_copies(text, p["pool"]) == 0
+    assert len(p["counters"]) == 5
+    for scope in ("mhc/coeff", "mhc/pre", "mhc/post", "mla/q", "moe/route",
+                  "moe/experts"):
+        assert scope in text, scope
+    _xing_memory(p, compiled, recorded)
+
+
+def test_xing_prefill_chunk_compiles_for_v5e_over_the_pools(
+        xing_programs, as_tpu):
+    """The largest bucket (512 tokens): the chunk's latent rows go into the
+    row's pages through its table and are attended there in blocks (no
+    kernel of the program's own, no dense cache of a row, nothing of
+    [chunk, vocab], no pool copied); the pools are donated and aliased."""
+    from ray_tpu.llm.paged import array_shapes, pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = xing_programs
+    spec, cfg = p["spec"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    assert p["engine_cfg"].prefill_buckets[-1] == 512
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 512), spec(jnp.int32, 1, 512),
+        p["pools"], spec(jnp.int32), spec(jnp.int32, width),
+        spec(jnp.int32), spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {}
+    assert pool_copies(text, p["pool"]) == 0
+    assert array_shapes(text, (512, cfg.vocab_size)) == 0
+    _xing_memory(p, compiled,
+                 p["config"]["memory_analysis"]["chunk_prefill_512"])
