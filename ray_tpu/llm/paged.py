@@ -46,6 +46,7 @@ import numpy as np
 from .._internal import accel as _accel
 from .._internal.config import CONFIG
 from ..models.llama import LlamaConfig, LlamaModel, init_kv_caches
+from ..models.moe import sorted_buckets
 from ..ops.latent_attention import (latent_kernel, pages_spared,
                                     share_schedule)
 from ..ops.paged_attention import paged_kernel
@@ -412,6 +413,11 @@ class PagedLLMEngine:
             # scatter to the mesh layout.
             params = jax.device_put(params, pshard)
         self.params = params
+        # the buckets whose chunks take the routed experts' sorted form
+        # (`models.moe.sorted_form`, a function of the bucket and the
+        # experts' shapes: what the chunk's program did at trace time);
+        # None for a model without routed experts
+        self._sorted_buckets = sorted_buckets(params, config.prefill_buckets)
         self._rng = rng
         kvh, hd = cfg.num_kv_heads, cfg.head_dim_
         P, ps = config.num_pages, config.page_size
@@ -541,6 +547,7 @@ class PagedLLMEngine:
         # each (`sampling.SAMPLER_TIERS`)
         self._sampler_steps = [0] * len(SAMPLER_TIERS)
         self._prefill_chunks = 0
+        self._prefill_chunks_sorted = 0     # of them, on the sorted form
         self._prefill_heads = 0     # chunks that ran the head (on one row)
         self._prompts_finished = 0
         # accelerator-plane step telemetry (StepTimer on the decode
@@ -1335,7 +1342,8 @@ class PagedLLMEngine:
         dispatched behind them, tokens dropped a tick late; and what the
         visits dispatched: decode rows, prefill chunks, those of them that
         ran the head, the prompts they finished, and the decode steps by
-        the sampler's branch."""
+        the sampler's branch; for a model with routed experts, the chunks
+        whose bucket put them on the sorted form (`prefill_chunks_sorted`)."""
         counts = {"lookahead_ticks": self._lookahead_ticks,
                   "drained_ticks": sum(self._drained_ticks.values()),
                   "discarded_tokens": self._discarded_tokens,
@@ -1345,6 +1353,9 @@ class PagedLLMEngine:
                   "prefill_chunks": self._prefill_chunks,
                   "prefill_heads": self._prefill_heads,
                   "prompts_finished": self._prompts_finished}
+        if self._sorted_buckets is not None:
+            # chunks whose routed experts ran as sorted pairs
+            counts["prefill_chunks_sorted"] = self._prefill_chunks_sorted
         if self._windowed:
             # windows compressed and the pages that gave back; the rows of
             # each kind the decode steps attended, from lengths alone
@@ -1679,6 +1690,8 @@ class PagedLLMEngine:
         prompt = seq.prompt
         off = seq.prefill_off
         chunk, take = self._chunk_size(seq)
+        if self._sorted_buckets and chunk in self._sorted_buckets:
+            self._prefill_chunks_sorted += 1
         tokens = np.zeros((1, chunk), np.int32)
         tokens[0, :take] = prompt[off:off + take]
         positions = np.minimum(

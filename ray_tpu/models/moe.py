@@ -17,6 +17,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.grouped_matmul import ROW_TILE, grouped_experts, kernel_takes
 from .llama import _partitioned
 
 
@@ -156,6 +157,59 @@ def relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
+# Tokens a call from which the routed experts take the sorted form
+# (`sorted_form`). One expert layer alone on a v5e, ms, bf16 matrices, float32
+# sums, the seeded router on random tokens (PERF.md section 6, PR 53, step 0:
+# `_scratch/probe53.py`, each form eight times in one program; "gmm" is the
+# same sorted form through megablox's kernel):
+#
+#   shape (held of E, k, l x f)           T    dense  sorted  gmm
+#   Xing      64 of  64,  4, 3584 x 1024  512  4.54   2.32    2.39
+#     gated                               256  2.46   2.11    2.37
+#                                         128  2.15   2.00    2.03
+#                                          64  2.05   1.90    2.02
+#   Keye      16 of 128,  8, 2048 x  768  512  0.52   0.41    0.42
+#     gated                               256  0.26   0.29    0.33
+#   Sarvam    16 of 128,  8, 4096 x 2048  256  1.52   1.34    1.35
+#     gated                               128  1.22   1.20    1.19
+#   Nemotron 128 of 512, 22, 1024 x 2688  256  2.03   2.08    2.12
+#     not gated
+#
+# The dense form's FLOPs over its bytes is T; the chip's ridge is ~240. At
+# 512 the sorted form wins at every width the repo has, by 2 x where every
+# expert is held. At 256 it wins by a seventh and an eighth at Xing's and
+# Sarvam's widths and loses at Keye's (a 151 MB read: the sort and the two
+# gathers, ~0.1 ms, are a third of the layer) and at Nemotron's (22 choices
+# a token: 5,632 pairs to sort and carry for 1,408 held); ISSUE 53 holds
+# every program of 256 tokens and fewer to the parent's text, so the
+# constant stands at the bucket above (ROADMAP S15: what is left).
+SORTED_FROM_TOKENS = 512
+
+
+def sorted_form(tokens: int, width: int, mlp_dim: int) -> bool:
+    """Does `held_expert_sum` take the sorted form for `tokens` tokens a
+    call and matrices [width, mlp_dim]? A function of static shapes alone,
+    the same on every backend (the engine counts its chunks by it): from
+    `SORTED_FROM_TOKENS` tokens, where the matrices are what the grouped
+    kernels take (`ops.grouped_matmul.kernel_takes`: whole lane tiles)."""
+    return tokens >= SORTED_FROM_TOKENS and kernel_takes(width, mlp_dim)
+
+
+def sorted_buckets(params, buckets):
+    """The token counts of `buckets` at which the routed experts of a model
+    with these parameters take the sorted form, or None where the tree holds
+    no routed experts (no `w_in` [held, l, f]). The engine counts its
+    chunks by it: a chunk is one row of `bucket` tokens."""
+    shapes = {leaf.shape[-2:] for path, leaf
+              in jax.tree_util.tree_flatten_with_path(params)[0]
+              if leaf.ndim == 3 and any(
+                  getattr(key, "key", None) == "w_in" for key in path)}
+    if not shapes:
+        return None
+    return frozenset(bucket for bucket in buckets
+                     if any(sorted_form(bucket, *shape) for shape in shapes))
+
+
 def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
                     w_gate=None):
     """The part of sum_i w_i E_i(x) that the experts held here give, with
@@ -172,25 +226,41 @@ def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
     first .. first + held - 1. Returns (out [T, l] float32, pairs [held]
     int32: the tokens routed to each held expert).
 
-    ONE form for a decode step and a prefill chunk: every held expert is
-    applied to every token, and the pair weights, scattered to [T, held]
-    (0 where the expert was not chosen), sum the results. No sort, no
-    gather, one read of every held expert's matrices whatever the routing,
-    which is what a step must read anyway once every held expert has a
-    token (a balanced router at 96 rows leaves a held expert without one
-    1.5 % of the time), and 4 T held l f FLOPs that stay under that
-    read's time up to the engine's largest bucket on a v5e (PERF.md
-    section 6, PR 35: 1.92 / 2.07 ms at 96 / 256 tokens against 2.09 / 2.23
-    for sorted pairs through a grouped-matmul kernel). The cost grows with
-    T x held, not with the pairs: a caller with thousands of tokens a call
-    wants the pairs sorted into a grouped matmul instead. With EVERY expert
-    of a layer held (64 gated experts 3584 x 1024, 4 a token: PERF.md
-    section 6, PR 52) a layer reads 1.41 GB, 1.72 ms at the chip's
-    bandwidth: at 48 rows the form takes 2.0 ms a layer, within a sixth of
-    that read; at a 512-token chunk 4.3 ms, the 721 GFLOP of T x held at
-    85 % of the MXU's peak, where the chosen pairs' 45 GFLOP and the same
-    read would want 1.8: the case for the sorted form, which ROADMAP S15
-    queues with these sizes."""
+    ONE sum, two schedules, chosen at trace time from T and the matrices'
+    shapes (`sorted_form`; no option, no field of any config):
+
+      * dense (`_dense_expert_sum`), a decode step and every chunk under
+        `SORTED_FROM_TOKENS`: every held expert applied to every token.
+        One read of the held matrices whatever the routing, and
+        4 T held l f FLOPs that stay under that read's time while T is
+        under the chip's ridge;
+      * sorted (`_sorted_expert_sum`), the chunks from there up: the T x k
+        pairs sorted by expert and multiplied group by group, each held
+        expert's matrices read once and applied to the rows that chose it.
+
+    Both are bf16 products with float32 sums over the same pairs; what the
+    sorted form leaves out is the pairs nobody chose, whose weight in the
+    dense form is an exact 0."""
+    if sorted_form(x.shape[0], w_in.shape[1], w_in.shape[2]):
+        return _sorted_expert_sum(x, chosen, weights, mask, w_in, w_out,
+                                  first, w_gate)
+    return _dense_expert_sum(x, chosen, weights, mask, w_in, w_out, first,
+                             w_gate)
+
+
+def _dense_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
+                      w_gate=None):
+    """`held_expert_sum`, every held expert on every token: the pair
+    weights, scattered to [T, held] (0 where the expert was not chosen),
+    sum the results. No sort, no gather, one read of every held expert's
+    matrices whatever the routing, which is what a step must read anyway
+    once every held expert has a token (a balanced router at 96 rows leaves
+    a held expert without one 1.5 % of the time). The cost grows with
+    T x held, not with the pairs: with 64 gated experts 3584 x 1024 held, 4
+    a token (PERF.md section 6, PR 52) a layer reads 1.41 GB, 1.72 ms at the
+    chip's bandwidth; at 48 rows the form takes 2.0 ms a layer, within a
+    sixth of that read; at 512 tokens 4.3 ms, the 721 GFLOP of T x held at
+    85 % of the MXU's peak (the table above `SORTED_FROM_TOKENS`)."""
     held = w_in.shape[0]
     local = chosen - first
     mine = (local >= 0) & (local < held) & mask[:, None]
@@ -210,6 +280,35 @@ def held_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
         taken.sum((0, 1)).astype(jnp.int32)
 
 
+def _sorted_expert_sum(x, chosen, weights, mask, w_in, w_out, first: int,
+                       w_gate=None):
+    """`held_expert_sum`, the pairs sorted by expert: a pair's key is its
+    expert's place among the held, or `held` where the expert is held
+    elsewhere or the token masked, a last group that no product visits; a
+    stable sort; the pairs' tokens gathered in that order [T k, l]; the
+    grouped products (`ops.grouped_matmul`: gate and up from one pass over
+    the rows, float32 sums, the hidden rows in the matrices' type before
+    `w_out` as in the dense form); the inverse permutation; the k results of
+    a token summed under their float32 weights. `pairs` is the groups'
+    sizes. No capacity and no padding of a group: no pair is dropped at any
+    imbalance. Rows past the last real group hold whatever the kernel found
+    there: they are selected out, never multiplied by zero."""
+    held = w_in.shape[0]
+    tokens, k = chosen.shape
+    local = chosen - first
+    mine = (local >= 0) & (local < held) & mask[:, None]
+    key = jnp.where(mine, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    pairs = (key[:, None] == jnp.arange(held)).sum(0).astype(jnp.int32)
+    rows = x.astype(w_in.dtype)[order // k]
+    # whole row tiles; the rows added lie behind the last group
+    rows = jnp.pad(rows, ((0, -rows.shape[0] % ROW_TILE), (0, 0)))
+    y = grouped_experts(rows, w_in, w_out, pairs, w_gate)
+    y = y[jnp.argsort(order)].reshape(tokens, k, -1)
+    return jnp.where(mine[..., None], y * weights[..., None], 0.0).sum(1), \
+        pairs
+
+
 class RoutedExperts(nn.Module):
     """The routed experts of one layer as ONE chip of an expert-parallel
     deployment holds them: the router keeps its width (`num_experts`) and
@@ -218,7 +317,10 @@ class RoutedExperts(nn.Module):
     sum that those give. Nothing stands in for the absent chips or their
     exchange; the shares of all chips add up to the whole layer
     (tests/test_nemotron_h.py). No capacity: no pair is dropped at any
-    imbalance (`held_expert_sum`).
+    imbalance (`held_expert_sum`, which applies every held expert to every
+    token in a step and a small chunk and sorts the pairs into grouped
+    products from `SORTED_FROM_TOKENS` tokens a call: one sum, the
+    schedule read from the call's static shapes).
 
     `u` [.., d] is what the router reads, `x` [.., l] what the experts
     read and write (the same array unless the experts live in a latent

@@ -564,6 +564,42 @@ def test_generation_through_the_tick_matches_the_reference_and_counts():
     assert tick["counters"]["latent_rows_attended"] > 0
 
 
+@pytest.fixture(scope="module")
+def lane_wide_engine():
+    """Experts of whole lane tiles [128, 128] and a bucket on each side of
+    `moe.SORTED_FROM_TOKENS`."""
+    return PagedLLMEngine(PagedEngineConfig(
+        model=tiny_model(hidden_size=128, moe_intermediate_size=128),
+        max_batch=2, max_len=704, page_size=8, num_pages=200,
+        prefill_buckets=(128, 512)))
+
+
+@pytest.mark.parametrize("length,chunks,on_sorted", [(100, 1, 0),
+                                                     (600, 2, 1)])
+def test_chunks_on_the_sorted_expert_form_are_counted(lane_wide_engine,
+                                                      length, chunks,
+                                                      on_sorted):
+    """The engine counts a chunk on the routed experts' sorted form by the
+    rule its program was traced by (`moe.sorted_form`: the bucket and the
+    experts' shapes): of a 600-token prompt the 512 bucket's, not the
+    tail's 128; of a 100-token prompt none. The tokens are the reference's
+    through either form (off the TPU the grouped products are XLA's)."""
+    engine = lane_wide_engine
+    assert engine._sorted_buckets == {512}
+    before = engine.stats()
+    prompts = [prompt_of(length, length).tolist()]
+    assert engine.generate(prompts, max_new_tokens=3) \
+        == _reference_greedy(engine.params, prompts, 3)
+    after = engine.stats()
+    assert after["prefill_chunks"] - before["prefill_chunks"] == chunks
+    assert after["prefill_chunks_sorted"] \
+        - before["prefill_chunks_sorted"] == on_sorted
+    if on_sorted:
+        from ray_tpu._internal import accel
+        tick = next(r for r in accel.step_summary() if r["kind"] == "tick")
+        assert tick["counters"]["prefill_chunks_sorted"] >= on_sorted
+
+
 def seeded_tables(seed, rows_on, rows=12, page=64, whole=False):
     """(tables, lengths) of `rows` rows: a document for each entry of
     `rows_on` (the rows on it), two to six compute steps of pages (whole
