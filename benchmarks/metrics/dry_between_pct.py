@@ -1,0 +1,9 @@
+"""Of the seconds the device was dry in the span read, the share that lay
+BETWEEN two visits (the serving loop's hop, other threads' Python), from the
+`tick` row's `dry_by_phase` (benchmarks/harness/ticktimeline.py). 0 with
+nothing dry."""
+from benchmarks.harness import ticktimeline
+
+
+def read(record):
+    return ticktimeline.phase_share_pct(record, ticktimeline.BETWEEN)

@@ -34,7 +34,11 @@ Three concerns, one per-process module:
   extents (``extent_hist``) and the steps that took several times the
   usual (``slow``), each with what paused the process meanwhile
   (:func:`note_pause` / :class:`pause`: the collector, a metrics flush,
-  a compile, a fetch that waits for the device).
+  a compile, a fetch that waits for the device). One that is asked to
+  (``timeline=True``: the engine's ``tick``) keeps each of its flushes,
+  stamped, for ten minutes (``timeline``), and a :class:`DryWatch` on
+  the step's timer accounts for the time the device had run out of work
+  (``dry_*``: how long, in how many gaps, under which phase).
 
 JAX is never imported by this module at module scope. The compile
 listeners arm once the process has imported jax; device snapshots only
@@ -676,7 +680,13 @@ _SLOW_FACTOR = 4.0
 # down from a first step that compiled), then by this share: steady to
 # ~3 %, and a burst of eight odd steps moves it by a fifth.
 _TYPICAL_GAIN = 1.0 / 32.0
-_SLOW_KEEP = 64
+# A StepAccumulator that keeps a timeline keeps every flush that ended in
+# the newest this many seconds (`step_summary()`, `timeline`): a reader's
+# two marks have been up to 200 s apart.
+_TIMELINE_KEEP_S = 600.0
+# Upper edges of the buckets a dry gap's length is counted into: the
+# scale of `_EXTENT_EDGES`, from 0.09 ms on.
+_GAP_EDGES = [0.001 * 2.0 ** (i / 4.0) for i in range(-14, 45)]
 # what stopped the process for less explains no slow step
 _PAUSE_MIN_S = 0.001
 _PAUSE_KEEP = 256
@@ -810,7 +820,11 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
                 counters: Optional[Dict[str, float]] = None,
                 phases_cpu: Optional[Dict[str, float]] = None,
                 extent_hist: Optional[Dict[int, int]] = None,
-                slow: Optional[List[Dict[str, Any]]] = None
+                slow: Optional[List[Dict[str, Any]]] = None,
+                end: Optional[float] = None, extent_s: float = 0.0,
+                dry_by_phase: Optional[Dict[str, float]] = None,
+                dry_gap_hist: Optional[Dict[int, int]] = None,
+                dry_gap_max_s: float = 0.0
                 ) -> Optional[Dict[str, float]]:
     """Fold one step (or ``steps`` uniform steps) into the process's
     step telemetry: step-time histogram, tokens/s EWMA gauge, MFU gauge
@@ -826,8 +840,15 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
     counts), ``phases_cpu`` (the thread's CPU seconds inside each
     phase) and a StepAccumulator's ``extent_hist`` (steps by bucket of
     ``_EXTENT_EDGES``) and ``slow`` steps, which gain here the pauses
-    stamped since (`note_pause`) that overlap them. Returns the derived
-    numbers, or None when the plane is disabled."""
+    stamped since (`note_pause`) that overlap them, and a DryWatch's
+    ``dry_by_phase`` (seconds the device was dry, by the phase they lay
+    under), ``dry_gap_hist`` (gaps by bucket of ``_GAP_EDGES``) and
+    ``dry_gap_max_s`` (the longest of them). With
+    ``end`` (``time.monotonic()`` at the flush of an accumulator that keeps
+    a timeline; ``extent_s``: the steps' extents summed) the call is also
+    kept as it came, one row of the kind's ``timeline``, for
+    ``_TIMELINE_KEEP_S``. Returns the derived numbers, or None when the
+    plane is disabled."""
     if accel_disabled() or wall_s <= 0:
         return None
     metrics = accel_metrics()
@@ -872,8 +893,10 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
                 "host_s": 0.0, "tokens_per_s": 0.0, "mfu": 0.0,
                 "cpu_s": 0.0, "phases": {}, "counters": {},
                 "phases_cpu": {}, "extent_hist": {},
-                "slow": collections.deque(maxlen=_SLOW_KEEP),
-                "slow_total": 0, "slow_seconds": 0.0}
+                "slow_total": 0, "slow_seconds": 0.0,
+                "dry_by_phase": {}, "dry_gap_hist": {},
+                "dry_gap_max_s": 0.0,
+                "timeline": None}
         agg["steps"] += steps
         agg["wall_s"] += wall_s
         agg["tokens"] += tokens
@@ -886,12 +909,31 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
         _sum_phases(agg["counters"], counters)
         _sum_phases(agg["phases_cpu"], phases_cpu)
         _sum_phases(agg["extent_hist"], extent_hist)
+        _sum_phases(agg["dry_by_phase"], dry_by_phase)
+        _sum_phases(agg["dry_gap_hist"], dry_gap_hist)
+        if dry_gap_max_s > agg["dry_gap_max_s"]:
+            agg["dry_gap_max_s"] = dry_gap_max_s
         for step in slow or ():
             step["pauses"] = _overlapping(
                 step["end"] - step["extent_s"], step["end"])
-            agg["slow"].append(step)
             agg["slow_total"] += 1
             agg["slow_seconds"] += step["extent_s"]
+        if end is not None:
+            ring = agg["timeline"]
+            if ring is None:
+                ring = agg["timeline"] = collections.deque()
+            # the caller's dicts, kept: a flush hands them over
+            ring.append({
+                "end": end, "steps": steps, "wall_s": wall_s,
+                "extent_s": extent_s, "cpu_s": cpu_s,
+                "phases": phases or {}, "phases_cpu": phases_cpu or {},
+                "counters": counters or {},
+                "extent_hist": extent_hist or {}, "slow": slow or [],
+                "dry_by_phase": dry_by_phase or {},
+                "dry_gap_hist": dry_gap_hist or {},
+                "dry_gap_max_s": dry_gap_max_s})
+            while ring[0]["end"] < end - _TIMELINE_KEEP_S:
+                ring.popleft()
         if tokens_per_s is not None:
             prev = agg["tokens_per_s"]
             agg["tokens_per_s"] = tokens_per_s if not prev else \
@@ -905,27 +947,46 @@ def report_step(kind: str, wall_s: float, tokens: int = 0,
             "device_s": device_s, "comm_s": comm_s, "host_s": host_s}
 
 
+def _hist_row(edges: List[float], hist: Dict[int, int]) -> Dict[str, Any]:
+    return {"edges_s": list(edges),
+            "counts": [int(hist.get(i, 0)) for i in range(len(edges) + 1)]}
+
+
 def step_summary() -> List[Dict[str, Any]]:
-    """Per-kind fold of every step this process reported. Everything is
-    cumulative but ``slow`` (the newest 64 slow steps; ``slow_total`` and
-    ``slow_seconds`` count them all), so a window is closed − opened. A
-    kind folded through a StepAccumulator has ``extent_hist``: ``edges_s``
-    (upper edges) and ``counts``, one longer (the last is the overflow)."""
+    """Per-kind fold of every step this process reported, cumulative: a
+    window is closed − opened. A kind folded through a StepAccumulator that
+    keeps a timeline has these keys more. ``timeline``: its flushes of the
+    newest ``_TIMELINE_KEEP_S`` seconds, oldest first, each a row of what
+    one flush held (``end`` on ``time.monotonic()``, ``steps``, ``wall_s``,
+    ``extent_s``, ``cpu_s``, ``phases``, ``phases_cpu``, ``counters``,
+    ``slow``, ``dry_by_phase``, ``dry_gap_max_s`` and, by bucket,
+    ``extent_hist`` and ``dry_gap_hist``), so a window can also be read from one summary: the
+    rows that ended in it. ``slow``: the slow steps of those rows
+    (``slow_total`` and ``slow_seconds`` count them all).
+    ``extent_hist`` and ``dry_gap_hist``: ``edges_s`` (upper edges) and
+    ``counts``, one longer (the last is the overflow). ``dry_by_phase``:
+    seconds the device had no work, by the phase of the step they lay
+    under (a DryWatch's; empty without one)."""
     with _STEP_LOCK:
         out = []
         for kind, agg in _step_stats.items():
             row = dict(agg, kind=kind, phases=dict(agg["phases"]),
                        counters=dict(agg["counters"]),
                        phases_cpu=dict(agg["phases_cpu"]))
-            hist = row.pop("extent_hist")
-            if hist:
-                row["extent_hist"] = {
-                    "edges_s": list(_EXTENT_EDGES),
-                    "counts": [int(hist.get(i, 0))
-                               for i in range(len(_EXTENT_EDGES) + 1)]}
-                row["slow"] = list(agg["slow"])
+            ring = row.pop("timeline")
+            if ring is not None:
+                row["timeline"] = list(ring)
+                row["slow"] = [step for flush in ring
+                               for step in flush["slow"]]
+                row["extent_hist"] = _hist_row(
+                    _EXTENT_EDGES, agg["extent_hist"])
+                row["dry_gap_hist"] = _hist_row(
+                    _GAP_EDGES, agg["dry_gap_hist"])
+                row["dry_by_phase"] = dict(agg["dry_by_phase"])
             else:
-                for name in ("slow", "slow_total", "slow_seconds"):
+                for name in ("slow_total", "slow_seconds", "extent_hist",
+                             "dry_gap_hist", "dry_gap_max_s",
+                             "dry_by_phase"):
                     del row[name]
             steps = max(1, int(agg["steps"]))
             row["mean_step_s"] = agg["wall_s"] / steps
@@ -966,34 +1027,45 @@ class StepAccumulator:
     sees mean-of-window observations (acceptable smoothing for a
     window of 16 uniform ticks); gauges/counters are exact.
 
-    Each step's extent (``wall_s`` unless the caller gives one that
-    includes what lay outside it) is counted into a bucket of
-    ``_EXTENT_EDGES`` and compared with ``_SLOW_FACTOR`` times the running
-    median of this accumulator's extents: a slow step is kept whole and
-    handed to ``report_step`` with the window (one ``bisect``, one add
-    and one comparison a step)."""
+    With ``timeline`` each step's extent (``wall_s`` unless the caller
+    gives one that includes what lay outside it) is counted into a bucket
+    of ``_EXTENT_EDGES`` and compared with ``_SLOW_FACTOR`` times the
+    running median of this accumulator's extents: a slow step is kept
+    whole and handed to ``report_step`` with the window (one ``bisect``,
+    one add and one comparison a step), and every flush is stamped and
+    kept as a row of the kind's ``timeline``. Without it (the default)
+    the kind's row is its sums alone."""
 
-    __slots__ = ("kind", "every", "device_kind",
+    __slots__ = ("kind", "every", "device_kind", "timeline",
                  "_n", "_wall", "_tokens", "_device", "_compile",
                  "_comm", "_flops", "_cpu", "_phases", "_counters",
-                 "_phases_cpu", "_hist", "_slow", "_typical", "_seen")
+                 "_phases_cpu", "_hist", "_slow", "_typical", "_seen",
+                 "_extent", "_dry_by", "_dry_hist", "_dry_max")
 
     def __init__(self, kind: str, every: int = 16,
-                 device_kind: Optional[str] = None):
+                 device_kind: Optional[str] = None,
+                 timeline: bool = False):
         self.kind = kind
         self.every = max(1, int(every))
         self.device_kind = device_kind
+        self.timeline = timeline
+        self._typical = 0.0
+        self._seen = 0
+        self._reset()
+
+    def _reset(self):
         self._n = 0
         self._wall = self._device = self._compile = 0.0
-        self._comm = self._flops = self._cpu = 0.0
+        self._comm = self._flops = self._cpu = self._extent = 0.0
         self._tokens = 0
         self._phases: Dict[str, float] = {}
         self._counters: Dict[str, float] = {}
         self._phases_cpu: Dict[str, float] = {}
         self._hist: Dict[int, int] = {}
         self._slow: List[Dict[str, Any]] = []
-        self._typical = 0.0
-        self._seen = 0
+        self._dry_by: Dict[str, float] = {}
+        self._dry_hist: Dict[int, int] = {}
+        self._dry_max = 0.0
 
     def add(self, wall_s: float, tokens: int = 0, device_s: float = 0.0,
             compile_s: float = 0.0, flops: float = 0.0,
@@ -1002,23 +1074,14 @@ class StepAccumulator:
             cpu_s: float = 0.0,
             counters: Optional[Dict[str, float]] = None,
             phases_cpu: Optional[Dict[str, float]] = None,
-            extent_s: Optional[float] = None):
-        extent = wall_s if extent_s is None else extent_s
-        bucket = bisect.bisect_left(_EXTENT_EDGES, extent)
-        hist = self._hist
-        hist[bucket] = hist.get(bucket, 0) + 1
-        typical = self._typical
-        if extent > typical * _SLOW_FACTOR and bucket and typical:
-            self._slow.append({
-                "end": time.monotonic(), "extent_s": extent,
-                "typical_s": typical, "wall_s": wall_s, "cpu_s": cpu_s,
-                "phases": dict(phases or ()),
-                "phases_cpu": dict(phases_cpu or ()),
-                "counters": dict(counters or ())})
-        seen = self._seen = self._seen + 1
-        gain = _TYPICAL_GAIN if seen > 32 else 1.0 / seen
-        self._typical = typical * (
-            1.0 + gain if extent > typical else 1.0 - gain) or extent
+            extent_s: Optional[float] = None,
+            dry: Optional[Tuple[Dict[str, float], Dict[int, int],
+                                float]] = None):
+        """``dry``: what `DryWatch.take` gave the step's timer."""
+        if self.timeline:
+            self._extent_of(wall_s if extent_s is None else extent_s,
+                            wall_s, cpu_s, phases, phases_cpu, counters,
+                            dry)
         self._n += 1
         self._wall += wall_s
         self._tokens += tokens
@@ -1033,26 +1096,53 @@ class StepAccumulator:
         if self._n >= self.every:
             self.flush()
 
+    def _extent_of(self, extent, wall_s, cpu_s, phases, phases_cpu,
+                   counters, dry):
+        """Count one step's extent and its share of the dry account; keep
+        the step if it is slow."""
+        by_phase = None
+        if dry is not None:
+            by_phase, gap_hist, gap_max = dry
+            _sum_phases(self._dry_by, by_phase)
+            _sum_phases(self._dry_hist, gap_hist)
+            self._dry_max = max(self._dry_max, gap_max)
+        self._extent += extent
+        bucket = bisect.bisect_left(_EXTENT_EDGES, extent)
+        hist = self._hist
+        hist[bucket] = hist.get(bucket, 0) + 1
+        typical = self._typical
+        if extent > typical * _SLOW_FACTOR and bucket and typical:
+            self._slow.append({
+                "end": time.monotonic(), "extent_s": extent,
+                "typical_s": typical, "wall_s": wall_s, "cpu_s": cpu_s,
+                "phases": dict(phases or ()),
+                "phases_cpu": dict(phases_cpu or ()),
+                "counters": dict(counters or ()),
+                "dry_s": sum((by_phase or {}).values()),
+                "dry_by_phase": by_phase or {}})
+        seen = self._seen = self._seen + 1
+        gain = _TYPICAL_GAIN if seen > 32 else 1.0 / seen
+        self._typical = typical * (
+            1.0 + gain if extent > typical else 1.0 - gain) or extent
+
     def flush(self) -> Optional[Dict[str, float]]:
         n = self._n
         if not n:
             return None
+        kept = {}
+        if self.timeline:
+            kept = dict(extent_hist=self._hist, slow=self._slow,
+                        end=time.monotonic(), extent_s=self._extent,
+                        dry_by_phase=self._dry_by,
+                        dry_gap_hist=self._dry_hist,
+                        dry_gap_max_s=self._dry_max)
         out = report_step(
             self.kind, self._wall, tokens=self._tokens,
             device_s=self._device, compile_s=self._compile,
             flops=self._flops, device_kind=self.device_kind, steps=n,
             comm_s=self._comm, phases=self._phases, cpu_s=self._cpu,
-            counters=self._counters, phases_cpu=self._phases_cpu,
-            extent_hist=self._hist, slow=self._slow)
-        self._n = 0
-        self._wall = self._device = self._compile = 0.0
-        self._comm = self._flops = self._cpu = 0.0
-        self._tokens = 0
-        self._phases = {}
-        self._counters = {}
-        self._phases_cpu = {}
-        self._hist = {}
-        self._slow = []
+            counters=self._counters, phases_cpu=self._phases_cpu, **kept)
+        self._reset()
         return out
 
 
@@ -1101,25 +1191,29 @@ class StepTimer:
     them waited.
 
     ``sink``: a StepAccumulator to fold into instead of reporting
-    immediately (hot loops — see the paged engine's tick). Near-zero
+    immediately (hot loops — see the paged engine's tick). ``watch``: the
+    owner's DryWatch, polled where a phase or a part ends and taken into
+    the sink on exit. Near-zero
     when the plane is disabled: __enter__/__exit__ degrade to two
     attribute checks, phase() hands out one shared no-op, no span is
     built and nothing is reported."""
 
     __slots__ = ("kind", "tokens", "flops", "device_kind", "enabled",
                  "phases", "phases_cpu", "counters", "cpu_s", "result",
-                 "sink", "_t0", "_c0", "_cpu0", "_span", "_outside",
-                 "_cpu_at", "_cpu_read")
+                 "sink", "watch", "_t0", "_c0", "_cpu0", "_span",
+                 "_outside", "_cpu_at", "_cpu_read")
 
     def __init__(self, kind: str, tokens: int = 0, flops: float = 0.0,
                  device_kind: Optional[str] = None,
-                 sink: Optional[StepAccumulator] = None):
+                 sink: Optional[StepAccumulator] = None,
+                 watch: Optional["DryWatch"] = None):
         self.kind = kind
         self.tokens = tokens
         self.flops = flops
         self.device_kind = device_kind
         self.sink = sink
         self.enabled = not accel_disabled()
+        self.watch = watch if self.enabled else None
         self.phases: Dict[str, float] = {}
         self.phases_cpu: Dict[str, float] = {}
         self.counters: Dict[str, float] = {}
@@ -1184,6 +1278,8 @@ class StepTimer:
         if self.enabled:
             self.phases[name] = self.phases.get(name, 0.0) + seconds
             self._outside += seconds
+            if self.watch is not None:
+                self.watch.mark(name, time.perf_counter())
 
     def count(self, name: str, n: float = 1) -> None:
         """``n`` more events of ``name`` in this step: summed by name
@@ -1204,13 +1300,16 @@ class StepTimer:
             return False
         compile_s = backend_compile_seconds_total() - self._c0
         if self.sink is not None:
+            watch = self.watch
             self.sink.add(wall, tokens=self.tokens,
                           device_s=self.device_s, compile_s=compile_s,
                           flops=self.flops, comm_s=self.comm_s,
                           phases=self.phases, cpu_s=self.cpu_s,
                           counters=self.counters,
                           phases_cpu=self.phases_cpu,
-                          extent_s=wall + self._outside)
+                          extent_s=wall + self._outside,
+                          dry=watch.take(self) if watch is not None
+                          else None)
         else:
             self.result = report_step(
                 self.kind, wall, tokens=self.tokens,
@@ -1251,6 +1350,8 @@ class _Phase:
             else time.thread_time()
         if self._name == "device":
             self._c0 = backend_compile_seconds_total()
+        if timer.watch is not None:
+            timer.watch.label = self._name
         span = self._span = _annotation(
             self._timer.kind + "/" + self._name)
         if span is not None:
@@ -1275,12 +1376,9 @@ class _Phase:
                 backend_compile_seconds_total() - self._c0))
         phases = timer.phases
         phases[name] = phases.get(name, 0.0) + seconds
+        if timer.watch is not None:
+            timer.watch.mark(name, now)
         return False
-
-
-# ---------------------------------------------------------------------------
-# the per-process report (get_accel_report RPC body)
-# ---------------------------------------------------------------------------
 
 
 class _Part:
@@ -1298,15 +1396,184 @@ class _Part:
     def __enter__(self):
         self._span = _traced_span(
             f"{self._timer.kind}/{self._phase}/{self._name}")
-        self._t0 = time.perf_counter()
+        watch = self._timer.watch
+        now = self._t0 = time.perf_counter()
+        if watch is not None:
+            # what of the phase lay before the part is the phase's own
+            watch.mark(self._phase, now)
+            watch.label = self._phase + "/" + self._name
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        seconds = time.perf_counter() - self._t0
+        now = time.perf_counter()
         if self._span is not None:
             self._span.__exit__(exc_type, exc, tb)
-        self._timer.count(f"{self._phase}_{self._name}_s", seconds)
+        self._timer.count(f"{self._phase}_{self._name}_s", now - self._t0)
+        watch = self._timer.watch
+        if watch is not None:
+            watch.mark(watch.label, now)
+            watch.label = self._phase
         return False
+
+
+class DryWatch:
+    """The account of a DRY device that an engine keeps of itself: how
+    long the device had run out of work, in how many gaps, and under which
+    phase of the host's step. The engine hands the device every program
+    it runs and the device runs them in order, so an output of the newest
+    one (the HANDLE: an array that nothing donates before the next
+    dispatch) is ready exactly when the device's queue is empty, and
+    ``is_ready()`` asks without waiting (0.2-0.4 us). Not DRAINED: that is
+    the engine reading its tokens with nothing dispatched behind them.
+
+    The engine calls ``dispatching()`` just before it hands the device a
+    program and ``dispatched(handle)`` just after; the step's timer
+    (``StepTimer(watch=...)``) calls ``mark`` where a phase, a part or the
+    time between two steps ends, and the engine calls ``poll()`` inside
+    its long loops. While the last poll read "busy" each of them polls
+    once. The first that reads "ready" bounds the moment the device ran
+    dry to the interval since the poll before it: the gap's LOWER length
+    runs from this poll to the next program's dispatch, its UPPER length
+    from the poll before, and the account takes their mean, half of that
+    interval, under the phase it lay in. What follows until the dispatch
+    is dry in full, under the phase each interval lay in, so the seconds
+    by phase sum to the mean of the two bounds.
+
+    ``take(timer)`` moves what has been counted since the last take into
+    the step: the counters ``dispatches``, ``dry_dispatches`` (those that
+    found the device dry), ``dry_s_lower`` and ``dry_s_upper``, and for the
+    step's accumulator the seconds by phase, the gaps that ended by bucket
+    of ``_GAP_EDGES`` and the longest of them. A gap that spans two steps
+    leaves each the seconds that lay in it and counts as a gap where it
+    ends. While a profiler trace runs a gap is also the span
+    ``dry/<phase>``, from the poll that found the device ready to the
+    dispatch that ended the gap. ``totals`` is the same account since the
+    watch was made (the engine's ``stats()["dry"]``)."""
+
+    __slots__ = ("label", "totals", "_handle", "_busy", "_at", "_gap",
+                 "_span", "_n", "_dry_n", "_lower", "_upper", "_by",
+                 "_hist", "_max")
+
+    def __init__(self):
+        # the phase, or phase/part, that is open on the stepping thread
+        self.label = ""
+        self.totals: Dict[str, Any] = {
+            "dispatches": 0, "dry_dispatches": 0, "dry_s": 0.0,
+            "dry_s_lower": 0.0, "dry_s_upper": 0.0, "by_phase": {},
+            "gap_max_s": 0.0}
+        self._handle = None
+        self._busy = False     # what the last poll read
+        self._at = 0.0         # perf_counter at the last poll or mark
+        self._gap = 0.0        # the open gap's length so far (the mean)
+        self._span = None
+        self._take()
+
+    def _take(self):
+        self._n = self._dry_n = 0
+        self._lower = self._upper = 0.0
+        self._by: Dict[str, float] = {}
+        self._hist: Dict[int, int] = {}
+        self._max = 0.0
+
+    def mark(self, label: str, now: float) -> None:
+        """The interval since the last mark lay under ``label`` and ends
+        ``now`` (``time.perf_counter()``)."""
+        handle = self._handle
+        if handle is None:
+            return
+        seconds = now - self._at
+        self._at = now
+        if self._busy:
+            try:
+                if not handle.is_ready():
+                    return
+            except RuntimeError:
+                pass   # deleted: donated by a program of somebody else's
+            self._busy = False
+            self._span = _traced_span("dry/" + label)
+            self._upper += seconds
+            seconds *= 0.5
+        else:
+            self._lower += seconds
+            self._upper += seconds
+        self._gap += seconds
+        self._by[label] = self._by.get(label, 0.0) + seconds
+
+    def poll(self) -> None:
+        """One poll from inside a loop of the open phase; nothing once the
+        device has been found dry (the next mark counts the interval)."""
+        if self._busy:
+            self.mark(self.label, time.perf_counter())
+
+    def dispatching(self) -> None:
+        """Just before a program is handed to the device: the one poll
+        that says whether this dispatch finds it dry."""
+        self._n += 1
+        if self._busy:
+            self.mark(self.label or "outside", time.perf_counter())
+
+    def dispatched(self, handle) -> None:
+        """Just after: the device has work again, and ``handle`` is ready
+        when it has done all of it."""
+        now = time.perf_counter()
+        if self._handle is not None and not self._busy:
+            self.mark(self.label or "outside", now)
+            gap, self._gap = self._gap, 0.0
+            bucket = bisect.bisect_left(_GAP_EDGES, gap)
+            self._hist[bucket] = self._hist.get(bucket, 0) + 1
+            self._max = max(self._max, gap)
+            self._dry_n += 1
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+                self._span = None
+        self._handle = handle
+        self._busy = True
+        self._at = now
+
+    def waited(self, array) -> None:
+        """The caller has just waited for ``array``: if that is the handle
+        (a read with nothing dispatched behind it), the device was busy
+        until now."""
+        if self._busy and array is self._handle:
+            self._at = time.perf_counter()
+
+    def idle(self) -> None:
+        """The owner has no work left, so none for the device: what
+        follows is nobody's wait, and the open gap is no gap."""
+        self._handle = None
+        self._busy = False
+        self._gap = 0.0
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def take(self, timer: "StepTimer"
+             ) -> Optional[Tuple[Dict[str, float], Dict[int, int], float]]:
+        """See the class docstring. None if no second was dry."""
+        if not self._n and not self._upper:
+            return None
+        totals = self.totals
+        timer.count("dispatches", self._n)
+        timer.count("dry_dispatches", self._dry_n)
+        timer.count("dry_s_lower", self._lower)
+        timer.count("dry_s_upper", self._upper)
+        totals["dispatches"] += self._n
+        totals["dry_dispatches"] += self._dry_n
+        out = None
+        if self._upper:
+            out = self._by, self._hist, self._max
+            totals["dry_s_lower"] += self._lower
+            totals["dry_s_upper"] += self._upper
+            totals["dry_s"] += 0.5 * (self._lower + self._upper)
+            _sum_phases(totals["by_phase"], self._by)
+            totals["gap_max_s"] = max(totals["gap_max_s"], self._max)
+        self._take()
+        return out
+
+
+# ---------------------------------------------------------------------------
+# the per-process report (get_accel_report RPC body)
+# ---------------------------------------------------------------------------
 
 
 def accel_report(force_jax: bool = False) -> Dict[str, Any]:

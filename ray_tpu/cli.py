@@ -701,15 +701,27 @@ def cmd_stack(args):
 
 
 def _print_extents(row):
-    """A step kind's distribution of extents and its newest slow steps
-    (accel plane: `extent_hist`, `slow`), each with its largest phase and
-    the parts of it that were timed apart."""
+    """A step kind's distribution of extents, its dry-device account and
+    its newest slow steps (accel plane: `extent_hist`, `dry_by_phase`,
+    `slow`), each with its largest phase and the parts of it that were
+    timed apart."""
     from ray_tpu._internal import accel
     hist = row["extent_hist"]
     p50, p99, top = (accel.extent_quantile(hist, q) * 1e3
                      for q in (0.5, 0.99, 1.0))
     print(f"    extent p50={p50:.1f}ms p99={p99:.1f}ms max<={top:.0f}ms "
           f"· slow {row['slow_total']} ({row['slow_seconds']:.2f}s)")
+    dry = row.get("dry_by_phase")
+    if dry:
+        # the device out of work while the owner had some (`DryWatch`)
+        seconds = sum(dry.values())
+        extents = row["wall_s"] + row["phases"].get("between", 0.0)
+        worst = ", ".join(f"{phase} {value:.2f}" for phase, value in sorted(
+            dry.items(), key=lambda kv: -kv[1])[:3])
+        print(f"    dry {seconds:.2f}s ({100.0 * seconds / extents:.1f}%) in "
+              f"{row['counters'].get('dry_dispatches', 0):.0f} of "
+              f"{row['counters'].get('dispatches', 0):.0f} dispatches, "
+              f"longest {row['dry_gap_max_s'] * 1e3:.0f}ms · {worst}")
     now = row.get("now") or time.monotonic()
     for step in row["slow"][-5:]:
         phase, seconds = max(step["phases"].items(), key=lambda kv: kv[1],

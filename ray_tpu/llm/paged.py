@@ -220,6 +220,11 @@ def _no_phase(*names: str):
     return contextlib.nullcontext()
 
 
+def _no_poll():
+    """`DryWatch.poll` for an engine that keeps no watch (the accel
+    plane's kill switch)."""
+
+
 def pool_copies(compiled_text: str, pool_shape) -> int:
     """`copy` ops of a compiled program (`compiled.as_text()`) whose
     result has the shape of one whole pool (a page pool's, a state
@@ -564,8 +569,17 @@ class PagedLLMEngine:
             if self._accel is not None else None
         # the whole continuous tick by phase (kind "tick"; "decode" above
         # keeps its extent, dispatch to last callback, inside it)
-        self._tick_accum = _accel.StepAccumulator("tick") \
+        self._tick_accum = _accel.StepAccumulator("tick", timeline=True) \
             if self._accel is not None else None
+        # the account of a dry device (README, "Tick phases"): polled
+        # before and set after every program this engine dispatches
+        # (`_dispatching` / `_dispatched`), where a phase of the tick ends
+        # and inside the stepping thread's long loops (the radix's)
+        self._dry = _accel.DryWatch() if self._accel is not None else None
+        # one poll of it, from inside the stepping thread's loops
+        self._poll = self._dry.poll if self._dry is not None else _no_poll
+        if self._dry is not None:
+            self.radix.poll = self._poll
         # perf_counter at the last tick's end if work was left then:
         # the next tick's `between` runs from it
         self._tick_end: Optional[float] = None
@@ -1289,7 +1303,8 @@ class PagedLLMEngine:
         if self._counters_asked:
             self.read_counters()
         entered = time.perf_counter()
-        tick = _accel.StepTimer("tick", sink=self._tick_accum)
+        tick = _accel.StepTimer("tick", sink=self._tick_accum,
+                                watch=self._dry)
         if self._tick_end is not None:
             tick.outside("between", entered - self._tick_end)
         before = self._ahead_counts()
@@ -1314,6 +1329,8 @@ class PagedLLMEngine:
             self._tick_end = time.perf_counter()
         else:
             self._tick_end = None
+            if self._dry is not None:
+                self._dry.idle()
             # drained: flush the partial windows so step telemetry
             # never lags an idle engine by up to `every` ticks
             self._flush_step_rows()
@@ -1388,6 +1405,17 @@ class PagedLLMEngine:
                 radix_evictions=self._radix_evictions)
         return counts
 
+    def _dispatching(self):
+        """Just before this engine hands the device a program."""
+        if self._dry is not None:
+            self._dry.dispatching()
+
+    def _dispatched(self, out):
+        """Just after: `out`, an array of the program's outputs that
+        nothing donates before the next dispatch."""
+        if self._dry is not None:
+            self._dry.dispatched(out)
+
     def _flush_step_rows(self):
         """When the engine drains and in `stats()`: the accumulators hand
         their partial window to the accel plane, and the gauges are set
@@ -1420,8 +1448,10 @@ class PagedLLMEngine:
         """`write_state` for every prefill that finished this tick."""
         with self._mesh_scope():
             for slot, staged in self._state_due:
+                self._dispatching()
                 self.state = self._write_state(
                     self.state, staged, jnp.asarray(slot, jnp.int32))
+                self._dispatched(self.state[0][0])
                 self._state_installs += 1
         self._state_due.clear()
 
@@ -1577,12 +1607,14 @@ class PagedLLMEngine:
         its shared span gathered in so the tail attends over it without
         recomputing."""
         with self._mesh_scope():
+            self._dispatching()
             dense = self._dense_zero_caches()
             if seq.own_from:
                 pad = np.zeros(self.config.pages_per_seq, np.int32)
                 pad[:seq.own_from] = seq.pages[:seq.own_from]
                 dense = self._gather_pages(self.k_pages, self.v_pages,
                                            dense, jnp.asarray(pad))
+            self._dispatched(jax.tree_util.tree_leaves(dense)[0])
         seq.dense_caches = dense
 
     def _prefill_tick(self, part=_no_phase):
@@ -1672,8 +1704,10 @@ class PagedLLMEngine:
         window = np.zeros((full,), np.int32)
         window[:] = seq.pages[base:]
         with self._mesh_scope():
+            self._dispatching()
             self.k_pages, self.v_pages = self._compress_window(
                 self.params, self.k_pages, self.v_pages, window)
+            self._dispatched(self.k_pages[0])
         for page in seq.pages[base + kept:]:
             self.pool.decref(page)
         del seq.pages[base + kept:]
@@ -1722,10 +1756,12 @@ class PagedLLMEngine:
         else:
             staged, extra = seq.dense_caches, valid
         with self._mesh_scope():
+            self._dispatching()
             logits, staged = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 staged, jnp.asarray(off, jnp.int32), *extra,
                 jnp.asarray(last, jnp.int32))
+            self._dispatched(logits)
         if self._windowed:
             self.k_pages, self.v_pages = staged
             if cfg.model.window_closes(off + take):
@@ -1761,10 +1797,12 @@ class PagedLLMEngine:
         ids = list(write_ids) + [0] * (cfg.pages_per_seq
                                        - len(write_ids))
         with self._mesh_scope():
+            self._dispatching()
             self.k_pages, self.v_pages = self._write_pages(
                 self.k_pages, self.v_pages, dense_caches,
                 jnp.asarray(ids, jnp.int32),
                 jnp.asarray(start_page * cfg.page_size, jnp.int32))
+            self._dispatched(self.k_pages[0])
 
     def _finish_prefill(self, index: int):
         """Prompt fully cached: write the owned tail pages, commit full
@@ -1792,10 +1830,12 @@ class PagedLLMEngine:
         key = self._rng
         if temp > 0:
             self._rng, key = jax.random.split(self._rng)
+        self._dispatching()
         self._tokens = first_token(
             self._tokens, seq.last_logits, np.int32(index), key,
             np.full((1,), temp, np.float32), np.full((1,), top_k, np.int32),
             np.full((1,), top_p, np.float32), sampled=temp > 0)
+        self._dispatched(self._tokens)
         seq.last_logits = None
         self._tokens.copy_to_host_async()
         self._unread.append((index, seq))
@@ -1852,7 +1892,10 @@ class PagedLLMEngine:
         at = self.config.model.cache_rows if self._windowed \
             else (lambda length: length)
         rows = {i: self.seqs[i] for i in active}
-        for i in sorted(active, key=lambda i: self.seqs[i].admit_at):
+        for n, i in enumerate(
+                sorted(active, key=lambda i: self.seqs[i].admit_at)):
+            if not n & 7:
+                self._poll()
             seq = rows[i]
             while self.seqs[i] is seq \
                     and at(seq.length) // ps >= len(seq.pages):
@@ -2011,6 +2054,8 @@ class PagedLLMEngine:
         unread, self._unread = self._unread, []
         with phase("wait"), _accel.pause("drain/" + reason):
             values = self._fetch(self._tokens)
+            if self._dry is not None:
+                self._dry.waited(self._tokens)
         with phase("emit"):
             self._emit_tokens(unread, values)
 
@@ -2101,7 +2146,10 @@ class PagedLLMEngine:
             temps = np.zeros((B,), np.float32)
             top_ks = np.zeros((B,), np.int32)
             top_ps = np.ones((B,), np.float32)
-            for i in active:
+            poll = self._poll
+            for n, i in enumerate(active):
+                if not n & 7:     # a poll is ~1 us on a slow host
+                    poll()
                 seq = self.seqs[i]
                 block_tables[i, :len(seq.pages)] = seq.pages
                 lengths[i] = seq.length
@@ -2134,6 +2182,7 @@ class PagedLLMEngine:
                 else:
                     self._index_pages_distinct += np.count_nonzero(seen)
                 seen[:] = False
+            poll()
             if self._latent:
                 # and what the kernel does not copy of them: a group's
                 # shared span for every member but one, by the schedule
@@ -2141,8 +2190,10 @@ class PagedLLMEngine:
                 self._latent_pages_copied -= int(  # host-sync ok: numpy
                     pages_spared(share_schedule(
                         block_tables, lengths, cfg.page_size)))
+                poll()
             self._decode_rows += len(active)
             self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
+            poll()
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
         timer = accel.StepTimer(
@@ -2155,10 +2206,12 @@ class PagedLLMEngine:
                 with (timer.device() if timer is not None
                       else contextlib.nullcontext()):
                     with phase("stage"):
-                        args = (jnp.asarray(block_tables),
-                                jnp.asarray(lengths), self._tokens,
-                                key, jnp.asarray(temps),
-                                jnp.asarray(top_ks), jnp.asarray(top_ps))
+                        def upload(array):
+                            poll()
+                            return jnp.asarray(array)
+                        args = (upload(block_tables), upload(lengths),
+                                self._tokens, key, upload(temps),
+                                upload(top_ks), upload(top_ps))
                     with phase("dispatch"):
                         unread, tokens = self._unread, self._tokens
                         if self._in_place or self.state is not None:
@@ -2166,6 +2219,7 @@ class PagedLLMEngine:
                             # count or scan
                             live = np.zeros((B,), bool)
                             live[active] = True
+                        self._dispatching()
                         if self._in_place:
                             (self._tokens, self._row_pools,
                              self.counters) = self._decode(
@@ -2182,6 +2236,7 @@ class PagedLLMEngine:
                                 self.params, self.k_pages, self.v_pages,
                                 self.state, jnp.asarray(live), *args,
                                 self.counters)
+                        self._dispatched(self._tokens)
                         # the copy to the host starts when the step ends,
                         # whatever is queued behind it by then
                         self._tokens.copy_to_host_async()
@@ -2277,6 +2332,12 @@ class PagedLLMEngine:
             # the step ahead (`_ahead_counts`; `drained_by`: why)
             **self._ahead_counts(),
             "drained_by": dict(self._drained_ticks),
+            # the device out of work (`accel.DryWatch`): programs
+            # dispatched, those that found it dry, the seconds it was
+            # (`dry_s`, the mean of the two bounds) and under which phase
+            "dry": dict(self._dry.totals,
+                        by_phase=dict(self._dry.totals["by_phase"]))
+            if self._dry is not None else {},
             "sampler": dict(zip(SAMPLER_TIERS, self._sampler_steps)),
             # recurrent state beside the pages (zeros for a model
             # without it)

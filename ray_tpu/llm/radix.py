@@ -38,7 +38,10 @@ class RadixPrefixCache:
     One node = one full page of prompt tokens = one physical page id
     (ids are shared across layers, exactly like sequence block tables).
     ``max_entries`` is the node budget enforced after each insert;
-    ``evict_pages`` frees pages on demand under pool pressure.
+    ``evict_pages`` frees pages on demand under pool pressure. ``poll``,
+    where the owner sets one, is called once a page of an insert and once
+    a node evicted (the engine's `DryWatch.poll`: a long prompt's commit
+    is the longest stretch its stepping thread spends off the device).
     """
 
     def __init__(self, pool, page_size: int, max_entries: int = 128):
@@ -50,6 +53,7 @@ class RadixPrefixCache:
         self.entries = 0
         self.hits = 0
         self.misses = 0
+        self.poll = None
 
     # -- lookup / commit ---------------------------------------------------
 
@@ -100,7 +104,10 @@ class RadixPrefixCache:
         self._clock += 1
         node = self._root
         added = 0
+        poll = self.poll
         for i in range(len(tokens) // ps):
+            if poll is not None:
+                poll()
             key = tuple(tokens[i * ps:(i + 1) * ps])
             child = node.children.get(key)
             if child is None:
@@ -140,6 +147,8 @@ class RadixPrefixCache:
             max_entries = self.max_entries
         freed = 0
         while self.entries > max_entries:
+            if self.poll is not None:
+                self.poll()
             leaves = self._evictable_leaves()
             if not leaves:
                 break  # everything left is shared with a live sequence
@@ -154,6 +163,8 @@ class RadixPrefixCache:
         freed."""
         freed = 0
         while freed < want:
+            if self.poll is not None:
+                self.poll()
             leaves = self._evictable_leaves()
             if not leaves:
                 break

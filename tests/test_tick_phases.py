@@ -276,7 +276,7 @@ def usual_then(acc, kind, **slow):
 @pytest.mark.parametrize("steps", [1, 16, 37])
 def test_extent_hist_counts_every_step_once(steps):
     kind = f"hist-{steps}"
-    acc = accel.StepAccumulator(kind)
+    acc = accel.StepAccumulator(kind, timeline=True)
     visit(acc, kind)
     acc.flush()
     before = row_of(kind)
@@ -326,7 +326,7 @@ def test_a_slow_thread_clock_is_inside_the_phase_it_measures(monkeypatch):
 def test_slow_visit_is_kept_whole(phase):
     kind = f"slow-{phase}"
     t0 = time.monotonic()
-    step = usual_then(accel.StepAccumulator(kind), kind,
+    step = usual_then(accel.StepAccumulator(kind, timeline=True), kind,
                       sleeps={phase: 0.12}, between=0.003)
     assert max(step["phases"], key=step["phases"].get) == phase
     assert step["phases"][phase] >= 0.12
@@ -354,7 +354,7 @@ def test_slow_visit_is_kept_whole(phase):
 def test_pause_that_overlaps_a_slow_visit_is_listed(pause, overlap):
     kind = f"pause-{pause[0]}"
     what = f"test-{pause[0]}"
-    acc = accel.StepAccumulator(kind)
+    acc = accel.StepAccumulator(kind, timeline=True)
     began = []
 
     def stamp():
@@ -392,7 +392,7 @@ def test_forced_collection_during_a_visit_is_stamped(generation):
     assert accel.watch_gc() and accel.watch_gc()   # idempotent
     try:
         assert gc.callbacks.count(accel._on_gc) == 1
-        step = usual_then(accel.StepAccumulator(kind), kind,
+        step = usual_then(accel.StepAccumulator(kind, timeline=True), kind,
                           sleeps={"emit": 0.1},
                           inside=lambda: gc.collect(generation))
     finally:
@@ -403,28 +403,69 @@ def test_forced_collection_during_a_visit_is_stamped(generation):
     assert stamped[-1]["seconds"] <= step["phases"]["emit"]
 
 
-def test_slow_keeps_64_and_slow_total_keeps_counting():
+class Clock:
+    """`time`, as `accel` sees it, at a scripted moment."""
+
+    def __init__(self, at=1000.0):
+        self.at = at
+
+    def monotonic(self):
+        return self.at
+
+    perf_counter = monotonic
+
+    def thread_time(self):
+        return 0.0
+
+
+def test_slow_is_kept_by_time_and_slow_total_keeps_counting(monkeypatch):
+    """`slow` is a view of the kind's `timeline`, which keeps a flush for
+    ten minutes whatever their number (the list kept 64 before PR 54: a
+    reader's two marks 100 s apart lost the window's visits)."""
+    clock = Clock()
+    monkeypatch.setattr(accel, "time", clock)
     kind = "slow-many"
-    acc = accel.StepAccumulator(kind)
-    for _ in range(100):
+    acc = accel.StepAccumulator(kind, timeline=True)
+    for _ in range(300):          # 300 slow steps in 200 s
         for _ in range(10):
             acc.add(0.010)
         acc.add(0.5, extent_s=1.0)
+        clock.at += 200.0 / 300
     acc.flush()
     row = row_of(kind)
-    assert row["steps"] == 1100 and sum(row["extent_hist"]["counts"]) == 1100
-    assert row["slow_total"] == 100 and len(row["slow"]) == 64
-    assert row["slow_seconds"] == pytest.approx(100.0)
+    assert row["steps"] == 3300 and sum(row["extent_hist"]["counts"]) == 3300
+    assert row["slow_total"] == 300 and len(row["slow"]) == 300
+    assert row["slow_seconds"] == pytest.approx(300.0)
     assert all(step["extent_s"] == 1.0 and step["wall_s"] == 0.5
                for step in row["slow"])
     ends = [step["end"] for step in row["slow"]]
-    assert ends == sorted(ends)
+    assert ends == sorted(ends) and 199.0 < ends[-1] - ends[0] < 200.0
+    timeline = row["timeline"]
+    assert len(timeline) == -(-3300 // 16)
+    assert sum(flush["steps"] for flush in timeline) == 3300
+    assert [step for flush in timeline for step in flush["slow"]] \
+        == row["slow"]
     # the usual extent followed the many, not the few
     assert row["slow"][-1]["typical_s"] == pytest.approx(0.010, rel=0.35)
     assert accel.extent_quantile(row["extent_hist"], 0.5) == pytest.approx(
         0.010, rel=0.2)
     assert accel.extent_quantile(row["extent_hist"], 0.99) == pytest.approx(
         1.0, rel=0.2)
+    # 599 s after the oldest flush every row is still there; 202 s later
+    # only what ended inside ten minutes
+    clock.at += 399.0
+    for _ in range(16):
+        acc.add(0.010)
+    assert len(row_of(kind)["timeline"]) == len(timeline) + 1
+    clock.at += 202.0
+    for _ in range(15):
+        acc.add(0.010)
+    acc.add(0.5, extent_s=1.0)
+    row = row_of(kind)
+    assert [flush["end"] for flush in row["timeline"]] \
+        == [clock.at - 202.0, clock.at]
+    assert len(row["slow"]) == 1 and row["slow_total"] == 301
+    assert row["steps"] == 3332 and sum(row["extent_hist"]["counts"]) == 3332
 
 
 @pytest.mark.parametrize("what", ["fields", "stamp", "span", "gc"])
@@ -555,11 +596,20 @@ def test_part_is_a_counter_of_its_timer_and_nothing_under_the_switch():
 def test_cli_prints_extents_and_the_parts_of_a_slow_steps_phase(capsys):
     from ray_tpu import cli
     kind = "printed"
-    usual_then(accel.StepAccumulator(kind), kind, sleeps={"emit": 0.1})
+    usual_then(accel.StepAccumulator(kind, timeline=True), kind,
+               sleeps={"emit": 0.1})
     row = dict(row_of(kind), now=time.monotonic())
     row["slow"][-1]["counters"].update(emit_handoff_s=0.0625, emits=3)
     cli._print_extents(row)
+    assert "dry " not in capsys.readouterr().out    # no account, no line
+    row.update(dry_by_phase={"prefill/finish": 0.75, "stage": 0.25},
+               dry_gap_max_s=0.125, wall_s=19.0,
+               phases=dict(row["phases"], between=1.0))
+    row["counters"].update(dispatches=400, dry_dispatches=12)
+    cli._print_extents(row)
     out = capsys.readouterr().out
+    assert "dry 1.00s (5.0%) in 12 of 400 dispatches, longest 125ms " \
+        "· prefill/finish 0.75, stage 0.25" in out
     assert "extent p50=" in out and f"slow {row['slow_total']} (" in out
     assert "emit=1" in out and "(handoff 62.5)" in out
     assert "pauses: none stamped" in out
