@@ -1402,7 +1402,8 @@ class PagedLLMEngine:
                 prefix_shared_tokens=self._prefix_shared_tokens,
                 prefill_computed_tokens=self._prefill_computed_tokens,
                 prefill_ctx_rows=self._prefill_ctx_rows,
-                radix_evictions=self._radix_evictions)
+                radix_evictions=self._radix_evictions,
+                radix_evict_walks=self.radix.walks)
         return counts
 
     def _dispatching(self):
@@ -2302,6 +2303,8 @@ class PagedLLMEngine:
         step), `sparse_rows_selected` (sum of min(context, topk)) and
         `sparse_rows_context` (sum of the contexts), `prefix_shared_tokens`,
         `prefill_computed_tokens`, `prefill_ctx_rows`, `radix_evictions`,
+        `radix_evict_walks` (walks of the whole radix that eviction made:
+        at most one a call that had to drop a node, `radix.py`),
         and `index_cache_bytes` / `sparse_kernel`."""
         self._flush_step_rows()  # surfaces the partial window
         index_bytes = sum(int(np.prod(pool.shape)) * pool.dtype.itemsize

@@ -129,6 +129,185 @@ def test_radix_lru_evicts_only_unreferenced_leaves():
     assert radix.entries == 0 and radix.pages() == []
 
 
+@pytest.mark.parametrize("case", ["commit", "pressure"])
+def test_radix_eviction_walks_once_a_call_at_the_long_prompt_shape(case):
+    """The long-prompt cell's tree, small: twelve live rows pin a chain of
+    eight pages each, far over the entry budget; four rows end. `commit`:
+    the next finished prompt's insert drops exactly the four released
+    chains, oldest first and each from its tail up, in ONE walk of the
+    tree. `pressure`: with the pool dry, `_alloc_page` and admission's
+    `evict_pages(want)` take the same victims in the same order, a walk a
+    call whatever `want` is."""
+    budget = 4 if case == "commit" else 1024
+    CONFIG.apply_system_config({"prefix_cache_entries": budget})
+    try:
+        engine = PagedLLMEngine(PagedEngineConfig(
+            model=tiny_model(), max_batch=13, max_len=128, page_size=8,
+            num_pages=128, prefill_buckets=(32,)))
+        pool, radix = engine.pool, engine.radix
+
+        def finish(row):
+            prompt = [row + 1] * 32 + list(range(32))  # eight whole pages
+            pages = _alloc_chain(pool, 8)
+            engine.seqs[row].pages = pages
+            engine._register_prefix(prompt, pages)
+            return pages
+
+        chains = [finish(row) for row in range(12)]
+        assert radix.entries == 96, "a page a live row maps never leaves"
+        released = [7, 2, 9, 5]  # the rows end in this order ...
+        for row in released:
+            for page in chains[row]:
+                pool.decref(page)
+            engine.seqs[row].pages = []
+        oldest_first = sorted(released)  # ... but age is the commit's
+        expect = [page for row in oldest_first
+                  for page in reversed(chains[row])]
+        pinned = {page for row in range(12) if row not in released
+                  for page in chains[row]}
+        walks = radix.walks
+        if case == "commit":
+            last = finish(12)
+            pinned |= set(last)
+            assert radix.walks - walks == 1
+            assert pool._free[-32:] == expect, \
+                "victims: the released chains, oldest first, tail to head"
+            assert radix.entries == 72
+        else:
+            held = [pool.alloc() for _ in range(pool.num_free())]
+            engine.seqs[12].pages = held
+            for step in range(5):  # a row grows a page at a time
+                page = engine._alloc_page()
+                assert page == expect[step]
+                engine.seqs[12].pages.append(page)
+            assert radix.walks - walks == 5
+            assert radix.evict_pages(6) == 6  # admission asks for a span
+            assert radix.walks - walks == 6
+            assert pool._free == expect[5:11]
+            assert radix.evict_pages(0) == 0 and radix.walks - walks == 6
+            assert radix.entries == 96 - 11
+        assert pinned <= set(radix.pages()), "a pinned page left the tree"
+        assert all(pool.refs[page] == 2 for page in pinned)
+        assert engine.page_leak_check() == 0
+        # nothing over the budget, nothing dry: no walk at all
+        CONFIG.apply_system_config({"prefix_cache_entries": 1024})
+        walks = radix.walks
+        engine._register_prefix([99] * 16, engine.seqs[0].pages[:2])
+        assert radix.evict() == 0 and radix.walks == walks
+    finally:
+        CONFIG.apply_system_config({"prefix_cache_entries": 128})
+
+
+class _WalkPerVictimRadix(RadixPrefixCache):
+    """The plain reference: eviction as it stood before it kept a heap,
+    a walk of every node and a `min` for each node it drops."""
+
+    def _evictable_leaves(self):
+        out = []
+        stack = [self._root]
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if node is not self._root and not node.children \
+                    and self._pool.refs[node.page] == 1:
+                out.append(node)
+        return out
+
+    def _drop_oldest(self) -> bool:
+        leaves = self._evictable_leaves()
+        if leaves:
+            victim = min(leaves, key=lambda n: n.last_use)
+            del victim.parent.children[victim.key]
+            self._pool.decref(victim.page)
+            self.entries -= 1
+        return bool(leaves)
+
+    def evict(self, max_entries=None):
+        if max_entries is None:
+            max_entries = self.max_entries
+        freed = 0
+        while self.entries > max_entries and self._drop_oldest():
+            freed += 1
+        return freed
+
+    def evict_pages(self, want):
+        freed = 0
+        while freed < want and self._drop_oldest():
+            freed += 1
+        return freed
+
+
+def test_radix_eviction_matches_the_walk_per_victim_reference():
+    """200 random rounds of commit / match / release / pin / unpin /
+    evict / evict_pages on two pools, one under the radix and one under
+    the reference above: the same entries, the same pages held and the
+    same count on every page after each round (so the same victims in
+    the same order: a freed page is the next one allocated). The leaves
+    never tie on `last_use`, which is what makes the order one order."""
+    rng = np.random.RandomState(55)
+    sides = []
+    for cls in (RadixPrefixCache, _WalkPerVictimRadix):
+        pool = PagePool(160)
+        sides.append((pool, cls(pool, PS, max_entries=24), [], []))
+
+    def both(act):
+        return [act(*side) for side in sides]
+
+    def commit(pool, radix, rows, pins, tokens):
+        shared = radix.match(tokens)
+        want = len(tokens) // PS - len(shared)
+        if pool.num_free() < want:
+            radix.evict_pages(want - pool.num_free())
+        if pool.num_free() < want:
+            radix.release(shared)
+            return None
+        pages = shared + _alloc_chain(pool, want)
+        rows.append(pages)
+        return radix.insert(tokens, pages)
+
+    def end_row(pool, radix, rows, pins, pick):
+        for page in rows.pop(pick % len(rows)) if rows else []:
+            pool.decref(page)
+
+    def pin(pool, radix, rows, pins, pick):
+        held = sorted(radix.pages())
+        if held:
+            pins.append(held[pick % len(held)])
+            pool.incref(pins[-1])
+
+    def unpin(pool, radix, rows, pins, pick):
+        if pins:
+            pool.decref(pins.pop(pick % len(pins)))
+
+    for _ in range(200):
+        op, pick = rng.randint(8), int(rng.randint(1 << 20))
+        if op < 3:
+            tokens = [int(t) for t in
+                      rng.randint(1, 4, size=rng.randint(PS, 9 * PS))]
+            both(lambda *side: commit(*side, tokens))
+        elif op == 3:
+            tokens = [int(t) for t in rng.randint(1, 4, size=6 * PS)]
+            both(lambda pool, radix, *_: radix.release(radix.match(tokens)))
+        elif op == 4:
+            both(lambda *side: end_row(*side, pick))
+        elif op == 5:
+            both(lambda *side: (pin if pick % 2 else unpin)(*side, pick))
+        elif op == 6:
+            freed = both(lambda pool, radix, *_: radix.evict(pick % 24))
+            assert freed[0] == freed[1]
+        else:
+            freed = both(lambda pool, radix, *_: radix.evict_pages(pick % 12))
+            assert freed[0] == freed[1]
+        (pool, radix, _, _), (ref_pool, ref, _, _) = sides
+        assert radix.entries == ref.entries
+        assert sorted(radix.pages()) == sorted(ref.pages())
+        assert (pool.refs == ref_pool.refs).all()
+        assert pool._free == ref_pool._free
+        ages = [node.last_use for node in ref._evictable_leaves()]
+        assert len(set(ages)) == len(ages), "two leaves of one age"
+    assert ref.entries and sides[0][1].walks, "the rounds evicted nothing"
+
+
 def test_radix_property_vs_reference():
     """Random insert/match traffic against a brute-force reference:
     match() must return exactly the longest inserted full-page prefix

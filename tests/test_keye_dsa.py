@@ -622,6 +622,7 @@ def test_a_later_turn_maps_the_document_in_place():
     assert after["prefill_computed_tokens"] \
         - before["prefill_computed_tokens"] == 100 + 13 - 96
     assert after["radix_evictions"] == 0 and after["leaked_pages"] == 0
+    assert after["radix_evict_walks"] == 0  # nothing to drop: no walk
     # and nothing was copied anywhere: no dense staging, no pool copy
     assert all(s.dense_caches is None for s in shared.seqs)
     alone = tiny_engine(shared.params)

@@ -294,6 +294,7 @@ def test_a_second_ask_maps_the_shared_pages_and_computes_the_tail_alone():
         - before["prefill_computed_tokens"] == 50 + 13 - 48
     assert after["prefix_hits"] - before["prefix_hits"] == 1
     assert after["radix_evictions"] == 0 and after["leaked_pages"] == 0
+    assert after["radix_evict_walks"] == 0  # nothing to drop: no walk
     # and nothing was copied anywhere: the dense staging never existed
     assert all(s.dense_caches is None for s in shared.seqs)
     alone = tiny_engine(shared.params)
