@@ -60,6 +60,7 @@ if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
     from ..models.falcon_h1 import FalconH1Config
     from ..models.keye_dsa import KeyeDSAConfig
+    from ..models.lfm2 import Lfm2Config
     from ..models.nemotron_h import NemotronHConfig
     from ..models.sarvam_mla import SarvamMLAConfig
 
@@ -91,7 +92,10 @@ class PagedEngineConfig:
     # heads and head size, its type, and its flax module (`module()`; a
     # LlamaConfig's is LlamaModel). One whose rows carry recurrent state
     # beside their pages says in what shape and type (`state_shapes()`)
-    # and makes it (`init_state(rows)`). One whose layers are not all of
+    # and makes it (`init_state(rows)`): a scanning layer keeps the arrays
+    # `state_shapes()` names, in that order, each a pool of `max_batch`
+    # rows: a convolution window and a scan state (`conv`, `ssm`), or a
+    # window alone. One whose layers are not all of
     # one kind says what each keeps (`layer_caches()`), makes state for
     # the layers that scan only, and may carry per-layer accumulators
     # through the decode step (`init_counters()`); `_module_of` /
@@ -122,9 +126,14 @@ class PagedEngineConfig:
     # the same page ids and block table; its prefill chunks write all three
     # straight into the row's pages, and a radix-shared prefix maps K, V and
     # index pages in place (a radix node stays a page id; nothing is
-    # copied).
+    # copied). One that lays its own K and V pools says in what shape
+    # (`page_pool(pages, page_size)`; `_pooled`: heads narrower than a lane
+    # tile stand side by side in a row, `ops.paged_attention`): its prefill
+    # chunks then write and attend the row's pages through its table, and
+    # of a prefilling row only the scanning layers' state is staged.
     model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig",
-                 "EvaByteConfig", "SarvamMLAConfig", "KeyeDSAConfig"]
+                 "EvaByteConfig", "SarvamMLAConfig", "KeyeDSAConfig",
+                 "Lfm2Config"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -174,6 +183,13 @@ def _indexed(cfg) -> bool:
     and attends the selected ones alone (learned sparse attention): an
     index-key pool a layer beside its K and V pools."""
     return hasattr(cfg, "index_cache")
+
+
+def _pooled(cfg) -> bool:
+    """Whether this model lays its own K and V pools and its prefill chunks
+    write the row's pages themselves (built for a model whose rows carry
+    recurrent state: only that is staged for a prefilling row)."""
+    return hasattr(cfg, "page_pool")
 
 
 def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
@@ -394,6 +410,20 @@ class PagedLLMEngine:
         # models whose prefill chunks write the row's pages themselves, and
         # whose decode step takes `_row_pools` and the counters donated
         self._in_place = self._latent or self._indexed
+        # a state-carrying model whose chunks write the row's pages too,
+        # in pools of its own shape (`_pooled`)
+        self._pooled = _pooled(cfg)
+        if self._pooled:
+            if not _recurrent(cfg) or self._windowed or self._in_place:
+                raise NotImplementedError(
+                    "pools of a model's own shape are built for a model "
+                    "whose rows carry recurrent state and nothing else")
+            ps_, buckets = config.page_size, config.prefill_buckets
+            if buckets[-1] % ps_ or any(b % ps_ and ps_ % b
+                                        for b in buckets):
+                raise ValueError(
+                    f"prefill buckets {buckets} are not each whole pages "
+                    f"of {ps_} or a part of one")
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -432,10 +462,13 @@ class PagedLLMEngine:
             cfg.latent_cache()[1], reference) if self._latent \
             else sparse_kernel(reference, ps, cfg.index_cache()) \
             if self._indexed \
-            else paged_kernel(hd, reference)
+            else paged_kernel(hd, reference, cfg.page_pool(P, ps)[-1]
+                              if self._pooled else hd)
         # kernel layout: [kv_heads, num_pages, page_size, head_dim]; the
-        # selected tokens' gather wants a token's kv heads in one row
-        shape = (1, P, ps, kvh * hd) if self._indexed else (kvh, P, ps, hd)
+        # selected tokens' gather wants a token's kv heads in one row; a
+        # model that lays its own says how
+        shape = (1, P, ps, kvh * hd) if self._indexed \
+            else cfg.page_pool(P, ps) if self._pooled else (kvh, P, ps, hd)
         def _zero_pages():
             z = jnp.zeros(shape, cfg.dtype)
             if self._page_sharding is not None:
@@ -452,7 +485,8 @@ class PagedLLMEngine:
             jnp.zeros((1, P, ps, cfg.index_cache()), cfg.dtype)
             for _ in range(attending)] if self._indexed else []
         # recurrent state beside the pages, for a model that has it: per
-        # layer that scans, (conv, ssm) pools of max_batch rows, row = slot
+        # layer that scans, a tuple of pools of max_batch rows (what
+        # `state_shapes()` names: (conv, ssm), or (conv,)), row = slot
         # index (a slot that is not decoding is masked out of the decode
         # step, and an install overwrites a row whole); None otherwise
         self.state = cfg.init_state(config.max_batch) \
@@ -472,6 +506,8 @@ class PagedLLMEngine:
         self._state_due: List[Tuple[int, Any]] = []
         self._state_installs = 0
         self._prefix_skipped_recurrent = 0
+        # prefill chunks of a `_pooled` model: each wrote the row's pages
+        self._prefill_chunks_in_place = 0
         # windows compressed (by the phase the row was in), the pages that
         # gave back, prompts whose prefix was not looked up because a page
         # of this model is no prefix's K/V once its window has closed, and
@@ -899,13 +935,20 @@ class PagedLLMEngine:
                                         donate_argnums=(1, 2))
 
     def lower_chunk(self, bucket: Optional[int] = None):
-        """The prefill chunk of a `_windowed` or `_in_place` model lowered at
-        this engine's shapes (the largest bucket's unless told), from shapes
-        alone, in the form the tick runs (`last` given)."""
+        """The prefill chunk of a `_windowed`, `_in_place` or `_pooled`
+        model lowered at this engine's shapes (the largest bucket's unless
+        told), from shapes alone, in the form the tick runs (`last`
+        given)."""
         cfg = self.config
         bucket = bucket or cfg.prefill_buckets[-1]
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
         params, k_pages, v_pages = self._shapes()
+        if self._pooled:
+            staged = {"kv": list(zip(k_pages, v_pages)),
+                      "state": jax.eval_shape(lambda: cfg.model.init_state(1))}
+            return self._chunk_prefill.lower(
+                params, i32(1, bucket), i32(1, bucket), staged, i32(),
+                i32(cfg.pages_per_seq), i32(), i32())
         if self._in_place:
             pools = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -936,18 +979,25 @@ class PagedLLMEngine:
         """The programs of a model whose rows carry recurrent state, in
         place of the three above that would not know it: the decode step
         takes and returns the state pools donated beside the page pools,
-        a prefill chunk hands (conv, ssm) on in its staging pytree and is
-        told how many of its tokens are real, and `write_state` installs a
-        finished prefill's state into its slot. A layer is handed, and
-        hands back, what its kind keeps (`_layer_caches`) and nothing
-        else: `k_pages` / `v_pages` hold a pool per layer that attends,
-        `state` a pair per layer that scans, `counters` a tuple per layer
-        that counts."""
+        a prefill chunk hands the scanning layers' state on in its staging
+        pytree and is told how many of its tokens are real, and
+        `write_state` installs a finished prefill's state into its slot. A
+        layer is handed, and hands back, what its kind keeps
+        (`_layer_caches`) and nothing else: `k_pages` / `v_pages` hold a
+        pool per layer that attends, `state` a tuple per layer that scans
+        (the arrays `state_shapes()` names, in its order: `(conv, ssm)`,
+        or a window alone), `counters` a tuple per layer that counts. The
+        chunk of a `_pooled` model takes the page pools in its staging
+        pytree's "kv" and the row's block table, and writes and attends the
+        row's pages where they lie: a prefilling row then stages its state
+        and no K/V."""
         config, cfg, model = self.config, self.config.model, self.model
         kinds = _layer_caches(cfg)
+        names = tuple(cfg.state_shapes())
+        pooled = _pooled(cfg)
 
         def by_kind(new):
-            """A model's per-layer tuples (k, v, conv, ssm, counters...),
+            """A model's per-layer tuples (k, v, state..., counters...),
             each holding its kind's part only, as the four lists."""
             nk, nv, nstate, ncount = [], [], [], []
             for (attends, scans, counts), kept in zip(kinds, new):
@@ -956,7 +1006,7 @@ class PagedLLMEngine:
                     nk.append(kept.pop(0))
                     nv.append(kept.pop(0))
                 if scans:
-                    nstate.append((kept.pop(0), kept.pop(0)))
+                    nstate.append(tuple(kept.pop(0) for _ in names))
                 if counts:
                     ncount.append(tuple(kept))
             return nk, nv, nstate, ncount
@@ -974,16 +1024,18 @@ class PagedLLMEngine:
                     cache.update(k=k, v=v, block_tables=block_tables,
                                  lengths=lengths)
                 if scans:
-                    cache["conv"], cache["ssm"] = next(states)
+                    cache.update(zip(names, next(states)))
                 if counts:
                     cache["pairs"], cache["steps"] = next(counts_of)
                 caches.append(cache)
             return caches
 
-        def chunk_caches(staged):
-            """What each layer is handed in a prefill chunk."""
+        def chunk_caches(staged, table=()):
+            """What each layer is handed in a prefill chunk (`table`: the
+            row's block table behind a `_pooled` model's pools, in a
+            1-tuple)."""
             dense, states = iter(staged["kv"]), iter(staged["state"])
-            return [(tuple(next(dense)) if attends else ())
+            return [(tuple(next(dense)) + table if attends else ())
                     + (tuple(next(states)) if scans else ())
                     for attends, scans, _ in kinds]
 
@@ -1008,26 +1060,46 @@ class PagedLLMEngine:
         # `counters` is () for a model without any: no argument, no result
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 3, 12))
 
-        def chunk_prefill(params, tokens, positions, staged, offset, valid,
-                          last=None):
-            """One prefill chunk of one row. `staged`: {"kv": dense
-            (k, v) per layer that attends, "state": (conv, ssm) per layer
-            that scans}. Attention overwrites or masks the padded tail; a
-            layer that scans (or counts) is told `valid`, the count of
-            real tokens, and keeps the rest out of what it hands on.
-            `last` and the logits returned: as the dense `chunk_prefill`'s
-            (`chunk_logits`)."""
+        def chunk(params, tokens, positions, staged, offset, valid, last,
+                  table=()):
             hidden, new = model.apply(
                 {"params": params}, tokens, positions=positions,
-                kv_caches=chunk_caches(staged), cache_index=offset,
+                kv_caches=chunk_caches(staged, table), cache_index=offset,
                 valid=valid, head=False)
             nk, nv, nstate, _ = by_kind(new)
             return chunk_logits(model, params, hidden, last), {
                 "kv": list(zip(nk, nv)), "state": nstate}
 
+        def chunk_prefill(params, tokens, positions, staged, offset, valid,
+                          last=None):
+            """One prefill chunk of one row. `staged`: {"kv": dense
+            (k, v) per layer that attends, "state": what `state_shapes()`
+            names per layer that scans}. Attention overwrites or masks the
+            padded tail; a layer that scans (or counts) is told `valid`,
+            the count of real tokens, and keeps the rest out of what it
+            hands on. `last` and the logits returned: as the dense
+            `chunk_prefill`'s (`chunk_logits`)."""
+            return chunk(params, tokens, positions, staged, offset, valid,
+                         last)
+
+        if pooled:
+            def chunk_prefill(params, tokens, positions, staged,  # noqa: F811
+                              offset, table, valid, last=None):
+                """The chunk of a `_pooled` model: `staged["kv"]` holds the
+                (k, v) page POOLS per layer that attends, `table`
+                [pages_per_seq] the row's page ids (the null page where it
+                holds none). The chunk's first `valid` K/V rows are
+                written into the row's pages and attended there with
+                everything cached before them; the rest as above."""
+                return chunk(params, tokens, positions, staged, offset,
+                             valid, last, (table,))
+
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
         def _staging_zero():
+            if pooled:
+                # the pools stand in for "kv" when a chunk is dispatched
+                return {"kv": [], "state": cfg.init_state(1)}
             slack = config.prefill_buckets[-1]   # as _dense_zero_caches
             length = config.pages_per_seq * config.page_size + slack
             shape = (1, cfg.num_kv_heads, length, cfg.head_dim_)
@@ -1096,15 +1168,18 @@ class PagedLLMEngine:
                    for pool in self.k_pages[:1] + self.index_pages[:1])
 
     def state_copies(self, compiled_text: str) -> int:
-        """Whole-pool copies (`pool_copies`) at the shape of this engine's
-        scan-state pool, the large one (0 for a model without recurrent
-        state). The decode step must hold none: it updates the donated
-        pool in place, one read and one write. The convolution's window
-        pool is not counted: a few MB a layer, shifted whole every tick,
-        which the TPU compiler stages through fast memory."""
+        """Whole-pool copies (`pool_copies`) at the shape of the largest
+        pool a scanning layer of this engine keeps (0 for a model without
+        recurrent state): the scan state where a layer keeps `(conv,
+        ssm)`, the convolution's window where it keeps that alone. The
+        decode step must hold none: it updates the donated pool in place,
+        one read and one write. A window beside a scan state is not
+        counted: a few MB a layer, shifted whole every tick, which the TPU
+        compiler stages through fast memory."""
         if self.state is None:
             return 0
-        return pool_copies(compiled_text, self.state[0][1].shape)
+        largest = max(self.state[0], key=lambda pool: pool.size)
+        return pool_copies(compiled_text, largest.shape)
 
     def _mesh_scope(self):
         """Context for jit calls: marks the serving mesh active so the
@@ -1373,6 +1448,14 @@ class PagedLLMEngine:
         if self._sorted_buckets is not None:
             # chunks whose routed experts ran as sorted pairs
             counts["prefill_chunks_sorted"] = self._prefill_chunks_sorted
+        if self._pooled:
+            # chunks that wrote their K/V into the row's pages themselves,
+            # the prompt tokens they computed and the cached rows they
+            # attended
+            counts.update(
+                prefill_chunks_in_place=self._prefill_chunks_in_place,
+                prefill_computed_tokens=self._prefill_computed_tokens,
+                prefill_ctx_rows=self._prefill_ctx_rows)
         if self._windowed:
             # windows compressed and the pages that gave back; the rows of
             # each kind the decode steps attended, from lengths alone
@@ -1754,6 +1837,12 @@ class PagedLLMEngine:
             staged = self._row_pools
             extra = (self._row_table(seq), jnp.asarray(take, jnp.int32))
             self._prefill_ctx_rows += off + take
+        elif self._pooled:
+            staged = dict(seq.dense_caches,
+                          kv=list(zip(self.k_pages, self.v_pages)))
+            extra = (self._row_table(seq),) + valid
+            self._prefill_chunks_in_place += 1
+            self._prefill_ctx_rows += off + take
         else:
             staged, extra = seq.dense_caches, valid
         with self._mesh_scope():
@@ -1769,6 +1858,10 @@ class PagedLLMEngine:
                 self._close_window(seq, "prefill")
         elif self._in_place:
             self._row_pools = staged
+        elif self._pooled:
+            self.k_pages = [k for k, _ in staged["kv"]]
+            self.v_pages = [v for _, v in staged["kv"]]
+            seq.dense_caches = dict(staged, kv=[])
         else:
             seq.dense_caches = staged
         if finishes:
@@ -1817,7 +1910,7 @@ class PagedLLMEngine:
         request = seq.request
         prompt = seq.prompt
         # a `_windowed` or `_in_place` model's chunks wrote the row's pages
-        write_ids = [] if self._windowed or self._in_place \
+        write_ids = [] if self._windowed or self._in_place or self._pooled \
             else seq.pages[seq.own_from:]
         staged = seq.dense_caches
         if self.state is not None:

@@ -128,7 +128,14 @@ def sigmoid_top_k(u, router, bias, k: int, scale: float):
     Nemotron-H family's expert layers repeat), in float32 whatever the
     model computes in: scores s = sigmoid(u W_g) over ALL experts; the k
     experts with the largest s + bias are chosen (the bias chooses and
-    does not weigh); their weights are scale * s / sum of the chosen s.
+    does not weigh); their weights are scale * s / (sum of the chosen s
+    + 1e-20). The 1e-20 is DeepSeek-V3's constant, which Nemotron, Sarvam
+    and Xing publish too; LFM2 publishes 1e-6 there and is served with
+    this one all the same: four sigmoid scores sum to about 2, so the
+    departure is 5e-7 of a weight, a hundredth of a bf16 rounding of the
+    products the weight multiplies (settled in PR 56: one router for every
+    family; the configuration file lists it under `assumed`, and the
+    reference keeps the published 1e-6).
     u [T, d]; router [d, E]; bias [E]. Returns (chosen [T, k] int32,
     weights [T, k] float32, scores [T, E] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(

@@ -1,18 +1,35 @@
 """Paged decode attention: one query token a row over the row's K/V pages.
 
-`paged_attend` is the one entry point of every model's paged-decode branch
-(models/llama.py, models/falcon_h1.py, models/nemotron_h.py). Two paths:
+`paged_attend` is the one entry point of every model's paged-decode branch.
+Its callers and the head sizes they bring: models/llama.py (Mistral, 128),
+models/falcon_h1.py (128), models/nemotron_h.py (128), models/evabyte.py
+(128), each over a pool `[kv_heads, pages, page_size, head_dim]`; and
+models/lfm2.py (64) over a PACKED pool (below). Two paths:
 
-1. A Pallas TPU kernel written for the engine's page pool
-   (`[kv_heads, pages, page_size, head_dim]`), taken on a TPU when
-   `head_dim % 128 == 0`. A program is a ROW; one asynchronous copy moves a
-   page for ALL local kv heads (`kv_heads` runs of `page_size * head_dim`
-   elements); the copies of a block of pages are in flight while the block
-   before is computed, across rows too. The products take the pool's
-   (bf16) operands and accumulate in float32; the softmax statistics, the
-   probabilities into `P . V` and the output accumulator are float32.
-2. A gather fallback elsewhere (the CPU, toy head sizes, a model whose
-   `attention_impl` is "reference"): each row's pages materialised densely.
+1. A Pallas TPU kernel written for the engine's page pool, taken on a TPU
+   when the pool's rows are whole lane tiles (`lanes % 128 == 0`): heads
+   128 wide (or a multiple) in the plain pool, heads 64 wide (any width
+   that divides 128) in a packed one. A program is a ROW; one asynchronous
+   copy moves a page for ALL local kv heads (`kv_heads` runs of `page_size
+   * lanes` elements); the copies of a block of pages are in flight while
+   the block before is computed, across rows too. The products take the
+   pool's (bf16) operands and accumulate in float32; the softmax
+   statistics, the probabilities into `P . V` and the output accumulator
+   are float32.
+2. A gather fallback elsewhere (the CPU, toy head sizes in a plain pool, a
+   model whose `attention_impl` is "reference"): each row's pages
+   materialised densely.
+
+A packed pool (`packed_pool_shape`) stands `128 / head_dim` kv heads side
+by side in one 128-lane row, `[kv_heads * head_dim / 128, pages, page_size,
+128]`: a 64-wide head in a plain pool is padded to 128 lanes in the chip's
+memory, twice the bytes held and read. The kernel is the same one: the
+queries of the kv heads that share a row are laid block-diagonally over its
+lanes (zeros under the other heads' lanes), so `q . k` over 128 lanes is
+each head's own product, and of `P . V`'s 128 lanes each query keeps its own
+head's. `paged_attend_chunk` and `write_chunk_pages` are a prefill chunk's
+two halves over either pool: the chunk's rows into the row's pages, and
+the chunk's queries over the row's pages a block at a time.
 
 `paged_kernel` names the path a decode program built here will hold.
 """
@@ -20,6 +37,7 @@
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,39 +56,68 @@ _LOGIT_BYTES = 64 << 10
 _SUBLANES = 8
 
 
-def paged_kernel(head_dim: int, reference: bool = False) -> str:
-    """The path `paged_attend` takes here for heads of `head_dim`:
+def paged_kernel(head_dim: int, reference: bool = False,
+                 lanes: int = 0) -> str:
+    """The path `paged_attend` takes here for heads of `head_dim` over a
+    pool whose rows are `lanes` wide (`head_dim` itself in a plain pool):
     "pallas" or "gather"."""
     if (jax.default_backend() == "tpu" and not reference
-            and head_dim % NUM_LANES == 0):
+            and (lanes or head_dim) % NUM_LANES == 0):
         return "pallas"
     return "gather"
+
+
+def packed_pool_shape(kv_heads: int, head_dim: int, pages: int,
+                      page_size: int):
+    """A page pool for heads narrower than a lane tile: as many kv heads
+    side by side in a row as 128 lanes hold (and as divide `kv_heads`),
+    kv head h in lanes (h % f) * head_dim .. of row h // f. Two heads of
+    64 fill a row; a plain pool's shape where nothing packs."""
+    side = math.gcd(kv_heads, NUM_LANES // head_dim) \
+        if head_dim < NUM_LANES and NUM_LANES % head_dim == 0 else 1
+    return (kv_heads // side, pages, page_size, side * head_dim)
+
+
+def _side_by_side(pool, head_dim: int) -> int:
+    """kv heads that share a row of `pool` (1: a plain pool)."""
+    return pool.shape[-1] // head_dim
 
 
 def paged_attend(q, k_pages, v_pages, lengths, tables, *,
                  reference: bool = False):
     """q [rows, heads, hd], unscaled; k_pages / v_pages [kv_heads, pages,
     page_size, hd] (LOCAL heads and kv heads under a tensor axis: attention
-    is head-parallel, no collective); lengths [rows] tokens cached BEFORE
+    is head-parallel, no collective), or packed (`packed_pool_shape`);
+    lengths [rows] tokens cached BEFORE
     this one, which is already written at position lengths[row]; tables
     [rows, pages_per_row] physical page ids. Row b attends positions 0 ..
     lengths[b]. Returns [rows, heads, hd]."""
     hd = q.shape[-1]
-    if paged_kernel(hd, reference) == "pallas":
-        return _paged_attend_pallas(
-            (q * hd ** -0.5).astype(k_pages.dtype), k_pages, v_pages,
-            lengths + 1, tables)
+    side = _side_by_side(k_pages, hd)
+    if paged_kernel(hd, reference, k_pages.shape[-1]) == "pallas":
+        scaled = (q * hd ** -0.5).astype(k_pages.dtype)
+        if side == 1:
+            return _paged_attend_pallas(scaled, k_pages, v_pages,
+                                        lengths + 1, tables)
+        return _paged_attend_packed(scaled, k_pages, v_pages, lengths + 1,
+                                    tables)
     # Gather fallback: materialize each row's pages densely.
     # [B, pages_per_seq, kvh, ps, hd] -> [B, kvh, L, hd]
     rows, page_size = q.shape[0], k_pages.shape[2]
     gk = jnp.transpose(k_pages, (1, 0, 2, 3))[tables]
     gv = jnp.transpose(v_pages, (1, 0, 2, 3))[tables]
+    if side > 1:
+        # [B, pages, kvh / f, ps, f * hd] -> [B, pages, kvh, ps, hd]
+        gk, gv = (jnp.moveaxis(g.reshape(g.shape[:4] + (side, hd)), 4, 3)
+                  .reshape(g.shape[:2] + (-1, page_size, hd))
+                  for g in (gk, gv))
+    kv_heads = gk.shape[2]
     span = tables.shape[1] * page_size
     gk = jnp.transpose(gk, (0, 2, 1, 3, 4)).reshape(
-        rows, k_pages.shape[0], span, hd)
+        rows, kv_heads, span, hd)
     gv = jnp.transpose(gv, (0, 2, 1, 3, 4)).reshape(
-        rows, v_pages.shape[0], span, hd)
-    groups = q.shape[1] // k_pages.shape[0]
+        rows, kv_heads, span, hd)
+    groups = q.shape[1] // kv_heads
     gk = jnp.repeat(gk, groups, axis=1)
     gv = jnp.repeat(gv, groups, axis=1)
     logits = jnp.einsum(
@@ -81,6 +128,112 @@ def paged_attend(q, k_pages, v_pages, lengths, tables, *,
     logits = jnp.where(mask[:, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhk,bhkd->bhd", probs, gv.astype(jnp.float32))
+
+
+def _paged_attend_packed(q, k_pages, v_pages, lengths, tables,
+                         block_pages=None):
+    """The kernel over a packed pool. q [rows, heads, hd] SCALED and in the
+    pool's type; lengths as `_paged_attend_pallas` takes them. A row of the
+    pool holds `side` kv heads: their queries stand together as that row's
+    group, each under its own head's lanes and zero under the others', so
+    the kernel's 128-lane products are the heads' own; of its 128-lane
+    output each query keeps its head's lanes."""
+    rows, heads, hd = q.shape
+    side = _side_by_side(k_pages, hd)
+    packed = k_pages.shape[0]
+    groups = heads // (packed * side)
+    q = q.reshape(rows, packed, side, groups, hd)
+    wide = jnp.einsum("rpsgd,st->rpsgtd", q, jnp.eye(side, dtype=q.dtype))
+    out = _paged_attend_pallas(
+        wide.reshape(rows, heads, side * hd), k_pages, v_pages, lengths,
+        tables, block_pages=block_pages)
+    out = out.reshape(rows, packed, side, groups, side, hd)
+    return jnp.stack([out[:, :, s, :, s] for s in range(side)],
+                     axis=2).reshape(rows, heads, hd)
+
+
+# Cached tokens a step of `paged_attend_chunk`'s loop takes.
+_CHUNK_BLOCK_TOKENS = 512
+
+
+def write_chunk_pages(pool, rows, table, start, valid):
+    """A prefill chunk's K (or V) rows into ONE row's pages. pool plain or
+    packed; rows [chunk, kv_heads, hd], token i at position start + i;
+    table [pages_per_row] the row's page ids. `start` is a whole number of
+    chunks (the engine's chunks start at multiples of its largest bucket),
+    and a chunk is whole pages or a part of one page: the pages are written
+    as they stand, one update a page. A page that begins in the padded tail
+    (token i >= valid) goes to the null page; the padded tokens of the page
+    that holds the last real one stay in it, where the row's own later
+    tokens overwrite them before anything attends them."""
+    page_size = pool.shape[2]
+    chunk = rows.shape[0]
+    part = min(chunk, page_size)
+    if chunk % part or page_size % part:
+        raise ValueError(f"a chunk of {chunk} tokens is neither whole pages "
+                         f"of {page_size} nor a part of one")
+    # [chunk, kv_heads, hd] -> [packed, chunk, lanes]
+    rows = jnp.transpose(rows.reshape(chunk, pool.shape[0], pool.shape[3]),
+                         (1, 0, 2)).astype(pool.dtype)
+    for j in range(chunk // part):
+        at = start + j * part
+        page = jnp.where(j * part < valid,
+                         table[jnp.minimum(at // page_size,
+                                           table.shape[0] - 1)], 0)
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[:, None, j * part:(j + 1) * part],
+            (0, page, at % page_size, 0))
+    return pool
+
+
+def paged_attend_chunk(q, k_pages, v_pages, table, start):
+    """One prefill chunk of ONE row over its pages. q [chunk, heads, hd]
+    SCALED, query i at position start + i, its own K/V already written
+    (`write_chunk_pages`); the pools plain or packed; table [pages_per_row]
+    the row's page ids (the null page where it holds none). Query i attends
+    positions 0 .. start + i. The cached rows are taken a block of pages at
+    a time with running softmax statistics (float32; the two products take
+    the pool's type and accumulate in float32), as many blocks as the
+    chunk's last position reaches: neither the logits nor a dense copy of
+    the row's cache ever stands whole. Returns [chunk, heads, hd] float32."""
+    chunk, heads, hd = q.shape
+    packed, _, page_size, lanes = k_pages.shape
+    side = lanes // hd
+    kv_heads = packed * side
+    groups = heads // kv_heads
+    block_pages = max(1, _CHUNK_BLOCK_TOKENS // page_size)
+    block = block_pages * page_size
+    # whole blocks: a slice that ran past the table would be moved back
+    table = jnp.pad(table, (0, -table.shape[0] % block_pages))
+    queries = q.reshape(chunk, packed, side, groups, hd).astype(k_pages.dtype)
+    at = (start + jnp.arange(chunk))[:, None]
+
+    def attend_block(b, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, b * block_pages,
+                                           block_pages)
+        held = lambda pool: pool[:, ids].reshape(  # noqa: E731
+            packed, block, side, hd)
+        keys, values = held(k_pages), held(v_pages)
+        logits = jnp.einsum("qpsgd,ptsd->psgqt", queries, keys,
+                            preferred_element_type=jnp.float32)
+        seen = (b * block + jnp.arange(block))[None, :] <= at
+        logits = jnp.where(seen, logits, NEG_INF)
+        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        correction = jnp.exp(m - m_new)
+        return (m_new, l * correction + p.sum(-1, keepdims=True),
+                acc * correction + jnp.einsum(
+                    "psgqt,ptsd->psgqd", p.astype(v_pages.dtype), values,
+                    preferred_element_type=jnp.float32))
+
+    stats = (packed, side, groups, chunk, 1)
+    _, l, acc = jax.lax.fori_loop(
+        0, (start + chunk + block - 1) // block, attend_block,
+        (jnp.full(stats, NEG_INF, jnp.float32),
+         jnp.zeros(stats, jnp.float32),
+         jnp.zeros((packed, side, groups, chunk, hd), jnp.float32)))
+    return jnp.transpose(acc / l, (3, 0, 1, 2, 4)).reshape(chunk, heads, hd)
 
 
 def _chunk_tokens(kv_heads: int, queries: int) -> int:

@@ -1222,3 +1222,146 @@ def test_xing_prefill_chunk_compiles_for_v5e_over_the_pools(
     _xing_memory(p, compiled,
                  p["config"]["memory_analysis"]["chunk_prefill_512"],
                  temporaries="no_higher")
+
+
+# ---------------------------------------------------------------------------
+# the LFM2 cell's programs (2 + 20 of 40 layers: 17 gated short convolutions,
+# 5 attentions whose 64-wide heads stand two to a row of a packed pool, 8
+# of 64 experts held)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lfm2_programs(v5e):
+    """The `serve-lfm2-mixlen-closed128` cell's engine programs: its config
+    file's widths, rows and pool, its builder, its 22 layers, with the
+    shapes of their arguments on one described chip, on an engine that
+    never allocated anything."""
+    from benchmarks.harness.builders_lfm2 import lfm2_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "lfm2-24b-a2b-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = lfm2_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._recurrent_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = cfg.page_pool(engine_cfg.num_pages, engine_cfg.page_size)
+    attending = cfg.layer_types.count("full_attention")
+    return {"engine": engine, "engine_cfg": engine_cfg, "cfg": cfg,
+            "config": config, "spec": spec, "pool": pool,
+            "params": placed(jax.eval_shape(lambda: unbox(engine.model.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32))["params"]))),
+            "rows": engine_cfg.max_batch,
+            "pools": [spec(cfg.dtype, *pool)] * attending,
+            "state": lambda rows: placed(jax.eval_shape(
+                lambda: cfg.init_state(rows))),
+            "counters": placed(jax.eval_shape(cfg.init_counters))}
+
+
+def _lfm2_memory(p, compiled, recorded, largest=False):
+    """Arguments and temporaries no higher than the file's
+    `memory_analysis` states them, the pools aliased, and the rule of the
+    cut (ISSUE 56): 1.5 GB free beside the program, and beside the
+    `largest` program not 256 pages more. That is what the pool RESERVES;
+    what the cell's traffic fills of it is the cell's `pool_in_use_pct`
+    (a fifth: PERF.md section 4)."""
+    memory = compiled.memory_analysis()
+    pools = 2 * len(p["pools"]) * 2 * math.prod(p["pool"])
+    assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.argument_size_in_bytes <= 1.001 * recorded["argument_bytes"]
+    assert memory.temp_size_in_bytes <= 1.2 * recorded["temp_bytes"]
+    used = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert used < V5E_BYTES_LIMIT - 1.5e9
+    if largest:
+        more = 256 * p["config"]["memory_analysis"]["table"]["page_bytes"]
+        assert used + more >= V5E_BYTES_LIMIT - 1.5e9
+
+
+def test_lfm2_decode_step_compiles_for_v5e_within_memory(
+        lfm2_programs, as_tpu):
+    """96 rows, a block table 152 wide, 32 heads 64 wide against 8 kv heads
+    that stand two to a 128-lane row of the pool: the paged kernel in each
+    of the 5 attending layers (no gather fallback), no pool copied, the
+    pools, the seventeen window pools and the 20 counter pairs donated and
+    updated in place; the parameters are the file's table (2.13 B at 22
+    layers, the issue's 3.76 B at 40)."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    from ray_tpu.ops.paged_attention import paged_kernel
+    p = lfm2_programs
+    spec, rows, cfg = p["spec"], p["rows"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    recorded = p["config"]["memory_analysis"]["decode_step_batch96"]
+    assert (rows, width) == (96, 152) == (96, recorded["block_table_width"])
+    assert p["pool"] == (4, p["engine_cfg"].num_pages, 64, 128) \
+        == tuple(recorded["pool_shape"])
+    assert paged_kernel(64, lanes=128) == "pallas"
+    assert paged_kernel(64) == "gather" and paged_kernel(128) == "pallas"
+    table = p["config"]["memory_analysis"]["table"]
+    leaves = jax.tree_util.tree_leaves(p["params"])
+    assert sum(math.prod(a.shape) for a in leaves) \
+        == table["weights_params"] == 2129332096
+    assert sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves) \
+        == table["weights_bytes"]
+    state = p["state"](rows)
+    assert len(state) == 17 == recorded["window_pools"]
+    assert len(p["counters"]) == 20 == recorded["counter_pairs"]
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pools"], p["pools"], state, spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, width), spec(jnp.int32, rows),
+        spec(jnp.int32, rows), spec(jnp.uint32, 2), spec(jnp.float32, rows),
+        spec(jnp.int32, rows), spec(jnp.float32, rows),
+        p["counters"]).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_attention": 5}
+    assert pool_copies(text, p["pool"]) == 0
+    for scope in ("conv/in", "conv/filter", "conv/out", "attn/qk_norm",
+                  "attn/attend", "moe/route", "moe/experts"):
+        assert scope in text, scope
+    _lfm2_memory(p, compiled, recorded)
+
+
+def test_lfm2_prefill_chunk_compiles_for_v5e_into_the_pages(
+        lfm2_programs, as_tpu):
+    """The largest bucket (512 tokens): the chunk's K/V go into the row's
+    pages through its table and are attended there in blocks: no dense
+    K/V of a row ([1, 8, max_len + bucket, 64]) among its arguments or
+    temporaries, nothing of [chunk, vocab], no pool copied; the 20 expert
+    layers' pairs go sorted through the two grouped kernels; the pools
+    are donated and aliased."""
+    from ray_tpu.llm.paged import array_shapes, pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = lfm2_programs
+    spec, cfg = p["spec"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    assert p["engine_cfg"].prefill_buckets[-1] == 512
+    staged = {"kv": list(zip(p["pools"], p["pools"])),
+              "state": p["state"](1)}
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 512), spec(jnp.int32, 1, 512),
+        staged, spec(jnp.int32), spec(jnp.int32, width), spec(jnp.int32),
+        spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"grouped_hidden": 20, "grouped_out": 20}
+    assert pool_copies(text, p["pool"]) == 0
+    positions = width * p["engine_cfg"].page_size + 512
+    assert array_shapes(text, (1, 8, positions, 64)) == 0
+    assert array_shapes(text, (8, positions, 64)) == 0
+    assert array_shapes(text, (512, cfg.vocab_size)) == 0
+    _lfm2_memory(p, compiled,
+                 p["config"]["memory_analysis"]["chunk_prefill_512"],
+                 largest=True)
