@@ -37,7 +37,9 @@ where the two differ:
     over them with a kv head read as a slice of `head_dim` lanes of the
     rows as the gather leaves them: the gathered rows are never split into
     `[rows, top_k, kv_heads, head_dim]`, which the chip would relay out,
-    a whole gathered pool a layer. XLA gathers, not a kernel: seen as
+    a whole gathered pool a layer. The rows' places in the pools come from
+    the block table by a one-hot product (`pool_rows`), not by a gather of
+    page ids. XLA gathers of the rows, not a kernel: seen as
     `[tokens, kv_heads * head_dim]` a bf16 pool stands in HBM in tiles of
     8 token rows, the chip's compiler refuses a copy of one row, and a
     kernel that copies the aligned 8 rows a selected token lies in (8 KB
@@ -82,6 +84,14 @@ _COPY_BLOCK_STEPS = 2
 _PACKED_ROWS = 16
 # Rows of a float32 tile: so many rows' scores leave the kernel together.
 _SUBLANES = 8
+# Groups of 128 columns up to which `positions_of` takes a slot's row of
+# running counts by a one-hot product and not by a gather. In the Keye
+# cell's decode step on the chip, 48 rows x 2048 slots, `dsa/select` a
+# layer, gather -> product: 0.397 -> 0.295 ms at 528 groups (the cell's
+# 66,304 tokens of table), 0.795 -> 0.749 at 1056, 0.843 -> 0.835 at 1536
+# (PERF.md section 6, PR 57): the product's gain, 0.102, 0.046 and 0.008
+# ms, is spent near 1,600 groups.
+_ONE_HOT_GROUPS = 1536
 
 
 def sparse_kernel(reference: bool, page_size: int, lanes: int) -> str:
@@ -381,9 +391,13 @@ def positions_of(keep, k: int):
     ascending; m where a row keeps fewer. Slot j's group of 128 lanes is
     the count of groups whose running total is <= j, its lane the count of
     that group's lanes whose running count is <= j's rank in the group:
-    comparisons, sums and ONE gather of a 128-lane row a slot (a binary
-    search a slot gathers single elements, 17 times, and took 17 ms a
-    layer on the chip where this takes under one)."""
+    comparisons, sums and the group's 128-lane row of running counts a
+    slot (a binary search a slot gathers single elements, 17 times, and
+    took 17 ms a layer on the chip where this takes under one). The row is
+    taken by a product of the slot's one-hot over the groups with the
+    counts (exact: a count is <= 128, whole in bf16, and each sum holds
+    one of them) up to `_ONE_HOT_GROUPS` groups, by ONE gather of a row a
+    slot above: the product grows with the groups, the gather does not."""
     n, m = keep.shape
     within, totals = _group_counts(keep)
     upto = jnp.cumsum(totals, axis=-1)                       # [n, groups]
@@ -392,8 +406,14 @@ def positions_of(keep, k: int):
     passed = upto[:, None, :] <= slot                        # [n, k, groups]
     group = jnp.minimum(passed.sum(-1), groups - 1)
     rank = slot[..., 0] - jnp.where(passed, totals[:, None, :], 0).sum(-1)
-    rows = within.reshape(n * groups, NUM_LANES)[
-        group + jnp.arange(n)[:, None] * groups]             # [n, k, 128]
+    if groups <= _ONE_HOT_GROUPS:
+        here = group[..., None] == jnp.arange(groups)
+        rows = jnp.einsum("nkg,ngl->nkl", here.astype(jnp.bfloat16),
+                          within.astype(jnp.bfloat16),
+                          preferred_element_type=F32)        # [n, k, 128]
+    else:
+        rows = within.reshape(n * groups, NUM_LANES)[
+            group + jnp.arange(n)[:, None] * groups]
     at = group * NUM_LANES + (rows <= rank[..., None]).sum(-1)
     return jnp.where(slot[..., 0] < upto[:, -1:], at, m).astype(jnp.int32)
 
@@ -420,6 +440,46 @@ def select_top_k(scores, lengths, k: int, *, reference: bool = False):
     return jnp.where(slot, at, 0).astype(jnp.int32), count
 
 
+def pool_rows(tables, positions, page_size: int, tokens: int):
+    """tables [rows, pages_per_row] page ids, positions [rows, k] in [0,
+    pages_per_row * page_size): the row of a pool of `tokens` token rows
+    (pages * page_size) at which each position's token stands, `tables[r,
+    p // page_size] * page_size + p % page_size`, [rows, k] int32, with no
+    gather. The table is read as groups of 128 lanes: a position's lane by
+    ONE product of its one-hot `[rows, k, 128]` (bf16) with the table's
+    first rows, byte by byte, the groups side by side (a byte is exact in
+    bf16, a sum of one byte and zeros exact in float32; as many bytes as
+    `tokens - 1` has, so exact for every page a pool of that shape holds),
+    its group by a compare over the groups. One form for every width: a
+    table 1036 wide is 9 groups x 3 bytes = 27 columns of one pass of the
+    matrix unit at K = 128, one 4096 wide 96. `take_along_axis`, the plain
+    form `sparse_attend(reference=True)` keeps, is a gather of single
+    int32 elements, 98,304 a layer at 10 ns each on the chip: 1.00 ms a
+    layer in the Keye cell's decode step where this takes 0.07, the step
+    29.66 -> 24.14 ms; a compare-and-sum over the table's whole width read
+    24.08 and a one-hot over it 24.12 at this width, both k x width; one
+    flat `take` 27.87 (the cell's program under each form on the chip;
+    PERF.md section 6, PR 57)."""
+    rows, k = positions.shape
+    count = max(1, -(-(tokens - 1).bit_length() // 8))
+    first = jnp.pad(tables * page_size,
+                    ((0, 0), (0, -tables.shape[1] % NUM_LANES))).reshape(
+                        rows, -1, NUM_LANES)                  # [rows, g, 128]
+    groups = first.shape[1]
+    pieces = jnp.stack([(first >> (8 * i)) & 255 for i in range(count)], -1)
+    pieces = jnp.transpose(pieces, (0, 2, 1, 3)).reshape(
+        rows, NUM_LANES, groups * count).astype(jnp.bfloat16)
+    page = positions // page_size
+    lane = (page % NUM_LANES)[..., None] == jnp.arange(NUM_LANES)
+    group = (page // NUM_LANES)[..., None] == jnp.arange(groups)
+    found = jnp.einsum("rkl,rlc->rkc", lane.astype(jnp.bfloat16), pieces,
+                       preferred_element_type=F32).reshape(
+                           rows, k, groups, count)
+    found = jnp.where(group[..., None], found, 0).sum(-2).astype(jnp.int32)
+    return sum(found[..., i] << (8 * i) for i in range(count)) \
+        + positions % page_size
+
+
 def sparse_attend(q, k_pool, v_pool, positions, count, tables, *,
                   kv_heads: int, reference: bool = False):
     """(c) q [rows, heads, head_dim] scaled; pools [1, pages, page_size,
@@ -433,11 +493,20 @@ def sparse_attend(q, k_pool, v_pool, positions, count, tables, *,
     gather leaves them; `reference` splits the rows into `[rows, k,
     kv_heads, head_dim]` for ONE product over all kv heads instead, which
     on the chip relays out every gathered row (PERF.md section 6, PR 51).
-    The same sums either way. Returns [rows, heads, head_dim] float32."""
+    A position's row of the pools is found through the block table by
+    `pool_rows` (a one-hot product, no gather; one form for every table
+    width and every pool), under `reference` by `take_along_axis` on the
+    page ids, a gather of single elements: the same int32 to the last
+    element. The same sums either way. Returns [rows, heads, head_dim]
+    float32."""
     rows, heads, head_dim = q.shape
     page_size = k_pool.shape[2]
-    page = jnp.take_along_axis(tables, positions // page_size, axis=1)
-    token = page * page_size + positions % page_size          # [rows, k]
+    if reference:
+        page = jnp.take_along_axis(tables, positions // page_size, axis=1)
+        token = page * page_size + positions % page_size      # [rows, k]
+    else:
+        token = pool_rows(tables, positions, page_size,
+                          k_pool.shape[1] * page_size)
     keys, values = (pool.reshape(-1, pool.shape[-1])[token]
                     for pool in (k_pool, v_pool))
     queries = q.reshape(rows, kv_heads, heads // kv_heads,

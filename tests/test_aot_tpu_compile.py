@@ -13,6 +13,7 @@ hold no kernel at all and prove nothing.
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -938,6 +939,48 @@ def test_the_gathered_rows_are_attended_as_they_lie_on_v5e(
     assert array_shapes(as_they_lie, (rows, k, width)) > 0
     if kv_heads > 1:
         assert array_shapes(text(reference=True), split) > 0
+
+
+@pytest.mark.parametrize("rows,k,width,pages,page_size", [
+    (48, 2048, 1036, 13312, 64),            # the cell's
+    (8, 2048, 4096, 13312, 64),             # the published context's table
+    (5, 512, 100, 2000, 16)])               # under one group of 128 pages
+def test_the_selected_rows_are_found_with_no_gather_of_page_ids_on_v5e(
+        v5e, as_tpu, rows, k, width, pages, page_size):
+    """`sparse_attend` compiled for the chip holds exactly two gathers,
+    K's rows and V's: the block table is read by a one-hot product
+    (`pool_rows`), so no gather gives `[rows, k]` int32 (98,304 single
+    elements a layer at the cell's shapes, 1.00 ms of the chip: PERF.md
+    section 6, PR 57), nothing of `[rows, k, table width]` stands
+    anywhere in any type, and the temporaries are no more than the
+    reference form's, which keeps the third gather."""
+    from ray_tpu.llm.paged import array_shapes
+    from ray_tpu.ops import sparse_attention as sa
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(kind, *shape):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one)
+
+    pool = spec(jnp.bfloat16, 1, pages, page_size, 512)
+    args = (spec(jnp.float32, rows, 32, 128), pool, pool,
+            spec(jnp.int32, rows, k), spec(jnp.int32, rows),
+            spec(jnp.int32, rows, width))
+
+    def compiled(**how):
+        return jax.jit(lambda *a: sa.sparse_attend(
+            *a, kv_heads=4, **how)).lower(*args).compile()
+
+    def gathers(program):
+        return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* gather\(",
+                                 program.as_text()))
+
+    taken, plain = compiled(), compiled(reference=True)
+    selected = f"bf16[{rows},{k},512]"
+    assert gathers(taken) == [selected, selected]
+    assert gathers(plain) == [selected, selected, f"s32[{rows},{k}]"]
+    assert array_shapes(taken.as_text(), (rows, k, width)) == 0
+    assert taken.memory_analysis().temp_size_in_bytes \
+        <= plain.memory_analysis().temp_size_in_bytes
 
 
 def _row_piece_copy(pool_shape, piece_rows: int):

@@ -290,6 +290,68 @@ def test_the_running_count_is_a_cumsum(m):
                           np.cumsum(flags, axis=-1))
 
 
+def _positions_by_a_gathered_row(keep, k):
+    """`positions_of` as it stood before PR 57, kept here to compare with:
+    a slot's 128-lane row of running counts by ONE gather."""
+    n, m = keep.shape
+    within, totals = sa._group_counts(keep)
+    upto = jnp.cumsum(totals, axis=-1)
+    groups = upto.shape[1]
+    slot = jnp.arange(k, dtype=jnp.float32)[None, :, None]
+    passed = upto[:, None, :] <= slot
+    group = jnp.minimum(passed.sum(-1), groups - 1)
+    rank = slot[..., 0] - jnp.where(passed, totals[:, None, :], 0).sum(-1)
+    rows = within.reshape(n * groups, 128)[
+        group + jnp.arange(n)[:, None] * groups]
+    at = group * 128 + (rows <= rank[..., None]).sum(-1)
+    return jnp.where(slot[..., 0] < upto[:, -1:], at, m).astype(jnp.int32)
+
+
+EDGE = sa._ONE_HOT_GROUPS * 128
+
+
+@pytest.mark.parametrize("m,k", [
+    (1, 4), (127, 8), (128, 8), (129, 8), (700, 16), (3000, 64),
+    (EDGE, 8),              # the last width whose row is taken by a product
+    (EDGE + 1, 8),          # the first whose row is gathered
+    (EDGE + 700, 16)])
+def test_the_kept_positions_are_the_gathered_rows_positions(m, k):
+    """Rows that keep nothing, one column, fewer than k, exactly k, more
+    than k (scattered, all in one group, the table's last columns) and
+    every column: the positions of `positions_of` are the former form's
+    (a gathered row a slot) and the first k kept columns, m behind them,
+    on both sides of the static rule that picks how a slot's row is
+    taken."""
+    rng = np.random.default_rng(m + k)
+    keep = np.zeros((7, m), bool)
+    keep[1, rng.integers(m)] = True
+    keep[2, rng.choice(m, min(m, k - 1), replace=False)] = True
+    keep[3, rng.choice(m, min(m, k), replace=False)] = True
+    keep[4] = rng.random(m) < 0.4
+    keep[5, max(0, m - 3 * k):] = True
+    keep[6] = True
+    want = np.full((7, k), m, np.int32)
+    for row, flags in enumerate(keep):
+        first = np.flatnonzero(flags)[:k]
+        want[row, :len(first)] = first
+    got = np.asarray(jax.jit(sa.positions_of, static_argnums=1)(
+        jnp.asarray(keep), k))
+    assert got.dtype == np.int32 and np.array_equal(got, want)
+    assert np.array_equal(got, np.asarray(
+        _positions_by_a_gathered_row(jnp.asarray(keep), k)))
+
+
+def test_how_a_slots_row_is_taken_follows_the_groups_alone():
+    """No gather up to `_ONE_HOT_GROUPS` groups of 128 columns, one
+    above: the choice is the shape's."""
+    def gathers(m):
+        return jax.jit(sa.positions_of, static_argnums=1).lower(
+            jax.ShapeDtypeStruct((2, m), jnp.bool_), 8).as_text().count(
+                "stablehlo.gather")
+
+    assert gathers(EDGE) == 0 and gathers(EDGE + 1) > 0
+
+
 # (page size, each row's tokens to score, the table's pages a row): what
 # the non-reference scoring paths must give as the whole gather does. DEAD
 # is a row the engine holds no request in: it scores one token, on the
@@ -553,6 +615,85 @@ def test_no_gathered_row_is_split_into_kv_heads():
     as_they_lie = lowered()
     assert split not in as_they_lie and split in lowered(reference=True)
     assert "tensor<2x32x512x" in as_they_lie
+
+
+# (rows, k, table width, page size, pages of the pool): the pool's token
+# rows decide how many bytes of a page's first row the product carries
+# (256 rows one, 65,536 two, 2^24 three), the width how many groups of 128
+# lanes stand side by side
+POOL_ROW_CASES = {
+    "the-cells-shapes-cut-small": (6, 256, 1036, 64, 13312),
+    "a-table-under-one-group": (3, 40, 16, 8, 300),
+    "exactly-one-group": (2, 24, 128, 8, 2000),
+    "one-lane-into-the-second-group": (2, 24, 129, 8, 2000),
+    "the-published-context-4096-wide": (2, 96, 4096, 64, 262144),
+    "a-pool-of-256-rows-one-byte": (2, 16, 5, 8, 32),
+    "a-pool-of-257-rows-two-bytes": (2, 16, 5, 1, 257),
+    "page-255-the-pools-last": (2, 16, 9, 16, 256),
+    "page-256": (2, 16, 9, 16, 257),
+    "a-pool-of-65536-rows-two-bytes": (2, 16, 40, 64, 1024),
+    "a-pool-of-65537-rows-three-bytes": (2, 16, 40, 1, 65537),
+    "page-65535-the-pools-last": (2, 16, 40, 4, 65536),
+    "page-65536": (2, 16, 40, 4, 65537),
+    "a-pool-of-2-to-the-24-rows-three-bytes": (2, 16, 300, 64, 262144),
+    "one-page-more-four-bytes": (2, 16, 300, 64, 262145),
+    "the-largest-pool-int32-addresses": (2, 16, 300, 64, 2 ** 25 - 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(POOL_ROW_CASES))
+def test_a_position_is_resolved_to_its_pool_row_without_a_gather(case):
+    """`pool_rows` = `take_along_axis(tables, positions // page_size) *
+    page_size + positions % page_size`, element for element: the pool's
+    last page (the largest id a pool of that shape holds) and the ids
+    around a byte's edge in the tables, positions on both sides of every
+    page edge and of a 128-page group's, a table's first position and its
+    last, zeros behind a row's count; and the lowered form holds no
+    gather."""
+    rows, k, width, page_size, pages = POOL_ROW_CASES[case]
+    rng = np.random.default_rng(len(case))
+    tables = rng.integers(0, pages, (rows, width)).astype(np.int32)
+    edges = [i for i in (0, 1, 255, 256, 257, 65535, 65536, 65537,
+                         pages - 2, pages - 1) if 0 <= i < pages]
+    at = rng.choice(width, min(width, len(edges)), replace=False)
+    tables[0, at] = edges[-len(at):]
+    tables[-1, -1] = tables[-1, 0] = pages - 1
+    last = width * page_size - 1
+    positions = rng.integers(0, last + 1, (rows, k)).astype(np.int32)
+    pages_at = [p for p in (1, 2, 127, 128, 129, width - 1) if p < width]
+    fixed = sorted({0, last, *(p * page_size - 1 for p in pages_at),
+                    *(p * page_size for p in pages_at),
+                    *(int(a) * page_size for a in at)})[:k - 3]
+    positions[0, :len(fixed)] = fixed
+    positions[:, k - 3:] = 0                     # behind a row's count
+    want = np.take_along_axis(tables, positions // page_size, 1) \
+        * page_size + positions % page_size
+    resolve = jax.jit(lambda t, p: sa.pool_rows(t, p, page_size,
+                                                pages * page_size))
+    got = np.asarray(resolve(tables, positions))
+    assert got.dtype == np.int32 and got.shape == (rows, k)
+    assert np.array_equal(got, want)
+    assert want.max() >= (pages - 1) * page_size
+    assert "gather" not in resolve.lower(tables, positions).as_text()
+
+
+def test_the_reference_form_keeps_the_plain_gather_of_page_ids():
+    """`reference=True` resolves through `take_along_axis` as it always
+    did (one gather more than the two of rows: it is what the form above
+    is compared with, and stays independent of it); the decode form holds
+    the two row gathers alone."""
+    args, kv_heads = attend_case("four-kv-heads-eight-queries-each")
+
+    def gathers(**how):
+        text = jax.jit(lambda *a: sa.sparse_attend(
+            *a, kv_heads=kv_heads, **how)).lower(*args).as_text()
+        found = [line.rsplit("->", 1)[1].strip() for line
+                 in text.splitlines() if "stablehlo.gather" in line]
+        return sorted(found)
+
+    rows = "tensor<2x32x512xf32>"
+    assert gathers() == [rows, rows]
+    assert gathers(reference=True) == [rows, rows, "tensor<2x32xi32>"]
 
 
 # -- (iv) topk >= context: the sparse path is dense paged attention ------
