@@ -98,6 +98,20 @@ def _build() -> SimpleNamespace:
             "Prefix-cache pages currently mapped by at least "
             "one live sequence (copy-on-write shares)",
             tag_keys=("engine", "pid")),
+        masks_in_flight=Gauge(
+            "rtpu_llm_masks_in_flight",
+            "Masked positions in the open blocks of the rows of a "
+            "model that generates by diffusion over blocks",
+            tag_keys=("engine", "pid")),
+        block_forwards=Counter(
+            "rtpu_llm_block_forwards_total",
+            "Row-forwards of open blocks dispatched (generation by "
+            "diffusion over blocks), by kind: denoise or commit",
+            tag_keys=("engine", "kind")),
+        block_tokens_out=Counter(
+            "rtpu_llm_block_tokens_out_total",
+            "Tokens handed out by finished blocks",
+            tag_keys=("engine",)),
         prefix_hits=Counter(
             "rtpu_prefix_cache_hits_total",
             "Prefill requests that reused cached full-page "

@@ -14,7 +14,7 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
-from .serving import LLMServer
+from .serving import BLOCK_SETTINGS, LLMServer
 from .tokenizer import ByteTokenizer, get_tokenizer  # noqa: F401 — re-export
 
 
@@ -73,6 +73,9 @@ class OpenAIServer(LLMServer):
         temperature = body.get("temperature")
         top_k = body.get("top_k")
         top_p = body.get("top_p")
+        # extensions for a model that generates by diffusion over blocks
+        block_settings = {key: body[key] for key in BLOCK_SETTINGS
+                          if body.get(key) is not None}
         # the proxy-stamped id (X-RTPU-Request-Id) IS the completion id
         # when present, so `why_slow(<header id>)` resolves client-side
         request_id = self._context_request_id() \
@@ -81,7 +84,7 @@ class OpenAIServer(LLMServer):
             stream_id = await self.generate_stream_start(
                 prompt_tokens, max_new_tokens=max_new,
                 temperature=temperature, top_k=top_k, top_p=top_p,
-                request_id=request_id)
+                request_id=request_id, **block_settings)
             self._sse[stream_id] = {
                 "chat": chat, "id": request_id,
                 "created": int(time.time()), "first": True}
@@ -89,7 +92,7 @@ class OpenAIServer(LLMServer):
         out = await self.generate(
             prompt_tokens, max_new_tokens=max_new,
             temperature=temperature, top_k=top_k, top_p=top_p,
-            request_id=request_id)
+            request_id=request_id, **block_settings)
         text = self.tokenizer.decode(out["tokens"])
         created = int(time.time())
         usage = {"prompt_tokens": len(prompt_tokens),

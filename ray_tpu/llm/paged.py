@@ -54,7 +54,8 @@ from ..ops.sparse_attention import sparse_kernel
 from . import reqtrace
 from ._metrics import llm_metrics
 from .radix import RadixPrefixCache
-from .sampling import SAMPLER_TIERS, sample_tokens, sampler_tier
+from .sampling import (SAMPLER_TIERS, sample_tokens, sample_with_confidence,
+                       sampler_tier, unmask_block, unmask_count)
 
 if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
@@ -63,6 +64,7 @@ if TYPE_CHECKING:
     from ..models.lfm2 import Lfm2Config
     from ..models.nemotron_h import NemotronHConfig
     from ..models.sarvam_mla import SarvamMLAConfig
+    from ..models.sdar import SdarConfig
 
 _TAGS = {"engine": "paged"}
 # gauges are per-process series (see _metrics.py on the merge semantics)
@@ -84,6 +86,14 @@ class GenerationRequest:
     # folded into per-tenant/per-route percentiles (llm/reqtrace.py)
     tenant: Optional[str] = None
     route: Optional[str] = None
+    # for a model that generates by diffusion over blocks (`_blockwise`;
+    # None: the model configuration's own): denoising forwards a block
+    # (1 .. block_length: quality against latency), the unmasking rule
+    # ("static" or "dynamic": both rank by low confidence) and the dynamic
+    # rule's threshold; every other model ignores them
+    denoising_steps: Optional[int] = None
+    remasking: Optional[str] = None
+    confidence_threshold: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -130,10 +140,19 @@ class PagedEngineConfig:
     # (`page_pool(pages, page_size)`; `_pooled`: heads narrower than a lane
     # tile stand side by side in a row, `ops.paged_attention`): its prefill
     # chunks then write and attend the row's pages through its table, and
-    # of a prefilling row only the scanning layers' state is staged.
+    # of a prefilling row only the scanning layers' state is staged. One
+    # that GENERATES BY DIFFUSION OVER BLOCKS says how long a block is and
+    # which id is the mask (`block_length`, `mask_token_id`; `_blockwise`):
+    # a row's step is then one forward of the `block_length` positions of
+    # its open block, attended both ways, and yields 0 .. block_length
+    # tokens; a block whose last mask is gone is committed by one more
+    # forward, and only then does the row's cached length move
+    # (`PagedLLMEngine._block_tick`); the prompt's whole blocks are
+    # prefilled in place under the same mask and its last `len %
+    # block_length` tokens open the first block.
     model: Union[LlamaConfig, "FalconH1Config", "NemotronHConfig",
                  "EvaByteConfig", "SarvamMLAConfig", "KeyeDSAConfig",
-                 "Lfm2Config"]
+                 "Lfm2Config", "SdarConfig"]
     max_batch: int = 4            # concurrent decode rows
     max_len: int = 512            # per-request logical cap
     page_size: int = 16
@@ -190,6 +209,12 @@ def _pooled(cfg) -> bool:
     write the row's pages themselves (built for a model whose rows carry
     recurrent state: only that is staged for a prefilling row)."""
     return hasattr(cfg, "page_pool")
+
+
+def _blockwise(cfg) -> bool:
+    """Whether this model generates by diffusion over blocks: a row's step
+    carries the positions of its open block, not one token."""
+    return hasattr(cfg, "block_length")
 
 
 def _layer_caches(cfg) -> Tuple[Tuple[bool, bool, bool], ...]:
@@ -358,6 +383,32 @@ class _Seq:
     # engine shipped); None until then and after `first_token` took it
     last_logits: Any = None
     admit_at: int = 0            # admission order (preemption picks max)
+    # a row of a `_blockwise` model. `length` is its COMMITTED length (a
+    # commit forward dispatched counts): the positions in flight are the
+    # open block's, `block_at` .. `block_at` + block_length - 1 (-1: no block
+    # is open). `block_tail`: the prompt's last `len % block_length` tokens,
+    # which open the first block as fixed ids (`block_fixed` of them in the
+    # open block). `block_masks`: the masks the block holds after the
+    # forwards dispatched, as the static rule counts them (the dynamic rule
+    # may be ahead: `_emit_blocks`); `block_step`: denoising forwards
+    # dispatched in it; `block_take`: the tokens it hands out; `block_last`:
+    # no block opens behind it; `block_counted`: its tokens are in
+    # `dispatched`; `block_rule`: the request's (denoising steps,
+    # threshold). `flight`: per forward dispatched and not yet read,
+    # (block_at, fixed, take, last, masks believed left)
+    block_at: int = -1
+    block_tail: List[int] = dataclasses.field(default_factory=list)
+    block_fixed: int = 0
+    block_masks: int = 0
+    block_step: int = 0
+    block_take: int = 0
+    block_last: bool = False
+    block_counted: bool = False
+    block_rule: Tuple[int, float] = (1, 2.0)
+    block_opened_ts: float = 0.0
+    blocks_done: int = 0
+    flight: "collections.deque" = dataclasses.field(
+        default_factory=collections.deque)
 
 
 class PagedLLMEngine:
@@ -424,6 +475,22 @@ class PagedLLMEngine:
                 raise ValueError(
                     f"prefill buckets {buckets} are not each whole pages "
                     f"of {ps_} or a part of one")
+        # rows whose step carries the positions of an open block
+        self._blockwise = _blockwise(cfg)
+        if self._blockwise:
+            if self._tp > 1:
+                raise NotImplementedError(
+                    "generation by diffusion over blocks over a tensor mesh "
+                    "is not built: the block step's pools and counters are "
+                    "not sharded")
+            L = cfg.block_length
+            if config.page_size % L or config.max_len % L \
+                    or any(b % L for b in config.prefill_buckets):
+                raise ValueError(
+                    f"page_size {config.page_size}, max_len "
+                    f"{config.max_len} and the prefill buckets "
+                    f"{config.prefill_buckets} are not each whole blocks of "
+                    f"{L} positions")
         if self._tp > 1:
             if cfg.num_kv_heads % self._tp or cfg.num_heads % self._tp:
                 raise ValueError(
@@ -501,6 +568,14 @@ class PagedLLMEngine:
         self._counters_host = jax.device_get(self.counters)
         self._counters_at = 0          # `_steps` when that copy was made
         self._counters_asked = False
+        # the same accumulators of a `_blockwise` model's prefill chunks of
+        # the largest bucket (tokens routed to each held expert, chunks
+        # that routed it any), donated to the chunk beside the pools;
+        # `_chunks_counted`: how many such chunks were dispatched
+        self.chunk_counters = cfg.init_counters() \
+            if _blockwise(cfg) else []
+        self._chunk_counters_host = jax.device_get(self.chunk_counters)
+        self._chunks_counted = 0
         # (slot, staged state) of prefills finished this tick, installed
         # in the tick's `state` phase
         self._state_due: List[Tuple[int, Any]] = []
@@ -569,6 +644,21 @@ class PagedLLMEngine:
         # makes no trip through the host. `_unread` lists the (slot, seq)
         # whose newest token is in it and has not been read.
         self._tokens = jnp.zeros((config.max_batch,), jnp.int32)
+        if self._blockwise:
+            # a `_blockwise` model's is the report of its last block step,
+            # [rows, block_length + 2]: every row's block ids as the step
+            # left them (the next step's input, as it stands), the masks the
+            # step found in the block and the masks it left
+            self._tokens = jnp.zeros(
+                (config.max_batch, cfg.block_length + 2), jnp.int32)
+        # what the block steps did (`_blockwise`): row-forwards dispatched,
+        # those of them that were commits, tokens handed out, blocks the
+        # dynamic rule finished ahead of the static count
+        self._block_forwards = 0
+        self._commit_forwards = 0
+        self._block_tokens_out = 0
+        self._blocks_early = 0
+        self._block_metered = (0, 0)   # of the first two, in the metrics
         if mesh is not None:
             self._tokens = jax.device_put(
                 self._tokens, NamedSharding(mesh, PSpec()))
@@ -622,6 +712,10 @@ class PagedLLMEngine:
         self._num_params = sum(
             int(np.prod(p.shape))
             for p in jax.tree_util.tree_leaves(self.params))
+        # the parameters a position's forward multiplies by (a `_blockwise`
+        # model's step is timed by them; its configuration counts them)
+        self._active_params = cfg.active_params() if self._blockwise \
+            else self._num_params
         model = self.model
         page_sharding = self._page_sharding
 
@@ -757,6 +851,8 @@ class PagedLLMEngine:
             self._latent_programs()
         if self._indexed:
             self._indexed_programs()
+        if self._blockwise:
+            self._block_programs()
 
     @property
     def _row_pools(self):
@@ -823,6 +919,113 @@ class PagedLLMEngine:
                            for k, v, index in zip(*pools)],
                 cache_index=offset, valid=valid, head=False)
             return chunk_logits(model, params, hidden, last), by_kind(new)[0]
+
+        self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
+
+    def _block_programs(self):
+        """The programs of a model that generates by diffusion over blocks
+        (`_blockwise`). ONE block step serves a denoising forward and a
+        commit alike: every live row's open block (its ids held on the
+        device between steps, in the report the step before returned) is
+        forwarded at positions `lengths` .. `lengths` + block_length - 1,
+        its K/V rows written into the row's pages there (over what the
+        forward before wrote: a denoising forward's K/V are not kept, and
+        the forward of a block without a mask is its commit), attended with
+        block_length queries a row over `lengths` + block_length positions,
+        and the rule applied on the device to the logits of all rows' block
+        positions. A prefill chunk takes the pools donated and the row's
+        block table, writes and attends the row's pages under the block mask
+        and returns no logits: nothing is sampled from a prompt.
+        `_dense_zero_caches`, `_write_pages` and `_gather_pages` stay what
+        the dense engine builds and are never called: a shared prefix is
+        attended where it lies (whole pages are whole blocks, and a block's
+        K/V depend on nothing behind it)."""
+        model, cfg = self.model, self.config.model
+        L, mask_id = cfg.block_length, cfg.mask_token_id
+
+        def block_caches(k_pages, v_pages, counters, live, block_tables,
+                         lengths):
+            """What each layer is handed in a block step."""
+            return [{"k": k, "v": v, "active": live,
+                     "block_tables": block_tables, "lengths": lengths,
+                     "pairs": pairs, "steps": steps}
+                    for k, v, (pairs, steps)
+                    in zip(k_pages, v_pages, counters)]
+
+        def by_kind(new):
+            """A model's per-layer tuples (k, v, counters...) as the two
+            lists of pools and the counters."""
+            return ([kept[0] for kept in new], [kept[1] for kept in new],
+                    [tuple(kept[2:]) for kept in new if len(kept) > 2])
+
+        # for a caller that applies the model itself (the benchmark's
+        # parity check reads logits where the engine's step returns ids)
+        self._by_kind, self._block_caches = by_kind, block_caches
+
+        def decode_step(params, k_pages, v_pages, live, block_tables,
+                       lengths, report, opened, fresh, count, threshold,
+                       rng, temperature, top_k, top_p, counters):
+            """`report` [rows, block_length + 2]: what the step before
+            returned (this step's returns it anew). `opened` [rows]: the
+            row opens a block with the ids `fresh` [rows, block_length];
+            `count`, `threshold` [rows]: `sampling.unmask_block`'s; the
+            sampler's [rows] parameters hold for every position of a row."""
+            ids = jnp.where(opened[:, None], fresh, report[:, :L])
+            hidden, new = model.apply(
+                {"params": params}, ids,
+                positions=lengths[:, None] + jnp.arange(L),
+                kv_caches=block_caches(k_pages, v_pages, counters, live,
+                                       block_tables, lengths),
+                cache_index=None, head=False)
+            logits = chunk_logits(
+                model, params, hidden.reshape(1, -1, hidden.shape[-1]),
+                None)[0]                              # [rows * L, vocab]
+            each = lambda a: jnp.repeat(a, L)         # noqa: E731
+            with jax.named_scope("sdar/confidence"):
+                # the mask's own id is never a candidate (a position that
+                # took it would read as masked for ever)
+                logits = jnp.where(
+                    jnp.arange(logits.shape[-1]) == mask_id, -1e30, logits)
+                candidates, confidence = sample_with_confidence(
+                    rng, logits, each(temperature), each(top_k),
+                    each(top_p))
+            with jax.named_scope("sdar/unmask"):
+                out, before, after = unmask_block(
+                    ids, candidates.reshape(ids.shape),
+                    confidence.reshape(ids.shape), mask_id, count,
+                    threshold)
+                out = jnp.where(live[:, None], out, ids)
+                report = jnp.concatenate(
+                    [out, before[:, None], after[:, None]], axis=1)
+            return (report.astype(jnp.int32),) + by_kind(new)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(1, 2, 15))
+
+        largest = self.config.prefill_buckets[-1]
+
+        def chunk_prefill(params, tokens, positions, pools, offset, table,
+                          valid):
+            """One prefill chunk of one row over its pages. `pools`: (k
+            pools, v pools, the chunks' expert counters); `table`
+            [pages_per_seq] the row's page ids, shared prefix pages first,
+            the null page where it holds none. The chunk's first `valid`
+            rows (whole blocks) are written into the row's pages and
+            attended there, with everything cached before them, under the
+            block mask. A chunk of the largest bucket adds what it routed
+            to the counters; a smaller one hands them on. Returns (a
+            witness of the chunk's end, [1] float32: no logits, the head is
+            not run; the pools)."""
+            k_pages, v_pages, counters = pools
+            hidden, new = model.apply(
+                {"params": params}, tokens, positions=positions,
+                kv_caches=[(k, v, table) for k, v in zip(k_pages, v_pages)],
+                cache_index=offset, valid=valid, head=False)
+            nk, nv, routed = by_kind(new)
+            if tokens.shape[1] == largest:
+                counters = [(pairs + got, steps + (got > 0).astype(jnp.int32))
+                            for (pairs, steps), (got,)
+                            in zip(counters, routed)]
+            return hidden[:, -1, 0].astype(jnp.float32), (nk, nv, counters)
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
@@ -935,10 +1138,10 @@ class PagedLLMEngine:
                                         donate_argnums=(1, 2))
 
     def lower_chunk(self, bucket: Optional[int] = None):
-        """The prefill chunk of a `_windowed`, `_in_place` or `_pooled`
-        model lowered at this engine's shapes (the largest bucket's unless
-        told), from shapes alone, in the form the tick runs (`last`
-        given)."""
+        """The prefill chunk of a `_windowed`, `_in_place`, `_pooled` or
+        `_blockwise` model lowered at this engine's shapes (the largest
+        bucket's unless told), from shapes alone, in the form the tick runs
+        (`last` given)."""
         cfg = self.config
         bucket = bucket or cfg.prefill_buckets[-1]
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
@@ -949,6 +1152,12 @@ class PagedLLMEngine:
             return self._chunk_prefill.lower(
                 params, i32(1, bucket), i32(1, bucket), staged, i32(),
                 i32(cfg.pages_per_seq), i32(), i32())
+        if self._blockwise:
+            counters = jax.eval_shape(cfg.model.init_counters)
+            return self._chunk_prefill.lower(
+                params, i32(1, bucket), i32(1, bucket),
+                (k_pages, v_pages, counters),
+                i32(), i32(cfg.pages_per_seq), i32())
         if self._in_place:
             pools = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -1133,6 +1342,18 @@ class PagedLLMEngine:
         def vec(dtype, *shape):
             return jax.ShapeDtypeStruct((B,) + shape, dtype)
 
+        if self._blockwise:
+            L = cfg.model.block_length
+            return self._decode.lower(
+                jax.tree_util.tree_map(like, self.params),
+                [like(p) for p in self.k_pages],
+                [like(p) for p in self.v_pages], vec(jnp.bool_),
+                vec(jnp.int32, cfg.pages_per_seq), vec(jnp.int32),
+                vec(jnp.int32, L + 2), vec(jnp.bool_), vec(jnp.int32, L),
+                vec(jnp.int32), vec(jnp.float32),
+                jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype),
+                vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+                jax.tree_util.tree_map(like, self.counters))
         state = () if self.state is None else (
             jax.tree_util.tree_map(like, self.state), vec(jnp.bool_))
         counters = () if self.state is None and not self._in_place else (
@@ -1198,6 +1419,12 @@ class PagedLLMEngine:
         n = len(request.prompt_tokens)
         if n >= self.config.max_len:
             raise ValueError("prompt longer than max_len")
+        if self._blockwise:
+            self._block_settings(request)    # raises on a rule it has not
+            if self.config.model.mask_token_id in request.prompt_tokens:
+                raise ValueError(
+                    "the prompt holds the mask's id: a position that stands "
+                    "for a token not yet generated")
         request._done_callback = done_callback  # type: ignore
         request._token_callback = token_callback  # type: ignore
         request._submit_ts = time.monotonic()  # type: ignore
@@ -1244,6 +1471,13 @@ class PagedLLMEngine:
             raise NotImplementedError(
                 f"{what} ships dense K/V: a model whose layers keep index "
                 "keys beside them cannot be prefilled on another engine "
+                "yet")
+        if self._blockwise:
+            raise NotImplementedError(
+                f"{what} ships dense K/V and the logits a first token is "
+                "sampled from: a model that generates by diffusion over "
+                "blocks prefills its pages in place and samples nothing "
+                "from a prompt, and cannot be prefilled on another engine "
                 "yet")
 
     def cancel(self, request_id: str) -> bool:
@@ -1384,7 +1618,10 @@ class PagedLLMEngine:
             tick.outside("between", entered - self._tick_end)
         before = self._ahead_counts()
         with tick:
-            self._decode_tick(tick.phase)
+            if self._blockwise:
+                self._block_tick(tick.phase)
+            else:
+                self._decode_tick(tick.phase)
             with tick.phase("reap"):
                 self._reap_cancelled()
             with tick.phase("admit"):
@@ -1423,9 +1660,10 @@ class PagedLLMEngine:
         between steps (the benchmark's marks) may call it itself."""
         if self.counters:
             with _accel.pause("read_counters"):
-                counters = jax.device_get(  # host-sync ok: on request
-                    self.counters)
+                counters, of_chunks = jax.device_get(  # host-sync ok:
+                    (self.counters, self.chunk_counters))   # on request
             self._counters_host = counters
+            self._chunk_counters_host = of_chunks
         self._counters_at, self._counters_asked = self._steps, False
 
     def _ahead_counts(self) -> Dict[str, int]:
@@ -1448,6 +1686,21 @@ class PagedLLMEngine:
         if self._sorted_buckets is not None:
             # chunks whose routed experts ran as sorted pairs
             counts["prefill_chunks_sorted"] = self._prefill_chunks_sorted
+        if self._blockwise:
+            # row-forwards the block steps dispatched (`decode_rows` counts
+            # the same), those that were commits, tokens handed out, blocks
+            # the dynamic rule finished ahead of the static count; chunks
+            # wrote the rows' pages themselves
+            counts.update(
+                block_forwards=self._block_forwards,
+                commit_forwards=self._commit_forwards,
+                block_tokens_out=self._block_tokens_out,
+                blocks_early=self._blocks_early,
+                prefix_shared_tokens=self._prefix_shared_tokens,
+                prefill_computed_tokens=self._prefill_computed_tokens,
+                prefill_ctx_rows=self._prefill_ctx_rows,
+                # chunks of the largest bucket: those `chunk_expert_*` count
+                prefill_chunks_largest=self._chunks_counted)
         if self._pooled:
             # chunks that wrote their K/V into the row's pages themselves,
             # the prompt tokens they computed and the cached rows they
@@ -1527,6 +1780,9 @@ class PagedLLMEngine:
         metrics.waiting.set(waiting, tags=_GAUGE_TAGS)
         metrics.shared_pages.set(self.radix.shared_pages(),
                                  tags=_GAUGE_TAGS)
+        if self._blockwise:
+            metrics.masks_in_flight.set(self._masks_in_flight(),
+                                        tags=_GAUGE_TAGS)
 
     def _install_states(self):
         """`write_state` for every prefill that finished this tick."""
@@ -1610,7 +1866,8 @@ class PagedLLMEngine:
                 shipped = getattr(request, "_prefilled", None)
                 if shipped is None:
                     # chunks of these go straight into the row's pages
-                    if not self._windowed and not self._in_place:
+                    if not self._windowed and not self._in_place \
+                            and not self._blockwise:
                         self._stage_prefill_cache(seq)
                 else:
                     # prefilled elsewhere (`submit_prefilled`): enters
@@ -1664,6 +1921,16 @@ class PagedLLMEngine:
         seq = self.seqs[index]
         seq.request = request
         seq.prompt = prompt
+        if self._blockwise:
+            # the prompt's whole blocks are prefilled; the rest of it opens
+            # the first block as fixed ids. After a preemption `prompt`
+            # ends at a block's boundary (tokens are handed out by the
+            # block, and the first block closes the prompt's last), so its
+            # re-prefill under the block mask gives the K/V the commits
+            # gave, and nothing is left over
+            whole = len(prompt) - len(prompt) % self.config.model.block_length
+            seq.prompt, seq.block_tail = prompt[:whole], prompt[whole:]
+            assert not (resume and seq.block_tail), "resumed inside a block"
         seq.resume = resume
         seq.phase = "prefill"
         seq.pages = shared + new_ids
@@ -1724,6 +1991,13 @@ class PagedLLMEngine:
         while budget > 0 and order:
             i = order.pop(0)
             seq = self.seqs[i]
+            if self._blockwise and seq.prefill_off >= len(seq.prompt):
+                # no whole block of the prompt is left to compute (shorter
+                # than a block, or every page of it came from the radix)
+                with part("prefill", "finish"):
+                    self._finish_prefill(i)
+                self._prompts_finished += 1
+                continue
             if self._windowed and not self._chunk_pages(i, seq):
                 budget -= 1
                 continue     # parked again: the pool is short
@@ -1825,8 +2099,14 @@ class PagedLLMEngine:
         # the row the first token is sampled from, if this chunk holds it:
         # the program applies the head to that row and to nothing else
         finishes = off + take == len(prompt)
-        last = take - 1 if finishes else -1
-        if self._windowed:
+        last = (jnp.asarray(take - 1 if finishes else -1, jnp.int32),)
+        if self._blockwise:
+            staged = (self.k_pages, self.v_pages, self.chunk_counters)
+            extra = (self._row_table(seq), jnp.asarray(take, jnp.int32))
+            self._prefill_ctx_rows += off + take
+            self._chunks_counted += chunk == cfg.prefill_buckets[-1]
+            last, finishes = (), False       # no head, no logits
+        elif self._windowed:
             # chunks start at multiples of the largest bucket, which
             # divides the window: none straddles a close
             window = cfg.model.window_size
@@ -1849,10 +2129,11 @@ class PagedLLMEngine:
             self._dispatching()
             logits, staged = self._chunk_prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                staged, jnp.asarray(off, jnp.int32), *extra,
-                jnp.asarray(last, jnp.int32))
+                staged, jnp.asarray(off, jnp.int32), *extra, *last)
             self._dispatched(logits)
-        if self._windowed:
+        if self._blockwise:
+            self.k_pages, self.v_pages, self.chunk_counters = staged
+        elif self._windowed:
             self.k_pages, self.v_pages = staged
             if cfg.model.window_closes(off + take):
                 self._close_window(seq, "prefill")
@@ -1911,7 +2192,7 @@ class PagedLLMEngine:
         prompt = seq.prompt
         # a `_windowed` or `_in_place` model's chunks wrote the row's pages
         write_ids = [] if self._windowed or self._in_place or self._pooled \
-            else seq.pages[seq.own_from:]
+            or self._blockwise else seq.pages[seq.own_from:]
         staged = seq.dense_caches
         if self.state is not None:
             self._state_due.append((index, staged["state"]))
@@ -1920,6 +2201,16 @@ class PagedLLMEngine:
             self._write_owned_pages(staged, write_ids, seq.own_from)
         seq.dense_caches = staged = None
         self._register_prefix(prompt, seq.pages)
+        if self._blockwise:
+            # nothing is sampled from a prompt: the row's first block opens
+            # with the next block step
+            seq.phase = "decode"
+            seq.length = len(prompt)
+            seq.generated = []
+            seq.dispatched = 0
+            seq.block_at = -1
+            seq.block_rule = self._block_settings(request)
+            return
         temp, top_k, top_p = self._sampling(request)
         key = self._rng
         if temp > 0:
@@ -1942,7 +2233,13 @@ class PagedLLMEngine:
         """Whether the newest token computed or in flight for `seq` is
         its last by the rules that do not look at it: the request's
         budget (tokens from before a preemption count) or the engine's
-        length cap. Such a row is in no further decode step."""
+        length cap. Such a row is in no further decode step. A row of a
+        `_blockwise` model: the last denoising forward of its last block
+        is dispatched, as the static rule counts (that block's commit would
+        be attended by nothing, and is not run)."""
+        if self._blockwise:
+            return seq.block_at >= 0 and not seq.block_masks \
+                and seq.block_last
         return len(seq.resume) + seq.dispatched \
             >= seq.request.max_new_tokens \
             or seq.length >= self.config.max_len - 1
@@ -1985,6 +2282,10 @@ class PagedLLMEngine:
         # unless the model's rows keep something else than their context
         at = self.config.model.cache_rows if self._windowed \
             else (lambda length: length)
+        if self._blockwise:
+            # the last position of the block a row has open or opens next
+            last = self.config.model.block_length - 1
+            at = lambda length: length + last       # noqa: E731
         rows = {i: self.seqs[i] for i in active}
         for n, i in enumerate(
                 sorted(active, key=lambda i: self.seqs[i].admit_at)):
@@ -2167,6 +2468,8 @@ class PagedLLMEngine:
         finish. A row that ended since its entry was made is skipped (its
         token was counted as discarded when it was released), a row
         cancelled since is released here and its token dropped."""
+        if self._blockwise:
+            return self._emit_blocks(unread, values)
         for slot, seq in unread:
             if self.seqs[slot] is not seq:
                 continue
@@ -2369,6 +2672,260 @@ class PagedLLMEngine:
                     time.monotonic() - tick_start, tags=_TAGS)
                 metrics.decode_tokens.inc(len(active), tags=_TAGS)
 
+    # -- generation by diffusion over blocks (`_blockwise`) ----------------
+
+    def _block_settings(self, request: GenerationRequest):
+        """(denoising forwards a block, the dynamic rule's threshold or a
+        number no probability passes under the static rule) of a request,
+        the model configuration's where it does not say."""
+        model = self.config.model
+        steps = getattr(request, "denoising_steps", None) \
+            or model.denoising_steps
+        rule = getattr(request, "remasking", None) or model.remasking
+        if rule not in ("static", "dynamic"):
+            raise ValueError(f"remasking rule {rule!r} is neither 'static' "
+                             f"nor 'dynamic'")
+        threshold = getattr(request, "confidence_threshold", None)
+        if threshold is None:
+            threshold = model.confidence_threshold
+        return int(steps), (float(threshold) if rule == "dynamic" else 2.0)
+
+    def _masks_in_flight(self) -> int:
+        """Masks the open blocks hold, as the static rule counts them."""
+        return sum(s.block_masks for s in self.seqs
+                   if s.request is not None and s.block_at >= 0)
+
+    def _plan_forward(self, seq: _Seq):
+        """What the next block step does for `seq`, from what the host
+        knows without reading anything: (the ids that open a block or
+        None, the positions the static rule fixes in this forward; 0 for a
+        commit). The static rule's yield is known here; the dynamic rule's
+        is at least that, and `_emit_blocks` learns the rest a visit late."""
+        model = self.config.model
+        L = model.block_length
+        fresh = None
+        if seq.block_at < 0:
+            fixed = seq.block_tail
+            seq.block_tail = []
+            seq.block_at, seq.block_fixed = seq.length, len(fixed)
+            seq.block_masks, seq.block_step = L - len(fixed), 0
+            budget = seq.request.max_new_tokens - len(seq.resume) \
+                - seq.dispatched
+            seq.block_take = min(seq.block_masks, budget)
+            seq.block_last = seq.block_take >= budget \
+                or seq.block_at + 2 * L > self.config.max_len
+            seq.block_counted = False
+            seq.block_opened_ts = time.monotonic()
+            fresh = fixed + [model.mask_token_id] * seq.block_masks
+            if not seq.blocks_done:
+                reqtrace.record(seq.request.request_id, reqtrace.BLOCK,
+                                what="open", at=seq.block_at,
+                                fixed=len(fixed))
+        commit = not seq.block_masks
+        count = 0
+        if commit:
+            if seq.length == len(seq.prompt):
+                reqtrace.record(seq.request.request_id, reqtrace.BLOCK,
+                                what="commit", at=seq.block_at)
+            seq.length += L
+            self._commit_forwards += 1
+        else:
+            count = min(unmask_count(L, seq.block_rule[0], seq.block_step),
+                        seq.block_masks)
+            seq.block_masks -= count
+            seq.block_step += 1
+            if not seq.block_masks:
+                seq.dispatched += seq.block_take
+                seq.block_counted = True
+        seq.flight.append((seq.block_at, seq.block_fixed, seq.block_take,
+                           seq.block_last, seq.block_masks))
+        if commit:
+            seq.block_at = -1
+        return fresh, count
+
+    def _block_tick(self, phase):  # rtpu: hot-loop
+        """`_decode_tick` for a model that generates by diffusion over
+        blocks: dispatch the next forward of every live row's block (a
+        denoising forward, or the commit of a block whose last mask is
+        gone, or the first forward of the block a row opens), THEN read the
+        report of the step dispatched a visit earlier and hand out the
+        tokens of the blocks it finished."""
+        tick_start = time.monotonic()
+        cfg = self.config
+        B, L = cfg.max_batch, cfg.model.block_length
+        with phase("grow"):
+            active = self._ensure_decode_pages([
+                i for i, s in enumerate(self.seqs)
+                if s.request is not None and s.phase == "decode"
+                and not s.cancelled and not self._exhausted(s)])
+        if not active:
+            self._drain("idle", phase)
+            return
+        with phase("stage"):
+            trace = not reqtrace.reqtrace_disabled()
+            if trace:
+                trace_rids = [self.seqs[i].request.request_id
+                              for i in active]
+                compile_t0 = self._compile_total()
+            block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            live = np.zeros((B,), bool)
+            opened = np.zeros((B,), bool)
+            fresh = np.zeros((B, L), np.int32)
+            counts = np.zeros((B,), np.int32)
+            thresholds = np.full((B,), 2.0, np.float32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            top_ps = np.ones((B,), np.float32)
+            poll = self._poll
+            for n, i in enumerate(active):
+                if not n & 7:
+                    poll()
+                seq = self.seqs[i]
+                block_tables[i, :len(seq.pages)] = seq.pages
+                thresholds[i] = seq.block_rule[1]
+                ids, counts[i] = self._plan_forward(seq)
+                if ids is not None:
+                    opened[i], fresh[i] = True, ids
+                # where this forward's K/V rows go: the block's positions
+                lengths[i] = seq.flight[-1][0]
+                live[i] = True
+                temps[i], top_ks[i], top_ps[i] = \
+                    self._sampling(seq.request)
+            self._decode_rows += len(active)
+            self._block_forwards += len(active)
+            self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
+            poll()
+            self._rng, key = jax.random.split(self._rng)
+        accel = self._accel
+        timer = accel.StepTimer(
+            "decode", tokens=L * len(active),
+            flops=2.0 * self._active_params * L * len(active),
+            sink=self._step_accum) \
+            if accel is not None else None
+        with timer if timer is not None else contextlib.nullcontext():
+            with self._mesh_scope(), (timer.device() if timer is not None
+                                      else contextlib.nullcontext()):
+                with phase("stage"):
+                    def upload(array):
+                        poll()
+                        return jnp.asarray(array)
+                    args = (upload(live), upload(block_tables),
+                            upload(lengths), self._tokens, upload(opened),
+                            upload(fresh), upload(counts),
+                            upload(thresholds), key, upload(temps),
+                            upload(top_ks), upload(top_ps))
+                with phase("dispatch"):
+                    unread, report = self._unread, self._tokens
+                    self._dispatching()
+                    (self._tokens, self.k_pages, self.v_pages,
+                     self.counters) = self._decode(
+                        self.params, self.k_pages, self.v_pages, *args,
+                        self.counters)
+                    self._dispatched(self._tokens)
+                    self._tokens.copy_to_host_async()
+                    self._unread = [(i, self.seqs[i]) for i in active]
+                    del args
+                if unread:
+                    self._lookahead_ticks += 1
+                    with phase("wait"):
+                        values = self._fetch(report)
+            if unread:
+                with phase("emit"):
+                    self._emit_blocks(unread, values)
+            with phase("gauges"):
+                if trace:
+                    compile_s = self._compile_total() - compile_t0
+                    if compile_s > 1e-6:
+                        for rid in trace_rids:
+                            reqtrace.record(
+                                rid, reqtrace.COMPILE,
+                                compile_s=round(compile_s, 6),
+                                phase="decode")
+                metrics = llm_metrics()
+                metrics.token_latency.observe(
+                    time.monotonic() - tick_start, tags=_TAGS)
+                forwards, commits = self._block_metered
+                self._block_metered = (self._block_forwards,
+                                       self._commit_forwards)
+                commits = self._commit_forwards - commits
+                metrics.block_forwards.inc(
+                    commits, tags=dict(_TAGS, kind="commit"))
+                metrics.block_forwards.inc(
+                    self._block_forwards - forwards - commits,
+                    tags=dict(_TAGS, kind="denoise"))
+
+    def _emit_blocks(self, unread: List[Tuple[int, _Seq]], values):
+        """`_emit_tokens` for a `_blockwise` model: `values` is a block
+        step's report on the host, a row a slot (`block_step`). A row's
+        forward that found masks and left none finished its block: the
+        block's tokens are handed out in position order (the prompt's tail
+        and what lies past the request's budget left out), and the row ends
+        if the block was its last or held the EOS. Where the dynamic rule
+        finished a block ahead of the static count, the forward dispatched
+        behind it found no mask and WAS the block's commit: the row's
+        account is set right here, a visit late, and no forward is spent
+        twice."""
+        L = self.config.model.block_length
+        metrics = llm_metrics()
+        for slot, seq in unread:
+            if self.seqs[slot] is not seq:
+                continue
+            if seq.cancelled:
+                self._end_request(seq.request, None, index=slot,
+                                  where="decode")
+                continue
+            at, fixed, take, last, believed = seq.flight.popleft()
+            row = values[slot]
+            if not row[L] or row[L + 1]:
+                continue      # a commit, or a block that still holds masks
+            if believed:
+                self._block_done_early(seq, at, take, last)
+            seq.blocks_done += 1
+            if seq.blocks_done == 1:
+                reqtrace.record(seq.request.request_id, reqtrace.BLOCK,
+                                what="done", at=at, tokens=take,
+                                forwards=seq.block_step, open_s=round(
+                                    time.monotonic() - seq.block_opened_ts,
+                                    6))
+            handed = len(seq.generated)
+            callback = getattr(seq.request, "_token_callback", None)
+            ended = last
+            for token in row[fixed:fixed + take]:
+                seq.generated.append(token)
+                if not handed and len(seq.generated) == 1:
+                    self._note_first_token(seq)
+                if callback is not None:
+                    callback(seq.request, token)
+                if token == self.config.eos_token:
+                    ended = True
+                    break
+            handed = len(seq.generated) - handed
+            self._tokens_generated += handed
+            self._block_tokens_out += handed
+            metrics.decode_tokens.inc(handed, tags=_TAGS)
+            metrics.block_tokens_out.inc(handed, tags=_TAGS)
+            if ended:
+                reqtrace.record(seq.request.request_id, reqtrace.BLOCK,
+                                what="last", at=at, blocks=seq.blocks_done)
+                self._finish(slot)
+
+    def _block_done_early(self, seq: _Seq, at: int, take: int, last: bool):
+        """The dynamic rule emptied the block at `at` in a forward after
+        which the static count still had masks in it."""
+        self._blocks_early += 1
+        if seq.block_at != at:
+            return
+        if not seq.block_counted:
+            seq.dispatched += take
+            seq.block_counted = True
+        seq.block_masks = 0
+        if seq.flight and not last:
+            # the forward dispatched behind it found no mask: the commit
+            seq.length += self.config.model.block_length
+            seq.block_at = -1
+            self._commit_forwards += 1
+
     # -- conveniences ------------------------------------------------------
 
     def generate(self, prompts: List[List[int]],
@@ -2450,6 +3007,12 @@ class PagedLLMEngine:
                                     if kept) or "-" for kind in kinds],
             "expert_pairs": [pairs.tolist() for pairs, _ in counters],
             "expert_steps": [steps.tolist() for _, steps in counters],
+            # the same of a `_blockwise` model's prefill chunks of the
+            # largest bucket (`prefill_chunks_largest` of them)
+            "chunk_expert_pairs": [pairs.tolist() for pairs, _
+                                   in self._chunk_counters_host],
+            "chunk_expert_steps": [steps.tolist() for _, steps
+                                   in self._chunk_counters_host],
             # pool-balance audit; exact only between steps
             "leaked_pages": self.page_leak_check(),
             # "pallas" or "gather": the path `decode_step` holds, of

@@ -74,6 +74,12 @@ COMPILE = "COMPILE"
 # terminal event, on either side); the folds below leave it out of the
 # request's extent
 STREAMED = "STREAMED"
+# a row of a model that generates by diffusion over blocks (paged.py,
+# `_blockwise`): the `what` of a request's FIRST block (open, done, commit:
+# every block's marks would overrun the ring in seconds) and of its last
+# (`blocks`: how many it took); inside the request's decode span, and left
+# an instant on the serve timeline
+BLOCK = "BLOCK"
 
 TERMINAL = frozenset({FINISHED, CANCELLED, FAILED})
 
@@ -427,7 +433,7 @@ def to_chrome_trace(payloads: List[Dict[str, Any]]
             elif event == COMPILE:
                 dur = float(args.get("compile_s", 0.0))
                 emit("xla_compile", ts - dur, ts, args)
-            elif event in (PREEMPTED, RESUMED, ROUTED, STREAMED) \
+            elif event in (PREEMPTED, RESUMED, ROUTED, STREAMED, BLOCK) \
                     or event in TERMINAL:
                 rows.append({
                     "name": event.lower(), "cat": "reqtrace",

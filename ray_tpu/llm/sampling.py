@@ -98,3 +98,53 @@ def sample_tokens(rng, logits, temperature, top_k, top_p):
     return jax.lax.switch(sampler_tier(temperature, top_k, top_p),
                           (_greedy, _plain, _filtered),
                           rng, logits, temperature, top_k, top_p)
+
+
+def sample_with_confidence(rng, logits, temperature, top_k, top_p):
+    """`sample_tokens`, and beside each row's token its CONFIDENCE: the
+    probability softmax(logits) gives it, float32 (of the plain
+    distribution, whatever temperature and filters drew the token). What an
+    unmasking rule ranks a block's candidates by (`unmask_block`).
+    logits [R, V] float32. Returns (tokens [R] int32, confidence [R])."""
+    tokens = sample_tokens(rng, logits, temperature, top_k, top_p)
+    picked = jnp.take_along_axis(logits, tokens[:, None], axis=-1)[:, 0]
+    return tokens.astype(jnp.int32), \
+        jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+
+
+def unmask_count(block_length: int, denoising_steps: int, step: int) -> int:
+    """Positions the static rule fixes in denoising forward `step` (from
+    0) of a block: block_length // T, and one more in the first
+    block_length % T forwards, T = `denoising_steps` held to 1 ..
+    block_length (more forwards than positions would fix nothing)."""
+    steps = max(1, min(denoising_steps, block_length))
+    return block_length // steps + (step < block_length % steps)
+
+
+def unmask_block(ids, candidates, confidence, mask_id, count, threshold):
+    """One denoising forward's unmasking of every row's open block, by the
+    low-confidence rules of generation by diffusion over blocks. ids [B, L]
+    the block as the forward read it, `mask_id` where a position is still
+    masked; candidates, confidence [B, L] the token each position would
+    take and its probability; count [B] int32 the positions the STATIC rule
+    fixes now (`unmask_count`); threshold [B] float32, the DYNAMIC rule's:
+    every masked position whose confidence passes it is fixed where those
+    are at least `count`, else the `count` most confident (a row on the
+    static rule carries a threshold no probability passes, > 1). Ties go to
+    the earlier position. A fixed position is never masked again. Returns
+    (ids with the fixed positions filled [B, L], masks before [B], masks
+    after [B]); a block that came without a mask comes back as it was (its
+    forward is the block's commit)."""
+    masked = ids == mask_id
+    conf = jnp.where(masked, confidence, -1.0)
+    # rank among the row's positions, most confident first
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (jnp.arange(ids.shape[1])[None, :] < jnp.arange(
+            ids.shape[1])[:, None])[None])
+    fixed = masked & (ahead.sum(-1) < count[:, None])
+    passing = masked & (confidence > threshold[:, None])
+    fixed = jnp.where((passing.sum(-1) >= count)[:, None], passing, fixed)
+    out = jnp.where(fixed, candidates.astype(ids.dtype), ids)
+    return out, masked.sum(-1).astype(jnp.int32), \
+        (masked & ~fixed).sum(-1).astype(jnp.int32)

@@ -29,6 +29,10 @@ from . import reqtrace
 
 logger = logging.getLogger(__name__)
 
+# what a request's body may say to a model that generates by diffusion over
+# blocks (`paged.GenerationRequest`); every other model ignores them
+BLOCK_SETTINGS = ("denoising_steps", "remasking", "confidence_threshold")
+
 
 class _Stream:
     """One streamed request's buffer between the engine's thread and the
@@ -205,7 +209,11 @@ class LLMServer:
                        top_p: Optional[float] = None,
                        request_id: Optional[str] = None,
                        tenant: Optional[str] = None,
-                       route: Optional[str] = None) -> Dict[str, Any]:
+                       route: Optional[str] = None,
+                       **block_settings) -> Dict[str, Any]:
+        """`block_settings`: `denoising_steps`, `remasking`,
+        `confidence_threshold` of a request to a model that generates by
+        diffusion over blocks (`GenerationRequest`)."""
         from .paged import GenerationRequest
         loop = asyncio.get_running_loop()
         future = loop.create_future()
@@ -229,7 +237,7 @@ class LLMServer:
             request_id=request_id or self._context_request_id()
             or uuid.uuid4().hex,
             tenant=tenant or self._context().tenant,
-            route=route or self._context().route)
+            route=route or self._context().route, **block_settings)
         from ._metrics import llm_metrics
         await self._submit(request, on_done)
         try:
@@ -269,9 +277,12 @@ class LLMServer:
             top_p: Optional[float] = None,
             request_id: Optional[str] = None,
             tenant: Optional[str] = None,
-            route: Optional[str] = None) -> str:
+            route: Optional[str] = None,
+            **block_settings) -> str:
         """Begin a streamed generation; returns a stream id the caller
-        polls with `stream_next` (the proxy relays it as chunked HTTP)."""
+        polls with `stream_next` (the proxy relays it as chunked HTTP).
+        `block_settings`: as `generate`'s; such a model's tokens arrive a
+        finished block at a time, in position order."""
         from .paged import GenerationRequest
         loop = asyncio.get_running_loop()
         request_id = request_id or self._context_request_id() \
@@ -313,7 +324,7 @@ class LLMServer:
             temperature=temperature, top_k=top_k, top_p=top_p,
             request_id=request_id,
             tenant=tenant or self._context().tenant,
-            route=route or self._context().route)
+            route=route or self._context().route, **block_settings)
         await self._submit(request, on_done, token_callback=on_token)
         return stream_id
 
@@ -371,16 +382,20 @@ class LLMServer:
             or headers.get("x-rtpu-request-id")
         tenant = body.get("tenant") or headers.get("x-rtpu-tenant")
         route = headers.get("x-rtpu-route")
+        block_settings = {key: body[key] for key in BLOCK_SETTINGS
+                          if body.get(key) is not None}
         if body.get("stream"):
             stream_id = await self.generate_stream_start(
                 prompt, max_new_tokens=max_new, temperature=temp,
-                request_id=request_id, tenant=tenant, route=route)
+                request_id=request_id, tenant=tenant, route=route,
+                **block_settings)
             # The proxy recognises this marker and relays stream_next
             # batches as chunked HTTP on the same replica.
             return {"__rtpu_stream__": stream_id}
         return await self.generate(
             prompt, max_new_tokens=max_new, temperature=temp,
-            request_id=request_id, tenant=tenant, route=route)
+            request_id=request_id, tenant=tenant, route=route,
+            **block_settings)
 
     def engine_stats(self) -> Dict[str, Any]:
         return self._engine.stats()

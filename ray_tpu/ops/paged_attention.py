@@ -1,4 +1,7 @@
-"""Paged decode attention: one query token a row over the row's K/V pages.
+"""Paged decode attention: one query token a row over the row's K/V pages
+(`paged_attend`), or the few positions of a row's open block, each
+attending all of them (`paged_attend_block`: generation by diffusion over
+blocks, models/sdar.py).
 
 `paged_attend` is the one entry point of every model's paged-decode branch.
 Its callers and the head sizes they bring: models/llama.py (Mistral, 128),
@@ -152,6 +155,47 @@ def _paged_attend_packed(q, k_pages, v_pages, lengths, tables,
                      axis=2).reshape(rows, heads, hd)
 
 
+def paged_attend_block(q, k_pages, v_pages, lengths, tables, *,
+                       reference: bool = False):
+    """The positions of each row's open block over the row's pages, both
+    ways inside the block. q [rows, block, heads, hd], unscaled, query j of
+    row b at position lengths[b] + j; the block's K/V rows are written
+    already (`write_block_rows`); lengths [rows] the tokens committed before
+    the block. Every query of a row sees the same keys, positions 0 ..
+    lengths[b] + block - 1, so the block's queries stand as `block` more
+    members of each kv head's group: one call of `paged_attend`, the
+    kernel's loop over pages as it is, `heads / kv_heads * block` queries a
+    kv head. Returns [rows, block, heads, hd]."""
+    rows, block, heads, hd = q.shape
+    side = _side_by_side(k_pages, hd)
+    kv_heads = k_pages.shape[0] * side
+    groups = heads // kv_heads
+    folded = jnp.transpose(q.reshape(rows, block, kv_heads, groups, hd),
+                           (0, 2, 1, 3, 4)).reshape(rows, -1, hd)
+    out = paged_attend(folded, k_pages, v_pages, lengths + block - 1,
+                       tables, reference=reference)
+    return jnp.transpose(out.reshape(rows, kv_heads, block, groups, hd),
+                         (0, 2, 1, 3, 4)).reshape(rows, block, heads, hd)
+
+
+def write_block_rows(pool, rows, block_tables, lengths):
+    """The K (or V) rows of each batch row's open block into a plain page
+    pool. pool [kv_heads, pages, page_size, hd]; rows [kv_heads, B, block,
+    hd], position j of row b at lengths[b] + j. `lengths` and the page size
+    are whole blocks, so a block lies in one page: (block_tables[b,
+    lengths[b] // page_size], lengths[b] % page_size + j). The scatter
+    indexes the three leading dimensions, as `models.llama.write_token_rows`
+    does and for its reason. Rows that are not live write to the null page
+    their table names and may collide there."""
+    page_size, block = pool.shape[2], rows.shape[2]
+    batch = jnp.arange(rows.shape[1])
+    page_of = block_tables[batch, lengths // page_size]
+    offset = (lengths % page_size)[:, None] + jnp.arange(block)
+    heads = jnp.arange(pool.shape[0])[:, None, None]
+    return pool.at[heads, page_of[None, :, None], offset[None]].set(
+        rows.astype(pool.dtype))
+
+
 # Cached tokens a step of `paged_attend_chunk`'s loop takes.
 _CHUNK_BLOCK_TOKENS = 512
 
@@ -186,12 +230,16 @@ def write_chunk_pages(pool, rows, table, start, valid):
     return pool
 
 
-def paged_attend_chunk(q, k_pages, v_pages, table, start):
+def paged_attend_chunk(q, k_pages, v_pages, table, start,
+                       block_length=None):
     """One prefill chunk of ONE row over its pages. q [chunk, heads, hd]
     SCALED, query i at position start + i, its own K/V already written
     (`write_chunk_pages`); the pools plain or packed; table [pages_per_row]
     the row's page ids (the null page where it holds none). Query i attends
-    positions 0 .. start + i. The cached rows are taken a block of pages at
+    positions 0 .. start + i; with `block_length` (a power of two that
+    divides `start` and the chunk), to the end of its block of that many
+    positions: 0 .. (start + i) | (block_length - 1), the block-causal mask
+    of a model that generates by diffusion over blocks. The cached rows are taken a block of pages at
     a time with running softmax statistics (float32; the two products take
     the pool's type and accumulate in float32), as many blocks as the
     chunk's last position reaches: neither the logits nor a dense copy of
@@ -207,6 +255,11 @@ def paged_attend_chunk(q, k_pages, v_pages, table, start):
     table = jnp.pad(table, (0, -table.shape[0] % block_pages))
     queries = q.reshape(chunk, packed, side, groups, hd).astype(k_pages.dtype)
     at = (start + jnp.arange(chunk))[:, None]
+    if block_length is not None:
+        if block_length & (block_length - 1) or chunk % block_length:
+            raise ValueError(f"blocks of {block_length} positions are not a "
+                             f"power of two that divides a chunk of {chunk}")
+        at = at | (block_length - 1)
 
     def attend_block(b, carry):
         m, l, acc = carry

@@ -1323,6 +1323,10 @@ def _lfm2_memory(p, compiled, recorded, largest=False):
     (a fifth: PERF.md section 4)."""
     memory = compiled.memory_analysis()
     pools = 2 * len(p["pools"]) * 2 * math.prod(p["pool"])
+    # what the file's memory_analysis should say (pytest -s shows it)
+    print({"argument_bytes": memory.argument_size_in_bytes,
+           "temp_bytes": memory.temp_size_in_bytes,
+           "alias_bytes": memory.alias_size_in_bytes, "pool_bytes": pools})
     assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
     assert memory.alias_size_in_bytes >= pools
     assert memory.argument_size_in_bytes <= 1.001 * recorded["argument_bytes"]
@@ -1408,3 +1412,139 @@ def test_lfm2_prefill_chunk_compiles_for_v5e_into_the_pages(
     _lfm2_memory(p, compiled,
                  p["config"]["memory_analysis"]["chunk_prefill_512"],
                  largest=True)
+
+
+# -- generation by diffusion over blocks (SDAR) ---------------------------
+
+
+@pytest.fixture(scope="module")
+def sdar_programs(v5e):
+    """The `serve-sdar-fixedgen-closed160` cell's engine programs: its
+    config file's widths, rows and pool, its builder, its six layers, with
+    the shapes of their arguments on one described chip, on an engine that
+    never allocated anything."""
+    from benchmarks.harness.builders_sdar import sdar_engine
+    from ray_tpu.llm.paged import PagedLLMEngine
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "sdar-30b-a3b-chat-serve.json")) as f:
+        config = json.load(f)
+    engine_cfg = sdar_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = object.__new__(PagedLLMEngine)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._block_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = (cfg.num_kv_heads, engine_cfg.num_pages, engine_cfg.page_size,
+            cfg.head_dim)
+    return {"engine": engine, "engine_cfg": engine_cfg, "cfg": cfg,
+            "config": config, "spec": spec, "pool": pool,
+            "params": placed(jax.eval_shape(lambda: unbox(engine.model.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32))["params"]))),
+            "pools": [spec(cfg.dtype, *pool)] * cfg.num_layers,
+            "counters": placed(jax.eval_shape(cfg.init_counters))}
+
+
+def _sdar_memory(p, compiled, recorded):
+    """Arguments and temporaries no higher than the file's
+    `memory_analysis` states them, the pools aliased, and the rule of the
+    pool's size: the traffic's worst case (128 rows of 2,048 + 512 tokens)
+    and a quarter more, with over 1.5 GB free beside the program."""
+    memory = compiled.memory_analysis()
+    pools = 2 * len(p["pools"]) * 2 * math.prod(p["pool"])
+    # what the file's memory_analysis should say (pytest -s shows it)
+    print({"argument_bytes": memory.argument_size_in_bytes,
+           "temp_bytes": memory.temp_size_in_bytes,
+           "alias_bytes": memory.alias_size_in_bytes, "pool_bytes": pools})
+    assert pools == p["config"]["memory_analysis"]["table"]["pool_bytes"]
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.argument_size_in_bytes <= 1.001 * recorded["argument_bytes"]
+    assert memory.temp_size_in_bytes <= 1.2 * recorded["temp_bytes"]
+    used = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert used < V5E_BYTES_LIMIT - 1.5e9
+    engine = p["engine_cfg"]
+    worst = engine.max_batch * (2048 + 512) // engine.page_size
+    assert engine.num_pages == worst + worst // 4 == 6400
+
+
+@pytest.mark.timeout_s(600)
+def test_sdar_block_step_compiles_for_v5e_within_memory(
+        sdar_programs, as_tpu):
+    """128 rows x 4 block positions, a block table 48 wide: the paged
+    kernel in each of the six layers at 32 queries a kv head (no gather
+    fallback), the 512 token rows' 4,096 pairs through the two grouped
+    kernels a layer (the sorted form, at decode), no pool copied, the pools
+    and the six counter pairs donated and updated in place; the parameters
+    are the file's table (4.36 B at six layers); the largest program."""
+    from ray_tpu.llm.paged import pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = sdar_programs
+    spec, cfg = p["spec"], p["cfg"]
+    rows, width = p["engine_cfg"].max_batch, p["engine_cfg"].pages_per_seq
+    L = cfg.block_length
+    recorded = p["config"]["memory_analysis"]["decode_step_batch128"]
+    assert (rows, width) == (128, 48) == (128, recorded["block_table_width"])
+    assert p["pool"] == tuple(recorded["pool_shape"])
+    table = p["config"]["memory_analysis"]["table"]
+    leaves = jax.tree_util.tree_leaves(p["params"])
+    assert sum(math.prod(a.shape) for a in leaves) \
+        == table["weights_params"] == 4361055744
+    assert sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves) \
+        == table["weights_bytes"]
+    assert len(p["counters"]) == 6 == recorded["counter_pairs"]
+    compiled = p["engine"]._decode.lower(
+        p["params"], p["pools"], p["pools"], spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, width), spec(jnp.int32, rows),
+        spec(jnp.int32, rows, L + 2), spec(jnp.bool_, rows),
+        spec(jnp.int32, rows, L), spec(jnp.int32, rows),
+        spec(jnp.float32, rows), spec(jnp.uint32, 2),
+        spec(jnp.float32, rows), spec(jnp.int32, rows),
+        spec(jnp.float32, rows), p["counters"]).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_attention": 6,
+                                    "grouped_hidden": 6, "grouped_out": 6}
+    assert pool_copies(text, p["pool"]) == 0
+    for scope in ("attn/qk_norm", "sdar/attend", "moe/route", "moe/experts",
+                  "sdar/confidence", "sdar/unmask"):
+        assert scope in text, scope
+    _sdar_memory(p, compiled, recorded)
+
+
+@pytest.mark.timeout_s(600)
+def test_sdar_prefill_chunk_compiles_for_v5e_into_the_pages(
+        sdar_programs, as_tpu):
+    """The largest bucket (512 tokens): the chunk's K/V go into the row's
+    pages through its table and are attended there in blocks under the
+    block mask: no dense K/V of a row among its arguments or temporaries,
+    nothing of [chunk, vocab] (a prompt samples nothing: no head), no pool
+    copied; the pairs go sorted through the two grouped kernels a layer;
+    the chunks' own expert counters (six pairs of [128] int32) ride donated
+    beside the pools."""
+    from ray_tpu.llm.paged import array_shapes, pool_copies
+    from ray_tpu.ops.attention import pallas_kernels
+    p = sdar_programs
+    spec, cfg = p["spec"], p["cfg"]
+    width = p["engine_cfg"].pages_per_seq
+    assert p["engine_cfg"].prefill_buckets[-1] == 512
+    compiled = p["engine"]._chunk_prefill.lower(
+        p["params"], spec(jnp.int32, 1, 512), spec(jnp.int32, 1, 512),
+        (p["pools"], p["pools"], p["counters"]), spec(jnp.int32),
+        spec(jnp.int32, width), spec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"grouped_hidden": 6, "grouped_out": 6}
+    assert pool_copies(text, p["pool"]) == 0
+    positions = width * p["engine_cfg"].page_size + 512
+    assert array_shapes(text, (1, 4, positions, 128)) == 0
+    assert array_shapes(text, (512, cfg.vocab_size)) == 0
+    _sdar_memory(p, compiled,
+                 p["config"]["memory_analysis"]["chunk_prefill_512"])
