@@ -55,7 +55,8 @@ from . import reqtrace
 from ._metrics import llm_metrics
 from .radix import RadixPrefixCache
 from .sampling import (SAMPLER_TIERS, sample_tokens, sample_with_confidence,
-                       sampler_tier, unmask_block, unmask_count)
+                       unmask_block, unmask_count)
+from .staging import StagedRows
 
 if TYPE_CHECKING:
     from ..models.evabyte import EvaByteConfig
@@ -663,6 +664,10 @@ class PagedLLMEngine:
             self._tokens = jax.device_put(
                 self._tokens, NamedSharding(mesh, PSpec()))
         self._unread: List[Tuple[int, _Seq]] = []
+        # what the step is told about its rows (tables, lengths, sampling
+        # triples, the rows that decode), kept between visits on the host
+        # and on the device: only what changed is written or sent
+        self._stage = StagedRows(config.max_batch, config.pages_per_seq)
         # requests that ended since step() last returned
         self._finished: List[Tuple[GenerationRequest, Any]] = []
         # decode steps dispatched before the tokens of the step before
@@ -1670,14 +1675,18 @@ class PagedLLMEngine:
         """How the step ahead fared so far: decode steps dispatched
         before the last one's tokens were read, reads with nothing
         dispatched behind them, tokens dropped a tick late; and what the
-        visits dispatched: decode rows, prefill chunks, those of them that
-        ran the head, the prompts they finished, and the decode steps by
-        the sampler's branch; for a model with routed experts, the chunks
-        whose bucket put them on the sorted form (`prefill_chunks_sorted`)."""
+        visits dispatched: decode rows, the steps and the kept arrays sent
+        for them (`stage_steps`, `stage_uploads`), prefill chunks, those of
+        them that ran the head, the prompts they finished, and the decode
+        steps by the sampler's branch; for a model with routed experts, the
+        chunks whose bucket put them on the sorted form
+        (`prefill_chunks_sorted`)."""
         counts = {"lookahead_ticks": self._lookahead_ticks,
                   "drained_ticks": sum(self._drained_ticks.values()),
                   "discarded_tokens": self._discarded_tokens,
                   "decode_rows": self._decode_rows,
+                  "stage_steps": self._stage.steps,
+                  "stage_uploads": self._stage.uploads,
                   **{f"sampler_{tier}_steps": steps for tier, steps
                      in zip(SAMPLER_TIERS, self._sampler_steps)},
                   "prefill_chunks": self._prefill_chunks,
@@ -2515,10 +2524,13 @@ class PagedLLMEngine:
         """The decode half of a visit: dispatch the next step for every
         row that will decode again, THEN read the tokens of the step
         dispatched a visit earlier. `phase` is the tick's
-        `StepTimer.phase`."""
+        `StepTimer.phase`. What the step is told about its rows is kept
+        between visits (`staging.StagedRows`): `sync` writes the rows that
+        joined, left or took a page, the accounts of what the step attends
+        are sums over the kept lengths and page counts, and of the arrays
+        only `lengths` is sent every visit."""
         tick_start = time.monotonic()
         cfg = self.config
-        B = cfg.max_batch
         with phase("grow"):
             # a row whose token in flight is its last by rule, or that was
             # cancelled, is in no further step; lazy page growth for the
@@ -2538,41 +2550,42 @@ class PagedLLMEngine:
                 trace_rids = [self.seqs[i].request.request_id
                               for i in active]
                 compile_t0 = self._compile_total()
-            block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            top_ps = np.ones((B,), np.float32)
-            poll = self._poll
-            for n, i in enumerate(active):
-                if not n & 7:     # a poll is ~1 us on a slow host
-                    poll()
-                seq = self.seqs[i]
-                block_tables[i, :len(seq.pages)] = seq.pages
-                lengths[i] = seq.length
-                temps[i], top_ks[i], top_ps[i] = \
-                    self._sampling(seq.request)
-                if self._windowed:
-                    summary, window = cfg.model.attended_rows(seq.length)
-                    self._summary_rows += summary
-                    self._window_rows += window
-                if self._latent:
-                    self._latent_rows_attended += seq.length + 1
-                    self._latent_pages_rowwise += len(seq.pages)
-                    self._latent_pages_copied += len(seq.pages)
-                if self._indexed:
-                    self._index_rows_scanned += seq.length + 1
-                    self._index_pages_rowwise += len(seq.pages)
-                    self._sparse_rows_context += seq.length + 1
-                    self._sparse_rows_selected += min(
-                        seq.length + 1, cfg.model.index_topk)
+            stage, poll = self._stage, self._poll
+            poll()
+            stage.sync(active, self.seqs, self._sampling)
+            poll()
+            index = stage.index
+            lengths = stage.lengths[index]
+            if self._windowed:
+                summary, window = cfg.model.attended_rows(lengths)
+                self._summary_rows += int(summary.sum())  # host-sync ok: numpy
+                self._window_rows += int(window.sum())  # host-sync ok: numpy
+            if self._in_place:
+                # the cached rows the step attends or scores, its own
+                # token's among them, and the pages they lie in, a row
+                rows = len(active) \
+                    + int(lengths.sum())  # host-sync ok: numpy
+                pages = int(stage.held[index].sum())  # host-sync ok: numpy
+            if self._latent:
+                self._latent_rows_attended += rows
+                self._latent_pages_rowwise += pages
+                self._latent_pages_copied += pages
+            if self._indexed:
+                self._index_rows_scanned += rows
+                self._index_pages_rowwise += pages
+                self._sparse_rows_context += rows
+                self._sparse_rows_selected += int(  # host-sync ok: numpy
+                    np.minimum(lengths + 1, cfg.model.index_topk).sum())
+            seqs = self.seqs
+            for i in active:
                 # this step's token, in flight from here on
+                seq = seqs[i]
                 seq.length += 1
                 seq.dispatched += 1
             if self._in_place:
                 # the pages those rows hold, each once
                 seen = self._page_seen
-                seen[block_tables[active].ravel()] = True
+                seen[stage.tables[index].ravel()] = True
                 seen[0] = False
                 if self._latent:
                     self._latent_pages_distinct += np.count_nonzero(seen)
@@ -2586,10 +2599,10 @@ class PagedLLMEngine:
                 # the device makes of the same arrays
                 self._latent_pages_copied -= int(  # host-sync ok: numpy
                     pages_spared(share_schedule(
-                        block_tables, lengths, cfg.page_size)))
+                        stage.tables, stage.lengths, cfg.page_size)))
                 poll()
             self._decode_rows += len(active)
-            self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
+            self._sampler_steps[stage.tier] += 1
             poll()
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
@@ -2603,25 +2616,24 @@ class PagedLLMEngine:
                 with (timer.device() if timer is not None
                       else contextlib.nullcontext()):
                     with phase("stage"):
-                        def upload(array):
+                        def send(name):
                             poll()
-                            return jnp.asarray(array)
-                        args = (upload(block_tables), upload(lengths),
-                                self._tokens, key, upload(temps),
-                                upload(top_ks), upload(top_ps))
+                            return stage.send(name)
+                        args = (send("tables"), send("lengths"),
+                                self._tokens, key, send("temps"),
+                                send("top_ks"), send("top_ps"))
+                        # the rows that decode, for the layers that count
+                        # or scan
+                        live = (send("live"),) if self._in_place \
+                            or self.state is not None else ()
                     with phase("dispatch"):
                         unread, tokens = self._unread, self._tokens
-                        if self._in_place or self.state is not None:
-                            # the rows that decode, for the layers that
-                            # count or scan
-                            live = np.zeros((B,), bool)
-                            live[active] = True
                         self._dispatching()
                         if self._in_place:
                             (self._tokens, self._row_pools,
                              self.counters) = self._decode(
-                                self.params, self._row_pools,
-                                jnp.asarray(live), *args, self.counters)
+                                self.params, self._row_pools, *live, *args,
+                                self.counters)
                         elif self.state is None:
                             (self._tokens, self.k_pages,
                              self.v_pages) = self._decode(
@@ -2631,15 +2643,15 @@ class PagedLLMEngine:
                             (self._tokens, self.k_pages, self.v_pages,
                              self.state, self.counters) = self._decode(
                                 self.params, self.k_pages, self.v_pages,
-                                self.state, jnp.asarray(live), *args,
-                                self.counters)
+                                self.state, *live, *args, self.counters)
                         self._dispatched(self._tokens)
                         # the copy to the host starts when the step ends,
                         # whatever is queued behind it by then
                         self._tokens.copy_to_host_async()
+                        stage.sent(advance=True)
                         self._unread = [(i, self.seqs[i]) for i in active]
                         # freed here, inside a phase, not after the last
-                        del args
+                        del args, live
                     if self._windowed:
                         with phase("compress"):
                             for i in active:
@@ -2647,6 +2659,7 @@ class PagedLLMEngine:
                                         self.seqs[i].length):
                                     self._close_window(self.seqs[i],
                                                        "decode")
+                                    stage.stale(i)
                     if unread:
                         self._lookahead_ticks += 1
                         with phase("wait"):
@@ -2767,34 +2780,30 @@ class PagedLLMEngine:
                 trace_rids = [self.seqs[i].request.request_id
                               for i in active]
                 compile_t0 = self._compile_total()
-            block_tables = np.zeros((B, cfg.pages_per_seq), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            live = np.zeros((B,), bool)
+            stage, poll = self._stage, self._poll
+            poll()
+            # tables, triples and the rows that decode are kept between
+            # visits as `_decode_tick`'s are; `lengths` is written below
+            stage.sync(active, self.seqs, self._sampling)
+            # what a block step takes besides, anew every forward
+            lengths = stage.lengths
             opened = np.zeros((B,), bool)
             fresh = np.zeros((B, L), np.int32)
             counts = np.zeros((B,), np.int32)
             thresholds = np.full((B,), 2.0, np.float32)
-            temps = np.zeros((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            top_ps = np.ones((B,), np.float32)
-            poll = self._poll
             for n, i in enumerate(active):
                 if not n & 7:
                     poll()
                 seq = self.seqs[i]
-                block_tables[i, :len(seq.pages)] = seq.pages
                 thresholds[i] = seq.block_rule[1]
                 ids, counts[i] = self._plan_forward(seq)
                 if ids is not None:
                     opened[i], fresh[i] = True, ids
                 # where this forward's K/V rows go: the block's positions
                 lengths[i] = seq.flight[-1][0]
-                live[i] = True
-                temps[i], top_ks[i], top_ps[i] = \
-                    self._sampling(seq.request)
             self._decode_rows += len(active)
             self._block_forwards += len(active)
-            self._sampler_steps[sampler_tier(temps, top_ks, top_ps)] += 1
+            self._sampler_steps[stage.tier] += 1
             poll()
             self._rng, key = jax.random.split(self._rng)
         accel = self._accel
@@ -2807,14 +2816,17 @@ class PagedLLMEngine:
             with self._mesh_scope(), (timer.device() if timer is not None
                                       else contextlib.nullcontext()):
                 with phase("stage"):
+                    def send(name):
+                        poll()
+                        return stage.send(name)
+
                     def upload(array):
                         poll()
                         return jnp.asarray(array)
-                    args = (upload(live), upload(block_tables),
-                            upload(lengths), self._tokens, upload(opened),
-                            upload(fresh), upload(counts),
-                            upload(thresholds), key, upload(temps),
-                            upload(top_ks), upload(top_ps))
+                    args = (send("live"), send("tables"), send("lengths"),
+                            self._tokens, upload(opened), upload(fresh),
+                            upload(counts), upload(thresholds), key,
+                            send("temps"), send("top_ks"), send("top_ps"))
                 with phase("dispatch"):
                     unread, report = self._unread, self._tokens
                     self._dispatching()
@@ -2824,6 +2836,7 @@ class PagedLLMEngine:
                         self.counters)
                     self._dispatched(self._tokens)
                     self._tokens.copy_to_host_async()
+                    stage.sent(advance=False)
                     self._unread = [(i, self.seqs[i]) for i in active]
                     del args
                 if unread:
@@ -2955,7 +2968,10 @@ class PagedLLMEngine:
         `prefill_computed_tokens`, `prefill_ctx_rows`, `radix_evictions`,
         `radix_evict_walks` (walks of the whole radix that eviction made:
         at most one a call that had to drop a node, `radix.py`),
-        and `index_cache_bytes` / `sparse_kernel`."""
+        and `index_cache_bytes` / `sparse_kernel`. `stage_steps` /
+        `stage_uploads`: steps dispatched and the kept arrays sent for them
+        (`staging.StagedRows`; their quotient is the uploads a step, of the
+        five arrays, or six, that a step takes)."""
         self._flush_step_rows()  # surfaces the partial window
         index_bytes = sum(int(np.prod(pool.shape)) * pool.dtype.itemsize
                           for pool in self.index_pages)

@@ -549,7 +549,10 @@ def test_prefill_parts_lie_inside_the_phase_and_are_no_phase(five_prompts):
     assert counters["prefill_chunk_s"] + counters["prefill_finish_s"] \
         <= phases["prefill"]
     # the phases still tile the visit: a part is a counter, not a phase
-    assert set(phases) <= set(PHASES) | {"state"}
+    # (the row is the process's: a phase that only another file's engine
+    # ran, a windowed model's `compress`, stands in it with no seconds)
+    assert {name for name, seconds in phases.items() if seconds} \
+        <= set(PHASES) | {"state"}
 
 
 @pytest.mark.parametrize("name,count", [("prompts_finished", 5),
