@@ -86,6 +86,9 @@ class LlamaConfig:
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def module(self) -> "LlamaModel":
+        return LlamaModel(self)
+
     # ---- presets ----
     @staticmethod
     def tiny_test():

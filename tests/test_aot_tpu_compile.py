@@ -329,7 +329,7 @@ def falcon_programs(v5e):
     """The `serve-falconh1-chat-closed128` cell's engine programs (its
     config file's widths, rows and pool, its builder) at one layer, with
     the shapes of their arguments on one described chip: the engine's own
-    `_recurrent_programs`, on an engine that never allocated anything."""
+    `_kind_programs`, on an engine that never allocated anything."""
     from benchmarks.harness.builders_falcon_h1 import falcon_h1_engine
     from ray_tpu.llm.paged import PagedLLMEngine
     from ray_tpu.parallel.mesh import unbox
@@ -338,9 +338,9 @@ def falcon_programs(v5e):
         config = dict(json.load(f), num_hidden_layers=1)
     engine_cfg = falcon_h1_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._recurrent_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -463,9 +463,9 @@ def nemotron_programs(v5e):
         config = json.load(f)
     engine_cfg = nemotron_h_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._recurrent_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -715,9 +715,9 @@ def sarvam_programs(v5e):
         config = json.load(f)
     engine_cfg = sarvam_mla_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._latent_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -826,9 +826,9 @@ def keye_programs(v5e):
         config = json.load(f)
     engine_cfg = keye_dsa_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._indexed_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -1154,9 +1154,9 @@ def xing_programs(v5e):
         config = json.load(f)
     engine_cfg = xing_mhc_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._latent_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -1287,9 +1287,9 @@ def lfm2_programs(v5e):
         config = json.load(f)
     engine_cfg = lfm2_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._recurrent_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -1431,9 +1431,9 @@ def sdar_programs(v5e):
         config = json.load(f)
     engine_cfg = sdar_engine(config, seed=0)
     cfg = engine_cfg.model
-    engine = object.__new__(PagedLLMEngine)
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
     engine.config, engine.model = engine_cfg, cfg.module()
-    engine._block_programs()
+    engine._kind_programs()
     one = SingleDeviceSharding(v5e[0])
 
     def placed(tree):

@@ -82,7 +82,7 @@ def loop_arrays(engine, active):
         seq = engine.seqs[i]
         out["tables"][i, :len(seq.pages)] = seq.pages
         # a block step is told where the open block starts
-        out["lengths"][i] = seq.flight[-1][0] if engine._blockwise \
+        out["lengths"][i] = seq.flight[-1][0] if engine.kind == "blockwise" \
             else seq.length - 1
         out["live"][i] = True
         out["temps"][i], out["top_ks"][i], out["top_ps"][i] = \
@@ -101,14 +101,14 @@ def rows_attended(engine, active):
     for i in active:
         seq = engine.seqs[i]
         length = seq.length - 1
-        if engine._windowed:
+        if engine.kind == "windowed":
             summary, window = model.attended_rows(length)
             out["summary_rows"] += summary
             out["window_rows"] += window
-        if engine._latent:
+        if engine.kind == "latent":
             out["latent_rows_attended"] += length + 1
             out["latent_pages_rowwise"] += len(seq.pages)
-        if engine._indexed:
+        if engine.kind == "indexed":
             out["index_rows_scanned"] += length + 1
             out["index_pages_rowwise"] += len(seq.pages)
             out["sparse_rows_context"] += length + 1
@@ -179,8 +179,9 @@ class Spy:
         for name, value in rows_attended(engine, self._active).items():
             self.account[name] += value
         self.steps[-1]["account"] = dict(self.account)
+        counts = engine._ahead_counts()      # a kind reports its own alone
         self.steps[-1]["engine_account"] = {
-            name: getattr(engine, "_" + name) for name in self.account}
+            name: counts.get(name, 0) for name in self.account}
         return args
 
 
@@ -327,14 +328,14 @@ def test_the_vector_accounts_equal_their_per_row_form(run):
         # the engine's sums when step n's program is called hold step n's
         assert step["engine_account"] == step["account"], n
     last = spy.steps[-1]["account"]
-    if engine._windowed:
+    if engine.kind == "windowed":
         assert last["window_rows"] > 0 and last["summary_rows"] > 0
-    if engine._latent:
+    if engine.kind == "latent":
         assert last["latent_rows_attended"] > last["latent_pages_rowwise"] > 0
-    if engine._indexed:
+    if engine.kind == "indexed":
         assert last["index_rows_scanned"] == last["sparse_rows_context"] > 0
         assert 0 < last["sparse_rows_selected"] <= last["sparse_rows_context"]
-    if not (engine._windowed or engine._latent or engine._indexed):
+    if engine.kind not in ("windowed", "latent", "indexed"):
         assert not any(last.values())
 
 

@@ -131,7 +131,7 @@ def test_the_tiny_config_keeps_the_published_layer_and_the_engines_contract(
         engine):
     cfg = engine.config.model
     assert cfg.num_heads // cfg.num_kv_heads == 2 and cfg.block_length == L
-    assert engine._blockwise and not engine._in_place
+    assert engine.kind == "blockwise"
     stats = engine.stats()
     assert stats["layer_kinds"] == ["pc", "pc"]
     assert len(engine.k_pages) == 2 and engine.k_pages[0].shape == (2, 64, 8, 8)
