@@ -94,6 +94,36 @@ class DenseEngine(PagedLLMEngine):
 
     kind = "dense"
 
+    @property
+    def _staged_rows(self) -> int:
+        """Positions of a prefilling row's dense cache. Covers the worst
+        chunked-prefill write: the last chunk is bucket-rounded, so a
+        prompt ending near max_len writes up to (largest_bucket - 1)
+        tokens of padding past it. Without the slack,
+        dynamic_update_slice would CLAMP the start index and silently
+        corrupt earlier positions."""
+        config = self.config
+        return config.pages_per_seq * config.page_size \
+            + config.prefill_buckets[-1]
+
+    def _init_cache(self):
+        super()._init_cache()
+        # the cached rows the prefill chunks attended (a chunk's rows up to
+        # its last real token), and the rows of the dense caches they
+        # attended them in: all of which a chunk attended before
+        # `ops.attention.attend_cache`
+        self._prefill_ctx_rows = 0
+        self._prefill_cache_rows = 0
+
+    def _chunk_done(self, seq: _Seq, staged, chunk: int, take: int):
+        seq.dense_caches = staged
+        self._prefill_ctx_rows += seq.prefill_off + take
+        self._prefill_cache_rows += self._staged_rows
+
+    def _kind_counts(self):
+        return {"prefill_ctx_rows": self._prefill_ctx_rows,
+                "prefill_cache_rows": self._prefill_cache_rows}
+
     def _dense_programs(self):
         """`_decode`, `_chunk_prefill`, `_dense_zero_caches`,
         `_write_pages`, `_gather_pages`: built for every kind (the
@@ -149,15 +179,10 @@ class DenseEngine(PagedLLMEngine):
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
+        staged_rows = self._staged_rows
+
         def _dense_zero_caches():
-            # Length covers the worst chunked-prefill write: the last
-            # chunk is bucket-rounded, so a prompt ending near max_len
-            # writes up to (largest_bucket - 1) tokens of padding past
-            # it. Without the slack, dynamic_update_slice would CLAMP
-            # the start index and silently corrupt earlier positions.
-            slack = config.prefill_buckets[-1]
-            return init_kv_caches(
-                cfg, 1, config.pages_per_seq * config.page_size + slack)
+            return init_kv_caches(cfg, 1, staged_rows)
 
         self._dense_zero_caches = jax.jit(
             _dense_zero_caches,
@@ -286,7 +311,7 @@ class RecurrentEngine(NoPrefix, DenseEngine):
         self._state_installs = 0
 
     def _kind_programs(self):
-        config, cfg, model = self.config, self.config.model, self.model
+        cfg, model = self.config.model, self.model
         kinds = layer_caches(cfg)
         names = tuple(cfg.state_shapes())
 
@@ -373,10 +398,10 @@ class RecurrentEngine(NoPrefix, DenseEngine):
 
         self._chunk_prefill = jax.jit(chunk_prefill, donate_argnums=(3,))
 
+        staged_rows = self._staged_rows
+
         def _staging_zero():
-            slack = config.prefill_buckets[-1]   # as _dense_zero_caches
-            length = config.pages_per_seq * config.page_size + slack
-            shape = (1, cfg.num_kv_heads, length, cfg.head_dim_)
+            shape = (1, cfg.num_kv_heads, staged_rows, cfg.head_dim_)
             return {"kv": [(jnp.zeros(shape, cfg.dtype),
                             jnp.zeros(shape, cfg.dtype))
                            for attends, _, _ in kinds if attends],
@@ -500,10 +525,8 @@ class PooledEngine(RecurrentEngine):
 
     def _init_cache(self):
         super()._init_cache()
-        # prefill chunks (each wrote the row's pages) and the cached rows
-        # they attended
+        # prefill chunks (each wrote the row's pages)
         self._prefill_chunks_in_place = 0
-        self._prefill_ctx_rows = 0
 
     def _chunk_args(self, seq: _Seq, chunk: int, take: int):
         staged = dict(seq.dense_caches,
@@ -741,9 +764,8 @@ class InPlaceEngine(PagesInPlace):
     def _init_cache(self):
         super()._init_cache()
         self.counters = _counters_of(self.config.model)
-        # the cached rows the prefill chunks attended, and the pages the
-        # decoding rows held a step, each once (`_account_decode`)
-        self._prefill_ctx_rows = 0
+        # the pages the decoding rows held a step, each once
+        # (`_account_decode`)
         self._page_seen = np.zeros((self.config.num_pages,), bool)
         self._pages_distinct = 0
 
@@ -1078,7 +1100,6 @@ class BlockwiseEngine(PagesInPlace):
         # many such chunks were dispatched
         self.chunk_counters = cfg.init_counters()
         self._chunks_counted = 0
-        self._prefill_ctx_rows = 0
         # what the block steps did: row-forwards dispatched, those of them
         # that were commits, tokens handed out, blocks the dynamic rule
         # finished ahead of the static count

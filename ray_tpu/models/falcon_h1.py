@@ -41,6 +41,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import attend_cache
 from ..ops.paged_attention import paged_attend
 from ..ops.ssm import ssd_chunked_scan, ssm_step
 from .llama import (RMSNorm, _flash_on_mesh, _partitioned, apply_rope,
@@ -181,17 +182,7 @@ class HybridAttention(nn.Module):
             cv = jax.lax.dynamic_update_slice_in_dim(
                 cv, v.astype(cv.dtype), cache_index, axis=2)
             new_cache = (ck, cv)
-            groups = cfg.num_heads // cfg.num_kv_heads
-            logits = jnp.einsum(
-                "bhqd,bhkd->bhqk", q.astype(F32),
-                jnp.repeat(ck, groups, axis=1).astype(F32)) * hd ** -0.5
-            seen = jnp.arange(ck.shape[2])[None, None, :] \
-                <= positions[:, :, None]
-            probs = jax.nn.softmax(
-                jnp.where(seen[:, None], logits, -1e30), axis=-1)
-            out = jnp.einsum(
-                "bhqk,bhkd->bhqd", probs,
-                jnp.repeat(cv, groups, axis=1).astype(F32)).astype(cfg.dtype)
+            out = attend_cache(q, ck, cv, cache_index, positions)
         elif cfg.attention_impl == "reference":
             from ..ops.attention import attention_reference
             out = attention_reference(q, k, v, True)

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ..ops.attention import flash_attention, flash_uses_pallas
+from ..ops.attention import (attend_cache, flash_attention,
+                             flash_uses_pallas)
 from ..ops.paged_attention import paged_attend
 from ..parallel.mesh import constrain, current_kernel_mesh
 
@@ -336,20 +337,7 @@ class Attention(nn.Module):
             cv = jax.lax.dynamic_update_slice_in_dim(
                 cv, v.astype(cv.dtype), cache_index, axis=2)
             new_cache = (ck, cv)
-            groups = cfg.num_heads // cfg.num_kv_heads
-            kk = jnp.repeat(ck, groups, axis=1)
-            vv = jnp.repeat(cv, groups, axis=1)
-            scale = hd ** -0.5
-            logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                                kk.astype(jnp.float32)) * scale
-            kv_pos = jnp.arange(kk.shape[2])[None, :]
-            q_pos = positions[:, :, None] if positions.ndim == 2 \
-                else positions[None, :, None]
-            mask = kv_pos[:, None, :] <= q_pos  # [b, q, k]
-            logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", probs,
-                             vv.astype(jnp.float32)).astype(cfg.dtype)
+            out = attend_cache(q, ck, cv, cache_index, positions)
         else:
             impl = cfg.attention_impl if cfg.use_flash else "chunked"
             if impl == "reference":

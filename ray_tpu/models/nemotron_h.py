@@ -53,12 +53,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import attend_cache
 from ..ops.paged_attention import paged_attend
 from .falcon_h1 import FalconH1Config, MambaMixer, _dense
 from .llama import RMSNorm, _flash_on_mesh, _partitioned, write_token_rows
 from .moe import RoutedExperts, relu2
 
-F32 = jnp.float32
 KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
 
 
@@ -184,17 +184,7 @@ class PositionlessAttention(nn.Module):
             cv = jax.lax.dynamic_update_slice_in_dim(
                 cv, v.astype(cv.dtype), cache_index, axis=2)
             new_cache = (ck, cv)
-            groups = cfg.num_heads // cfg.num_kv_heads
-            qg = q.reshape(q.shape[0], cfg.num_kv_heads, groups,
-                           q.shape[2], hd).astype(F32)
-            logits = jnp.einsum("bgjqd,bgkd->bgjqk", qg,
-                                ck.astype(F32)) * hd ** -0.5
-            seen = jnp.arange(ck.shape[2])[None, None, :] \
-                <= positions[:, :, None]
-            probs = jax.nn.softmax(
-                jnp.where(seen[:, None, None], logits, -1e30), axis=-1)
-            out = jnp.einsum("bgjqk,bgkd->bgjqd", probs, cv.astype(F32))
-            out = out.reshape(q.shape).astype(cfg.dtype)
+            out = attend_cache(q, ck, cv, cache_index, positions)
         elif cfg.attention_impl == "reference":
             from ..ops.attention import attention_reference
             out = attention_reference(q, k, v, True)

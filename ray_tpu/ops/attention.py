@@ -18,6 +18,10 @@ The hot op of every transformer in the framework. Tiers:
    tests; `flash_attention` dispatches to (2) when shapes don't fit the
    kernel constraints or offsets are used (ring attention's rotating chunks
    handle their own masking).
+4. `attend_cache` — new positions over a DENSE cache written at a scalar
+   index (a prefill chunk, a single-sequence decode): only the blocks the
+   cache has filled, a kv head's query heads as rows of one product; a
+   Pallas kernel on the TPU for the shapes it takes, an XLA loop elsewhere.
 
 All functions take q/k/v as [batch, heads, seq, head_dim] (BHSD), GQA as
 fewer kv heads (num_q_heads % num_kv_heads == 0). `q_offset`/`kv_offset`
@@ -134,6 +138,113 @@ def attention_chunked(q, k, v, causal: bool = True,
     (m, l, acc), _ = jax.lax.scan(
         step, init, (jnp.arange(n_chunks), kc_t, vc_t))
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+# Cached positions, and at most how many query rows, a step of
+# `attend_cache` takes (on the chip, Mistral's chunk of 256: 1,024 rows a step
+# 0.38 ms a 16-layer chunk, 512 rows 0.46, 256 rows 0.60: PERF.md §6, PR 61)
+_CACHE_BLOCK = 512
+_CACHE_ROWS = 1024
+
+
+def attend_cache(q, ck, cv, cache_index, positions):
+    """`s` new positions of every row over a DENSE cache written at the
+    scalar `cache_index`: a prefill chunk, or a single-sequence decode
+    (`s` = 1). q [b, heads, s, d], unscaled; ck / cv [b, kv_heads,
+    capacity, d], the new rows already written; positions [b, s] (or [s],
+    every row's): query i attends the cache positions <= positions[.., i].
+    The cache is taken a block of positions at a time with running softmax
+    statistics (float32; q · K takes the cache's type and accumulates in
+    float32), as many blocks as `cache_index + s` reaches, a value: one
+    program whatever the cache has filled. A kv head's group of query
+    heads stands as rows of one product against that head's block, so
+    neither the logits, nor K or V expanded to the query heads, nor
+    anything past the filled span's last block is ever built or read.
+    On the TPU, for the shapes `_cache_blocks` takes, the blocks are the
+    grid of a kernel (`attend_cache`), which rounds the probabilities to
+    the cache's type for P · V as the paged kernel does; elsewhere an XLA
+    loop, the plain form, which keeps them float32 (a tiny model's greedy
+    tokens are held to the float32 reference's). Returns [b, heads, s, d]
+    in q's type."""
+    _validate(q, ck, cv)
+    b, heads, s, d = q.shape
+    kv_heads = ck.shape[1]
+    groups = heads // kv_heads
+    queries = q.reshape(b, kv_heads, groups * s, d).astype(ck.dtype)
+    at = jnp.tile(jnp.broadcast_to(positions, (b, s)), (1, groups))
+    blocks = None if _interpret() or _on_many_devices() \
+        else _cache_blocks(groups, s, ck.shape[2], d)
+    if blocks is None:
+        out = _attend_cache_loop(queries, ck, cv, at, cache_index + s)
+    else:
+        out = _attend_cache_pallas(queries, ck, cv, at, cache_index + s,
+                                   *blocks)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+def _on_many_devices() -> bool:
+    """Under a kernel mesh of several devices GSPMD partitions the XLA loop
+    on its own and cannot partition a Mosaic call."""
+    from ..parallel.mesh import current_kernel_mesh
+    mesh = current_kernel_mesh()
+    return mesh is not None and mesh.size > 1
+
+
+def _cache_blocks(groups: int, s: int, capacity: int, d: int
+                  ) -> Optional[Tuple[int, int]]:
+    """(Query rows, cached positions) a step of the kernel takes: whole
+    groups of `s` rows up to `_CACHE_ROWS`, and the longest of
+    `_CACHE_BLOCK`, its half and its quarter that the capacity is whole
+    blocks of. None for shapes the kernel does not take (a decode token's
+    few rows, heads that are no whole lanes)."""
+    if d % NUM_LANES or s % 16 or s > _CACHE_ROWS:
+        return None
+    fits = [n for n in (_CACHE_BLOCK, _CACHE_BLOCK // 2, _CACHE_BLOCK // 4)
+            if n % NUM_LANES == 0 and capacity % n == 0]
+    if not fits:
+        return None
+    whole = max(k for k in range(1, groups + 1)
+                if groups % k == 0 and k * s <= _CACHE_ROWS)
+    return whole * s, fits[0]
+
+
+def _attend_cache_loop(queries, ck, cv, at, filled):
+    """queries [b, kv_heads, rows, d] in the cache's type, at [b, rows] the
+    last position each row attends, `filled` the positions written:
+    [b, kv_heads, rows, d] float32."""
+    b, kv_heads, rows, d = queries.shape
+    capacity = ck.shape[2]
+    block = min(_CACHE_BLOCK, capacity)
+    at = at[:, None, :, None]
+
+    def attend_block(i, carry):
+        m, l, acc = carry
+        # the last block of a capacity that is no whole number of blocks
+        # is moved back over positions the block before it took
+        start = jnp.minimum(i * block, capacity - block)
+        at_k = start + jnp.arange(block)
+        keys = jax.lax.dynamic_slice_in_dim(ck, start, block, axis=2)
+        values = jax.lax.dynamic_slice_in_dim(cv, start, block, axis=2)
+        # what lies behind the filled span is no number to multiply by 0
+        values = jnp.where((at_k < filled)[:, None], values, 0)
+        logits = jnp.einsum("bgrd,bgkd->bgrk", queries, keys,
+                            preferred_element_type=jnp.float32) * d ** -0.5
+        seen = (at_k <= at) & (at_k >= i * block)
+        logits = jnp.where(seen, logits, NEG_INF)
+        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        correction = jnp.exp(m - m_new)
+        return (m_new, l * correction + p.sum(-1, keepdims=True),
+                acc * correction + jnp.einsum(
+                    "bgrk,bgkd->bgrd", p, values.astype(jnp.float32)))
+
+    stats = (b, kv_heads, rows, 1)
+    _, l, acc = jax.lax.fori_loop(
+        0, (filled + block - 1) // block, attend_block,
+        (jnp.full(stats, NEG_INF, jnp.float32),
+         jnp.zeros(stats, jnp.float32),
+         jnp.zeros((b, kv_heads, rows, d), jnp.float32)))
+    return acc / l
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +420,102 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(kb == nk - 1)
     def _finalize():
         dq_ref[0] = dq_scratch[:].astype(dq_ref.dtype)
+
+
+def _cache_kernel(filled_ref, q_ref, at_ref, k_ref, v_ref, o_ref, m_scratch,
+                  l_scratch, acc_scratch, *, sm_scale, block_k):
+    """One (kv head, block of query rows) over the cache's blocks in turn:
+    `_fwd_kernel`'s statistics, the products in the cache's type, the mask
+    from each row's own last position, and nothing computed for a block
+    behind the filled span (its index maps to the last one needed, so
+    nothing is copied for it either)."""
+    kb = pl.program_id(2)
+    filled = filled_ref[0]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
+        l_scratch[:] = jnp.zeros_like(l_scratch)
+        acc_scratch[:] = jnp.zeros_like(acc_scratch)
+
+    @pl.when(kb * block_k < filled)
+    def _compute():
+        k, v = k_ref[0], v_ref[0]
+        logits = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        logits = jnp.where(k_pos <= at_ref[0], logits, NEG_INF)
+        # what lies behind the filled span is no number to multiply by 0
+        written = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 0) < filled
+        v = jnp.where(written, v, jnp.zeros_like(v))
+        d = v.shape[-1]
+        m_prev = m_scratch[:]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - _lane_tile(m_new, block_k))
+        correction = jnp.exp(m_prev - m_new)
+        m_scratch[:] = m_new
+        l_scratch[:] = l_scratch[:] * correction + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_scratch[:] = acc_scratch[:] * _lane_tile(correction, d) + \
+            jax.lax.dot(p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finalize():
+        d = o_ref.shape[-1]
+        o_ref[0] = (acc_scratch[:] / _lane_tile(l_scratch[:], d)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k"))
+def _attend_cache_pallas(queries, ck, cv, at, filled, block_q: int,
+                         block_k: int):
+    """`_attend_cache_loop`'s arguments and result (in the cache's type),
+    as a kernel over (row x kv head, blocks of `block_q` query rows, blocks
+    of `block_k` cached positions). Jitted so that a model's layers share
+    ONE trace of the kernel's body, as `_paged_attend_pallas`: traced a
+    layer at a time, 16 layers x 4 buckets added ~5 s to every start of
+    the chat cell's replica (PERF.md §6, PR 61)."""
+    b, kv_heads, rows, d = queries.shape
+    capacity = ck.shape[2]
+
+    def q_index(h, qb, kb, filled):
+        return (h, qb, 0)
+
+    def kv_index(h, qb, kb, filled):
+        return (h, jnp.minimum(kb, (filled[0] - 1) // block_k), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_cache_kernel, sm_scale=d ** -0.5,
+                          block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * kv_heads, rows // block_q, capacity // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_index),
+                pl.BlockSpec((1, block_q, 1), lambda h, qb, kb, filled:
+                             (h // kv_heads, qb, 0)),
+                pl.BlockSpec((1, block_k, d), kv_index),
+                pl.BlockSpec((1, block_k, d), kv_index)],
+            out_specs=pl.BlockSpec((1, block_q, d), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b * kv_heads, rows, d), ck.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="attend_cache",
+    )(jnp.reshape(filled, (1,)).astype(jnp.int32),
+      queries.reshape(b * kv_heads, rows, d),
+      at.astype(jnp.int32)[:, :, None],
+      ck.reshape(b * kv_heads, capacity, d),
+      cv.reshape(b * kv_heads, capacity, d))
+    return out.reshape(b, kv_heads, rows, d)
 
 
 def _kernel_params(sq: int, sk: int, d: int):

@@ -445,6 +445,113 @@ def test_hybrid_prefill_chunk_compiles_for_v5e(falcon_programs, as_tpu):
     assert memory.output_size_in_bytes < staged_bytes + 2 * 4 * vocab
 
 
+# (query heads a kv head, kv heads, the dense cache's positions): the three
+# configurations whose prefill chunk attends a dense cache
+_DENSE_CHUNKS = {"mistral": (4, 8, 2560), "falcon_h1": (5, 4, 1792),
+                 "nemotron_h": (16, 2, 1792)}
+
+
+@pytest.mark.parametrize("bucket", [32, 256])
+@pytest.mark.parametrize("name", sorted(_DENSE_CHUNKS))
+def test_attend_cache_compiles_for_v5e_at_the_shapes_it_takes(
+        v5e, as_tpu, name, bucket):
+    """The kernel under `ops.attention.attend_cache` at the smallest and
+    the largest bucket of each cell whose chunk attends a dense cache: whole
+    groups of query rows up to 1,024 a step (Falcon-H1's five groups of 256
+    go one at a time), blocks of 512 cached positions where the capacity is
+    whole blocks of them and of 256 where it is not."""
+    from ray_tpu.ops import attention
+    groups, kv_heads, capacity = _DENSE_CHUNKS[name]
+    blocks = attention._cache_blocks(groups, bucket, capacity, HEAD_DIM)
+    rows = groups * bucket
+    assert blocks == {
+        ("mistral", 32): (128, 512), ("mistral", 256): (1024, 512),
+        ("falcon_h1", 32): (160, 256), ("falcon_h1", 256): (256, 256),
+        ("nemotron_h", 32): (512, 256), ("nemotron_h", 256): (1024, 256),
+    }[name, bucket]
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cache = spec(jnp.bfloat16, 1, kv_heads, capacity, HEAD_DIM)
+    kernels = _kernels(
+        lambda *a: attention._attend_cache_pallas(*a, *blocks),
+        spec(jnp.bfloat16, 1, kv_heads, rows, HEAD_DIM), cache, cache,
+        spec(jnp.int32, 1, rows), spec(jnp.int32))
+    assert kernels == {"attend_cache": 1}
+
+
+def test_attend_cache_leaves_a_decode_token_and_odd_heads_to_the_loop():
+    """One new position a row (the dense `LLMEngine`'s decode), heads that
+    are no whole lanes and a capacity that is no whole blocks of 128 are
+    not the kernel's: `attend_cache` runs its XLA loop there."""
+    from ray_tpu.ops.attention import _cache_blocks
+    assert _cache_blocks(4, 1, 2560, 128) is None
+    assert _cache_blocks(4, 256, 2560, 64) is None
+    assert _cache_blocks(4, 256, 2560 + 16, 128) is None
+    assert _cache_blocks(4, 256, 2560, 128) == (1024, 512)
+
+
+def test_dense_prefill_chunk_builds_no_logits_of_its_whole_cache(
+        v5e, as_tpu):
+    """The chat and doc-QA cells' chunk (`mistral-7b-v0.3-serve.json` at one
+    layer, bucket 256) attends through `ops.attention.attend_cache`, one
+    kernel a layer: the logits of 32 heads x 256 queries over the row's
+    whole private cache (2,560 positions: 84 MB of float32 a layer) stand
+    nowhere in the program, whole, grouped or without their batch, and no
+    float32 array has a cache's shape or a cache's repeated to the query
+    heads. The donated caches alias; the temporaries are a third of what
+    the einsum branch took (104 MB at 16 layers, the configuration file's
+    record)."""
+    from benchmarks.harness.builders import llama_engine
+    from ray_tpu.llm.paged import PagedLLMEngine, array_shapes
+    from ray_tpu.ops.attention import pallas_kernels
+    from ray_tpu.parallel.mesh import unbox
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "mistral-7b-v0.3-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=1)
+    engine_cfg = llama_engine(config, seed=0)
+    cfg = engine_cfg.model
+    engine = PagedLLMEngine.__new__(PagedLLMEngine, engine_cfg)
+    engine.config, engine.model = engine_cfg, cfg.module()
+    engine._page_sharding = engine._dense_sharding = None
+    engine._dense_programs()
+    one = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    params = placed(jax.eval_shape(lambda: unbox(engine.model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    staged = placed(jax.eval_shape(engine._dense_zero_caches))
+    capacity = engine_cfg.max_len + 256
+    heads, kv_heads, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    assert staged[0][0].shape == (1, kv_heads, capacity, hd)
+    compiled = engine._chunk_prefill.lower(
+        params, spec(1, 256), spec(1, 256), staged, spec(),
+        spec()).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"attend_cache": 1}
+    rows = heads // kv_heads * 256
+    for logits in ((heads, 256, capacity), (1, heads, 256, capacity),
+                   (kv_heads, rows, capacity), (1, kv_heads, rows, capacity)):
+        assert array_shapes(text, logits) == 0
+    for shape in ((1, kv_heads, capacity, hd), (kv_heads, capacity, hd),
+                  (1, heads, capacity, hd), (heads, capacity, hd)):
+        assert "f32[" + ",".join(map(str, shape)) + "]" not in text
+    memory = compiled.memory_analysis()
+    staged_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(staged))
+    assert memory.alias_size_in_bytes >= staged_bytes
+    assert memory.temp_size_in_bytes < 40e6
+
+
 # ---------------------------------------------------------------------------
 # the Nemotron-H cell's programs (layers of three kinds, 128 held experts)
 # ---------------------------------------------------------------------------
@@ -539,7 +646,7 @@ def test_nemotron_decode_step_compiles_for_v5e_within_memory(
 
 def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
     """The largest bucket (256 tokens, the same einsums over every held
-    expert as a decode step's: no kernel of the program's own): the
+    expert as a decode step's: no kernel for them): the
     staging pytree holds dense K/V for the ONE layer that attends and
     a state for each of the five that scan, is donated and aliased, and
     nothing of a pool's shape is in the program."""
@@ -553,7 +660,9 @@ def test_nemotron_prefill_chunk_compiles_for_v5e(nemotron_programs, as_tpu):
         p["staged"], spec(jnp.int32), spec(jnp.int32),
         spec(jnp.int32)).compile()
     text = compiled.as_text()
-    assert pallas_kernels(text) == {}
+    # the one layer that attends, through `ops.attention.attend_cache`
+    # (PR 61); the expert layers still hold no kernel of their own
+    assert pallas_kernels(text) == {"attend_cache": 1}
     assert pool_copies(text, p["pool"]) == 0
     assert pool_copies(text, p["state"][0][1].shape) == 0
     staged_bytes = sum(math.prod(a.shape) * a.dtype.itemsize

@@ -5,8 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.attention import (attention_chunked, attention_reference,
-                                   flash_attention)
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import (attend_cache, attention_chunked,
+                                   attention_reference, flash_attention)
 from ray_tpu.parallel.mesh import MeshConfig
 from ray_tpu.parallel.ring_attention import ring_attention, ulysses_attention
 
@@ -100,6 +101,137 @@ def test_pallas_bwd_gqa_interpret():
     for a, b in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
+
+
+# (query heads a kv head, new positions, where they start, rows, the cache's
+# type): blocks of 64 positions; a capacity of 5 blocks and 40 (6 and 40 under
+# 256 new positions), so the last block is moved back over the one before it
+_CACHE_CASES = [
+    (1, 1, "start", 1, jnp.float32), (4, 1, "mid", 2, jnp.float32),
+    (16, 1, "last", 1, jnp.bfloat16), (1, 1, "mid", 1, jnp.bfloat16),
+    (4, 32, "start", 1, jnp.bfloat16), (1, 32, "mid", 2, jnp.bfloat16),
+    (16, 32, "last", 2, jnp.float32), (4, 32, "last", 1, jnp.bfloat16),
+    (4, 256, "start", 1, jnp.float32), (16, 256, "mid", 1, jnp.bfloat16),
+    (1, 256, "last", 2, jnp.float32), (4, 256, "mid", 2, jnp.bfloat16),
+]
+
+
+def _cache_case(groups, s, where, b, dtype, d=16, capacity=None):
+    """q, caches whose first start + s positions are filled and the rest
+    NaN, and start."""
+    capacity = capacity or (6 if s == 256 else 5) * 64 + 40
+    start = {"start": 0, "mid": 101, "last": capacity - s}[where]
+    kv_heads = 1 if groups == 16 else 2
+    rng = np.random.RandomState(groups + s + start)
+    q = jnp.asarray(rng.randn(b, kv_heads * groups, s, d), dtype)
+    filled = start + s
+    caches = []
+    for _ in range(2):
+        cache = np.full((b, kv_heads, capacity, d), np.nan, np.float32)
+        cache[:, :, :filled] = rng.randn(b, kv_heads, filled, d)
+        caches.append(jnp.asarray(cache, dtype))
+    return q, caches[0], caches[1], start
+
+
+def _assert_attends_the_filled_span(got, q, ck, cv, start):
+    filled = start + q.shape[2]
+    want = attention_reference(q, ck[:, :, :filled], cv[:, :, :filled],
+                               q_offset=start)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    # float32: the order of summation alone; bf16 (operands in the cache's
+    # type, float32 accumulation): the paged kernel's own limit
+    limit = 2e-5 if q.dtype == jnp.float32 else 2 ** -6
+    assert np.abs(got - want).max() <= limit * np.abs(want).max()
+
+
+@pytest.mark.parametrize(
+    "groups,s,where,b,dtype", _CACHE_CASES,
+    ids=[f"g{g}-s{s}-{w}-b{b}-{np.dtype(t).name}"
+         for g, s, w, b, t in _CACHE_CASES])
+def test_attend_cache_matches_reference_over_the_filled_span(
+        monkeypatch, groups, s, where, b, dtype):
+    """Query i attends positions 0 .. start + i of a dense cache and reads
+    nothing behind start + s as a number (it is NaN there): a single decode
+    token, a chunk inside a block and one across blocks, the first block,
+    one in the middle and the capacity's last, which is no whole block."""
+    monkeypatch.setattr(attention, "_CACHE_BLOCK", 64)
+    q, ck, cv, start = _cache_case(groups, s, where, b, dtype)
+    positions = jnp.broadcast_to(start + jnp.arange(s), (b, s))
+    got = attend_cache(q, ck, cv, jnp.int32(start), positions)
+    _assert_attends_the_filled_span(got, q, ck, cv, start)
+
+
+# ..., and the query rows a step of the kernel takes of them (whole groups,
+# up to 512 here)
+_KERNEL_CASES = [
+    (4, 32, "start", 1, jnp.float32, 128),
+    (4, 256, "mid", 1, jnp.bfloat16, 512),
+    (16, 32, "last", 2, jnp.bfloat16, 512),
+    (5, 32, "mid", 2, jnp.float32, 160),
+    (1, 256, "last", 1, jnp.bfloat16, 256),
+    (16, 128, "start", 1, jnp.bfloat16, 512),
+]
+
+
+@pytest.mark.parametrize(
+    "groups,s,where,b,dtype,rows", _KERNEL_CASES,
+    ids=[f"g{g}-s{s}-{w}-b{b}-{np.dtype(t).name}"
+         for g, s, w, b, t, _ in _KERNEL_CASES])
+def test_attend_cache_kernel_matches_reference_over_the_filled_span(
+        monkeypatch, groups, s, where, b, dtype, rows):
+    """The kernel the chip runs for these shapes (heads of 128, whole
+    groups of query rows a step, here in interpret mode) over a capacity of
+    five blocks of 128 positions: the same answers as the loop's, nothing
+    behind start + s read as a number, and the blocks behind the filled
+    span neither copied nor computed."""
+    monkeypatch.setattr(attention, "_CACHE_BLOCK", 256)   # 640: of 128
+    monkeypatch.setattr(attention, "_CACHE_ROWS", 512)
+    q, ck, cv, start = _cache_case(groups, s, where, b, dtype, d=128,
+                                   capacity=640)
+    kv_heads = ck.shape[1]
+    blocks = attention._cache_blocks(groups, s, 640, 128)
+    assert blocks == (rows, 128)
+    queries = q.reshape(b, kv_heads, groups * s, 128)
+    at = jnp.tile(jnp.broadcast_to(start + jnp.arange(s), (b, s)),
+                  (1, groups))
+    got = attention._attend_cache_pallas(
+        queries, ck, cv, at, jnp.int32(start + s), *blocks)
+    _assert_attends_the_filled_span(got.reshape(q.shape), q, ck, cv, start)
+    loop = attention._attend_cache_loop(queries, ck, cv, at,
+                                        jnp.int32(start + s))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(loop, np.float32),
+        atol=2e-5 if dtype == jnp.float32 else 2 ** -6)
+
+
+def test_attend_cache_is_one_program_whatever_the_cache_has_filled(
+        monkeypatch):
+    """The trip count is a value: two offsets, one trace, and a loop whose
+    bound is no constant of the program; every row's positions may be given
+    as one [s] vector."""
+    monkeypatch.setattr(attention, "_CACHE_BLOCK", 64)
+    traces = []
+
+    @jax.jit
+    def chunk(q, ck, cv, start):
+        traces.append(start)
+        return attend_cache(q, ck, cv, start,
+                            start + jnp.arange(q.shape[2]))
+
+    for where in ("start", "last"):
+        q, ck, cv, start = _cache_case(4, 32, where, 2, jnp.float32)
+        got = chunk(q, ck, cv, jnp.int32(start))
+        want = attention_reference(
+            q, ck[:, :, :start + 32], cv[:, :, :start + 32], q_offset=start)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert len(traces) == 1
+    text = chunk.lower(q, ck, cv, jnp.int32(0)).as_text()
+    assert "stablehlo.while" in text
+    # nothing of the capacity's length is built: not the logits, not K or
+    # V in float32 or repeated to the query heads
+    assert "x360xf32>" not in text and "x8x360x" not in text
 
 
 def test_ring_attention_matches_reference():

@@ -6,7 +6,12 @@ StableHLO text of each program hashes as it did at PR 59's tree (6307f01),
 recorded there by `program_texts` at these very sizes; `_ahead_counts()` and
 `stats()` report the keys they reported there; and every name the benchmark's
 harness reaches for on an engine is an attribute of the instance. CPU,
-float32, toy widths; nothing is compiled."""
+float32, toy widths; nothing is compiled.
+
+PR 61 recorded three hashes anew and no other: the `chunk_prefill` of the
+configurations whose chunk attends a dense cache (llama, falcon_h1,
+nemotron_h), which now goes through `ops.attention.attend_cache`; and gave
+those two kinds the counters `prefill_ctx_rows` and `prefill_cache_rows`."""
 
 import functools
 import os
@@ -116,7 +121,8 @@ def record(engine):
             "some": [name for name in SOME if hasattr(engine, name)]}
 
 
-# recorded at PR 59's tree (6307f01) by `record` over `CONFIGS`: the keys of
+# recorded at PR 59's tree (6307f01) by `record` over `CONFIGS` (PR 61: the
+# dense and the recurrent kinds' chunk and counters, see above): the keys of
 # `_ahead_counts()` every engine reports, those `stats()` adds to them on
 # every engine, and per configuration its programs' hashes, the keys its
 # kind adds to `_ahead_counts()`, the key `stats()` names its kernel by and
@@ -142,9 +148,9 @@ RECORDED = {'evabyte': {'ahead': ['pages_released', 'prefix_skipped_compressed',
                           'compress_window': 'bd8cbac8a25691c6',
                           'decode_step': '440d3530e50c21bf'},
              'some': ['_compress_window']},
- 'falcon_h1': {'ahead': [],
+ 'falcon_h1': {'ahead': ['prefill_cache_rows', 'prefill_ctx_rows'],
                'kernel': 'paged_kernel',
-               'programs': {'chunk_prefill': '1f2ec256bee68b38',
+               'programs': {'chunk_prefill': '1c33e8e8fc9a123a',
                             'decode_step': '34f2c4ce8d947c2f',
                             'write_state': '7c1c65117e4c0e0a'},
                'some': ['_write_state', '_by_kind', '_decode_caches',
@@ -167,14 +173,15 @@ RECORDED = {'evabyte': {'ahead': ['pages_released', 'prefix_skipped_compressed',
                        'write_state': '9cac13f65cdc3ba2'},
           'some': ['_write_state', '_by_kind', '_decode_caches',
                    '_chunk_caches']},
- 'llama': {'ahead': [],
+ 'llama': {'ahead': ['prefill_cache_rows', 'prefill_ctx_rows'],
            'kernel': 'paged_kernel',
-           'programs': {'chunk_prefill': 'c8272e1343f81aca',
+           'programs': {'chunk_prefill': 'd105d9660e33eed0',
                         'decode_step': 'd1875a319a18fe89'},
            'some': []},
- 'nemotron_h': {'ahead': ['prefill_chunks_sorted'],
+ 'nemotron_h': {'ahead': ['prefill_cache_rows', 'prefill_chunks_sorted',
+                          'prefill_ctx_rows'],
                 'kernel': 'paged_kernel',
-                'programs': {'chunk_prefill': 'd767bf9e0ae21180',
+                'programs': {'chunk_prefill': 'c5aa2227a41400e3',
                              'decode_step': '36d4f1ec9cc8d44b',
                              'write_state': 'e85cbd918151c7f2'},
                 'some': ['_write_state', '_by_kind', '_decode_caches',
@@ -266,6 +273,26 @@ def test_an_engine_is_of_the_one_kind_its_configuration_answers_to(name):
     assert type(engine) is kinds.ENGINES[engine.kind]
     assert isinstance(engine, PagedLLMEngine)
     assert (type(engine).__doc__ or "").strip()
+
+
+@pytest.mark.parametrize("name", ["llama", "falcon_h1", "nemotron_h"])
+def test_a_dense_cache_chunk_counts_what_it_attended_and_what_it_held(name):
+    """`prefill_ctx_rows`: a chunk's cached rows up to its last real token
+    (`off + take`, as the kinds that attend pages count it);
+    `prefill_cache_rows`: the row's whole dense cache a chunk, which is what
+    every chunk attended before `attend_cache`. Their ratio is the share of
+    that work that was real; both are on the `tick` row and in `stats()`."""
+    engine = CONFIGS[name][0]()
+    capacity = engine.config.max_len + engine.config.prefill_buckets[-1]
+    assert engine._staged_rows == capacity
+    assert jax.eval_shape(engine._dense_zero_caches) is not None
+    prompt = [5 + i % 90 for i in range(75)]     # chunks of 32, 32 and 11
+    assert engine.generate([prompt], max_new_tokens=2)[0]
+    stats = engine.stats()
+    assert stats["prefill_chunks"] == 3
+    assert stats["prefill_ctx_rows"] == 32 + 64 + 75
+    assert stats["prefill_cache_rows"] == 3 * capacity
+    assert engine._ahead_counts()["prefill_ctx_rows"] == 32 + 64 + 75
 
 
 def test_the_prefill_tick_calls_the_chunk_through_the_instance():

@@ -351,9 +351,11 @@ def test_a_dense_engine_owns_no_recurrent_state():
 # repeats the kv heads before it casts to float32 where this model's own
 # copy cast first. The same values; `chunk_prefill` holds no paged decode.
 # `decode_step` again in PR 43 (was 0d4903ff37ada98a): the step's own
-# `lax.cond` around the sampler went; `sample_tokens` holds the switch
+# `lax.cond` around the sampler went; `sample_tokens` holds the switch.
+# `chunk_prefill` again in PR 61 (was afb853d4dea3960f): the chunk attends
+# its dense cache through `ops.attention.attend_cache`
 FALCON_PROGRAMS = {"decode_step": "34f2c4ce8d947c2f",
-                   "chunk_prefill": "afb853d4dea3960f"}
+                   "chunk_prefill": "9336d76858fc601a"}
 
 
 @pytest.mark.parametrize("program", sorted(FALCON_PROGRAMS))

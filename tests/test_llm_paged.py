@@ -432,9 +432,11 @@ def program_hash(text: str) -> str:
 # `decode_step` again in PR 43 (was 618df5990d42e21b): `sample_tokens`, the
 # step's last call, is now a switch over three branches (argmax / draw /
 # sort and draw) where it was the third alone; `chunk_prefill` calls no
-# sampler and reads as before
+# sampler and reads as before. `chunk_prefill` again in PR 61 (was
+# b087c299668649d9): the chunk attends its dense cache through
+# `ops.attention.attend_cache`, here the loop over the filled blocks
 DENSE_PROGRAMS = {"decode_step": "315a3b48a436eb30",
-                  "chunk_prefill": "b087c299668649d9"}
+                  "chunk_prefill": "e469d5381fef3f10"}
 
 
 @pytest.mark.parametrize("program", sorted(DENSE_PROGRAMS))

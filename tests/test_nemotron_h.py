@@ -427,9 +427,11 @@ def test_a_shared_prefix_is_not_reused(engine):
 # contract for rows that keep summaries of closed windows changed no
 # program of a model that did not ask for it. `decode_step` again in PR 43
 # (was 6ef3901514180479): the step's own `lax.cond` around the sampler
-# went; `sample_tokens` holds the switch
+# went; `sample_tokens` holds the switch. `chunk_prefill` again in PR 61
+# (was daa7529285a45f19): the layer that attends does so through
+# `ops.attention.attend_cache`
 NEMOTRON_PROGRAMS = {"decode_step": "36d4f1ec9cc8d44b",
-                     "chunk_prefill": "daa7529285a45f19"}
+                     "chunk_prefill": "0d3835313f5f4811"}
 
 
 @pytest.mark.parametrize("program", sorted(NEMOTRON_PROGRAMS))
